@@ -28,14 +28,16 @@ def main():
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={args.stages}")
     import jax
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import triton_dist_tpu as tdt
     from triton_dist_tpu.layers.pp_comm import gpipe_forward
+    from triton_dist_tpu.utils.distributed import (enable_compile_cache,
+                                                   on_tpu)
+
+    enable_compile_cache()
 
     S, M = args.stages, args.microbatches
     mesh = tdt.make_mesh(pp=S, devices=jax.devices()[:S])
@@ -57,7 +59,7 @@ def main():
         out_specs=P(None, None, None), check_vma=False))
 
     np.asarray(f(w, x_mb))  # compile + warm
-    reps = 3 if os.environ.get("TDT_REAL_TPU") == "1" else 1
+    reps = 3 if on_tpu() else 1   # interpreter wall times: one is enough
     best = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
@@ -73,6 +75,7 @@ def main():
     print(json.dumps({
         "metric": "gpipe_step_seconds", "value": round(best, 6),
         "unit": "s", "vs_baseline": None,
+        "platform": jax.devices()[0].platform,
         "detail": {"stages": S, "microbatches": M, "impl": args.impl,
                    # backend cost_analysis scope varies; report both
                    # raw numbers rather than a ratio that mixes scopes.

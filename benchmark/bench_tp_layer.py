@@ -2,9 +2,9 @@
 / ``bench_tp_mlp.py`` analogue.
 
 Times the fused TP layer paths against the XLA-collective forms at a
-chosen shape, on whatever backend is attached (real chip: set
-TDT_REAL_TPU=1; otherwise the 8-device CPU mesh in interpret mode —
-useful for smoke-timing only). Prints one JSON line per measurement.
+chosen shape, on whatever backend JAX initialises (``JAX_PLATFORMS=cpu``
+selects the 8-device CPU mesh in interpret mode — a smoke of the
+plumbing, not a device timing). Prints one JSON line per measurement.
 
 Run: python benchmark/bench_tp_layer.py --layer mlp --m 2048
 """
@@ -55,13 +55,15 @@ def main():
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={args.tp}")
     import jax
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import triton_dist_tpu as tdt
     from triton_dist_tpu.models import ModelConfig, dense
+    from triton_dist_tpu.utils.distributed import (enable_compile_cache,
+                                                   on_tpu, platform)
+
+    enable_compile_cache()
 
     mesh = tdt.make_mesh(tp=args.tp, devices=jax.devices()[:args.tp])
     mctx = tdt.MeshContext.from_mesh(mesh)
@@ -107,8 +109,7 @@ def main():
                 out_specs=P("tp", None), check_vma=False))
     fns = {m: (lambda f=make(m): f(params, x)) for m in modes}
 
-    on_tpu = os.environ.get("TDT_REAL_TPU") == "1"
-    lo, hi, reps = (4, 16, args.reps or 3) if on_tpu else \
+    lo, hi, reps = (4, 16, args.reps or 3) if on_tpu() else \
         (1, 2, args.reps or 1)   # CPU interpret: smoke numbers only
     times = {m: _slope(fns[m], lo=lo, hi=hi, reps=reps) for m in modes}
     for m in modes:
@@ -117,6 +118,7 @@ def main():
             "value": round(times[m], 6), "unit": "s",
             "vs_baseline": (round(times["xla"] / max(times[m], 1e-12), 4)
                             if m != "xla" else 1.0),
+            "platform": platform(),
             "shape": {"m": args.m, "d": args.d, "ff": args.ff,
                       "tp": args.tp}}))
 

@@ -185,13 +185,14 @@ def main():
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={args.tp}")
     import jax
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     import triton_dist_tpu as tdt
     from triton_dist_tpu.models import Engine, ModelConfig, qwen_moe
     from triton_dist_tpu.serving import QueueFullError, ServingEngine
+    from triton_dist_tpu.utils.distributed import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax.numpy as jnp
 

@@ -5,8 +5,11 @@ Reference analogue: ``test_e2e_inference.py`` / the megakernel
 batch and reports per-token latency; add ``--megakernel`` to run every
 decode step as one persistent Pallas kernel per device.
 
-Run (CPU mesh): python examples/serve_dense.py
-Run (real TPUs): TDT_REAL_TPU=1 python examples/serve_dense.py --tp 8
+Runs on whatever backend JAX initialises; the tiny presets below are
+sized for the CPU interpreter.
+
+Run (CPU mesh): JAX_PLATFORMS=cpu python examples/serve_dense.py
+Run (TPU host): python examples/serve_dense.py --tp 4
 """
 
 import argparse
@@ -40,13 +43,15 @@ def main():
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + f" --xla_force_host_platform_device_count={args.tp}")
     import jax
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 
     import triton_dist_tpu as tdt
     from triton_dist_tpu.models import ModelConfig, Engine
+    from triton_dist_tpu.utils.distributed import (enable_compile_cache,
+                                                   platform)
+
+    enable_compile_cache()
 
     # vocab kept small so the megakernel arena stays under the CPU
     # interpret-mode per-buffer limit (docs/testing.md).
@@ -94,10 +99,9 @@ def main():
         dt = time.perf_counter() - t0
 
     print("generated tokens:\n", toks)
-    print(f"{toks.size} tokens in {dt:.2f}s "
+    print(f"{toks.size} tokens in {dt:.2f}s on platform={platform()} "
           f"({dt / max(toks.shape[1], 1) * 1e3:.1f} ms/step incl. "
-          "interpret overhead)" if os.environ.get("TDT_REAL_TPU") != "1"
-          else f"{dt / toks.shape[1] * 1e3:.2f} ms/step")
+          "compile)")
 
 
 if __name__ == "__main__":

@@ -8,7 +8,7 @@
 #     self-sim ring sweep at ring {2,4,8}, and the offline variant
 #     autotune round-trip (sweep -> persist -> cache hit);
 #  2. tests/test_fused_gemm.py -k ag_gemm (2D-mesh cases excluded:
-#     multi-axis meshes are an open compat-interpreter gap) — the
+#     multi-axis meshes are an open interpreter gap) — the
 #     kernel-level battery including the spy test that PROVES
 #     sim_ranks dispatches the real pipelined kernel;
 #  3. tests/test_schedule_math.py — the wide-K (K=4096) host-side
@@ -31,7 +31,7 @@ PY=${PY:-python}
 echo "== ag_gemm variant/parity battery (CPU mesh) =="
 $PY -m pytest tests/test_overlap.py -q -k "ag_gemm or choose_depth or stream_plan"
 
-echo "== ag_gemm kernel battery (2D-mesh compat gap excluded) =="
+echo "== ag_gemm kernel battery (2D-mesh interpreter gap excluded) =="
 $PY -m pytest tests/test_fused_gemm.py -q -k "ag_gemm and not 2d"
 
 echo "== wide-K schedule math (host-side, no device buffers) =="

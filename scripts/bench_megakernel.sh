@@ -7,7 +7,7 @@
 #     fairness sweep, and the skewed-cost idle-step comparison;
 #  2. an interpret-mode bench.py pass, asserting the record carries
 #     NON-NULL megakernel_decode_step_ms values for BOTH schedule modes
-#     (the BENCH_r05 regression: a CPU-only host emitted value: null).
+#     (a CPU-only host once emitted value: null).
 #
 # Sibling of scripts/bench_smoke.sh, wired as `make bench-megakernel`.
 set -euo pipefail

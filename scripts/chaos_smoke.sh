@@ -18,7 +18,7 @@
 #     uninterrupted run;
 #  4. a bench.py gate: detail.chaos_survived_faults non-null (the
 #     seeded soak inside the bench record completed with invariants
-#     intact) and detail.probe_attempts recorded.
+#     intact).
 #
 # Sibling of scripts/disagg_smoke.sh, wired as `make chaos-smoke`.
 set -euo pipefail
@@ -55,8 +55,8 @@ resumed=$(echo "$out" | grep '^\[restored ' | sed 's/^\[restored [^]]*\] //')
   || { echo "restored tokens diverged: '$resumed' != '$clean'"; exit 1; }
 echo "restored run token-exact: $resumed"
 
-echo "== bench gate: chaos_survived_faults + probe_attempts non-null =="
-timeout 600 $PY bench.py > /tmp/chaos_bench.json 2>/tmp/chaos_bench.err \
+echo "== bench gate: chaos_survived_faults non-null =="
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/chaos_bench.json 2>/tmp/chaos_bench.err \
   || { cat /tmp/chaos_bench.err; exit 1; }
 $PY - <<'EOF'
 import json
@@ -66,12 +66,9 @@ sf = d.get("chaos_survived_faults")
 assert sf is not None and sf >= 1, (
     f"chaos_survived_faults null: {sf!r} "
     f"(chaos_error={d.get('chaos_error')!r})")
-# 0 is legitimate: a cached cpu-only verdict skips the probe entirely.
-assert d.get("probe_attempts") is not None, "probe_attempts missing"
 print(f"chaos-smoke: ok (survived {sf} faults over "
       f"{d.get('chaos_ticks')} ticks, requests {d.get('chaos_requests')}, "
       f"retries={d.get('chaos_retries')} "
       f"failovers={d.get('chaos_failovers')} "
-      f"restored={d.get('chaos_restored_requests')}, "
-      f"probe_attempts={d.get('probe_attempts')})")
+      f"restored={d.get('chaos_restored_requests')})")
 EOF

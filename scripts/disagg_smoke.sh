@@ -42,7 +42,7 @@ echo "$out" | grep -Eq 'prefill_chunks=[1-9]' \
   || { echo "no chunked prefill ran"; exit 1; }
 
 echo "== bench gate: chunked-vs-monolithic prefill non-null, chunked >= monolithic =="
-timeout 600 $PY bench.py > /tmp/disagg_bench.json 2>/tmp/disagg_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/disagg_bench.json 2>/tmp/disagg_bench.err \
   || { cat /tmp/disagg_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

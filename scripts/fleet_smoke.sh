@@ -49,7 +49,7 @@ echo "$summary" | grep -q 'resumed=1' || {
   echo "expected resumed=1 (parked-tier handoff) in: $summary"; exit 1; }
 
 echo "== bench gate: fleet keys non-null =="
-timeout 600 $PY bench.py > /tmp/fleet_bench.json 2>/tmp/fleet_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/fleet_bench.json 2>/tmp/fleet_bench.err \
   || { cat /tmp/fleet_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

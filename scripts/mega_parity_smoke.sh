@@ -59,7 +59,7 @@ echo "$out" | grep -q 'mk: kv_dtype=int8 spec=2 checkpointable=yes' \
   || { echo "lane-capability line missing from the exit summary"; exit 1; }
 
 echo "== bench gate: megakernel parity keys non-null =="
-timeout 900 $PY bench.py > /tmp/mega_bench.json 2>/tmp/mega_bench.err \
+BENCH_BACKEND=cpu timeout 900 $PY bench.py > /tmp/mega_bench.json 2>/tmp/mega_bench.err \
   || { cat /tmp/mega_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

@@ -47,7 +47,7 @@ echo "$chunk" | grep -q 'chunked=\[8, 32\]' \
   || { echo "lane-capability line missing chunked=[8, 32]"; exit 1; }
 
 echo "== bench gate: mk chunked-prefill keys non-null, >= 2x =="
-timeout 900 $PY bench.py > /tmp/mkchunk_bench.json 2>/tmp/mkchunk_bench.err \
+BENCH_BACKEND=cpu timeout 900 $PY bench.py > /tmp/mkchunk_bench.json 2>/tmp/mkchunk_bench.err \
   || { cat /tmp/mkchunk_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

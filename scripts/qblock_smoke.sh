@@ -38,7 +38,7 @@ echo "$out" | grep -q 'attn=flash (chunk/verify flash)' \
   || { echo "exit summary missing attn=flash line"; exit 1; }
 
 echo "== bench gate: qblock keys non-null, flash <= ref =="
-timeout 600 $PY bench.py > /tmp/qblock_bench.json 2>/tmp/qblock_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/qblock_bench.json 2>/tmp/qblock_bench.err \
   || { cat /tmp/qblock_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

@@ -48,7 +48,7 @@ echo "$summary" | grep -q 'tenants=3' || {
   echo "expected tenants=3 (t0, t1, vip) in: $summary"; exit 1; }
 
 echo "== bench gate: slo keys non-null =="
-timeout 600 $PY bench.py > /tmp/slo_bench.json 2>/tmp/slo_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/slo_bench.json 2>/tmp/slo_bench.err \
   || { cat /tmp/slo_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

@@ -36,7 +36,7 @@ lines=$(echo "$out" | grep -c '^-> [0-9 ]*$' || true)
 [ "$lines" -eq 3 ] || { echo "expected 3 streamed replies, got $lines"; exit 1; }
 
 echo "== bench gate: spec + quant keys non-null =="
-timeout 600 $PY bench.py > /tmp/spec_bench.json 2>/tmp/spec_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/spec_bench.json 2>/tmp/spec_bench.err \
   || { cat /tmp/spec_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

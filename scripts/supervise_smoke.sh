@@ -78,7 +78,7 @@ print(f"crash/resume e2e token-exact: {oracle} "
 EOF
 
 echo "== bench gate: crash_recovery_ms + integrity_checks non-null =="
-timeout 600 $PY bench.py > /tmp/supervise_bench.json \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/supervise_bench.json \
     2>/tmp/supervise_bench.err \
   || { cat /tmp/supervise_bench.err; exit 1; }
 $PY - <<'EOF'

@@ -47,7 +47,7 @@ echo "$summary" | grep -q 'resumed=3' || {
   echo "expected 3 resumed sessions in: $summary"; exit 1; }
 
 echo "== bench gate: tier keys non-null =="
-timeout 600 $PY bench.py > /tmp/tier_bench.json 2>/tmp/tier_bench.err \
+BENCH_BACKEND=cpu timeout 600 $PY bench.py > /tmp/tier_bench.json 2>/tmp/tier_bench.err \
   || { cat /tmp/tier_bench.err; exit 1; }
 $PY - <<'EOF'
 import json

@@ -874,8 +874,7 @@ def test_megakernel_dynamic_token_exact_all_families(tp2_mesh):
 
 def test_dynamic_dropped_edge_terminates_or_raises(tp2_mesh):
     """Fault-injection gate: a dropped scoreboard edge under the
-    dynamic scheduler must terminate (the compat interpreter's
-    semaphores never block) or raise — never livelock. The Watchdog
+    dynamic scheduler must terminate or raise — never livelock. The Watchdog
     deadline converts a livelock into a hard failure."""
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
     from triton_dist_tpu.resilience import CommTimeoutError, faults

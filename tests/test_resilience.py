@@ -6,12 +6,6 @@ either bit-correct output (tolerated fault) or a structured
 ``CommTimeoutError`` carrying rank + op + progress (detected fault) —
 never a hang. Deadlock-prone plans run through the subprocess harness
 (``resilience.harness``), whose deadline is the no-hang guarantee.
-
-On the old generic discharge interpreter (``compat.degraded(
-"tpu_interpret_mode")``) semaphore waits do not block, so plans that
-deadlock the real protocol degrade to tolerated faults there; the
-assertions accept both verdicts of the contract, and the subprocess
-deadline still bounds every case.
 """
 
 import warnings
@@ -28,7 +22,6 @@ from triton_dist_tpu.ops.ag_gemm import (
 from triton_dist_tpu.resilience import (
     CommTimeoutError, InjectedFault, Watchdog, faults, harness, policy,
 )
-from triton_dist_tpu.utils import compat
 from triton_dist_tpu.utils.testing import assert_allclose, spmd
 
 # Bound for subprocess cases: covers jax import + trace in the child
@@ -114,11 +107,6 @@ def test_signal_faults_ag_gemm_terminate(plan):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    compat.degraded("tpu_interpret_mode"),
-    reason="megakernel needs the thread-per-device interpreter (the "
-           "discharge simulator rejects its dynamic-size DMA "
-           "transforms)")
 def test_dropped_edge_megakernel_terminates():
     """A suppressed scoreboard completion signal either leaves the
     merged queue's output intact (non-blocking backend) or wedges the

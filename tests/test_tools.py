@@ -115,6 +115,9 @@ def test_topology_slices_and_chip():
     assert mat[0][4] is None and mat[0][1] == 1
 
     assert T.detect_chip([_FakeDev(0, (0, 0), kind="TPU v5 lite")]) is V5E
+    # A TPU no row knows is an error, never another chip's peaks.
+    with pytest.raises(ValueError, match="TPU v9x"):
+        T.detect_chip([_FakeDev(0, (0, 0), kind="TPU v9x")])
     s = T.summary(devs)
     assert s["num_devices"] == 8 and s["torus_dims"] == [4, 1]
 

@@ -25,13 +25,6 @@ ByteDance-Seed/Triton-distributed (see SURVEY.md at the repo root):
 
 __version__ = "0.1.0"
 
-# JAX-version compat shims must install before any submodule touches the
-# aliased APIs (pallas_helpers evaluates pltpu.CompilerParams at def
-# time). Additive-only: a no-op on current JAX.
-from triton_dist_tpu.utils import compat as _compat  # noqa: E402
-
-_compat.install()
-
 from triton_dist_tpu.parallel.mesh import MeshContext, make_mesh  # noqa: F401
 from triton_dist_tpu.utils.distributed import (  # noqa: F401
     dist_print,

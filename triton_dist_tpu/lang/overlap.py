@@ -42,7 +42,7 @@ module is the one place the three reusable pieces live:
     staged locally and each chunk rides ONE remote put + ONE semaphore
     signal — never per-tile signals.
 
-Interpret-mesh rule (see ``utils/compat.py``): remote puts must be
+Interpret-mesh rule: remote puts must be
 rank-CONVERGENT — the same put sites in the same order on every rank.
 Swizzle modes therefore only reorder *waits and compute*; the put
 schedule of an op never depends on the mode (the "identity" mode of a

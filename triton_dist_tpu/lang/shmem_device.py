@@ -40,15 +40,6 @@ SIGNAL_SET = "set"   # reference: SignalOp::SET (DistributedAttrDefs.td:36)
 SIGNAL_ADD = "add"   # reference: SignalOp::ADD
 
 
-def _barriers_vacuous() -> bool:
-    """True when kernel-entry barriers have no meaning (and no
-    implementation): the old generic discharge interpreter runs the
-    mesh bulk-synchronously and has no rule for
-    ``get_barrier_semaphore`` — see ``utils/compat.py``."""
-    from triton_dist_tpu.utils import compat
-
-    return compat.degraded_interpret()
-
 # The full public surface (tests/test_shmem.py asserts this covers the
 # reference's ~80-name libshmem_device API one-to-one).
 __all__ = [
@@ -351,29 +342,6 @@ def broadcastmem(dst_ref, src_ref, root: int, send_sem, recv_sem, *,
     full barrier over ``axis`` in this kernel."""
     me = rank(axis)
     n = num_ranks(axis)
-    if _barriers_vacuous():
-        # Generic discharge interpreter: the root-only put below is a
-        # rank-DIVERGENT site, and divergent sites deadlock the hidden
-        # collectives that interpreter resolves remote DMA with. Use a
-        # uniform ring relay instead: every rank forwards its dst right
-        # each step, with the root re-seeding its dst from src first
-        # (the incoming left-neighbour value would otherwise erase the
-        # payload and the relay would carry a single moving wave instead
-        # of a growing prefix). After n-1 steps every rank holds the
-        # root's payload. Semantics are bulk-synchronous there (every
-        # DMA site is a barrier), so no waits.
-        right = jax.lax.rem(me + 1, n)
-        for _step in range(n - 1):
-            @pl.when(me == root)
-            def _():
-                pltpu.sync_copy(src_ref, dst_ref)
-            remote_put(dst_ref, dst_ref, send_sem, recv_sem, right,
-                       axis=axis, ctx=ctx)
-
-        @pl.when(me == root)
-        def _():
-            pltpu.sync_copy(src_ref, dst_ref)
-        return
     if barrier:
         barrier_all(axis, ctx=ctx)
 
@@ -612,8 +580,6 @@ def barrier_all(axis: str, *, ctx=None):
     ``barrier_all_intra_node_*`` kernels (``kernels/nvidia/common_ops.py``).
     Requires ``collective_id`` in the kernel's CompilerParams.
     """
-    if _barriers_vacuous():
-        return
     n = num_ranks(axis)
     inc = _skewed_barrier_inc(axis)
     sem = pltpu.get_barrier_semaphore()
@@ -649,8 +615,6 @@ def barrier_tile(axis: str, *, ctx=None, sem=None):
     whatever kernel this device is still running.
     """
     if sem is None:
-        if _barriers_vacuous():
-            return
         sem = pltpu.get_barrier_semaphore()
     n = num_ranks(axis)
     me = rank(axis)
@@ -673,8 +637,6 @@ def barrier(team):
     makes it the same operation as :func:`sync_all` scoped to a team
     (the delta :func:`quiet` documents).
     """
-    if _barriers_vacuous():
-        return
     sem = pltpu.get_barrier_semaphore()
     n = team.n_pes()
     for pe in range(n):

@@ -174,18 +174,29 @@ def sdpa(q, k, v, *, causal: bool, kv_len=None, use_flash=None):
         rep = h // kvh
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
+    # The bundled kernel wants both lengths in whole 128-blocks. Causal
+    # self-attention (prefill) pads up to one: keys padded at the END lie
+    # in every real query's future, and the padded query rows are cut.
+    blk = 128
+    self_causal = causal and sq == skv
     if use_flash is None:
         from triton_dist_tpu.utils.distributed import on_tpu, use_interpret
         use_flash = (on_tpu() and not use_interpret() and kv_len is None
-                     and sq >= 128 and skv >= 128 and hd >= 64)
+                     and sq >= blk and skv >= blk and hd >= 64
+                     and (self_causal or (sq % blk == 0
+                                          and skv % blk == 0)))
     if use_flash:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention)
+        pad = -sq % blk if self_causal else 0
+        if pad:
+            q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                       for t in (q, k, v))
         o = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal,
             sm_scale=1.0 / float(np_sqrt(hd)))
-        return o.transpose(0, 2, 1, 3)
+        return o.transpose(0, 2, 1, 3)[:, :sq]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))

@@ -1217,7 +1217,7 @@ class ModelBuilder:
         else:
             slot = q * C + c
         ttype = types_s[slot]
-        args = tuple(args_s[slot, j] for j in range(ARGS_MAX))
+        args = tuple(args_s[j, slot] for j in range(ARGS_MAX))
         refs = {"arena": arena, "k_cache": k_cache, "v_cache": v_cache,
                 "va": va, "vb": vb, "vc": vc, "vw": vw, "acc": acc,
                 "vhd": vhd, "vkt": vkt, "vsq": vsq, "send_sem": send_sem,
@@ -1243,7 +1243,7 @@ class ModelBuilder:
         # Scoreboard waits: block until every cross-core predecessor's
         # edge semaphore has been signalled (reference
         # scoreboard_wait_deps).
-        wstart, wcount = wait_tab_s[slot, 0], wait_tab_s[slot, 1]
+        wstart, wcount = wait_tab_s[0, slot], wait_tab_s[1, slot]
 
         def wait_step(k, _):
             pltpu.semaphore_wait(edge_sem.at[wait_edges_s[wstart + k]], 1)
@@ -1292,7 +1292,7 @@ class ModelBuilder:
         # targeted at the consumer core — sig_cores in the schedule
         # carries that mapping — but no execution environment available
         # here runs that variant, so the kernel does not consume it.)
-        sstart, scount = sig_tab_s[slot, 0], sig_tab_s[slot, 1]
+        sstart, scount = sig_tab_s[0, slot], sig_tab_s[1, slot]
 
         # Fault hook: a drop_edge plan suppresses one edge's completion
         # signal — the canonical scoreboard failure (a consumer's wait
@@ -1341,12 +1341,16 @@ class ModelBuilder:
         cfg = self.cfg
         # Slot tables are prefetched FLAT (slot-major): static slots
         # index them at q*C + c, dynamic slots at the claim-counter
-        # value — one kernel, two binding rules.
+        # value — one kernel, two binding rules. The slot index is the
+        # MINOR dim of the 2-D tables: SMEM pads the minor dim to 128
+        # words, so (n_slots, 8) costs n_slots*512 B where (8, n_slots)
+        # costs n_slots*32 B — at model widths the slot-major form
+        # overflows the 1 MB of SMEM with one layer.
         n_slots = self.qlen * self.num_cores
         types = jnp.asarray(self.task_types).reshape(n_slots)
-        args = jnp.asarray(self.task_args).reshape(n_slots, ARGS_MAX)
-        wait_tab = jnp.asarray(self.wait_tab).reshape(n_slots, 2)
-        sig_tab = jnp.asarray(self.sig_tab).reshape(n_slots, 2)
+        args = jnp.asarray(self.task_args).reshape(n_slots, ARGS_MAX).T
+        wait_tab = jnp.asarray(self.wait_tab).reshape(n_slots, 2).T
+        sig_tab = jnp.asarray(self.sig_tab).reshape(n_slots, 2).T
         wait_edges = jnp.asarray(self.wait_edges)
         sig_edges = jnp.asarray(self.sig_edges)
         bucket = jnp.asarray(self.claim_bucket).reshape(-1)

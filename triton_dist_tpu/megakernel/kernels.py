@@ -726,9 +726,7 @@ def allreduce_body(cfg, args, refs):
     # args[1] (tiles) is a traced prefetch read, but every ALLREDUCE the
     # builder records moves exactly ``ar_max_tiles`` tiles — use the
     # static value so the slab slice has a static SIZE (Mosaic needs
-    # one, and the jax-0.4.x discharge interpreter rejects traced
-    # dynamic-slice shapes — the one blocker that kept the whole
-    # megakernel family off the CPU compat backend).
+    # one).
     buf_off, tiles = args[0], cfg.ar_max_tiles
     b, n = cfg.batch, cfg.n_ranks
     if n == 1:

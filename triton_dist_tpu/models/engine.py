@@ -171,15 +171,32 @@ class Engine:
         self.model_kwargs = model_kwargs
         self.ep_transport = (model_kwargs.get("transport")
                              if moe_impl == "ep" else None)
-        if params is None:
-            params = model.init_params(jax.random.PRNGKey(seed), cfg, dtype)
-        self.params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            params, specs, is_leaf=lambda x: isinstance(x, jax.Array)
-            or isinstance(x, np.ndarray))
         self._specs = specs
+        if params is None:
+            self.params = self.sharded_init()(
+                jax.random.PRNGKey(seed), cfg, dtype)
+        else:
+            self.params = jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                params, specs, is_leaf=lambda x: isinstance(x, jax.Array)
+                or isinstance(x, np.ndarray))
 
         self._prefill, self._decode = self._build(mode)
+
+    def sharded_init(self):
+        """``model.init_params(key, cfg, dtype)`` jitted with
+        ``param_specs`` as its output sharding: every device generates
+        only its own shard of each weight (threefry is partitionable),
+        so no leaf ever exists unsharded — at 8B an unsharded init is
+        16 GB on device 0 before the first ``device_put``. Values equal
+        the eager ``model.init_params`` for the same key. ``cfg`` and
+        ``dtype`` are static, so engines of one configuration share one
+        traced and compiled initialiser."""
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), self._specs,
+            is_leaf=lambda s: isinstance(s, P))
+        return jax.jit(self.model.init_params, static_argnums=(1, 2),
+                       out_shardings=shardings)
 
     def _build(self, mode):
         """Jit the prefill/decode dispatches for ``mode`` (called once

@@ -7,8 +7,8 @@ that file for the spans the serving timeline wants to correlate:
 
 - **marker-keyed spans** — events whose name carries a
   :func:`~triton_dist_tpu.profiler.trace_scalar` label
-  (``pltpu.trace_value`` markers; VERDICT task 7's documented
-  alternative to an in-kernel clock). On jax 0.4.x the marker label
+  (``pltpu.trace_value`` markers; the documented alternative to
+  an in-kernel clock). On jax 0.4.x the marker label
   appears verbatim in the event name, so a substring match keys them.
 - optionally the longest raw XLA op spans (``top_ops``) — useful
   context when no markers were compiled in (e.g. a CPU interpret run,

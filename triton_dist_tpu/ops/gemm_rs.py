@@ -84,10 +84,15 @@ def gemm_rs_ref(a, b, *, axis: str = "tp", **_):
 
 def _rs_blocks(ctx: GemmRSContext, m_loc, n_dim, k_loc):
     """Shared tile-size clamp + divisibility check for both gemm_rs
-    kernel paths."""
+    kernel paths. tm snaps down to a divisor of the ragged local M
+    (``ag_gemm``'s policy — the two ops share one token count in a TP
+    layer, so a prompt length ``ag_gemm`` accepts must not be refused
+    here)."""
     tm = min(ctx.block_m, m_loc)
     tn = min(ctx.block_n, n_dim)
     tk = min(ctx.block_k, k_loc)
+    while tm > 1 and m_loc % tm:
+        tm //= 2
     if m_loc % tm or n_dim % tn or k_loc % tk:
         raise ValueError(
             f"block sizes (block_m={tm}, block_n={tn}, block_k={tk}) must "

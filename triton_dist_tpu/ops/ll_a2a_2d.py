@@ -90,12 +90,11 @@ def hop_put_counts(ctx: MeshContext, *, outer_axis: str = "dcn",
 
 def _resolve_impl(ctx: MeshContext, impl: str) -> str:
     """``impl="kernel"`` degrades to the numerically-identical
-    ``"xla"`` wire path when the Pallas route cannot run: the
-    interpret-mode discharge rules route remote DMA over THE one
-    non-trivial mesh axis (``utils/compat._shard_axis_of``), so a mesh
-    where two axes are real (the genuine 2D case on the CPU battery)
-    has no legal kernel hop. On hardware — or on a degenerate 1×n /
-    n×1 hierarchy under interpret — the kernel path stands."""
+    ``"xla"`` wire path when the Pallas route cannot run: under
+    interpret mode a mesh where two axes are real (the genuine 2D case
+    on the CPU battery) has no kernel hop that is known to run. On
+    hardware — or on a degenerate 1×n / n×1 hierarchy under interpret
+    — the kernel path stands."""
     if impl != "kernel":
         return impl
     from triton_dist_tpu.utils.distributed import use_interpret

@@ -185,8 +185,8 @@ def fast_allgather(x, *, ctx: MeshContext, axis: str = "tp",
 # Scale-column width on the wire: HBM slices on hardware must align to
 # the 128-lane tiling, interpret mode keeps width 1 (its buffers starve
 # past ~64 KB and it has no tiling constraint). Tests override this to
-# exercise the HARDWARE layout under interpret (VERDICT r4 weak #3 —
-# the divergence point must not be CPU-untestable).
+# exercise the HARDWARE layout under interpret (the divergence point
+# must not be CPU-untestable).
 _SCALE_WIDTH_OVERRIDE = None
 
 

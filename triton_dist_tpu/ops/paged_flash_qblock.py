@@ -80,12 +80,16 @@ def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
 
 def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
                    hd: int, cq: int, quantized: bool):
-    """Grid (B, P_max): slot-major page walk with the decode kernel's
-    double-buffered prefetch (per-parity semaphores); pages past a
-    slot's maximum attended position (``end_ref``) are skipped. No
-    partial exchange — this is the LOCAL (axis=None) form, the layout
-    the serving engine's TP-head-sharded pools use (every rank holds
-    the full sequence for its heads)."""
+    """Grid (B, KV, P_max): slot-major, then one KV head (and its
+    ``rep`` query heads) at a time, then that head's page walk with the
+    decode kernel's double-buffered prefetch (per-parity semaphores);
+    pages past a slot's maximum attended position (``end_ref``) are
+    skipped. One KV head per step keeps the VMEM working set at
+    (rep, Cq, ...) instead of (H, Cq, ...) — at H=32, Cq=512 the
+    all-heads form needs 30 MB of scoped VMEM, which Mosaic refuses
+    against its 16 MB limit. No partial exchange — this is the LOCAL
+    (axis=None) form, the layout the serving engine's TP-head-sharded
+    pools use (every rank holds the full sequence for its heads)."""
     ks_ref = vs_ref = None
     if quantized:
         (table_ref, end_ref, pos_ref, q_ref, kp_ref, vp_ref, ks_ref,
@@ -98,9 +102,9 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     kpage, vpage, m_s, l_s, acc_s, psem = scratch
 
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    g = pl.program_id(1)
+    p = pl.program_id(2)
     n_b = pl.num_programs(0)
-    h = kvh * rep
 
     # Page p of slot b lives at pool slot table[b, p]; pages past the
     # slot's maximum attended position carry no unmasked key for ANY
@@ -108,19 +112,19 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     # with resident pages, not capacity).
     end = jnp.clip(end_ref[b], 1, p_max * page)
     active = p * page < end
-    lin = b * p_max + p
+    lin = (b * kvh + g) * p_max + p
     par = jax.lax.rem(lin, 2)
 
-    def load(b2, p2, buf):
+    def load(b2, g2, p2, buf):
         pid = table_ref[b2, p2]
-        pltpu.make_async_copy(kp_ref.at[pid], kpage.at[buf],
+        pltpu.make_async_copy(kp_ref.at[pid, pl.ds(g2, 1)], kpage.at[buf],
                               psem.at[buf]).start()
-        pltpu.make_async_copy(vp_ref.at[pid], vpage.at[buf],
+        pltpu.make_async_copy(vp_ref.at[pid, pl.ds(g2, 1)], vpage.at[buf],
                               psem.at[buf]).start()
 
     @pl.when(jnp.logical_and(active, lin == 0))
     def _():
-        load(b, p, 0)        # cold start; later pages are prefetched
+        load(b, g, p, 0)     # cold start; later pages are prefetched
 
     @pl.when(active)
     def _():
@@ -131,35 +135,40 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
         pltpu.make_async_copy(vpage.at[par], vpage.at[par],
                               psem.at[par]).wait()
 
-    # Prefetch the next block's page while this one computes.
+    # Prefetch the next step's page while this one computes.
     nxt = lin + 1
-    b2 = jnp.minimum(nxt // p_max, n_b - 1)
+    b2 = jnp.minimum(nxt // (kvh * p_max), n_b - 1)
+    g2 = jax.lax.rem(nxt // p_max, kvh)
     p2 = jax.lax.rem(nxt, p_max)
     end2 = jnp.clip(end_ref[b2], 1, p_max * page)
-    active2 = jnp.logical_and(nxt < n_b * p_max, p2 * page < end2)
+    active2 = jnp.logical_and(nxt < n_b * kvh * p_max, p2 * page < end2)
 
     @pl.when(active2)
     def _():
-        load(b2, p2, jax.lax.rem(nxt, 2))
+        load(b2, g2, p2, jax.lax.rem(nxt, 2))
 
     @pl.when(p == 0)
     def _():
-        m_s[...] = jnp.full((h, cq), -jnp.inf, jnp.float32)
-        l_s[...] = jnp.zeros((h, cq), jnp.float32)
+        m_s[...] = jnp.full((rep, cq), -jnp.inf, jnp.float32)
+        l_s[...] = jnp.zeros((rep, cq), jnp.float32)
         acc_s[...] = jnp.zeros_like(acc_s)
 
     @pl.when(active)
     def _():
-        q2 = q_ref[0].astype(jnp.float32)                # (H, Cq, hd)
+        q2 = q_ref[0].astype(jnp.float32)                # (rep, Cq, hd)
         key_pos = p * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, page), 1)
-        mask = key_pos <= pos_ref[...]       # (Cq, 1) -> (Cq, page)
+        mask = key_pos <= pos_ref[0]         # (Cq, 1) -> (Cq, page)
         ksc = vsc = None
         if quantized:
-            # Per-page per-head dequant scales, gathered host-side
-            # through the block table — the fused-dequant hook.
-            ksc = ks_ref[b, p]
-            vsc = vs_ref[b, p]
+            # This head's per-page dequant scale, picked out of the
+            # (1, KV) row of the host-side block-table gather — the
+            # fused-dequant hook.
+            head = jax.lax.broadcasted_iota(jnp.int32, (1, kvh), 1) == g
+            ksc = jnp.sum(jnp.where(head, ks_ref[b, pl.ds(p, 1)], 0.0),
+                          axis=1)
+            vsc = jnp.sum(jnp.where(head, vs_ref[b, pl.ds(p, 1)], 0.0),
+                          axis=1)
         m, l, acc = qblock_page_attend(
             q2, kpage[par], vpage[par], m_s[...], l_s[...], acc_s[...],
             mask, rep, kscale=ksc, vscale=vsc)
@@ -167,12 +176,31 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
         l_s[...] = l
         acc_s[...] = acc
 
-    # The slot's last page step: normalize and emit. Page 0 is always
+    # The head's last page step: normalize and emit. Page 0 is always
     # active (end >= 1), so l has at least one key's mass per query.
     @pl.when(p == p_max - 1)
     def _():
         out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)[..., None]
         o_ref[...] = out[None].astype(o_ref.dtype)
+
+
+# Scoped-VMEM budget the Q-block working set is sized against (Mosaic's
+# default limit on a v5e is 16 MiB; the rest is headroom for the
+# compiler's own temporaries).
+QBLOCK_VMEM_BUDGET = 10 * 1024 * 1024
+
+
+def qblock_rows(cq: int, rep: int, hd: int, page: int, itemsize: int,
+                budget: int = QBLOCK_VMEM_BUDGET) -> int:
+    """Queries per kernel block: ``cq`` halved until one KV head's
+    working set fits ``budget`` — per query row the f32 accumulator,
+    the double-buffered q and out blocks, and the score/probability
+    tiles. Pure host arithmetic."""
+    per_row = rep * (hd * 4 + 4 * hd * itemsize + 2 * page * 4)
+    bq = cq
+    while bq * per_row > budget and bq % 2 == 0:
+        bq //= 2
+    return bq
 
 
 def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
@@ -218,32 +246,43 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
                 f"exceeds one block-table row's capacity {cap} "
                 f"({p_max} pages x {page}); the query asks for a key "
                 "its table row cannot hold")
+    # A Q-block too large for VMEM splits into row blocks that ride the
+    # grid as extra slots sharing their slot's table row. Each gets its
+    # own page-skip bound, so an early block of a causal chunk also
+    # stops at its own last position.
+    bq = qblock_rows(cq, rep, hd, page, q.dtype.itemsize)
+    nq = cq // bq
+    if nq > 1:
+        q = q.reshape(b * nq, bq, h, hd)
+        positions = positions.reshape(b * nq, bq)
+        block_table = jnp.repeat(block_table, nq, axis=0)
+    nb = b * nq
     # Max attended position + 1 per slot — the kernel's page-skip bound.
     end = jnp.max(positions, axis=1) + 1
-    q_hm = q.transpose(0, 2, 1, 3)              # (B, H, Cq, hd)
-    pos_t = positions.T                         # (Cq, B)
+    q_hm = q.transpose(0, 2, 1, 3)              # (nb, H, bq, hd)
 
     kernel = functools.partial(
         _qblock_kernel, page=page, p_max=p_max, kvh=kvh, rep=rep,
-        hd=hd, cq=cq, quantized=quantized)
+        hd=hd, cq=bq, quantized=quantized)
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),          # block_table
         pl.BlockSpec(memory_space=pltpu.SMEM),          # end
-        pl.BlockSpec((cq, 1), lambda bb, pp: (0, bb),
-                     memory_space=pltpu.VMEM),          # positions.T
-        pl.BlockSpec((1, h, cq, hd), lambda bb, pp: (bb, 0, 0, 0),
-                     memory_space=pltpu.VMEM),          # q (one slot)
+        pl.BlockSpec((1, bq, 1), lambda bb, gg, pp: (bb, 0, 0),
+                     memory_space=pltpu.VMEM),          # positions
+        pl.BlockSpec((1, rep, bq, hd), lambda bb, gg, pp: (bb, gg, 0, 0),
+                     memory_space=pltpu.VMEM),          # q (one KV group)
         pl.BlockSpec(memory_space=pl.ANY),              # k pool
         pl.BlockSpec(memory_space=pl.ANY),              # v pool
     ]
     operands = [block_table.astype(jnp.int32), end.astype(jnp.int32),
-                pos_t, q_hm, k_pages, v_pages]
+                positions[..., None], q_hm, k_pages, v_pages]
     if quantized:
         # Scales enter PRE-GATHERED through the block table as small
         # (B, P_max, KV) fp32 tables resident in VMEM (the decode
         # kernel's fused-dequant plumbing).
-        sc_spec = pl.BlockSpec((b, p_max, kvh), lambda bb, pp: (0, 0, 0),
+        sc_spec = pl.BlockSpec((nb, p_max, kvh),
+                               lambda bb, gg, pp: (0, 0, 0),
                                memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale[block_table].astype(jnp.float32),
@@ -251,28 +290,28 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
 
     out = core_call(
         kernel,
-        grid=(b, p_max),
-        out_shape=jax.ShapeDtypeStruct((b, h, cq, hd), q.dtype),
+        grid=(nb, kvh, p_max),
+        out_shape=jax.ShapeDtypeStruct((nb, h, bq, hd), q.dtype),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, cq, hd),
-                               lambda bb, pp: (bb, 0, 0, 0),
+        out_specs=pl.BlockSpec((1, rep, bq, hd),
+                               lambda bb, gg, pp: (bb, gg, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, page, hd), k_pages.dtype),  # kpage x2
-            pltpu.VMEM((2, kvh, page, hd), v_pages.dtype),  # vpage x2
-            pltpu.VMEM((h, cq), jnp.float32),               # m
-            pltpu.VMEM((h, cq), jnp.float32),               # l
-            pltpu.VMEM((h, cq, hd), jnp.float32),           # acc
+            pltpu.VMEM((2, 1, page, hd), k_pages.dtype),    # kpage x2
+            pltpu.VMEM((2, 1, page, hd), v_pages.dtype),    # vpage x2
+            pltpu.VMEM((rep, bq), jnp.float32),             # m
+            pltpu.VMEM((rep, bq), jnp.float32),             # l
+            pltpu.VMEM((rep, bq, hd), jnp.float32),         # acc
             pltpu.SemaphoreType.DMA((2,)),                  # page loads
         ],
         cost_estimate=pl.CostEstimate(
             flops=4 * b * cq * h * hd * p_max * page,
-            bytes_accessed=2 * b * p_max * page * kvh * hd
+            bytes_accessed=2 * nb * p_max * page * kvh * hd
             * k_pages.dtype.itemsize,
             transcendentals=b * cq * h * p_max * page,
         ),
     )(*operands)
-    return out.transpose(0, 2, 1, 3)            # (B, Cq, H, hd)
+    return out.transpose(0, 2, 1, 3).reshape(b, cq, h, hd)
 
 
 def paged_flash_qblock_ref(q, k_pages, v_pages, block_table, positions,

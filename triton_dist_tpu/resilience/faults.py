@@ -27,8 +27,7 @@ Fault kinds (``Fault.kind``):
   ``rank`` before issuing the ``k``-th remote put of ``op`` (``k=None``
   = every put). Plans may also set ``dma_on_wait=True`` to flip the
   interpreter's DMA completion to the maximally-late schedule
-  (``InterpretParams(dma_execution_mode="on_wait")`` — newer-JAX
-  thread-per-device interpreter only).
+  (``InterpretParams(dma_execution_mode="on_wait")``).
 - ``"drop_put"``   — the ``k``-th remote put of ``op`` is never issued
   on ``rank``: no data, no send/recv semaphore counts.
 - ``"dup_put"``    — the ``k``-th remote put of ``op`` is issued twice
@@ -36,8 +35,7 @@ Fault kinds (``Fault.kind``):
 - ``"drop_signal"``/``"dup_signal"`` — a ``dl.notify`` increment from
   ``rank`` is dropped / doubled.
 - ``"skew_barrier"`` — ``rank`` spins ``iters`` iterations before its
-  entry-barrier arrival (vacuous under the bulk-synchronous discharge
-  interpreter, where barriers are no-ops — see ``utils/compat.py``).
+  entry-barrier arrival.
 - ``"drop_edge"``  — the megakernel scoreboard signal for edge index
   ``k`` is never raised (every rank; the merged queue is SPMD). Unlike
   the put/call kinds, ``k=None`` here selects edge 0, not "all edges"
@@ -258,19 +256,11 @@ def put_fault() -> Optional[Fault]:
         return None
     idx = st.put_counts.get(op, 0)
     st.put_counts[op] = idx + 1
-    kinds = ("delay_dma",) if _divergent_flow_unsupported() else (
-        "drop_put", "dup_put", "delay_dma")
-    for kind in kinds:
+    for kind in ("drop_put", "dup_put", "delay_dma"):
         for f in plan.faults_of(kind, op):
             if f.k is None or f.k == idx:
                 return f
     return None
-
-
-def _divergent_flow_unsupported() -> bool:
-    from triton_dist_tpu.utils import compat
-
-    return compat.degraded_interpret()
 
 
 def signal_fault() -> Optional[Fault]:

@@ -4,9 +4,6 @@ Every fused op in this package has a semantically-equivalent XLA
 collective form (the ``mode="xla"`` oracles). This module decides —
 per op, automatically, logged once — when to take it:
 
-- the platform cannot express the fused op at all (e.g. the old
-  generic discharge interpreter cannot run rank-divergent one-sided
-  puts — see ``utils/compat.py``);
 - a fused dispatch raised at runtime (recorded via
   :func:`note_failure`; subsequent calls re-route);
 - the operator forced it (``TRITON_DIST_TPU_FORCE_XLA="ag_gemm,p2p"``
@@ -158,15 +155,6 @@ class RetryPolicy:
         """:meth:`call` without the attempt count."""
         return self.call(fn, **kw)[0]
 
-# Fused ops whose signal protocol is rank-divergent (one-sided puts
-# issued under a rank-dependent predicate — ``me == root``, causal
-# ``peer < n`` send pruning): inexpressible on the old bulk-synchronous
-# discharge interpreter, which resolves remote DMA through uniform
-# hidden collectives — a divergent site deadlocks the CPU mesh instead
-# of failing. Routed to XLA up front.
-DIVERGENT_PUT_OPS = frozenset(
-    {"p2p", "ulysses_fused", "broadcast", "sp_ag_attention"})
-
 
 class FallbackPolicy:
     """Per-op fused-vs-XLA dispatch decisions with log-once semantics."""
@@ -182,22 +170,10 @@ class FallbackPolicy:
         raw = os.environ.get("TRITON_DIST_TPU_FORCE_XLA", "")
         return frozenset(s.strip() for s in raw.split(",") if s.strip())
 
-    def platform_unsupported(self, op: str) -> Optional[str]:
-        from triton_dist_tpu.utils import compat
-
-        if op in DIVERGENT_PUT_OPS and compat.degraded_interpret():
-            return ("rank-divergent one-sided puts are inexpressible on "
-                    "the generic discharge interpreter")
-        return None
-
     def should_fallback(self, op: str) -> bool:
         forced = self.forced_ops()
         if "*" in forced or op in forced:
             self._log_once(op, "forced via TRITON_DIST_TPU_FORCE_XLA")
-            return True
-        reason = self.platform_unsupported(op)
-        if reason is not None:
-            self._log_once(op, reason)
             return True
         with self._lock:
             if op in self._failed:

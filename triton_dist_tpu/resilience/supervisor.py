@@ -56,6 +56,16 @@ Usage::
 ``run_supervised_soak`` in :mod:`~triton_dist_tpu.resilience.chaos`
 drives this through a seeded SIGKILL/stall/corruption schedule and
 gates every finished stream against an in-process oracle.
+
+Who owns the device: a chip belongs to one process at a time, so the
+parent must never initialise a JAX backend — and it does not. It
+imports the package (which imports ``jax``) but only handles JSON lines,
+files and the numpy contents of a snapshot; the engine, and with it the
+backend, exists in the child alone, and a respawn starts only after the
+previous child is gone. What is NOT ready for a chip is the child's
+environment: ``harness._child_env`` is the fault battery's and pins
+``JAX_PLATFORMS=cpu``, so a supervised engine runs on the CPU mesh
+wherever it is started (ROADMAP D9).
 """
 
 from __future__ import annotations

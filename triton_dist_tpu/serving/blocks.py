@@ -205,6 +205,23 @@ class PagedKVCache:
             k_scale=scale, v_scale=(None if scale is None
                                     else jnp.ones_like(scale)))
 
+    @classmethod
+    def empty_sharded(cls, mesh, spec_fn, axis: str, *shape_args,
+                      kv_dtype: str = "bf16", **kw):
+        """:meth:`empty` allocated directly under its target sharding:
+        every device zero-fills only its own KV-head shard of the pool
+        (an unsharded pool at a realistic size is gigabytes on device 0
+        before the first ``device_put``). ``spec_fn`` is the model's
+        ``paged_cache_specs``; ``shape_args`` are GLOBAL shapes (all KV
+        heads). Returns ``(cache, shardings)`` — the pool and the pinned
+        :func:`pool_shardings` every writer into it must emit."""
+        quantized = kv_quant_spec(kv_dtype)[0] is not None
+        shardings = pool_shardings(mesh, spec_fn(axis, quantized=quantized))
+        cache = jax.jit(
+            lambda: cls.empty(*shape_args, kv_dtype=kv_dtype, **kw),
+            out_shardings=shardings)()
+        return cache, shardings
+
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None

@@ -59,7 +59,6 @@ import numpy as np
 
 from triton_dist_tpu.serving.blocks import (
     SCRATCH_PAGE, BlockManager, OutOfPagesError, PagedKVCache,
-    pool_shardings,
 )
 from triton_dist_tpu.serving.chunked import DEFAULT_BUCKETS, ChunkedPrefill
 from triton_dist_tpu.serving.scheduler import RequestHandle
@@ -111,19 +110,14 @@ class PrefillWorker:
         # decode pool: pages migrate as their stored bytes (+ scales),
         # so the handoff is bit-exact and the decode side never
         # re-quantizes.
-        cache = PagedKVCache.empty(
+        self.cache, self.shardings = PagedKVCache.empty_sharded(
+            mesh, engine.model.paged_cache_specs, axis,
             cfg.num_hidden_layers, self.num_pages, page,
             cfg.num_key_value_heads, cfg.head_dim, num_slots=num_slots,
             p_max=p_max,
             dtype=jax.tree.leaves(engine.params)[0].dtype,
             kv_dtype=kv_dtype)
-        self.quantized = cache.quantized
-        self.shardings = pool_shardings(
-            mesh, engine.model.paged_cache_specs(
-                axis, quantized=cache.quantized))
-        self.cache = jax.tree.map(
-            jax.device_put, cache, self.shardings,
-            is_leaf=lambda x: isinstance(x, jax.Array))
+        self.quantized = self.cache.quantized
         self.chunker = ChunkedPrefill(engine, self.shardings, buckets,
                                       attn_impl=attn_impl,
                                       telemetry=telemetry)
@@ -140,7 +134,7 @@ class PrefillWorker:
         rep = NamedSharding(mesh, P())
         self._extract = jax.jit(
             lambda c, ids: c.gather_pages(ids),
-            out_shardings=((rep, rep, rep, rep) if cache.quantized
+            out_shardings=((rep, rep, rep, rep) if self.quantized
                            else (rep, rep)))
         # The reverse edge: a fixed-shape scatter INTO the staging
         # pool (donated, pinned to the pool's one sharding spelling)
@@ -148,7 +142,7 @@ class PrefillWorker:
         # chunk-stream start so the worker skips their compute, the
         # dual of the decode-side handoff fetch. One jit entry: the
         # payload is always scratch-padded to p_max pages.
-        if cache.quantized:
+        if self.quantized:
             self._inject = jax.jit(
                 lambda c, k, v, ks, vs, ids: c.scatter_pages(
                     k, v, ids, ks, vs),

@@ -616,21 +616,19 @@ class ServingEngine:
                 "engine instead")
         cfg, mesh, axis = eng.cfg, eng.mesh, eng.axis
         n = mesh.shape[axis]
-        # GLOBAL kv-head count here — the sharding below carves it into
-        # the per-shard kv_loc the decode step sees.
-        cache = PagedKVCache.empty(
+        # GLOBAL kv-head count here — the sharding carves it into the
+        # per-shard kv_loc the decode step sees; the pool is allocated
+        # under that sharding, never whole on one device.
+        cache, shardings = PagedKVCache.empty_sharded(
+            mesh, model.paged_cache_specs, axis,
             cfg.num_hidden_layers, num_pages, self.page,
             cfg.num_key_value_heads, cfg.head_dim, num_slots=num_slots,
             p_max=self.p_max,
             dtype=jax.tree.leaves(eng.params)[0].dtype,
             kv_dtype=self.kv_dtype)
-        from triton_dist_tpu.serving.blocks import pool_shardings
-
         kv_spec = model.paged_cache_specs(
             axis, quantized=cache.quantized)
-        shardings = pool_shardings(mesh, kv_spec)
-        self.cache = jax.tree.map(jax.device_put, cache, shardings,
-                                  is_leaf=lambda x: isinstance(x, jax.Array))
+        self.cache = cache
         # The pool's pinned shardings — every writer into it (prompt
         # blit, chunk steps, page-migration scatter) must return leaves
         # with EXACTLY these, or the decode dispatch re-specializes.

@@ -68,14 +68,21 @@ _KIND_SPECS = (
 
 def detect_chip(devices: Optional[Sequence] = None) -> ChipSpec:
     """ChipSpec for the attached hardware (reference: clock/SM queries
-    feeding ``gemm_perf_model``). Unknown/CPU backends get the V5P
-    default — the perf models stay usable as relative estimators."""
+    feeding ``gemm_perf_model``), keyed by ``device_kind`` (a v5e
+    reports ``"TPU v5 lite"``). A TPU whose kind matches no row raises:
+    another chip's peaks under this one's name would be a wrong number,
+    not an estimate. Non-TPU backends (the CPU test mesh) get the V5P
+    row so the perf models stay usable as relative estimators."""
     if devices is None:
         devices = jax.devices()
-    kind = getattr(devices[0], "device_kind", devices[0].platform).lower()
+    kind = getattr(devices[0], "device_kind", devices[0].platform)
     for sub, spec in _KIND_SPECS:
-        if sub in kind:
+        if sub in kind.lower():
             return spec
+    if devices[0].platform == "tpu":
+        raise ValueError(
+            f"unknown TPU device_kind {kind!r}: add its peaks to "
+            "tools/topology._KIND_SPECS")
     return V5P
 
 

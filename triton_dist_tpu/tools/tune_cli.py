@@ -9,8 +9,10 @@ tune_spmd`: one jitted SPMD step per candidate config, compiled and
 timed eagerly, winner persisted under the same cache key the op's
 ``*_tuned`` wrapper reads in-trace.
 
-Run (real chip):  TDT_REAL_TPU=1 python -m triton_dist_tpu.tools.tune_cli \
+Run:  python -m triton_dist_tpu.tools.tune_cli \
     --op ag_gemm --m 2048 --k 4096 --n 4096
+(``JAX_PLATFORMS=cpu`` runs the sweep on the 8-virtual-device CPU mesh —
+a smoke of the plumbing; its timings are interpreter wall times.)
 """
 
 import argparse
@@ -29,18 +31,20 @@ def main():
     ap.add_argument("--dtype", default="bfloat16")
     args = ap.parse_args()
 
+    # Only affects the CPU backend (JAX_PLATFORMS=cpu).
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=8")
     import jax
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8")
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import triton_dist_tpu as tdt
     from triton_dist_tpu import ops, tune
     from triton_dist_tpu.autotuner import tune_spmd
+    from triton_dist_tpu.utils.distributed import enable_compile_cache
+
+    enable_compile_cache()
 
     ndev = args.tp or len(jax.devices())
     mesh = tdt.make_mesh(tp=ndev, devices=jax.devices()[:ndev])

@@ -1,5 +1,6 @@
-"""Shared tutorial bring-up: 8 virtual CPU devices unless real multi-chip
-TPU hardware is attached (tutorials run anywhere; see docs/testing.md)."""
+"""Shared tutorial bring-up. Tutorials run on whatever backend JAX
+initialises; ``JAX_PLATFORMS=cpu`` selects the 8-virtual-device CPU mesh
+(the device-count flag below only affects the CPU; see docs/testing.md)."""
 
 import os
 import sys
@@ -18,9 +19,4 @@ def bootstrap(num_devices: int = 8):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + f" --xla_force_host_platform_device_count={num_devices}")
     import jax
-    # Default to the virtual CPU mesh; set TDT_REAL_TPU=1 on a real
-    # multi-chip slice. (Calling jax.devices() first would pin the
-    # backend, so the decision is env-driven.)
-    if os.environ.get("TDT_REAL_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     return jax
