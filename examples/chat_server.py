@@ -145,8 +145,10 @@ def main():
                          "overridden")
     ap.add_argument("--trace-out", default=None, metavar="DIR",
                     help="dump the merged Perfetto trace (host spans "
-                         "+ megakernel slot records + xprof device "
-                         "spans) and a metrics.json snapshot into DIR "
+                         "+ megakernel slot records), the xprof capture "
+                         "(which holds the host spans as tdt.* "
+                         "annotations beside the device's operations) "
+                         "and a metrics.json snapshot into DIR "
                          "on exit and on SIGTERM, and print the "
                          "one-line 'obs:' latency summary")
     ap.add_argument("--slo", action="store_true",

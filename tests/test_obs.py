@@ -161,9 +161,82 @@ def test_span_taxonomy_well_formed():
         assert k in SPAN_KINDS
 
 
+# The tick's own spans: the root, the leaves that tile it, and submit.
+TICK_KINDS = ("tick", "schedule", "decode_prep", "decode_enqueue",
+              "decode_wait", "decode_fetch", "prefill_fetch", "sample",
+              "emit", "submit")
+
+
+@pytest.mark.parametrize("kind", TICK_KINDS)
+def test_tick_span_kinds_in_taxonomy_and_op_histograms(kind):
+    from triton_dist_tpu.obs.telemetry import _OP_HIST_KINDS
+
+    assert kind in SPAN_KINDS
+    assert kind in _OP_HIST_KINDS, (
+        f"{kind} must reach stats()['latency']['ops'] in untraced runs")
+
+
 # ---------------------------------------------------------------------------
 # Telemetry facade modes
 # ---------------------------------------------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what a
+    profiler capture would be handed."""
+    seen = []
+
+    def __init__(self, name, **stats):
+        self.seen.append((name, stats))
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("mode", ["off", "counters", "spans"])
+def test_every_span_and_event_is_a_profiler_annotation(mode, monkeypatch):
+    from triton_dist_tpu.obs import telemetry
+
+    seen = []
+    tel = Telemetry(mode)
+    # Outside a capture (TraceMe's own switch) nothing is built.
+    assert tel._annotation("decode", {"step": 1}) is telemetry._NULL
+    monkeypatch.setattr(_FakeAnnotation, "seen", seen)
+    monkeypatch.setattr(telemetry, "TraceAnnotation", _FakeAnnotation)
+    with tel.span("tick", tick=4):
+        with tel.span("decode", step=9, batch=2, tenant="acme"):
+            pass
+        tel.event("admit", request_id="r1", slot=0, waited_ms=1.5)
+    tel.event("retry", op="migration")
+    tel.complete_span("queue_wait", 0.0, 1.0, request_id="r1")
+    if mode == "off":
+        assert seen == [], "off mode hands the profiler nothing"
+        assert tel.span("decode") is tel.span("tick", tick=0), (
+            "off mode allocates no span object")
+        return
+    # Correlation keys and the two counts ride as stats; the open
+    # tick's index reaches everything inside it and nothing after it;
+    # other attrs (tenant, op) stay out; a back-dated span is none.
+    assert seen == [
+        ("tdt.tick", {"tick": 4}),
+        ("tdt.decode", {"step": 9, "batch": 2, "tick": 4}),
+        ("tdt.admit", {"request_id": "r1", "slot": 0, "waited_ms": 1.5,
+                       "tick": 4}),
+        ("tdt.retry", {})]
+    assert tel.tick is None
+    if mode == "spans":
+        by_kind = {s.kind: s for s in tel.log.spans()}
+        assert by_kind["decode"].attrs["tick"] == 4
+        assert by_kind["admit"].attrs["tick"] == 4
+        assert "tick" not in by_kind["retry"].attrs
+    else:
+        assert len(tel.log) == 0
 
 def test_telemetry_mode_gating():
     t = [0.0]
@@ -422,7 +495,7 @@ def test_chaos_events_carry_clock_stamps(role_engines):
 def test_spans_bit_identical_and_jit_no_growth(engine):
     prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9]]
     runs = {}
-    for mode in ("off", "spans"):
+    for mode in ("off", "counters", "spans"):
         srv = ServingEngine(engine, num_slots=2, page=PAGE,
                             prefill_buckets=(4, 8), telemetry=mode)
         runs[mode] = srv.generate(prompts, max_new_tokens=4)
@@ -430,8 +503,106 @@ def test_spans_bit_identical_and_jit_no_growth(engine):
             f"telemetry={mode} grew the decode jit cache")
         assert srv.prefill_cache_size() <= 2, (
             f"telemetry={mode} leaked a prefill shape")
-    assert runs["off"] == runs["spans"], (
+        stats = srv.stats()
+        assert stats["ticks"] > 0
+        if mode == "off":
+            assert stats["latency"] is None and len(srv.obs.log) == 0
+            continue
+        # The tick's phases as histograms, where no profiler runs.
+        ops = stats["latency"]["ops"]
+        assert set(TICK_KINDS) <= set(ops), sorted(ops)
+        assert ops["tick"]["count"] == stats["ticks"]
+        assert (ops["decode_enqueue"]["count"]
+                == ops["decode_wait"]["count"]
+                == ops["decode_fetch"]["count"]
+                == ops["decode"]["count"] == stats["decode_dispatches"])
+        assert ops["sample"]["count"] == ops["emit"]["count"] == 12
+        assert ops["submit"]["count"] == ops["prefill_fetch"]["count"] == 3
+    assert runs["off"] == runs["counters"] == runs["spans"], (
         "span recording changed token outputs")
+
+
+def test_tick_spans_tile_the_tick(engine):
+    """On a clock that moves at every reading: the leaf spans of a tick
+    lie inside it, carry its index, and do not overlap; what one tick
+    holds is what the tick did."""
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    srv = ServingEngine(engine, num_slots=2, page=PAGE,
+                        prefill_buckets=(4, 8), telemetry="spans",
+                        clock=clock)
+    srv.generate([list(range(1, 11)), [4, 5]], max_new_tokens=3)
+    spans = srv.obs.log.spans()
+    ticks = [s for s in spans if s.kind == "tick"]
+    assert [s.attrs["tick"] for s in ticks] == list(range(len(ticks)))
+    assert len(ticks) == srv.stats()["ticks"]
+    parents, back_dated = ("tick", "decode"), ("queue_wait", "request")
+    seen = set()
+    for tick in ticks:
+        idx = tick.attrs["tick"]
+        inside = [s for s in spans if s is not tick
+                  and s.attrs.get("tick") == idx]
+        # queue_wait and request are back-dated: they CLOSE in the tick.
+        for s in inside:
+            assert s.kind in back_dated or tick.t0 < s.t0, s
+            assert (s.t1 or s.t0) < tick.t1, (
+                f"{s.kind} of tick {idx} escapes it")
+        leaves = sorted((s for s in inside if not s.instant
+                         and s.kind not in parents + back_dated),
+                        key=lambda s: s.t0)
+        for a, b in zip(leaves, leaves[1:]):
+            assert a.t1 < b.t0, f"{a.kind} overlaps {b.kind} in tick {idx}"
+        kinds = [s.kind for s in leaves]
+        seen.update(kinds)
+        assert kinds[0] == "schedule"
+        for dec in (s for s in inside if s.kind == "decode"):
+            kids = [s.kind for s in leaves if dec.t0 < s.t0 < dec.t1]
+            assert kids == ["decode_enqueue", "decode_wait",
+                            "decode_fetch"]
+            assert kinds[kinds.index("decode_enqueue") - 1] == "decode_prep"
+    assert seen == set(TICK_KINDS) - {"tick", "submit"} | {"prefill_chunk"}
+    # What is recorded between two ticks carries no index.
+    submits = [s for s in spans if s.kind == "submit"]
+    assert len(submits) == 2
+    assert all("tick" not in s.attrs for s in submits)
+
+
+def test_spans_reach_a_profiler_capture(engine, tmp_path):
+    """A capture started by any means holds the spans, on its clock and
+    with their keys as stats (on the chip: beside the device's ops)."""
+    from jax.profiler import ProfileData
+
+    srv = ServingEngine(engine, num_slots=2, page=PAGE)
+    srv.generate([[1, 2]], max_new_tokens=1)        # compile outside
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        srv.generate([[1, 2, 3]], max_new_tokens=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = [ev for plane in ProfileData.from_file(str(path)).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("tdt.")]
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev.name, []).append(dict(ev.stats))
+    ticks = [st["tick"] for st in by_name["tdt.tick"]]
+    assert ticks == sorted(ticks) and len(ticks) >= 2
+    assert [st["tick"] for st in by_name["tdt.decode_fetch"]] == [
+        st["tick"] for st in by_name["tdt.decode"]]
+    assert all(st["batch"] == 1 for st in by_name["tdt.decode"])
+    (admit,) = by_name["tdt.admit"]
+    assert admit["waited_ms"] >= 0 and admit["slot"] == 0
+    assert admit["request_id"] == by_name["tdt.emit"][0]["request_id"]
+    tick = next(ev for ev in events if ev.name == "tdt.tick")
+    fetch = next(ev for ev in events if ev.name == "tdt.decode_fetch")
+    assert tick.duration_ns > 0 and fetch.duration_ns > 0
 
 
 def test_spec_spans_bit_identical(engine):
@@ -476,19 +647,21 @@ def test_merged_perfetto_export_well_formed(engine, tmp_path):
     assert tid_by_slot and all(len(tids) == 1
                                for tids in tid_by_slot.values())
     # spans nested: each request's queue_wait and decode-side work sits
-    # inside its request span on the same clock.
+    # inside its request span on the same clock. Its two edges are
+    # spans themselves: submit opens before the scheduler stamps the
+    # request, and the emit that retires it closes after.
     reqs = {e["args"]["request_id"]: e for e in host
             if e["args"]["kind"] == "request"}
     for e in host:
         rid = e["args"].get("request_id")
         if rid in reqs and e["ph"] == "X" and e is not reqs[rid]:
-            r = reqs[rid]
-            assert r["ts"] <= e["ts"] + 1e-6
-            assert (e["ts"] + e.get("dur", 0)
+            r, kind = reqs[rid], e["args"]["kind"]
+            assert kind == "submit" or r["ts"] <= e["ts"] + 1e-6
+            assert (kind == "emit" or e["ts"] + e.get("dur", 0)
                     <= r["ts"] + r["dur"] + 1e-6), (
-                f"{e['args']['kind']} escapes its request span")
-    # the xprof tier is honest about being skipped
-    assert trace["metadata"]["xprof_reason"]
+                f"{kind} escapes its request span")
+            assert (e["ts"] <= r["ts"] + r["dur"]
+                    and r["ts"] <= e["ts"] + e.get("dur", 0) + 1e-6)
     # metrics snapshot rides the same session dir
     mp = sess.export_metrics(srv.stats())
     m = json.load(open(mp))

@@ -1,5 +1,5 @@
-"""Serving observability: span timelines, latency histograms, merged
-Perfetto traces.
+"""Serving observability: span timelines, latency histograms, spans in
+the profiler's capture, merged Perfetto traces.
 
 The telemetry substrate ROADMAP item 5c's "at production traffic you
 debug with traces, not reruns" calls for (see docs/observability.md):
@@ -10,13 +10,13 @@ debug with traces, not reruns" calls for (see docs/observability.md):
   histograms (TTFT / inter-token / per-op) with percentile summaries
   and per-tenant grouping;
 - :mod:`~triton_dist_tpu.obs.telemetry` — the per-engine facade behind
-  ``ServingEngine(telemetry="off"|"counters"|"spans")``;
-- :mod:`~triton_dist_tpu.obs.xprof` — best-effort device-span
-  extraction from an xprof capture, keyed to
-  :func:`~triton_dist_tpu.profiler.trace_scalar` markers;
+  ``ServingEngine(telemetry="off"|"counters"|"spans")``; every span and
+  event it records is also a ``jax.profiler.TraceAnnotation`` named
+  ``tdt.<kind>``, so a profiler capture holds the host spans beside the
+  device's operations, on one clock;
 - :mod:`~triton_dist_tpu.obs.trace` — the one-directory trace session
-  ``ServingEngine.trace()`` yields (xprof + host spans + megakernel
-  slot records -> one merged Perfetto file).
+  ``ServingEngine.trace()`` yields (the profiler's capture, and host
+  spans + megakernel slot records -> one merged Perfetto file).
 
 Everything here is host-side bookkeeping on the engine's injectable
 clock: recording never touches a jitted dispatch, so the serving
@@ -36,5 +36,4 @@ from triton_dist_tpu.obs.telemetry import (  # noqa: F401
     TELEMETRY_MODES,
     Telemetry,
 )
-from triton_dist_tpu.obs.xprof import extract_xprof_spans  # noqa: F401
 from triton_dist_tpu.obs.trace import TraceSession  # noqa: F401

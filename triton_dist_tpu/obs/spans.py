@@ -31,10 +31,22 @@ __all__ = ["SPAN_KINDS", "Span", "EventLog"]
 # The serving span/event taxonomy (docs/observability.md). Interval
 # spans carry t0 < t1 on the engine clock; instant events have t1 None.
 SPAN_KINDS = (
+    # the serving tick, tiled: ``tick`` is the root, the rest leaves
+    # (``decode`` below is the parent of the three ``decode_*`` after
+    # ``decode_prep``); every span inside a tick carries its index
+    "tick",              # span: one ServingEngine.step()
+    "schedule",          # span: deadlines, SLO pump, admission, requeue
+    "decode_prep",       # span: token gather, page growth, block table
+    "decode_enqueue",    # span: uploads, the jitted call, copy request
+    "decode_wait",       # span: host blocked until the logits exist
+    "decode_fetch",      # span: logits copied to the host
+    "prefill_fetch",     # span: wait for + copy of the last chunk's row
+    "sample",            # span: one slot's _pick
+    "emit",              # span: one slot's _emit (callbacks, retire)
     # request lifecycle
-    "submit",            # event: request entered the wait queue
+    "submit",            # span: submit()'s own work (validate, enqueue)
     "queue_wait",        # span: submit -> slot admission
-    "admit",             # event: slot assigned (status -> prefill)
+    "admit",             # event: slot assigned (attrs: waited_ms)
     "prefill",           # span: monolithic prefill dispatch + blit
     "prefill_chunk",     # span: one bucketed chunk dispatch (1 attempt)
     "migration",         # span: one KV page-migration attempt (disagg)

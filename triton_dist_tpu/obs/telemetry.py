@@ -7,8 +7,9 @@ Three modes, chosen at engine construction
   ``stats()`` still work; nothing here runs on the hot path).
 - ``"counters"`` — the cheap default: latency histograms (TTFT,
   inter-token latency, per-op durations) and named counters. No span
-  objects are allocated; the hot-path cost is two clock reads and one
-  histogram bisect per instrumented region.
+  objects are allocated; the hot-path cost is two clock reads, one
+  histogram bisect and one profiler annotation per instrumented
+  region.
 - ``"spans"`` — everything above PLUS the full typed-span timeline in
   the bounded :class:`~triton_dist_tpu.obs.spans.EventLog` (JSONL
   export, Perfetto merge).
@@ -17,12 +18,21 @@ All stamping is host-side on the engine's injectable clock — a fake
 clock makes timelines deterministic in tests, and nothing here is ever
 traced into a jit, so the decode/prefill no-growth gates hold with
 spans active.
+
+In every enabled mode each span and event is ALSO a
+``jax.profiler.TraceAnnotation`` named ``tdt.<kind>``: while a profiler
+capture is running (started by anyone, by any means) the host spans
+land in the capture's own ``.xplane.pb``, on the timebase of the
+device's ``XLA Ops``, with their correlation keys as stats. Outside a
+capture the annotation is TraceMe's own no-op.
 """
 
 from __future__ import annotations
 
 import time
 from typing import Callable, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from triton_dist_tpu.obs.hist import HistogramSet
 from triton_dist_tpu.obs.spans import EventLog, Span
@@ -38,7 +48,16 @@ _OP_HIST_KINDS = frozenset({
     "spec_draft", "spec_verify", "checkpoint", "restore", "request",
     "kv_offload", "kv_prefetch", "park", "resume",
     "route", "fleet_failover", "drain", "restore_fleet",
+    # the serving tick, tiled (docs/observability.md)
+    "tick", "schedule", "decode_prep", "decode_enqueue", "decode_wait",
+    "decode_fetch", "prefill_fetch", "sample", "emit", "submit",
 })
+
+# The fields an annotation carries as stats into a profiler capture:
+# the correlation keys, and the two counts read there (``batch`` of a
+# decode, ``waited_ms`` of an admission).
+_ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "bucket",
+                        "valid", "waited_ms"})
 
 
 class _NullSpan:
@@ -57,11 +76,12 @@ _NULL = _NullSpan()
 
 
 class _SpanCtx:
-    """One timed region: clock at enter/exit, histogram fold, and (in
-    spans mode) an EventLog append — error type recorded when the
-    region raised."""
+    """One timed region: a profiler annotation around it, clock at
+    enter/exit, histogram fold, and (in spans mode) an EventLog append
+    — error type recorded when the region raised. A ``tick`` span makes
+    its index the ambient ``Telemetry.tick`` while it is open."""
 
-    __slots__ = ("tel", "kind", "fields")
+    __slots__ = ("tel", "kind", "fields", "ann", "t0")
 
     def __init__(self, tel: "Telemetry", kind: str, fields: dict):
         self.tel = tel
@@ -69,17 +89,24 @@ class _SpanCtx:
         self.fields = fields
 
     def __enter__(self):
-        self.fields["_t0"] = self.tel.clock()
+        tel = self.tel
+        if self.kind == "tick":
+            tel.tick = self.fields["tick"]
+        self.ann = tel._annotation(self.kind, self.fields)
+        self.ann.__enter__()
+        self.t0 = tel.clock()
         return self
 
     def __exit__(self, etype, exc, tb):
         tel = self.tel
         fields = self.fields
-        t0 = fields.pop("_t0")
         t1 = tel.clock()
+        self.ann.__exit__(etype, exc, tb)
         if etype is not None:
             fields["error"] = etype.__name__
-        tel._finish_span(self.kind, t0, t1, fields)
+        tel._finish_span(self.kind, self.t0, t1, fields)
+        if self.kind == "tick":
+            tel.tick = None
         return False
 
 
@@ -102,6 +129,9 @@ class Telemetry:
         self.log = EventLog(capacity)
         self.hist = HistogramSet(**hist_kw)
         self.counters: Dict[str, int] = {}
+        # Index of the serving tick that is open, None between ticks:
+        # every span and event recorded meanwhile carries it.
+        self.tick: Optional[int] = None
 
     # -- mode predicates ---------------------------------------------
 
@@ -126,45 +156,63 @@ class Telemetry:
             return _NULL
         return _SpanCtx(self, kind, fields)
 
+    def _annotation(self, kind: str, fields: dict):
+        """The span or event as the profiler sees it: ``tdt.<kind>``
+        with its correlation keys as stats. Built only while a capture
+        runs (TraceMe's own switch), else the shared no-op."""
+        if not TraceAnnotation.is_enabled():
+            return _NULL
+        stats = {k: v for k, v in fields.items()
+                 if k in _ANNOTATED and v is not None}
+        tick = fields.get("tick", self.tick)
+        if tick is not None:
+            stats["tick"] = tick
+        return TraceAnnotation("tdt." + kind, **stats)
+
     def _finish_span(self, kind: str, t0: float, t1: float,
                      fields: dict) -> None:
         tenant = fields.get("tenant")
         if kind in _OP_HIST_KINDS:
             self.hist.observe(f"op:{kind}", t1 - t0, tenant)
         if self.mode == "spans":
-            self.log.append(Span(
-                kind=kind, t0=t0, t1=t1,
-                request_id=fields.pop("request_id", None),
-                slot=fields.pop("slot", None),
-                step=fields.pop("step", None),
-                tenant=fields.pop("tenant", None),
-                attrs=fields))
+            self._log(kind, t0, t1, fields)
+
+    def _log(self, kind: str, t0: float, t1: Optional[float],
+             fields: dict) -> None:
+        if self.tick is not None:
+            fields.setdefault("tick", self.tick)
+        self.log.append(Span(
+            kind=kind, t0=t0, t1=t1,
+            request_id=fields.pop("request_id", None),
+            slot=fields.pop("slot", None),
+            step=fields.pop("step", None),
+            tenant=fields.pop("tenant", None),
+            attrs=fields))
 
     def complete_span(self, kind: str, t0: float,
                       t1: Optional[float] = None, **fields) -> None:
         """Record a span whose start was stamped earlier (e.g.
         queue-wait: ``t0`` is the submit time). ``t1`` defaults to
-        now."""
+        now. It cannot be back-dated into a profiler capture, so it is
+        no annotation: the event that closes it carries its length
+        (``tdt.admit``'s ``waited_ms``)."""
         if self.mode == "off":
             return
         self._finish_span(kind, t0, self.clock() if t1 is None else t1,
                           fields)
 
     def event(self, kind: str, **fields) -> None:
-        """Instant event (spans mode only — events are timeline
-        entries, not distributions). Also bumps the ``kind`` counter in
-        any enabled mode."""
+        """Instant event: a timeline entry in spans mode (events are
+        not distributions), a zero-length annotation in a profiler
+        capture, and a bump of the ``kind`` counter, in any enabled
+        mode."""
         if self.mode == "off":
             return
         self.counters[kind] = self.counters.get(kind, 0) + 1
+        with self._annotation(kind, fields):
+            pass
         if self.mode == "spans":
-            self.log.append(Span(
-                kind=kind, t0=self.clock(), t1=None,
-                request_id=fields.pop("request_id", None),
-                slot=fields.pop("slot", None),
-                step=fields.pop("step", None),
-                tenant=fields.pop("tenant", None),
-                attrs=fields))
+            self._log(kind, self.clock(), None, fields)
 
     def observe(self, name: str, seconds: float,
                 tenant: Optional[str] = None) -> None:

@@ -5,7 +5,9 @@ owns the session directory, runs the xprof capture inside it (when
 available — a failed profiler start records a skip reason instead of
 killing the serve), collects megakernel slot records per decode step
 while active, and exports ONE merged Perfetto file plus a
-``metrics.json`` snapshot on demand.
+``metrics.json`` snapshot on demand. The capture needs no merging: it
+holds the host spans itself, as ``tdt.<kind>`` annotations beside the
+device's operations (:mod:`~triton_dist_tpu.obs.telemetry`).
 
 ``os.fspath(session)`` / ``str(session)`` return the session directory
 — pre-existing callers that treated the old ``trace()`` yield as a
@@ -37,21 +39,16 @@ class TraceSession:
 
     ``xprof``: ``"auto"`` starts a ``jax.profiler.trace`` capture and
     degrades to a recorded reason on failure; ``True`` propagates the
-    failure; ``False`` skips the capture (reason recorded). ``markers``
-    / ``top_ops`` feed
-    :func:`~triton_dist_tpu.obs.xprof.extract_xprof_spans` at export.
+    failure; ``False`` skips the capture (reason recorded).
     ``mk_keep`` bounds how many decode steps' megakernel slot records
     the session retains (newest win).
     """
 
     def __init__(self, path: str, telemetry, *, xprof="auto",
-                 markers=None, top_ops: int = 0, mk_keep: int = 4,
-                 create_perfetto_link: bool = False):
+                 mk_keep: int = 4, create_perfetto_link: bool = False):
         self.path = path
         self.telemetry = telemetry
         self.xprof = xprof
-        self.markers = markers
-        self.top_ops = top_ops
         self.mk_keep = mk_keep
         self.create_perfetto_link = create_perfetto_link
         self.xprof_reason: Optional[str] = None
@@ -116,17 +113,10 @@ class TraceSession:
 
     def export(self, path: Optional[str] = None) -> str:
         """Write the merged Perfetto trace (host spans + megakernel
-        slot records + marker-keyed xprof device spans). Returns the
-        file path; the xprof tier degrades to a recorded
-        ``xprof_reason`` when the capture is absent or markerless."""
-        from triton_dist_tpu.obs.xprof import extract_xprof_spans
+        slot records). Returns the file path."""
         from triton_dist_tpu.profiler.viewer import export_merged_trace
 
         path = path or os.path.join(self.path, "merged_trace.json")
-        xprof_events, reason = [], self.xprof_reason
-        if reason is None:
-            xprof_events, reason = extract_xprof_spans(
-                self.path, markers=self.markers, top_ops=self.top_ops)
         tel = self.telemetry
         meta = {"telemetry_mode": getattr(tel, "mode", None)}
         if tel is not None and tel.spans_on and tel.log.dropped:
@@ -137,8 +127,6 @@ class TraceSession:
                         and tel.spans_on else ()),
             slot_records=list(self._mk_records),
             tag_names=_mk_tag_names(),
-            xprof_events=xprof_events,
-            xprof_reason=reason,
             metadata=meta)
         return self.merged_path
 

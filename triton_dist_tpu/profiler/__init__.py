@@ -1,5 +1,5 @@
 from triton_dist_tpu.profiler.language import (  # noqa: F401
-    Profiler, record, trace_scalar,
+    Profiler, record,
 )
 from triton_dist_tpu.profiler.viewer import (  # noqa: F401
     export_to_perfetto_trace,

@@ -8,10 +8,9 @@ Reference: ``python/triton_dist/tools/profiler/language.py:38`` device
 TPU differences: Mosaic exposes no in-kernel clock, so slots record
 ``(tag, value)`` pairs (progress counters, semaphore reads, tile ids)
 in *program order*; true wall-time per region comes from the XLA/xprof
-trace (``profiler_utils.group_profile``), into which
-:func:`trace_scalar` (``pltpu.trace_value``) injects the same markers.
-The combination covers the reference's use cases: megakernel
-SM-activity metrics and per-tile progress inspection.
+trace (``profiler_utils.group_profile``). The combination covers the
+reference's use cases: megakernel SM-activity metrics and per-tile
+progress inspection.
 """
 
 from __future__ import annotations
@@ -58,9 +57,3 @@ def record(buf_ref, cursor_ref, tag: int, value):
         buf_ref[pl.ds(idx, 1), :] = row
 
     cursor_ref[0] = idx + 1
-
-
-def trace_scalar(label: str, value):
-    """Emit a scalar into the xprof/Perfetto trace from inside a kernel
-    (no-op outside a profiling capture)."""
-    pltpu.trace_value(label, jnp.asarray(value, jnp.int32))

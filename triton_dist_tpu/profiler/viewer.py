@@ -7,10 +7,11 @@ instant events per device track — enough to inspect schedules and
 progress interleaving (real timing lives in the xprof capture).
 
 :func:`export_merged_trace` is the serving-telemetry superset: host
-request spans (:mod:`triton_dist_tpu.obs`), megakernel slot records,
-and xprof-extracted device spans merge into ONE trace file — one
-Perfetto process per component, correlated by request id and step
-index carried in every event's ``args``.
+request spans (:mod:`triton_dist_tpu.obs`) and megakernel slot records
+merge into ONE trace file — one Perfetto process per component,
+correlated by request id and step index carried in every event's
+``args``. Device time is not merged in: the profiler's own capture
+holds the host spans beside the device's operations, on one clock.
 """
 
 from __future__ import annotations
@@ -23,7 +24,6 @@ import numpy as np
 # Merged-trace process ids: one Perfetto "process" per component.
 HOST_PID = 1        # host serving spans (engine clock)
 MEGAKERNEL_PID = 2  # in-kernel slot records (program order / cost model)
-XPROF_PID = 3       # device spans extracted from the xprof capture
 
 
 def _slot_events(buffers, tag_names, durs, *, pid: int,
@@ -149,8 +149,6 @@ def export_merged_trace(path: str, *, host_spans=(),
                         slot_records=(),
                         tag_names: Optional[Dict[int, str]] = None,
                         slot_durations=None,
-                        xprof_events=(),
-                        xprof_reason: Optional[str] = None,
                         metadata: Optional[dict] = None) -> str:
     """Write ONE chrome-trace JSON merging every telemetry tier.
 
@@ -164,15 +162,10 @@ def export_merged_trace(path: str, *, host_spans=(),
       ``slot_durations`` is given), each step offset on the synthetic
       axis and stamped with its ``step`` for correlation against the
       host decode spans.
-    - ``xprof_events``: device spans from
-      :func:`~triton_dist_tpu.obs.xprof.extract_xprof_spans` — pid 3,
-      original thread ids, the capture's own µs clock. When absent the
-      skip reason rides in the trace metadata (``xprof_reason``) so a
-      merged file is honest about the missing tier.
 
-    The three clock domains are NOT aligned (no shared epoch exists
-    across host monotonic / program order / xprof); correlation is by
-    the ``request_id`` / ``step`` keys in ``args``, which is what the
+    The two clock domains are NOT aligned (no shared epoch exists
+    across host monotonic / program order); correlation is by the
+    ``request_id`` / ``step`` keys in ``args``, which is what the
     serving debug loop joins on.
     """
     events = []
@@ -209,28 +202,10 @@ def export_merged_trace(path: str, *, host_spans=(),
                       else buffers.shape[1] + 8)
         events += _meta(MEGAKERNEL_PID, "megakernel", mk_threads)
 
-    if xprof_events:
-        base = min(float(e.get("ts", 0.0)) for e in xprof_events)
-        xp_threads = {}
-        for e in xprof_events:
-            tid = int(e.get("tid", 0)) % (1 << 20)
-            name = (e.get("args", {}) or {}).get("xprof_thread")
-            if name:
-                xp_threads.setdefault(tid, name)
-            ev = dict(e, pid=XPROF_PID, tid=tid,
-                      ts=float(e.get("ts", 0.0)) - base)
-            ev.setdefault("args", {})
-            ev["args"] = dict(ev["args"], timing="xprof")
-            events.append(ev)
-        events += _meta(XPROF_PID, "device:xprof", xp_threads)
-
     meta = {"clock_domains": {
         "host:serving": "engine clock (injectable monotonic)",
         "megakernel": "program order / calibrated cost model",
-        "device:xprof": "xprof capture clock",
     }}
-    if xprof_reason:
-        meta["xprof_reason"] = xprof_reason
     if metadata:
         meta.update(metadata)
     trace = {"traceEvents": events, "displayTimeUnit": "ms",

@@ -88,9 +88,7 @@ def group_profile(name: str = "trace", *, log_dir: str = "/tmp/tdt_traces",
     ``create_perfetto_trace`` additionally materializes the capture as
     ``perfetto_trace.json.gz`` in the session directory (forwarded to
     ``jax.profiler.trace`` when this jax supports it; silently dropped
-    on older versions — the ``*.trace.json.gz`` the capture always
-    writes is what :func:`~triton_dist_tpu.obs.extract_xprof_spans`
-    mines either way).
+    on older versions).
     """
     import inspect
 

@@ -435,6 +435,7 @@ class ServingEngine:
             "prefetched_pages": 0, "parks": 0, "resumes": 0,
             "router_prefetched_pages": 0, "worker_prefetched_pages": 0,
             "integrity_failures": 0, "slo_preemptions": 0,
+            "ticks": 0,
         }
         self.prefill_buckets = (tuple(sorted(set(int(b) for b in
                                                  prefill_buckets)))
@@ -845,38 +846,60 @@ class ServingEngine:
         :class:`~triton_dist_tpu.serving.scheduler.QueueFullError` on
         backpressure and ``ValueError`` for requests that could never
         fit (fail fast, mirroring ``Engine.serve``'s bound check)."""
-        if isinstance(request, Request):
-            if kw:
-                raise TypeError(
-                    f"keyword args {sorted(kw)} ignored when passing a "
-                    "Request — set them on the Request itself")
-        else:
-            request = Request(prompt=list(request), **kw)
-        if len(request.prompt) == 0:
-            raise ValueError("empty prompt")
-        if request.max_new_tokens < 1:
-            raise ValueError("max_new_tokens must be >= 1")
-        total = len(request.prompt) + request.max_new_tokens
-        cap = self.p_max * self.page
-        if total > cap or total > self.max_len:
-            raise ValueError(
-                f"prompt {len(request.prompt)} + gen "
-                f"{request.max_new_tokens} exceeds capacity "
-                f"{min(cap, self.max_len)}")
-        if self.slo is not None:
-            h = self.slo.submit(self, request)
-        else:
-            h = self.sched.submit(request)
-        self.obs.event("submit", request_id=h.request.request_id,
-                       tenant=h.request.tenant,
-                       prompt_tokens=len(h.request.prompt),
-                       max_new_tokens=h.request.max_new_tokens)
+        # The span is submit()'s own work, so that the time between two
+        # ticks splits into the program's part and the caller's; the
+        # scheduler names the request, so its keys are filled in last.
+        with self.obs.span("submit") as span:
+            if isinstance(request, Request):
+                if kw:
+                    raise TypeError(
+                        f"keyword args {sorted(kw)} ignored when "
+                        "passing a Request — set them on the Request "
+                        "itself")
+            else:
+                request = Request(prompt=list(request), **kw)
+            if len(request.prompt) == 0:
+                raise ValueError("empty prompt")
+            if request.max_new_tokens < 1:
+                raise ValueError("max_new_tokens must be >= 1")
+            total = len(request.prompt) + request.max_new_tokens
+            cap = self.p_max * self.page
+            if total > cap or total > self.max_len:
+                raise ValueError(
+                    f"prompt {len(request.prompt)} + gen "
+                    f"{request.max_new_tokens} exceeds capacity "
+                    f"{min(cap, self.max_len)}")
+            if self.slo is not None:
+                h = self.slo.submit(self, request)
+            else:
+                h = self.sched.submit(request)
+            if self.obs.enabled:
+                span.fields.update(
+                    request_id=h.request.request_id,
+                    tenant=h.request.tenant,
+                    prompt_tokens=len(h.request.prompt),
+                    max_new_tokens=h.request.max_new_tokens)
         return h
 
     def step(self) -> int:
         """One serving tick: deadlines → admission/prefill → one joint
         decode dispatch → per-slot token handling. Returns how many
         live slots decoded (0 = idle tick)."""
+        tick = self.stats_counters["ticks"]
+        self.stats_counters["ticks"] = tick + 1
+        # The root span: every span and event below carries ``tick``,
+        # and the leaves (schedule, prefill_chunk, prefill_fetch,
+        # decode_prep, decode_enqueue/wait/fetch, sample, emit) tile it.
+        with self.obs.span("tick", tick=tick):
+            with self.obs.span("schedule"):
+                self._schedule()
+            if self._prefiller is not None:
+                self._advance_chunks()
+            return self._decode_tick()
+
+    def _schedule(self):
+        """The tick's head: resumes, deadlines, SLO arbitration and
+        admission into free slots."""
         if self._resuming:
             self._collect_resumes()
         now = self.sched.now()
@@ -899,22 +922,21 @@ class ServingEngine:
             # handle's LAST entry into the queue (a stalled/preempted
             # handle requeues and logs another wait — the timeline
             # records each wait, never the time it already spent
-            # running).
+            # running). It cannot be back-dated into a profiler
+            # capture, so the admit event carries its length.
             self.obs.complete_span(
                 "queue_wait", h.queued_at, now,
                 request_id=h.request.request_id, slot=h.slot,
                 tenant=h.request.tenant)
             self.obs.event("admit", request_id=h.request.request_id,
-                           slot=h.slot, tenant=h.request.tenant)
+                           slot=h.slot, tenant=h.request.tenant,
+                           waited_ms=(now - h.queued_at) * 1e3)
             self._admit(h, stalled)
         # Pool-starved admissions go back to the queue HEAD in their
         # original submission order (reversed appendleft — two stalls
         # in one tick must not leapfrog each other).
         for h in reversed(stalled):
             self.sched.queue.appendleft(h)
-        if self._prefiller is not None:
-            self._advance_chunks()
-        return self._decode_tick()
 
     def _drained(self) -> bool:
         """Nothing left to serve (subclasses add their in-flight
@@ -1399,8 +1421,8 @@ class ServingEngine:
     def trace(self, name: str = "serving", *,
               expert_histograms: bool = True,
               log_dir: str = "/tmp/tdt_traces", out_dir=None,
-              xprof="auto", markers=None, top_ops: int = 0,
-              mk_keep: int = 4, create_perfetto_link: bool = False):
+              xprof="auto", mk_keep: int = 4,
+              create_perfetto_link: bool = False):
         """One tracing context over the serving loop: the xprof
         capture, the per-step expert histograms, and the host span
         timeline all share ONE session directory and ONE context
@@ -1414,9 +1436,10 @@ class ServingEngine:
         ``mk_keep`` steps' slot records. On exit the session holds
         everything :meth:`TraceSession.export` needs to write ONE
         merged Perfetto file — host request spans (``telemetry=
-        "spans"``), megakernel slot records, and marker-keyed xprof
-        device spans (skip-with-reason when the capture or markers are
-        unavailable — e.g. any off-TPU run).
+        "spans"``) and megakernel slot records. The xprof capture
+        itself already holds every host span beside the device's
+        operations, as ``tdt.<kind>`` annotations on its own clock
+        (any enabled telemetry mode; docs/observability.md).
 
         The old signature still works: ``with srv.trace("x"):`` starts
         an xprof capture under ``{log_dir}/{name}`` exactly as before
@@ -1432,8 +1455,7 @@ class ServingEngine:
         @contextlib.contextmanager
         def _traced():
             sess = TraceSession(
-                path, self.obs, xprof=xprof, markers=markers,
-                top_ops=top_ops, mk_keep=mk_keep,
+                path, self.obs, xprof=xprof, mk_keep=mk_keep,
                 create_perfetto_link=create_perfetto_link)
             self._hist_active = expert_histograms
             self._trace_session = sess
@@ -1773,8 +1795,19 @@ class ServingEngine:
         h.status = "running"
         self._close_resume_span(h, path="reprefill")
         if not h.tokens:
-            first = self._pick(np.asarray(logits), h.request, 0)
-            self._emit(h, first)
+            with self.obs.span("prefill_fetch", slot=slot,
+                               request_id=h.request.request_id):
+                row = np.asarray(logits)
+            self._sample_emit(h, row)
+
+    def _sample_emit(self, h: RequestHandle, logits_row: np.ndarray):
+        """One slot's token: picked from its logits row, then emitted
+        (stream callback, retirement), each under its own span."""
+        rid = h.request.request_id
+        with self.obs.span("sample", slot=h.slot, request_id=rid):
+            tok = self._pick(logits_row, h.request, len(h.tokens))
+        with self.obs.span("emit", slot=h.slot, request_id=rid):
+            self._emit(h, tok)
 
     # -- KV memory hierarchy: demote / prefetch / park / resume ------
 
@@ -2196,40 +2229,10 @@ class ServingEngine:
                       and h.status == "prefill")]
         if not active:
             return 0
-        preempted = []
-        for h in active:
-            slot = h.slot
-            if self.mega and h.status == "prefill":
-                self._toks[slot] = h.lane[h.prompt_pos]
-            else:
-                self._toks[slot] = h.tokens[-1]
-            if self.manager is not None and not (
-                    self.mega and h.status == "prefill"):
-                # Page-boundary growth for the (generated) token being
-                # written this step; prefill-lane tokens land in pages
-                # alloc_prefill already reserved. Passing the position
-                # keeps the accounting idempotent across a timed-out
-                # step's retry. A row overflow here is a caller bug
-                # (submit validates capacity) — propagate.
-                try:
-                    self.manager.append(slot, int(self._lens[slot]))
-                except OutOfPagesError as e:
-                    # Pool dry MID-DECODE: preempt this request —
-                    # release its pages, requeue it at the head, and
-                    # let it resume later via re-prefill of prompt +
-                    # generated-so-far (deterministic, so still
-                    # token-exact). One starving request must not
-                    # crash the server.
-                    self._preempt(h, e)
-                    preempted.append(h)
-        if preempted:
-            active = [h for h in active if h not in preempted]
-            if not active:
-                return 0
-        tbl = np.zeros((self.num_slots, self.p_max), np.int32)
-        if self.manager is not None:
-            for h in active:
-                tbl[h.slot] = self.manager.table_row(h.slot)
+        with self.obs.span("decode_prep"):
+            active, tbl = self._decode_prep(active)
+        if not active:
+            return 0
 
         from triton_dist_tpu.resilience import faults
 
@@ -2307,9 +2310,47 @@ class ServingEngine:
                     continue
             h.decode_steps += 1
             self.stats_counters["decode_tokens"] += 1
-            tok = self._pick(logits[slot], h.request, len(h.tokens))
-            self._emit(h, tok)
+            self._sample_emit(h, logits[slot])
         return len(active)
+
+    def _decode_prep(self, active):
+        """Host work before the joint dispatch: each slot's input
+        token, page growth for the position it writes, and the block
+        table. Returns the handles that still decode (pool-dry ones are
+        preempted) and the table."""
+        preempted = []
+        for h in active:
+            slot = h.slot
+            if self.mega and h.status == "prefill":
+                self._toks[slot] = h.lane[h.prompt_pos]
+            else:
+                self._toks[slot] = h.tokens[-1]
+            if self.manager is not None and not (
+                    self.mega and h.status == "prefill"):
+                # Page-boundary growth for the (generated) token being
+                # written this step; prefill-lane tokens land in pages
+                # alloc_prefill already reserved. Passing the position
+                # keeps the accounting idempotent across a timed-out
+                # step's retry. A row overflow here is a caller bug
+                # (submit validates capacity) — propagate.
+                try:
+                    self.manager.append(slot, int(self._lens[slot]))
+                except OutOfPagesError as e:
+                    # Pool dry MID-DECODE: preempt this request —
+                    # release its pages, requeue it at the head, and
+                    # let it resume later via re-prefill of prompt +
+                    # generated-so-far (deterministic, so still
+                    # token-exact). One starving request must not
+                    # crash the server.
+                    self._preempt(h, e)
+                    preempted.append(h)
+        if preempted:
+            active = [h for h in active if h not in preempted]
+        tbl = np.zeros((self.num_slots, self.p_max), np.int32)
+        if self.manager is not None:
+            for h in active:
+                tbl[h.slot] = self.manager.table_row(h.slot)
+        return active, tbl
 
     # -- the speculative tick (spec_k >= 1, layer path) --------------
 
@@ -2639,48 +2680,60 @@ class ServingEngine:
     def _dispatch(self, tbl: np.ndarray) -> np.ndarray:
         """Run the joint decode under the (optional) watchdog; returns
         host logits (num_slots, vocab)."""
+        import jax.numpy as jnp
+
+        if not self.mega:
+            return self._dispatch_layers(tbl)
+        lens = jnp.asarray(self._lens)
+        toks = jnp.asarray(self._toks)
+        if self.manager is not None:
+            # Paged megakernel: install THIS tick's allocator table
+            # (flat (batch·p_max,), the builder's prefetch layout) —
+            # the engine's identity table is only its standalone
+            # default, and parked rows must hit the scratch page.
+            self.engine.block_table = jnp.asarray(
+                tbl.reshape(-1), jnp.int32)
+        if (self._mk_counts_base is None
+                and hasattr(self.engine, "expert_counts")
+                and getattr(self.cfg, "is_moe", False)):
+            # In-kernel counters accumulate monotonically in the
+            # arena; snapshot BEFORE the first serving dispatch so
+            # pre-serving warmup traffic never pollutes the load.
+            self._mk_counts_base = self.engine.expert_counts()
+        out = self.engine.decode_step(toks, lens)
+        if (self._trace_session is not None
+                and getattr(self.engine, "last_prof",
+                            None) is not None):
+            # Megakernel slot records for the merged trace: only
+            # while a trace session is open (prof_tracks syncs the
+            # step), keyed by this dispatch's step index.
+            self._trace_session.add_slot_record(
+                self.stats_counters["decode_dispatches"],
+                self.engine.builder.prof_tracks(
+                    self.engine.last_prof))
+        if self._mk_counts_base is not None:
+            total = self.engine.expert_counts()
+            self._note_expert_counts(total - self._mk_counts_base)
+            self._mk_counts_base = total
+        return np.asarray(out)
+
+    def _dispatch_layers(self, tbl: np.ndarray) -> np.ndarray:
+        """The layer path's joint decode in the three parts a device
+        idle gap can fall under: uploads and the jitted call returning
+        (``decode_enqueue``), the host blocked until the logits exist
+        (``decode_wait``), their copy to the host (``decode_fetch``)."""
         import dataclasses as _dc
 
+        import jax
         import jax.numpy as jnp
         from triton_dist_tpu.resilience.watchdog import block_until_ready
 
-        lens = jnp.asarray(self._lens)
-        live = jnp.asarray(self._live)
-        toks = jnp.asarray(self._toks)
-        if self.mega:
-            if self.manager is not None:
-                # Paged megakernel: install THIS tick's allocator table
-                # (flat (batch·p_max,), the builder's prefetch layout) —
-                # the engine's identity table is only its standalone
-                # default, and parked rows must hit the scratch page.
-                self.engine.block_table = jnp.asarray(
-                    tbl.reshape(-1), jnp.int32)
-            if (self._mk_counts_base is None
-                    and hasattr(self.engine, "expert_counts")
-                    and getattr(self.cfg, "is_moe", False)):
-                # In-kernel counters accumulate monotonically in the
-                # arena; snapshot BEFORE the first serving dispatch so
-                # pre-serving warmup traffic never pollutes the load.
-                self._mk_counts_base = self.engine.expert_counts()
-            out = self.engine.decode_step(toks, lens)
-            if (self._trace_session is not None
-                    and getattr(self.engine, "last_prof",
-                                None) is not None):
-                # Megakernel slot records for the merged trace: only
-                # while a trace session is open (prof_tracks syncs the
-                # step), keyed by this dispatch's step index.
-                self._trace_session.add_slot_record(
-                    self.stats_counters["decode_dispatches"],
-                    self.engine.builder.prof_tracks(
-                        self.engine.last_prof))
-            if self._mk_counts_base is not None:
-                total = self.engine.expert_counts()
-                self._note_expert_counts(total - self._mk_counts_base)
-                self._mk_counts_base = total
-        else:
+        with self.obs.span("decode_enqueue"):
+            toks = jnp.asarray(self._toks)
             cache = _dc.replace(self.cache,
                                 block_table=jnp.asarray(tbl),
-                                lens=lens, live=live)
+                                lens=jnp.asarray(self._lens),
+                                live=jnp.asarray(self._live))
             if self.ep and self.replicas is not None:
                 out, self.cache, ecounts = self._decode(
                     self.engine.params, toks, cache, self.replicas)
@@ -2691,12 +2744,19 @@ class ServingEngine:
                 ecounts = None
                 out, self.cache = self._decode(self.engine.params,
                                                toks, cache)
-            if self.timeout_s is not None:
-                # The counts output rides the SAME dispatch: it must
-                # sit inside the watchdog-bounded wait, or a wedged
-                # collective would hang the host in the counts
-                # conversion below before the deadline ever fires.
-                guarded = (out if ecounts is None else (out, ecounts))
+            # Ask for the copy now, behind the program: the explicit
+            # wait below then puts no host round trip between the
+            # program's end and the copy's start.
+            out.copy_to_host_async()
+        with self.obs.span("decode_wait"):
+            # The counts output rides the SAME dispatch: it must sit
+            # inside the watchdog-bounded wait, or a wedged collective
+            # would hang the host in the counts conversion below
+            # before the deadline ever fires.
+            guarded = (out if ecounts is None else (out, ecounts))
+            if self.timeout_s is None:
+                jax.block_until_ready(guarded)
+            else:
                 guarded = block_until_ready(
                     guarded, timeout_s=self.timeout_s,
                     op="serving.decode",
@@ -2707,10 +2767,11 @@ class ServingEngine:
                            ("decode_dispatches", "tokens_generated")}})
                 out, ecounts = (guarded if ecounts is not None
                                 else (guarded, None))
+        with self.obs.span("decode_fetch"):
             if ecounts is not None:
                 self._note_expert_counts(
                     np.asarray(ecounts).astype(np.int64))
-        return np.asarray(out)
+            return np.asarray(out)
 
     # -- expert-load telemetry + hot-expert rebalancing --------------
 
