@@ -5,6 +5,8 @@ torch oracle pattern for ``gqa_fwd_batch_decode`` (paged, ragged
 lengths, shuffled page tables).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -315,3 +317,347 @@ def test_paged_decode_page_shuffle_invariance():
     o2 = f(jnp.asarray(kp2[0]), jnp.asarray(vp2[0]), jnp.asarray(tbl2[0]))
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the pool keeps one layout (PR 26): the kernels take every layer's pool
+# whole, the writers update it in place, and the compiled step programs
+# hold no copy of the pool or of a layer
+# ---------------------------------------------------------------------------
+
+def _layer_pool_call(kernel, quantized):
+    """One paged kernel on a two-layer pool: → call(pool_k, pool_v,
+    layer, k_scale, v_scale) and the arguments of the 4-D form."""
+    from triton_dist_tpu.ops.paged_flash_qblock import paged_flash_qblock
+
+    rng = np.random.RandomState(40)
+    kp = rng.randn(2, B * P_MAX + 1, KVH, PAGE, HD).astype(np.float32)
+    vp = rng.randn(*kp.shape).astype(np.float32)
+    tbl = jnp.asarray(
+        1 + rng.permutation(B * P_MAX).reshape(B, P_MAX), jnp.int32)
+    if quantized:
+        parts = [_quantize_pool(kp[l], vp[l], jnp.int8, 127.0)
+                 for l in range(2)]
+        kp, vp, ks, vs = (jnp.stack([p[i] for p in parts])
+                          for i in range(4))
+    else:
+        kp, vp = (jnp.asarray(a, jnp.bfloat16) for a in (kp, vp))
+        ks = vs = None
+    if kernel == "decode":
+        q = jax.random.normal(jax.random.PRNGKey(41), (B, H, HD))
+        arg = jnp.array([SHARD - 3, PAGE + 1], jnp.int32)      # kv_len
+        fn = lambda k, v, **kw: paged_flash_decode(
+            q, k, v, tbl, arg, axis=None, **kw)
+    else:
+        q = jax.random.normal(jax.random.PRNGKey(41), (B, 3, H, HD))
+        arg = jnp.array([[2, 3, 4], [PAGE - 1, PAGE, PAGE + 1]],
+                        jnp.int32)                             # positions
+        fn = lambda k, v, **kw: paged_flash_qblock(q, k, v, tbl, arg, **kw)
+    return fn, kp, vp, ks, vs
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "qblock"])
+def test_kernel_on_whole_pool_equals_layer_slice(kernel, quantized):
+    """The 5-D (L, N, KV, page, hd) pool with a static ``layer`` reads
+    the same pages as the 4-D call on ``pool[layer]``: bit for bit
+    (layer 1 of two layers of random pages, so layer 0 would show)."""
+    fn, kp, vp, ks, vs = _layer_pool_call(kernel, quantized)
+    layer = 1
+    scales = ({} if ks is None
+              else dict(k_scale=ks[layer], v_scale=vs[layer]))
+    whole = jax.jit(lambda k, v: fn(k, v, layer=layer, **scales))(kp, vp)
+    sliced = jax.jit(lambda k, v: fn(k, v, **scales))(kp[layer], vp[layer])
+    assert np.array_equal(np.asarray(whole, np.float32),
+                          np.asarray(sliced, np.float32))
+
+
+def test_whole_pool_needs_a_static_layer():
+    fn, kp, vp, _, _ = _layer_pool_call("decode", False)
+    with pytest.raises(ValueError, match="static int layer"):
+        fn(kp, vp)
+    with pytest.raises(ValueError, match="only the whole 5-D pool"):
+        fn(kp[0], vp[0], layer=0)
+
+
+# -- the writers: in place, and bit-identical to the scatter they replace --
+
+W_PAGE, W_PMAX, W_SLOTS, W_KV, W_HD = 4, 3, 3, 2, 8
+
+
+def _write_cache(lens, tables, live):
+    """A two-layer bf16 pool of random bytes (so a write to the wrong
+    layer or page shows), page 0 the scratch page."""
+    from triton_dist_tpu.serving.blocks import PagedKVCache
+
+    rng = np.random.RandomState(50)
+    shape = (2, 1 + W_SLOTS * W_PMAX, W_KV, W_PAGE, W_HD)
+    return PagedKVCache(
+        k_pages=jnp.asarray(rng.randn(*shape), jnp.bfloat16),
+        v_pages=jnp.asarray(rng.randn(*shape), jnp.bfloat16),
+        block_table=jnp.asarray(tables, jnp.int32),
+        lens=jnp.asarray(lens, jnp.int32),
+        live=jnp.asarray(live, jnp.int32))
+
+
+def _scatter_rows(cache, layer, pids, off, k_rows, v_rows):
+    """The plain reference: the row scatter the writers were before."""
+    return dataclasses.replace(
+        cache,
+        k_pages=cache.k_pages.at[layer, pids, :, off, :].set(
+            k_rows.astype(cache.k_pages.dtype)),
+        v_pages=cache.v_pages.at[layer, pids, :, off, :].set(
+            v_rows.astype(cache.v_pages.dtype)))
+
+
+def _ref_append(cache, layer, k_tok, v_tok, budget=None):
+    page, p_max = cache.page, cache.block_table.shape[1]
+    k = k_tok.shape[1]
+    pos = cache.lens[:, None] + jnp.arange(k, dtype=jnp.int32)[None]
+    valid = pos // page < p_max
+    if budget is not None:
+        valid &= jnp.arange(k, dtype=jnp.int32)[None] < budget[:, None]
+    rows = jnp.clip(pos // page, 0, p_max - 1)
+    pids = jnp.where(
+        valid, jnp.take_along_axis(cache.block_table, rows, axis=1), 0)
+    return _scatter_rows(cache, layer, pids, pos % page, k_tok, v_tok)
+
+
+def _ref_chunk(cache, layer, k_tok, v_tok, table_row, positions, valid,
+               wfrom):
+    from triton_dist_tpu.ops.chunked_prefill import chunk_write_ids
+
+    pids, off = chunk_write_ids(positions, table_row, valid, wfrom,
+                                page=cache.page)
+    return _scatter_rows(cache, layer, pids, off, k_tok[:, 0], v_tok[:, 0])
+
+
+_TABLES = [[1, 2, 3], [4, 5, 6], [7, 8, 9]]
+_PARKED = [[1, 2, 3], [0, 0, 0], [7, 8, 9]]
+_WRITES = {
+    # name: (writer, lens, tables, live, arguments of the writer)
+    "decode-parked-slot": ("decode", [5, 0, 2], _PARKED, [1, 0, 1], {}),
+    "decode-two-parked-slots": (
+        "decode", [0, 0, 7], [[0, 0, 0], [0, 0, 0], [7, 8, 9]],
+        [0, 0, 1], {}),
+    "decode-page-boundary": ("decode", [3, 4, 8], _TABLES, [1, 1, 1], {}),
+    "block-crosses-a-page": ("block", [2, 3, 7], _TABLES, [1, 1, 1], {}),
+    "block-budget": ("block", [2, 3, 7], _TABLES, [1, 1, 1],
+                     {"budget": [1, 3, 0]}),
+    "block-past-the-table-row": ("block", [10, 11, 0], _PARKED, [1, 0, 1],
+                                 {"budget": [3, 3, 3]}),
+    "chunk-ends-inside-a-page": ("chunk", None, _TABLES, None,
+                                 dict(start=0, valid=6, wfrom=0)),
+    "chunk-valid-below-bucket": ("chunk", None, _TABLES, None,
+                                 dict(start=4, valid=1, wfrom=0)),
+    "chunk-wfrom-on-a-shared-page": ("chunk", None, _TABLES, None,
+                                     dict(start=0, valid=8, wfrom=6)),
+    "chunk-unaligned-start": ("chunk", None, _TABLES, None,
+                              dict(start=3, valid=7, wfrom=5)),
+    "chunk-padding-past-the-table-row": ("chunk", None, _TABLES, None,
+                                         dict(start=8, valid=4, wfrom=0)),
+    "chunk-nothing-to-write": ("chunk", None, _TABLES, None,
+                               dict(start=0, valid=0, wfrom=0)),
+}
+
+
+@pytest.mark.parametrize("name", list(_WRITES))
+def test_writers_equal_the_row_scatter(name):
+    """``append_decode``, ``append_block`` and ``write_chunk`` leave
+    every page but the scratch page bit-identical to the
+    ``.at[layer, pids, :, off, :].set`` form they replace (the scratch
+    page holds what nobody reads: padding, parked slots, rows past a
+    budget). Two layers, written at layer 1, then at layer 0."""
+    writer, lens, tables, live, kw = _WRITES[name]
+    cache = _write_cache(lens if lens is not None else [0] * W_SLOTS,
+                         tables, live if live is not None else [1] * W_SLOTS)
+    rng = np.random.RandomState(51)
+    n_tok = {"decode": 1, "block": 3, "chunk": 8}[writer]
+    shape = ((n_tok, 1, W_KV, W_HD) if writer == "chunk"
+             else (W_SLOTS, n_tok, W_KV, W_HD))
+    k_tok, v_tok = (jnp.asarray(rng.randn(*shape), jnp.float32)
+                    for _ in range(2))
+
+    def run(ref):
+        def step(cache):
+            for layer in (1, 0):
+                if writer == "chunk":
+                    row = cache.block_table[1]
+                    pos = kw["start"] + jnp.arange(n_tok, dtype=jnp.int32)
+                    fn = _ref_chunk if ref else type(cache).write_chunk
+                    cache = fn(cache, layer, k_tok, v_tok, row, pos,
+                               jnp.int32(kw["valid"]), jnp.int32(kw["wfrom"]))
+                elif ref:
+                    budget = kw.get("budget")
+                    cache = _ref_append(
+                        cache, layer, k_tok, v_tok,
+                        None if budget is None
+                        else jnp.asarray(budget, jnp.int32))
+                elif writer == "decode":
+                    cache = cache.append_decode(layer, k_tok, v_tok)
+                else:
+                    budget = kw.get("budget")
+                    cache = cache.append_block(
+                        layer, k_tok, v_tok,
+                        None if budget is None
+                        else jnp.asarray(budget, jnp.int32))
+            return cache.k_pages, cache.v_pages
+        return jax.jit(step)(cache)
+
+    for got, want, before in zip(run(False), run(True),
+                                 (cache.k_pages, cache.v_pages)):
+        got, want, before = (np.asarray(a[:, 1:]).view(np.uint16)
+                             for a in (got, want, before))
+        assert np.array_equal(got, want)
+        if "nothing" not in name:
+            assert not np.array_equal(got, before)
+
+
+# -- the compiled step programs: no copy of the pool, none of a layer ------
+
+_IN_PLACE = {"parameter", "dynamic-update-slice", "tuple", "bitcast",
+             "get-tuple-element"}
+
+
+def pool_copies(hlo: str, pool_shape, dtype: str = "bf16"):
+    """Instructions of an optimised HLO module's entry computation that
+    copy the KV pool or one layer of it: anything that produces an array
+    of ``pool_shape`` or ``pool_shape[1:]`` other than the parameter
+    itself, a Mosaic call, or an update in place (a
+    ``dynamic-update-slice``, alone or as all a fusion does to the
+    pool), and any such array in a layout that is not row-major.
+    Returns the offending lines, cut short."""
+    import re
+
+    def dims(shape):
+        return dtype + "[" + ",".join(str(d) for d in shape) + "]"
+
+    pool, layer = dims(pool_shape), dims(pool_shape[1:])
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line)
+
+    def parse(line):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        return m.groups() if m else ("", "")
+
+    def relaid(result):
+        laid = re.findall(re.escape(pool) + r"\{([\d,]+)", result)
+        laid += re.findall(re.escape(layer) + r"\{([\d,]+)", result)
+        return any(lay.split(",") != sorted(lay.split(","), reverse=True)
+                   for lay in laid)
+
+    bad = []
+    for line in comps["ENTRY"]:
+        result, op = parse(line)
+        if pool not in result and layer not in result:
+            continue
+        ok = op in _IN_PLACE and layer + "{" not in result.replace(pool, "")
+        if op == "fusion" and layer + "{" not in result.replace(pool, ""):
+            called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+            ok = all(parse(inner)[1] in _IN_PLACE
+                     for inner in comps[called] if pool in parse(inner)[0])
+        if not ok or relaid(result):
+            bad.append(line.strip()[:200])
+    return bad
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """libtpu's description of a v5e:2x2 (nothing attached, nothing
+    run), or skip. Inside the fixture: only the worker that is given one
+    of these tests may load the TPU's library."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — libtpu says why
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk-128", "chunk-512",
+                                     "verify"])
+def test_compiled_step_keeps_the_pool_in_place(v5e, program, monkeypatch):
+    """Each paged step of ``models.dense``, lowered for one v5e chip
+    as the serving engine jits it (pool donated, output shardings
+    pinned), compiles to a program whose entry computation neither
+    relayouts the pool nor cuts a layer out of it, and whose
+    temporaries are smaller than one layer (PR 26: they were the pool
+    twice). Compile only: nothing runs, nothing is timed."""
+    import triton_dist_tpu as tdt
+    from jax.sharding import NamedSharding
+    from triton_dist_tpu.models import ModelConfig, dense
+    from triton_dist_tpu.serving.blocks import PagedKVCache, pool_shardings
+    from triton_dist_tpu.utils import distributed
+
+    # Lower the kernels for Mosaic, as on the chip, not for the
+    # interpreter this process's CPU backend would choose.
+    monkeypatch.setattr(distributed, "platform", lambda: "tpu")
+    cfg = ModelConfig(vocab_size=1024, hidden_size=512,
+                      intermediate_size=1024, num_hidden_layers=2,
+                      num_attention_heads=8, num_key_value_heads=4,
+                      head_dim=128, attention_bias=True, qk_norm=False)
+    # A pool larger than the chip's 128 MiB of VMEM: a small one XLA
+    # prefetches there whole, which no serving pool gives it room for.
+    pages, page, slots, p_max, spec_k = 1025, 128, 4, 8, 4
+    mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
+    axis, dt = "tp", jnp.bfloat16
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=(
+                    s if isinstance(s, NamedSharding)
+                    else NamedSharding(mesh, s))),
+            tree, specs, is_leaf=lambda s: isinstance(s, P))
+
+    specs = dense.param_specs(cfg, axis)
+    params = on_mesh(jax.eval_shape(
+        lambda: dense.init_params(jax.random.PRNGKey(0), cfg, dt)), specs)
+    kv_spec = dense.paged_cache_specs(axis)
+    kv_sh = pool_shardings(mesh, kv_spec)
+    cache = on_mesh(jax.eval_shape(lambda: PagedKVCache.empty(
+        cfg.num_hidden_layers, pages, page, cfg.num_key_value_heads,
+        cfg.head_dim, num_slots=slots, p_max=p_max, dtype=dt)), kv_sh)
+    ints = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    kw = dict(mode="fused", axis=axis, attn_impl="flash",
+              ctxs=dense.make_fwd_contexts(
+                  tdt.MeshContext.from_mesh(mesh), axis))
+
+    if program == "decode":
+        step = lambda p, t, c: dense.decode_step_paged(p, t, c, cfg, **kw)
+        in_specs, out_specs = (specs, P(None), kv_spec), (P(None, None),
+                                                          kv_spec)
+        args, donate = (params, ints(slots), cache), 2
+    elif program == "verify":
+        step = lambda p, t, b, c: dense.verify_step_paged(
+            p, t, c, cfg, budget=b, **kw)
+        in_specs = (specs, P(None, None), P(None), kv_spec)
+        out_specs = (P(None, None, None), kv_spec)
+        args, donate = (params, ints(slots, spec_k), ints(slots), cache), 3
+    else:
+        step = lambda p, t, c, row, start, wfrom, valid: (
+            dense.prefill_chunk_paged(p, t, c, row, cfg, start=start,
+                                      wfrom=wfrom, valid=valid, **kw))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P())
+        out_specs = (P(None), kv_spec)
+        args = (params, ints(int(program[6:])), cache, ints(p_max),
+                ints(), ints(), ints())
+        donate = 2
+    compiled = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=(donate,),
+        out_shardings=(NamedSharding(mesh, out_specs[0]), kv_sh),
+    ).lower(*args).compile()
+
+    pool_shape = cache.k_pages.shape
+    assert pool_copies(compiled.as_text(), pool_shape) == []
+    layer_bytes = 2 * int(np.prod(pool_shape[1:]))
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

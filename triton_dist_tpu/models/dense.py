@@ -310,8 +310,8 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
                 * (jnp.arange(k, dtype=jnp.int32)[None] + 1), 1) - 1
             ksc, vsc = cache.layer_scales(li)
             o = paged_flash_qblock(
-                q[:, 0].reshape(s, k, hl, hd), cache.k_pages[li],
-                cache.v_pages[li], cache.block_table, qpos,
+                q[:, 0].reshape(s, k, hl, hd), cache.k_pages,
+                cache.v_pages, cache.block_table, qpos, layer=li,
                 k_scale=ksc, v_scale=vsc)
         else:
             kd, vd = cache.dense_layer(li)
@@ -427,8 +427,8 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
             qpos = jnp.where(i < valid, positions, last_valid)
             ksc, vsc = cache.layer_scales(li)
             o = paged_flash_qblock(
-                q[:, 0][None], cache.k_pages[li], cache.v_pages[li],
-                table_row[None], qpos[None],
+                q[:, 0][None], cache.k_pages, cache.v_pages,
+                table_row[None], qpos[None], layer=li,
                 k_scale=ksc, v_scale=vsc)[0]
         else:
             kd, vd = cache.dense_row(li, table_row)
@@ -505,8 +505,8 @@ def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
 
             ksc, vsc = cache.layer_scales(li)
             o = paged_flash_decode(
-                q[:, 0], cache.k_pages[li], cache.v_pages[li],
-                cache.block_table, kv_len, axis=None,
+                q[:, 0], cache.k_pages, cache.v_pages,
+                cache.block_table, kv_len, layer=li, axis=None,
                 k_scale=ksc, v_scale=vsc)
         else:
             kd, vd = cache.dense_layer(li)

@@ -103,6 +103,10 @@ def chunk_write_ids(positions, table_row, valid, wfrom, *, page: int):
     Returns ``(pids, offsets)``: padding / resident positions map to
     the reserved scratch page (id 0) — their writes are garbage the
     masks hide; real positions map to ``table_row[pos // page]``.
+    The rule as a row scatter would apply it: the megakernel's chunk
+    codes encode it, and ``PagedKVCache.write_chunk`` applies it a
+    whole page at a time (a row scatter into the pool costs a relayout
+    of the pool; ``tests/test_paged_decode.py`` holds the two equal).
     """
     c = positions.shape[0]
     i = jnp.arange(c, dtype=jnp.int32)

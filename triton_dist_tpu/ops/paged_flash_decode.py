@@ -7,10 +7,12 @@ cross-rank combine :393-482). Round 1 only had the dense-cache XLA
 composition (``ops/flash_decode.py``); this adds the kernel-level form:
 
 - **Paged KV**: the cache is a page pool ``(num_pages, KV, page, hd)``
-  plus a per-sequence ``block_table (B, P_max)`` of page ids (SMEM) —
-  pages stream through VMEM one at a time via dynamic-index DMA, so
-  arbitrary context lengths serve from a fixed pool (no dense (B, T)
-  cache materialization).
+  (or every layer's pool whole, ``(L, num_pages, KV, page, hd)``, with a
+  static ``layer``) plus a per-sequence ``block_table (B, P_max)`` of
+  page ids (SMEM) — pages stream through VMEM one at a time via
+  dynamic-index DMA, so arbitrary context lengths serve from a fixed
+  pool (no dense (B, T) cache materialization, and no layer cut out of
+  the pool: XLA would copy it).
 - **Online softmax in-kernel**: per (batch, page) grid step the running
   (m, l, acc) update happens in VMEM scratch — the flash recurrence.
 - **RDMA combine**: each rank packs (acc, m, l) partials and one-sided
@@ -115,9 +117,30 @@ def _lse_reduce(parts, hd: int):
     return jnp.concatenate([acc_tot, m_g[0], l_tot], axis=-1)
 
 
+def _pool_layer(pool, layer):
+    """A paged reader's view of its pool: ``(num_pages, KV, page, hd)``
+    as it is, or ``(L, num_pages, KV, page, hd)`` with a static Python
+    ``layer`` (the serving steps unroll their layers). Returns the
+    page-index prefix a kernel puts before the page id — ``()`` or
+    ``(layer,)`` — so a page is ``pool.at[(*prefix, pid)]`` either way
+    and the 5-D pool is never sliced outside the kernel."""
+    if pool.ndim == 5:
+        if not isinstance(layer, int):
+            raise ValueError(
+                "a 5-D (L, num_pages, KV, page, hd) pool needs a static "
+                f"int layer, got {layer!r}")
+        return (layer,)
+    if layer is not None:
+        raise ValueError(
+            f"layer={layer!r} given with a {pool.ndim}-D pool: only the "
+            "whole 5-D pool is indexed by layer")
+    return ()
+
+
 def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
                    kvh: int, rep: int, hd: int, shard_len: int,
-                   paged: bool, sim: bool, quantized: bool = False):
+                   paged: bool, sim: bool, quantized: bool = False,
+                   prefix: tuple = ()):
     """``axes``: list of (axis_name, n_ax) exchange stages, innermost
     first (1 entry = flat; 2 = hierarchical outer x inner, where the
     flat shard order is outer-major). ``paged=False`` reads a dense
@@ -127,7 +150,8 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     partials is exact) — the single-chip bench proxy.
     ``quantized=True``: the pools are int8/fp8 and two extra
     (B, P_max, KV) fp32 scale tables ride in VMEM — the dequant fuses
-    into each page's compute step (:func:`page_attend`)."""
+    into each page's compute step (:func:`page_attend`).
+    ``prefix``: :func:`_pool_layer`'s static index before the page id."""
     ks_ref = vs_ref = None
     if paged and quantized:
         (table_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
@@ -171,8 +195,8 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     def load(b2, p2, buf):
         if paged:
             pid = table_ref[b2, p2]
-            ksrc = kp_ref.at[pid]
-            vsrc = vp_ref.at[pid]
+            ksrc = kp_ref.at[(*prefix, pid)]
+            vsrc = vp_ref.at[(*prefix, pid)]
         else:
             # Dense head-major cache: page p2 is a T_loc slice — the
             # (KV, page, hd) block feeds page_attend with no transpose.
@@ -316,10 +340,10 @@ def _normalize_axes(axis, ctx, sim_ranks):
 
 def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
                  page, p_max, paged, sim_ranks=0, k_scale=None,
-                 v_scale=None):
+                 v_scale=None, prefix=()):
     """Shared host plumbing for the paged and dense decode kernels."""
     b, h, hd = q.shape
-    kvh = k_arr.shape[1]
+    kvh = k_arr.shape[-3]
     rep = h // kvh
     quantized = k_scale is not None
     axes, n, sim = _normalize_axes(axis, ctx, sim_ranks)
@@ -344,7 +368,7 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
     kernel = functools.partial(
         _decode_kernel, axes=axes, ctx=ctx, page=page, p_max=p_max,
         kvh=kvh, rep=rep, hd=hd, shard_len=shard_len, paged=paged,
-        sim=sim, quantized=quantized)
+        sim=sim, quantized=quantized, prefix=prefix)
 
     n_sem = max(sum(n_ax - 1 for _, n_ax in axes), 1)
     n_slots = max(max(n_ax for _, n_ax in axes), 1)
@@ -406,13 +430,18 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
 
 
 def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
-                       ctx: MeshContext = None, axis="sp",
+                       layer=None, ctx: MeshContext = None, axis="sp",
                        k_scale=None, v_scale=None):
-    """Distributed paged-KV GQA decode step (call inside shard_map).
+    """Paged-KV GQA decode step (in shard_map) on a 4-D pool, or 5-D + layer.
 
+    Distributed: call inside shard_map.
     q: (B, H, hd) replicated along ``axis``;
     k_pages/v_pages: (num_pages, KV, page, hd) — this rank's page pool
-    (head-major pages); int8/fp8 pools additionally REQUIRE
+    (head-major pages) — or every layer's pool whole, (L, num_pages,
+    KV, page, hd), with ``layer`` a static int: the kernel then fetches
+    ``pool.at[layer, pid]`` and the caller cuts no layer out of the pool
+    (a slice XLA would copy, see :class:`~triton_dist_tpu.serving.blocks.
+    PagedKVCache`); int8/fp8 pools additionally REQUIRE
     ``k_scale``/``v_scale`` (num_pages, KV) fp32 per-page per-head
     dequant scales (fused into the page prefetch compute) — reading a
     quantized pool without them fails loudly rather than attending
@@ -429,12 +458,14 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
     outer peer crosses the slow link.
     Returns (B, H, hd).
     """
-    _, kvh, page, _ = k_pages.shape
+    prefix = _pool_layer(k_pages, layer)
+    page = k_pages.shape[-2]
     p_max = block_table.shape[1]
     _require_pool_scales(k_pages, k_scale, reject_spurious=True)
     return _decode_call(q, k_pages, v_pages, block_table, kv_len,
                         ctx=ctx, axis=axis, page=page, p_max=p_max,
-                        paged=True, k_scale=k_scale, v_scale=v_scale)
+                        paged=True, k_scale=k_scale, v_scale=v_scale,
+                        prefix=prefix)
 
 
 def paged_flash_decode_ref(q, k_pages, v_pages, block_table, kv_len,

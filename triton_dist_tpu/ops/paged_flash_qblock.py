@@ -41,7 +41,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.lang import core_call
-from triton_dist_tpu.ops.paged_flash_decode import _require_pool_scales
+from triton_dist_tpu.ops.paged_flash_decode import (
+    _require_pool_scales, _pool_layer)
 
 
 def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
@@ -79,7 +80,7 @@ def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
 
 
 def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
-                   hd: int, cq: int, quantized: bool):
+                   hd: int, cq: int, quantized: bool, prefix: tuple = ()):
     """Grid (B, KV, P_max): slot-major, then one KV head (and its
     ``rep`` query heads) at a time, then that head's page walk with the
     decode kernel's double-buffered prefetch (per-parity semaphores);
@@ -89,7 +90,9 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     all-heads form needs 30 MB of scoped VMEM, which Mosaic refuses
     against its 16 MB limit. No partial exchange — this is the LOCAL
     (axis=None) form, the layout the serving engine's TP-head-sharded
-    pools use (every rank holds the full sequence for its heads)."""
+    pools use (every rank holds the full sequence for its heads).
+    ``prefix``: :func:`~triton_dist_tpu.ops.paged_flash_decode.
+    _pool_layer`'s static index before the page id."""
     ks_ref = vs_ref = None
     if quantized:
         (table_ref, end_ref, pos_ref, q_ref, kp_ref, vp_ref, ks_ref,
@@ -117,9 +120,10 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
 
     def load(b2, g2, p2, buf):
         pid = table_ref[b2, p2]
-        pltpu.make_async_copy(kp_ref.at[pid, pl.ds(g2, 1)], kpage.at[buf],
+        src = (*prefix, pid, pl.ds(g2, 1))
+        pltpu.make_async_copy(kp_ref.at[src], kpage.at[buf],
                               psem.at[buf]).start()
-        pltpu.make_async_copy(vp_ref.at[pid, pl.ds(g2, 1)], vpage.at[buf],
+        pltpu.make_async_copy(vp_ref.at[src], vpage.at[buf],
                               psem.at[buf]).start()
 
     @pl.when(jnp.logical_and(active, lin == 0))
@@ -204,12 +208,16 @@ def qblock_rows(cq: int, rep: int, hd: int, page: int, itemsize: int,
 
 
 def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
-                       k_scale=None, v_scale=None):
-    """Paged-KV GQA attention of a Q-BLOCK per slot (local form).
+                       layer=None, k_scale=None, v_scale=None):
+    """Paged-KV GQA attention of a Q-BLOCK per slot; 4-D pool, or 5-D + layer.
 
+    The local form (no partial exchange).
     q: (B, Cq, H, hd) — Cq queries per slot (head-major, this rank's
     heads); k_pages/v_pages: (num_pages, KV, page, hd) — this rank's
-    page pool, every attended key already resident (the chunk writer /
+    page pool — or every layer's pool whole, (L, num_pages, KV, page,
+    hd), with ``layer`` a static int (the kernel fetches
+    ``pool.at[layer, pid]``; no layer is cut out of the pool, which XLA
+    would copy) — every attended key already resident (the chunk writer /
     candidate block append runs BEFORE the attend, exactly like the
     gather path); int8/fp8 pools additionally REQUIRE ``k_scale``/
     ``v_scale`` (num_pages, KV) fp32 per-page per-head dequant scales;
@@ -228,7 +236,8 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
     Returns (B, Cq, H, hd).
     """
     b, cq, h, hd = q.shape
-    _, kvh, page, _ = k_pages.shape
+    prefix = _pool_layer(k_pages, layer)
+    kvh, page = k_pages.shape[-3:-1]
     p_max = block_table.shape[1]
     rep = h // kvh
     quantized = k_scale is not None
@@ -263,7 +272,7 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
 
     kernel = functools.partial(
         _qblock_kernel, page=page, p_max=p_max, kvh=kvh, rep=rep,
-        hd=hd, cq=bq, quantized=quantized)
+        hd=hd, cq=bq, quantized=quantized, prefix=prefix)
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),          # block_table

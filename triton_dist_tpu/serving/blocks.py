@@ -99,8 +99,8 @@ def _quant_range_write(pool, scales, layer, pids, loc, toks, tok_mask,
     s, n_t = pids.shape
     _, _, kvh, page, hd = pool.shape
     toks = toks.astype(jnp.float32)
-    old_scale = scales[layer][pids]                  # (S, n_t, KV)
-    gathered = pool[layer][pids]                     # (S, n_t, KV, pg, hd)
+    old_scale = scales[layer, pids]                    # (S, n_t, KV)
+    gathered = pool[layer, pids]                       # (S, n_t, KV, pg, hd)
     deq = gathered.astype(jnp.float32) * old_scale[..., None, None]
     dense = deq.transpose(0, 1, 3, 2, 4).reshape(s, n_t * page, kvh, hd)
     # One dump row past the window swallows masked (padding/resident)
@@ -123,6 +123,47 @@ def _quant_range_write(pool, scales, layer, pids, loc, toks, tok_mask,
     q = _quantize(blocks, new_scale[..., None, None], pool.dtype, qmax)
     return (pool.at[layer, pids].set(q),
             scales.at[layer, pids].set(new_scale))
+
+
+def _put_rows(pool, layer: int, pids, offs, toks):
+    """``pool[layer, pids[n], :, offs[n], :] = toks[n]``, one row at a
+    time, in place in the pool's own layout.
+
+    pool: (L, N, KV, page, hd), donated by every serving dispatch;
+    pids/offs: (n,) int32; toks: (n, KV, hd). Each row is one
+    ``lax.dynamic_update_slice`` of a ``(1, 1, KV, 1, hd)`` block — NOT
+    ``pool.at[layer, pids, :, offs, :].set(toks)``: that scatter's
+    window is (KV, hd), for which XLA's layout assignment wants the
+    pool with KV and the page offset swapped, so every program that
+    held one began with a relayout of the whole pool and ended with
+    one back (PERF.md section 6, PR 26). Rows with the same target land
+    in order (the last wins), as the scatter's did."""
+    rows = toks.astype(pool.dtype)[:, None, None, :, None, :]
+    for n in range(rows.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[n], (layer, pids[n], 0, offs[n], 0))
+    return pool
+
+
+def _merge_pages(pool, layer: int, pids, toks, write):
+    """Merge a window of whole pages into the pool, a page at a time,
+    in place in the pool's own layout: page ``pids[j]`` of ``layer``
+    takes ``toks[:, j·page:(j+1)·page]`` in the rows where ``write`` is
+    True and stays as it is in the others.
+
+    pool: (L, N, KV, page, hd); pids: (n_t,) int32; toks: (KV,
+    n_t·page, hd); write: (n_t·page,) bool. One dynamic slice, select
+    and ``lax.dynamic_update_slice`` of a ``(1, 1, KV, page, hd)`` block
+    a page (see :func:`_put_rows` for why not a scatter of rows)."""
+    kvh, page, hd = pool.shape[2:]
+    toks = toks.astype(pool.dtype)
+    for j in range(pids.shape[0]):
+        at = (layer, pids[j], 0, 0, 0)
+        rows = slice(j * page, (j + 1) * page)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, kvh, page, hd))
+        new = jnp.where(write[rows][:, None], toks[None, None, :, rows], old)
+        pool = jax.lax.dynamic_update_slice(pool, new, at)
+    return pool
 
 
 def pool_shardings(mesh, spec_tree):
@@ -166,6 +207,13 @@ class PagedKVCache:
     ``lens``: (num_slots,) int32 valid tokens per slot;
     ``live``: (num_slots,) int32 0/1 — the live slot mask (parked slots
     keep shape but neither advance nor persist their appends).
+
+    The pools keep ONE layout, row-major, inside every paged program:
+    the kernels take them whole with a static ``layer`` (nothing cuts
+    ``k_pages[layer]`` out) and the unquantized writers update them in
+    place (:func:`_put_rows`, :func:`_merge_pages`). A program that
+    asks for the pool in another layout, as a row scatter does, pays a
+    relayout of the whole pool at both ends.
 
     Quantized pools (``kv_dtype="int8"|"fp8"``) additionally carry
     ``k_scale``/``v_scale``: (L, num_pages, KV_loc) fp32 per-page
@@ -255,20 +303,10 @@ class PagedKVCache:
         is the dense half). k_tok/v_tok: (num_slots, 1, KV_loc, hd).
         Parked slots (all-zero table row) write the scratch page.
         Lengths advance once per step via :meth:`advance`, not here.
+        The one-token case of :meth:`append_block`: an unquantized pool
+        is updated in place, a row a slot (:func:`_put_rows`).
         """
-        if self.quantized:
-            return self._quant_append(layer, k_tok, v_tok)
-        page = self.page
-        row = self.lens // page
-        off = self.lens % page
-        pids = jnp.take_along_axis(self.block_table, row[:, None],
-                                   axis=1)[:, 0]
-        k_pages = self.k_pages.at[layer, pids, :, off, :].set(
-            k_tok[:, 0].astype(self.k_pages.dtype))
-        v_pages = self.v_pages.at[layer, pids, :, off, :].set(
-            v_tok[:, 0].astype(self.v_pages.dtype))
-        return dataclasses.replace(self, k_pages=k_pages,
-                                   v_pages=v_pages)
+        return self.append_block(layer, k_tok, v_tok)
 
     def append_block(self, layer: int, k_tok, v_tok,
                      budget=None) -> "PagedKVCache":
@@ -281,7 +319,10 @@ class PagedKVCache:
         tokens past a slot's block-table row or past its ``budget``
         (S,) — a fixed-K dispatch near a request's token budget must
         not let its over-budget candidates corrupt a real page's
-        contents (or, quantized, inflate its scale)."""
+        contents (or, quantized, inflate its scale). An unquantized
+        pool is updated in place in its own layout, a row a token
+        (:func:`_put_rows`); a quantized one requantizes whole pages
+        (:meth:`_quant_append`)."""
         if self.quantized:
             return self._quant_append(layer, k_tok, v_tok, budget)
         page = self.page
@@ -297,13 +338,14 @@ class PagedKVCache:
         pids = jnp.where(
             valid, jnp.take_along_axis(self.block_table, rows, axis=1),
             SCRATCH_PAGE)
-        off = pos % page
-        k_pages = self.k_pages.at[layer, pids, :, off, :].set(
-            k_tok.astype(self.k_pages.dtype))
-        v_pages = self.v_pages.at[layer, pids, :, off, :].set(
-            v_tok.astype(self.v_pages.dtype))
-        return dataclasses.replace(self, k_pages=k_pages,
-                                   v_pages=v_pages)
+        pids, off = pids.reshape(-1), (pos % page).reshape(-1)
+        kvl, hd = k_tok.shape[2:]
+        return dataclasses.replace(
+            self,
+            k_pages=_put_rows(self.k_pages, layer, pids, off,
+                              k_tok.reshape(-1, kvl, hd)),
+            v_pages=_put_rows(self.v_pages, layer, pids, off,
+                              v_tok.reshape(-1, kvl, hd)))
 
     def _quant_append(self, layer: int, k_tok, v_tok,
                       budget=None) -> "PagedKVCache":
@@ -353,26 +395,54 @@ class PagedKVCache:
 
         k_tok/v_tok: (C, 1, KV_loc, hd) — one row per chunk token;
         ``table_row``: (p_max,) int32 — the slot's block-table row;
-        ``positions``: (C,) int32 global positions; ``valid``/``wfrom``
-        route bucket padding and already-resident (prefix-shared)
-        positions to the scratch page (see
-        :func:`~triton_dist_tpu.ops.chunked_prefill.chunk_write_ids`)
-        so a chunk can never corrupt a page a live reader holds.
+        ``positions``: (C,) int32 global positions, consecutive;
+        ``valid``/``wfrom``: bucket padding and already-resident
+        (prefix-shared) positions are not written — the rule of
+        :func:`~triton_dist_tpu.ops.chunked_prefill.chunk_write_ids` —
+        so a chunk can never corrupt a page a live reader holds. An
+        unquantized pool is updated in place in its own layout, a whole
+        page at a time (:func:`_merge_pages`: 2 to 5 pages a chunk, not
+        one scatter row a token); a quantized one requantizes the
+        touched pages (:meth:`_quant_write_chunk`).
         """
-        from triton_dist_tpu.ops.chunked_prefill import chunk_write_ids
-
         if self.quantized:
             return self._quant_write_chunk(layer, k_tok, v_tok,
                                            table_row, positions, valid,
                                            wfrom)
-        pids, off = chunk_write_ids(positions, table_row, valid, wfrom,
-                                    page=self.page)
-        k_pages = self.k_pages.at[layer, pids, :, off, :].set(
-            k_tok[:, 0].astype(self.k_pages.dtype))
-        v_pages = self.v_pages.at[layer, pids, :, off, :].set(
-            v_tok[:, 0].astype(self.v_pages.dtype))
-        return dataclasses.replace(self, k_pages=k_pages,
-                                   v_pages=v_pages)
+        # Positions are consecutive (start + arange(C): the chunk
+        # contract), so the chunk lies in a window of n_t whole pages
+        # that begins at the start's page. Lay the rows out on that
+        # window, and merge it into the pool a page at a time.
+        page = self.page
+        c = positions.shape[0]
+        n_t = (c - 1) // page + 2
+        row0 = positions[0] // page
+        loc0 = positions[0] - row0 * page
+        i = jnp.arange(c, dtype=jnp.int32)
+        write = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_t * page,), bool),
+            jnp.logical_and(i < valid, positions >= wfrom), (loc0,))
+        # A page none of whose rows is written (bucket padding, a
+        # prefix-shared page, past the table row) is the scratch page.
+        rows = row0 + jnp.arange(n_t, dtype=jnp.int32)
+        written = jnp.any(write.reshape(n_t, page), axis=1)
+        pids = jnp.where(
+            jnp.logical_and(written, rows < table_row.shape[0]),
+            table_row[jnp.clip(rows, 0, table_row.shape[0] - 1)],
+            SCRATCH_PAGE)
+
+        def window(tok):
+            kvl, hd = tok.shape[2:]
+            return jax.lax.dynamic_update_slice(
+                jnp.zeros((kvl, n_t * page, hd), tok.dtype),
+                tok[:, 0].transpose(1, 0, 2), (0, loc0, 0))
+
+        return dataclasses.replace(
+            self,
+            k_pages=_merge_pages(self.k_pages, layer, pids,
+                                 window(k_tok), write),
+            v_pages=_merge_pages(self.v_pages, layer, pids,
+                                 window(v_tok), write))
 
     def _quant_write_chunk(self, layer, k_tok, v_tok, table_row,
                            positions, valid, wfrom) -> "PagedKVCache":
