@@ -210,6 +210,57 @@ def test_chunked_preempt_resume_deterministic(engine):
         "resume deviated from the plan")
 
 
+@pytest.mark.parametrize("lane", ["disaggregated", "spec_k", "megakernel",
+                                  "in_place"])
+def test_only_the_in_place_chunker_carries_decode_rows(engine,
+                                                       role_engines, lane):
+    """The decode batch rides a chunk program only where the chunks
+    write the pool the decoders read and the decode dispatch is the
+    plain step: a disaggregated prefill worker (its own pool), the
+    speculative lane (K tokens a slot) and the megakernel lane (chunk
+    tasks in its own kernel) build today's chunk program, with no
+    decode rows; the in-place layer path carries ``num_slots``. None
+    is chosen by an argument."""
+    if lane == "disaggregated":
+        pf, dec = role_engines
+        srv = DisaggServingEngine(dec, prefill_engine=pf, num_slots=2,
+                                  page=PAGE, prefill_buckets=BUCKETS)
+        chunker = srv._prefiller.chunker
+    elif lane == "spec_k":
+        srv = ServingEngine(engine, num_slots=2, page=PAGE, spec_k=1,
+                            prefill_buckets=BUCKETS)
+        chunker = srv.chunker
+    elif lane == "megakernel":
+        from triton_dist_tpu.megakernel.engine import MegaKernelEngine
+
+        cfg = ModelConfig.tiny(vocab_size=64, hidden_size=32,
+                               intermediate_size=32, num_hidden_layers=2,
+                               num_attention_heads=4,
+                               num_key_value_heads=2, head_dim=8)
+        mk = MegaKernelEngine(
+            cfg, Mesh(np.array(jax.devices()[:1]), ("tp",)), batch=2,
+            max_len=64, tile_w=16, t_tile=16, paged=True, page=16,
+            num_pages=9, prefill_buckets=BUCKETS)
+        srv = ServingEngine(mk, prefill_buckets=BUCKETS)
+        chunker = srv.chunker
+    else:
+        srv = ServingEngine(engine, num_slots=2, page=PAGE,
+                            prefill_buckets=BUCKETS)
+        chunker = srv.chunker
+    rows = getattr(chunker, "decode_rows", 0)
+    assert rows == (2 if lane == "in_place" else 0)
+    assert srv._rides == (lane == "in_place")
+    # One chunk, then two: the second prompt's last chunk runs in a
+    # tick in which the first request decodes.
+    hs = [srv.submit([1, 2, 3], max_new_tokens=4),
+          srv.submit(list(range(1, 20)), max_new_tokens=3)]
+    srv.run()
+    assert [h.status for h in hs] == ["done", "done"]
+    st = srv.stats()
+    assert (st["decode_dispatches_fused"] > 0) == (lane == "in_place")
+    assert st["decode_dispatches_fused"] <= st["decode_dispatches"]
+
+
 def test_chunked_wedged_chunk_fails_one_request(engine):
     """A dropped chunk dispatch (fault plan) fails the admitting
     request only; the running survivor stays token-exact."""

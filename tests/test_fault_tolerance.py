@@ -249,6 +249,67 @@ def test_retry_exhausted_retires_with_zero_leaked_pages(role_engines):
 
 
 # ---------------------------------------------------------------------------
+# The decode batch aboard a chunk program: one dispatch, both fault scopes
+# ---------------------------------------------------------------------------
+
+def _riding(tiny_engine, **kw):
+    """A server with one request decoding and a second just submitted,
+    so that the next tick's chunk program carries the decode batch."""
+    srv = ServingEngine(tiny_engine, num_slots=2, page=PAGE,
+                        prefill_buckets=BUCKETS, **kw)
+    ok = srv.submit([1, 2, 3], max_new_tokens=6)
+    srv.step()
+    assert ok.status == "running"
+    new = srv.submit(list(range(4, 13)), max_new_tokens=3)
+    return srv, ok, new
+
+
+@pytest.mark.parametrize("op", ["chunked_prefill", "serving_decode"])
+def test_dropped_fused_dispatch_retried_token_exact(tiny_engine, op):
+    """The dispatch that carries both a chunk and the decode batch
+    opens both fault scopes; a transient drop at either is absorbed by
+    one retry, and both requests serve the tokens of a clean run."""
+    srv, ok, new = _riding(tiny_engine,
+                           retry=RetryPolicy(max_attempts=2))
+    with faults.inject(faults.get_plan("fail_kth_call", op=op, k=0)):
+        srv.run()
+    assert (ok.status, new.status) == ("done", "done")
+    assert ok.tokens == _baseline(tiny_engine, [1, 2, 3], 6)
+    assert new.tokens == _baseline(tiny_engine, list(range(4, 13)), 3)
+    st = srv.stats()
+    assert st["retries"] == 1
+    assert 0 < st["decode_dispatches_fused"] <= st["decode_dispatches"]
+    chaos.check_invariants(srv)
+
+
+@pytest.mark.parametrize("op,failed", [("chunked_prefill", "new"),
+                                       ("serving_decode", "both")])
+def test_dropped_fused_dispatch_contained_by_scope(tiny_engine, op,
+                                                   failed):
+    """No retry armed: a drop at the chunk's scope fails the chunk's
+    request alone and the decoder stays token-exact; a drop at the
+    decode's scope is the decode tick's containment (its victim), and
+    the chunk aboard the same dispatch goes with it. The server
+    survives either."""
+    srv, ok, new = _riding(tiny_engine)
+    with faults.inject(faults.get_plan("fail_kth_call", op=op, k=0)):
+        srv.run()
+    assert new.status == "failed"
+    assert isinstance(new.error, faults.InjectedFault)
+    if failed == "new":
+        assert ok.status == "done"
+        assert ok.tokens == _baseline(tiny_engine, [1, 2, 3], 6)
+    else:
+        assert ok.status == "failed"
+    assert srv.stats()["retries"] == 0
+    assert srv.stats()["pool"]["used_pages"] == 0, "pages leaked"
+    again = srv.submit([5, 5], max_new_tokens=3)
+    srv.run()
+    assert again.tokens == _baseline(tiny_engine, [5, 5], 3)
+    chaos.check_invariants(srv)
+
+
+# ---------------------------------------------------------------------------
 # Prefill-worker failover
 # ---------------------------------------------------------------------------
 

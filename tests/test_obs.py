@@ -561,14 +561,74 @@ def test_tick_spans_tile_the_tick(engine):
         assert kinds[0] == "schedule"
         for dec in (s for s in inside if s.kind == "decode"):
             kids = [s.kind for s in leaves if dec.t0 < s.t0 < dec.t1]
-            assert kids == ["decode_enqueue", "decode_wait",
-                            "decode_fetch"]
+            # The batch aboard a chunk program: the chunks are enqueued
+            # between the batch's upload and the wait.
+            chunks = [k for k in kids if k == "prefill_chunk"]
+            assert bool(chunks) == bool(dec.attrs["fused"])
+            assert kids == (["decode_enqueue"] + chunks
+                            + ["decode_wait", "decode_fetch"])
             assert kinds[kinds.index("decode_enqueue") - 1] == "decode_prep"
     assert seen == set(TICK_KINDS) - {"tick", "submit"} | {"prefill_chunk"}
     # What is recorded between two ticks carries no index.
     submits = [s for s in spans if s.kind == "submit"]
     assert len(submits) == 2
     assert all("tick" not in s.attrs for s in submits)
+
+
+def test_fused_tick_holds_every_leaf_kind(engine):
+    """A tick whose decode batch rides a chunk program: every leaf kind
+    a tick can hold occurs in it where its work does, the leaves tile
+    the tick, and its one ``decode`` span says how many sequences it
+    served and that it rode (``fused``); a tick with no chunk says 0."""
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    srv = ServingEngine(engine, num_slots=3, page=PAGE,
+                        prefill_buckets=(4, 8), telemetry="spans",
+                        clock=clock)
+    # One chunk, two chunks, three: in the second tick one request
+    # decodes while two prefill, and one of those runs its last chunk.
+    srv.generate([[7, 8], list(range(1, 11)), list(range(1, 20))],
+                 max_new_tokens=4)
+    spans = srv.obs.log.spans()
+    decodes = [s for s in spans if s.kind == "decode"]
+    assert all({"batch", "fused"} <= set(s.attrs) for s in decodes)
+    assert [s.step for s in decodes] == list(range(len(decodes)))
+    rode = [s for s in decodes if s.attrs["fused"]]
+    st = srv.stats()
+    assert len(rode) == st["decode_dispatches_fused"] > 0
+    assert len(decodes) == st["decode_dispatches"] > len(rode)
+    leaf_kinds = {"schedule", "decode_prep", "prefill_chunk",
+                  "decode_enqueue", "decode_wait", "decode_fetch",
+                  "prefill_fetch", "sample", "emit"}
+    full = 0
+    for dec in rode:
+        idx = dec.attrs["tick"]
+        tick = next(s for s in spans if s.kind == "tick"
+                    and s.attrs["tick"] == idx)
+        leaves = sorted((s for s in spans if s.attrs.get("tick") == idx
+                         and not s.instant and s.kind in leaf_kinds),
+                        key=lambda s: s.t0)
+        assert all(tick.t0 < s.t0 and s.t1 < tick.t1 for s in leaves)
+        for a, b in zip(leaves, leaves[1:]):
+            assert a.t1 < b.t0, f"{a.kind} overlaps {b.kind} in tick {idx}"
+        kinds = [s.kind for s in leaves]
+        assert kinds[:4] == ["schedule", "decode_prep", "decode_enqueue",
+                             "prefill_chunk"]
+        # The wait comes after the tick's LAST chunk was enqueued, and
+        # a slot whose prompt became resident is fetched after the
+        # decode rows' tokens.
+        assert kinds.index("decode_wait") > max(
+            i for i, k in enumerate(kinds) if k == "prefill_chunk")
+        assert dec.attrs["batch"] == kinds.count("sample") - kinds.count(
+            "prefill_fetch")
+        if "prefill_fetch" in kinds:
+            assert kinds.index("prefill_fetch") > kinds.index("sample")
+        full += set(kinds) == leaf_kinds
+    assert full >= 1, "no fused tick held every leaf kind"
 
 
 def test_spans_reach_a_profiler_capture(engine, tmp_path):

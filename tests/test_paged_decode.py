@@ -359,22 +359,27 @@ def _layer_pool_call(kernel, quantized):
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("kernel", ["decode", "qblock"])
 def test_kernel_on_whole_pool_equals_layer_slice(kernel, quantized):
-    """The 5-D (L, N, KV, page, hd) pool with a static ``layer`` reads
-    the same pages as the 4-D call on ``pool[layer]``: bit for bit
-    (layer 1 of two layers of random pages, so layer 0 would show)."""
+    """The 5-D (L, N, KV, page, hd) pool with a ``layer`` reads the
+    same pages as the 4-D call on ``pool[layer]``: bit for bit (layer 1
+    of two layers of random pages, so layer 0 would show). The layer is
+    data to the kernel: a Python int and a traced scalar read alike."""
     fn, kp, vp, ks, vs = _layer_pool_call(kernel, quantized)
     layer = 1
     scales = ({} if ks is None
               else dict(k_scale=ks[layer], v_scale=vs[layer]))
     whole = jax.jit(lambda k, v: fn(k, v, layer=layer, **scales))(kp, vp)
+    traced = jax.jit(lambda k, v, li: fn(k, v, layer=li, **scales))(
+        kp, vp, jnp.int32(layer))
     sliced = jax.jit(lambda k, v: fn(k, v, **scales))(kp[layer], vp[layer])
     assert np.array_equal(np.asarray(whole, np.float32),
                           np.asarray(sliced, np.float32))
+    assert np.array_equal(np.asarray(traced, np.float32),
+                          np.asarray(sliced, np.float32))
 
 
-def test_whole_pool_needs_a_static_layer():
+def test_whole_pool_needs_a_layer():
     fn, kp, vp, _, _ = _layer_pool_call("decode", False)
-    with pytest.raises(ValueError, match="static int layer"):
+    with pytest.raises(ValueError, match="needs a layer"):
         fn(kp, vp)
     with pytest.raises(ValueError, match="only the whole 5-D pool"):
         fn(kp[0], vp[0], layer=0)
@@ -581,14 +586,18 @@ def v5e():
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk-128", "chunk-512",
-                                     "verify"])
+                                     "verify", "fused-128", "fused-512"])
 def test_compiled_step_keeps_the_pool_in_place(v5e, program, monkeypatch):
     """Each paged step of ``models.dense``, lowered for one v5e chip
     as the serving engine jits it (pool donated, output shardings
     pinned), compiles to a program whose entry computation neither
     relayouts the pool nor cuts a layer out of it, and whose
     temporaries are smaller than one layer (PR 26: they were the pool
-    twice). Compile only: nothing runs, nothing is timed."""
+    twice), and which holds each Pallas kernel once, not once a layer.
+    ``fused-*`` is the chunk program with the decode batch
+    aboard (``chunk_decode_paged``): two writers into the pool a layer,
+    the case in which XLA's layout assignment turned before. Compile
+    only: nothing runs, nothing is timed."""
     import triton_dist_tpu as tdt
     from jax.sharding import NamedSharding
     from triton_dist_tpu.models import ModelConfig, dense
@@ -641,6 +650,17 @@ def test_compiled_step_keeps_the_pool_in_place(v5e, program, monkeypatch):
         in_specs = (specs, P(None, None), P(None), kv_spec)
         out_specs = (P(None, None, None), kv_spec)
         args, donate = (params, ints(slots, spec_k), ints(slots), cache), 3
+    elif program.startswith("fused"):
+        step = lambda p, t, c, row, start, wfrom, valid, d: (
+            dense.chunk_decode_paged(
+                p, t, d, c, row, cfg, start=start, wfrom=wfrom,
+                valid=valid, decode_attn_impl="flash", **kw))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P(),
+                    P(None))
+        out_specs = (P(None), P(None, None), kv_spec)
+        args = (params, ints(int(program[6:])), cache, ints(p_max),
+                ints(), ints(), ints(), ints(slots))
+        donate = 2
     else:
         step = lambda p, t, c, row, start, wfrom, valid: (
             dense.prefill_chunk_paged(p, t, c, row, cfg, start=start,
@@ -650,12 +670,19 @@ def test_compiled_step_keeps_the_pool_in_place(v5e, program, monkeypatch):
         args = (params, ints(int(program[6:])), cache, ints(p_max),
                 ints(), ints(), ints())
         donate = 2
-    compiled = jax.jit(
+    lowered = jax.jit(
         jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs, check_vma=False),
         donate_argnums=(donate,),
-        out_shardings=(NamedSharding(mesh, out_specs[0]), kv_sh),
-    ).lower(*args).compile()
+        out_shardings=tuple(NamedSharding(mesh, s)
+                            for s in out_specs[:-1]) + (kv_sh,),
+    ).lower(*args)
+    # The layer is an operand of the kernels, so a program's layers
+    # share ONE lowering of each: set-up's seconds are these (a layer
+    # baked into the kernel made it one a layer, PR 30).
+    assert lowered.as_text().count("tpu_custom_call") == (
+        2 if program.startswith("fused") else 1)
+    compiled = lowered.compile()
 
     pool_shape = cache.k_pages.shape
     assert pool_copies(compiled.as_text(), pool_shape) == []

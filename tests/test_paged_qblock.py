@@ -312,3 +312,142 @@ def test_bad_attn_impl_values_raise(engine):
     with pytest.raises(ValueError, match="chunk_attn"):
         ServingEngine(engine, num_slots=2, page=SRV_PAGE,
                       chunk_attn="kernel")
+
+
+# ---------------------------------------------------------------------------
+# the decode batch rides the chunk's program: one program, the same math
+# ---------------------------------------------------------------------------
+
+FUSED_SLOTS = 4
+FUSED_BUCKETS = (4, 8)
+# (bucket, start, wfrom, valid) of the chunk, and the decode batch's
+# live mask: the chunk's own slot (the last) is parked in that batch.
+FUSED_CASES = {
+    "padded_chunk": (8, 0, 0, 5, (1, 1, 0, 0)),
+    # Two pages resident from a prefix hit, the cursor clamped one
+    # below them: position 15 is computed, never written; the start is
+    # not page-aligned.
+    "prefix_hit_unaligned": (4, 15, 16, 4, (1, 1, 1, 0)),
+    "start_mid_page": (8, 5, 0, 8, (1, 0, 1, 0)),
+    "every_row_parked": (8, 8, 0, 8, (0, 0, 0, 0)),
+}
+
+
+@pytest.fixture(scope="module")
+def fused_engines(engine):
+    """A dense engine and one that serves through the MoE ``ffn_fn``
+    hook (TP expert regime), float32."""
+    from triton_dist_tpu.models import qwen_moe
+
+    cfg = ModelConfig.tiny_moe(num_experts=8)
+    moe = Engine(cfg, engine.mesh, mode="xla", max_len=MAX_LEN,
+                 model=qwen_moe,
+                 params=qwen_moe.init_params(jax.random.PRNGKey(4), cfg))
+    return {"dense": engine, "moe": moe}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+# The attention does not know the FFN: the kernels once, on dense.
+@pytest.mark.parametrize("family,attn", [("dense", "ref"), ("moe", "ref"),
+                                         ("dense", "flash")])
+def test_fused_step_equals_chunk_then_decode(fused_engines, family, attn,
+                                             case):
+    """``chunk_decode_paged`` against the pair it replaces, the chunk
+    program and then the decode program on the same pool: the same pool
+    contents, chunk logits row, decode logits and lengths."""
+    from triton_dist_tpu.serving.chunked import ChunkedPrefill
+
+    eng = fused_engines[family]
+    srv = ServingEngine(eng, num_slots=FUSED_SLOTS, page=SRV_PAGE,
+                        prefill_buckets=FUSED_BUCKETS, attn_impl=attn)
+    fused = srv.chunker
+    assert fused.decode_rows == FUSED_SLOTS
+    plain = ChunkedPrefill(eng, srv._cache_shardings, FUSED_BUCKETS,
+                           attn_impl=srv.chunk_attn)
+    bucket, start, wfrom, valid, live = FUSED_CASES[case]
+    rng = np.random.RandomState(7)
+    p_max = srv.p_max
+    tbl = (1 + np.arange(FUSED_SLOTS * p_max, dtype=np.int32)
+           ).reshape(FUSED_SLOTS, p_max)
+    row = tbl[-1].copy()
+    live = np.asarray(live, np.int32)
+    lens = np.asarray([5, 11, 16, 0], np.int32) * live
+    tbl = tbl * live[:, None]
+    toks = rng.randint(0, eng.cfg.vocab_size, bucket).astype(np.int32)
+    dec_toks = rng.randint(0, eng.cfg.vocab_size,
+                           FUSED_SLOTS).astype(np.int32)
+
+    def pool():
+        """The same random pool twice: every program donates its own."""
+        r = np.random.RandomState(11)
+        c = srv.cache
+        fill = lambda a, sh: jax.device_put(
+            r.randn(*a.shape).astype(a.dtype), sh)
+        return dataclasses.replace(
+            c, k_pages=fill(c.k_pages, srv._cache_shardings.k_pages),
+            v_pages=fill(c.v_pages, srv._cache_shardings.v_pages))
+
+    def batch(c):
+        return dataclasses.replace(
+            c, block_table=jnp.asarray(tbl), lens=jnp.asarray(lens),
+            live=jnp.asarray(live))
+
+    chunk_ref, c = plain.step(eng.params, toks, pool(), row, start,
+                              wfrom, valid)
+    dec_ref, c_ref = srv._decode(eng.params, jnp.asarray(dec_toks),
+                                 batch(c))
+    if live.any():
+        chunk_got, dec_got, c_got = fused.step_decode(
+            eng.params, toks, batch(pool()), row, start, wfrom, valid,
+            jnp.asarray(dec_toks))
+        np.testing.assert_allclose(np.asarray(dec_got)[live == 1],
+                                   np.asarray(dec_ref)[live == 1],
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        chunk_got, c_got = fused.step(eng.params, toks, pool(), row,
+                                      start, wfrom, valid)
+    np.testing.assert_allclose(np.asarray(chunk_got),
+                               np.asarray(chunk_ref), rtol=1e-5,
+                               atol=1e-5)
+    # Every page but the scratch one (page 0: what padding and parked
+    # rows write, and nobody reads).
+    for got, ref in ((c_got.k_pages, c_ref.k_pages),
+                     (c_got.v_pages, c_ref.v_pages)):
+        np.testing.assert_allclose(np.asarray(got)[:, 1:],
+                                   np.asarray(ref)[:, 1:], rtol=1e-5,
+                                   atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(c_got.lens), lens + live)
+    np.testing.assert_array_equal(np.asarray(c_ref.lens), lens + live)
+    assert fused.cache_size() <= len(FUSED_BUCKETS)
+
+
+# The interpreted kernels take seconds a dispatch: fewer, shorter prompts.
+@pytest.mark.parametrize("attn,lens", [
+    ("ref", (13, 3, 21, 8, 5, 17, 2, 11)), ("flash", (9, 3, 6))])
+def test_decode_rides_chunks_token_exact(engine, attn, lens):
+    """Mixed lengths over few slots, so that ticks hold a chunk and
+    live decoders: the tokens are ``Engine.serve``'s, what ``step()``
+    returns adds up to ``decode_tokens``, some decode dispatches rode a
+    chunk program, and each bucket still has ONE compiled program."""
+    rng = np.random.RandomState(5)
+    prompts = [[int(t) for t in rng.randint(0, CFG.vocab_size, n)]
+               for n in lens]
+    want = [_baseline(engine, p, 6) for p in prompts]
+    srv = ServingEngine(engine, num_slots=3 if attn == "ref" else 2,
+                        page=SRV_PAGE, prefill_buckets=(4, 8),
+                        attn_impl=attn)
+    hs = [srv.submit(p, max_new_tokens=6) for p in prompts]
+    decoded = 0
+    for _ in range(400):
+        if srv.sched.idle:
+            break
+        decoded += srv.step()
+    assert [h.tokens for h in hs] == want
+    st = srv.stats()
+    assert decoded == st["decode_tokens"]
+    assert 0 < st["decode_dispatches_fused"] <= st["decode_dispatches"]
+    assert st["decode_dispatches_fused"] < st["decode_dispatches"], (
+        "ticks with no chunk run the decode program")
+    assert srv.prefill_cache_size() <= 2
+    assert srv.decode_cache_size() == 1
+    assert st["pool"]["used_pages"] == 0

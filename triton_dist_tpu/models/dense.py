@@ -240,6 +240,92 @@ def decode_step(params, token_ids, cache: KVCache, cfg: ModelConfig, *,
     return logits, cache.advance()
 
 
+def _paged_layers(params, x, positions, cache, cfg: ModelConfig, attend,
+                  *, mode, axis, ctxs, ffn_fn):
+    """The layer loop every paged step shares: replicated rows ``x``
+    (n, d) at per-row ``positions`` through norm → the decode-contract
+    projection → ``attend`` → output projection → FFN, then the final
+    norm. ``attend(li, q, k_tok, v_tok, cache) -> (o, cache)`` is what
+    tells the steps apart: where the rows' K/V are written and what
+    each row's query reads (``o``: anything that reshapes to (n, -1)).
+    Returns ``(x (n, d), cache)``."""
+    n = x.shape[0]
+    dec_mode = "xla" if mode == "xla" else "fused_ar"
+    for li, layer_params in enumerate(params["layers"]):
+        h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
+        q, k_tok, v_tok = tp_attn.decode_project(
+            layer_params["attn"], h, cfg, positions, axis=axis)
+        o, cache = attend(li, q, k_tok, v_tok, cache)
+        x = x + tp_attn.decode_output(
+            layer_params["attn"], o.reshape(n, -1), h, mode=dec_mode,
+            axis=axis, ar_ctx=ctxs.ar)
+        h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
+        if ffn_fn is None:
+            mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
+            x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
+                               axis=axis, ag_ctx=ctxs.ag, rs_ctx=ctxs.rs,
+                               ar_ctx=ctxs.ar)
+        else:
+            x = x + ffn_fn(layer_params, h)
+    return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache
+
+
+def _chunk_attend(li, q, cache, table_row, positions, start, valid,
+                  attn_impl):
+    """A prefill chunk's queries (C, 1, H_loc, hd) over its slot's
+    pages, causal by global position — after the chunk's own K/V were
+    written. Returns (C, H_loc, hd)."""
+    if attn_impl == "flash":
+        from triton_dist_tpu.ops.paged_flash_qblock import (
+            paged_flash_qblock)
+
+        # Bucket-padding rows clamp to the last VALID position:
+        # their outputs are discarded garbage either way, but
+        # unclamped they would stretch the kernel's page-walk
+        # bound (max position) to the padded tail — 8x the DMA
+        # traffic for exactly the short-prompt-in-a-big-bucket
+        # case the kernel exists to make cheap.
+        i = jnp.arange(positions.shape[0], dtype=jnp.int32)
+        last_valid = (jnp.asarray(start, jnp.int32)
+                      + jnp.maximum(jnp.asarray(valid, jnp.int32)
+                                    - 1, 0))
+        qpos = jnp.where(i < valid, positions, last_valid)
+        ksc, vsc = cache.layer_scales(li)
+        return paged_flash_qblock(
+            q[:, 0][None], cache.k_pages, cache.v_pages,
+            table_row[None], qpos[None], layer=li,
+            k_scale=ksc, v_scale=vsc)[0]
+    from triton_dist_tpu.ops.chunked_prefill import chunk_attend
+
+    kd, vd = cache.dense_row(li, table_row)
+    return chunk_attend(q[:, 0], kd, vd, positions)
+
+
+def _decode_attend(li, q, cache, attn_impl):
+    """One query a slot (S, 1, H_loc, hd) over the slot's pages at its
+    own length — after the step's token was appended."""
+    # Active slots attend including the token appended this step;
+    # parked slots clamp to 1 so a fully-masked row cannot NaN the
+    # softmax (their output is discarded anyway).
+    kv_len = jnp.maximum(cache.lens + cache.live, 1).astype(jnp.int32)
+    if attn_impl in ("kernel", "flash"):
+        from triton_dist_tpu.ops.paged_flash_decode import (
+            paged_flash_decode)
+
+        ksc, vsc = cache.layer_scales(li)
+        return paged_flash_decode(
+            q[:, 0], cache.k_pages, cache.v_pages, cache.block_table,
+            kv_len, layer=li, axis=None, k_scale=ksc, v_scale=vsc)
+    kd, vd = cache.dense_layer(li)
+    return tp_attn.sdpa(q, kd, vd, causal=False, kv_len=kv_len)
+
+
+def _last_valid_row(x, valid):
+    """Row ``valid - 1`` of a chunk's (C, d) rows, as (1, d)."""
+    return jax.lax.dynamic_slice_in_dim(
+        x, jnp.maximum(jnp.asarray(valid, jnp.int32) - 1, 0), 1, axis=0)
+
+
 def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
                       budget=None, mode: str = "xla", axis: str = "tp",
                       ctxs: FwdContexts = FwdContexts(),
@@ -279,19 +365,13 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     masked garbage the next block overwrites), and rolls page
     accounting back via ``BlockManager.truncate_to``.
     """
-    from triton_dist_tpu.ops.chunked_prefill import block_attend
-
     s, k = token_ids.shape
     x = params["embed"][token_ids.reshape(s * k)]     # (S·K, d)
-    dec_mode = "xla" if mode == "xla" else "fused_ar"
     lens = cache.lens
     positions = (lens[:, None]
                  + jnp.arange(k, dtype=jnp.int32)[None]).reshape(s * k)
 
-    for li, layer_params in enumerate(params["layers"]):
-        h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
-        q, k_tok, v_tok = tp_attn.decode_project(
-            layer_params["attn"], h, cfg, positions, axis=axis)
+    def attend(li, q, k_tok, v_tok, cache):
         hl, hd = q.shape[2], q.shape[3]
         kvl = k_tok.shape[2]
         cache = cache.append_block(
@@ -309,31 +389,20 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
                 lens[:, None] + cache.live[:, None]
                 * (jnp.arange(k, dtype=jnp.int32)[None] + 1), 1) - 1
             ksc, vsc = cache.layer_scales(li)
-            o = paged_flash_qblock(
+            return paged_flash_qblock(
                 q[:, 0].reshape(s, k, hl, hd), cache.k_pages,
                 cache.v_pages, cache.block_table, qpos, layer=li,
-                k_scale=ksc, v_scale=vsc)
-        else:
-            kd, vd = cache.dense_layer(li)
-            o = block_attend(q[:, 0].reshape(s, k, hl, hd), kd, vd,
-                             lens, cache.live)
-        x = x + tp_attn.decode_output(
-            layer_params["attn"], o.reshape(s * k, -1), h,
-            mode=dec_mode, axis=axis, ar_ctx=ctxs.ar)
-        h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
-        if ffn_fn is None:
-            mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
-            x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
-                               axis=axis, ag_ctx=ctxs.ag, rs_ctx=ctxs.rs,
-                               ar_ctx=ctxs.ar)
-        else:
-            x = x + ffn_fn(layer_params, h)
+                k_scale=ksc, v_scale=vsc), cache
+        from triton_dist_tpu.ops.chunked_prefill import block_attend
 
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    logits_loc = jnp.dot(x, params["lm_head"].T,
-                         preferred_element_type=jnp.float32)
-    logits = jax.lax.all_gather(logits_loc, axis, axis=1, tiled=True)
-    return logits.reshape(s, k, -1), cache
+        kd, vd = cache.dense_layer(li)
+        return block_attend(q[:, 0].reshape(s, k, hl, hd), kd, vd,
+                            lens, cache.live), cache
+
+    x, cache = _paged_layers(params, x, positions, cache, cfg, attend,
+                             mode=mode, axis=axis, ctxs=ctxs,
+                             ffn_fn=ffn_fn)
+    return _lm_head(params, x, axis).reshape(s, k, -1), cache
 
 
 def paged_cache_specs(axis: str = "tp", quantized: bool = False):
@@ -396,61 +465,21 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
     final chunk's logits seed the first generated token; earlier
     chunks' logits are discarded.
     """
-    from triton_dist_tpu.ops.chunked_prefill import chunk_attend
-
     c = chunk_toks.shape[0]
     x = params["embed"][chunk_toks]          # (C, d) replicated
-    dec_mode = "xla" if mode == "xla" else "fused_ar"
     positions = (jnp.asarray(start, jnp.int32)
                  + jnp.arange(c, dtype=jnp.int32))
 
-    for li, layer_params in enumerate(params["layers"]):
-        h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
-        q, k_tok, v_tok = tp_attn.decode_project(
-            layer_params["attn"], h, cfg, positions, axis=axis)
+    def attend(li, q, k_tok, v_tok, cache):
         cache = cache.write_chunk(li, k_tok, v_tok, table_row,
                                   positions, valid, wfrom)
-        if attn_impl == "flash":
-            from triton_dist_tpu.ops.paged_flash_qblock import (
-                paged_flash_qblock)
+        return _chunk_attend(li, q, cache, table_row, positions, start,
+                             valid, attn_impl), cache
 
-            # Bucket-padding rows clamp to the last VALID position:
-            # their outputs are discarded garbage either way, but
-            # unclamped they would stretch the kernel's page-walk
-            # bound (max position) to the padded tail — 8x the DMA
-            # traffic for exactly the short-prompt-in-a-big-bucket
-            # case the kernel exists to make cheap.
-            i = jnp.arange(c, dtype=jnp.int32)
-            last_valid = (jnp.asarray(start, jnp.int32)
-                          + jnp.maximum(jnp.asarray(valid, jnp.int32)
-                                        - 1, 0))
-            qpos = jnp.where(i < valid, positions, last_valid)
-            ksc, vsc = cache.layer_scales(li)
-            o = paged_flash_qblock(
-                q[:, 0][None], cache.k_pages, cache.v_pages,
-                table_row[None], qpos[None], layer=li,
-                k_scale=ksc, v_scale=vsc)[0]
-        else:
-            kd, vd = cache.dense_row(li, table_row)
-            o = chunk_attend(q[:, 0], kd, vd, positions)
-        x = x + tp_attn.decode_output(
-            layer_params["attn"], o.reshape(c, -1), h, mode=dec_mode,
-            axis=axis, ar_ctx=ctxs.ar)
-        h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
-        if ffn_fn is None:
-            mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
-            x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
-                               axis=axis, ag_ctx=ctxs.ag, rs_ctx=ctxs.rs,
-                               ar_ctx=ctxs.ar)
-        else:
-            x = x + ffn_fn(layer_params, h)
-
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    last = jax.lax.dynamic_slice_in_dim(
-        x, jnp.maximum(jnp.asarray(valid, jnp.int32) - 1, 0), 1, axis=0)
-    logits_loc = jnp.dot(last, params["lm_head"].T,
-                         preferred_element_type=jnp.float32)
-    logits = jax.lax.all_gather(logits_loc, axis, axis=1, tiled=True)
+    x, cache = _paged_layers(params, x, positions, cache, cfg, attend,
+                             mode=mode, axis=axis, ctxs=ctxs,
+                             ffn_fn=ffn_fn)
+    logits = _lm_head(params, _last_valid_row(x, valid), axis)
     return logits[0], cache
 
 
@@ -485,46 +514,72 @@ def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     ``ffn_fn(layer_params, h) -> h`` overrides the FFN block (the MoE
     model's hook), exactly as in :func:`decode_step`.
     """
-    b = token_ids.shape[0]
     x = params["embed"][token_ids]
-    dec_mode = "xla" if mode == "xla" else "fused_ar"
-    lens = cache.lens
-    # Active slots attend including the token appended this step;
-    # parked slots clamp to 1 so a fully-masked row cannot NaN the
-    # softmax (their output is discarded anyway).
-    kv_len = jnp.maximum(lens + cache.live, 1).astype(jnp.int32)
 
-    for li, layer_params in enumerate(params["layers"]):
-        h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
-        q, k_tok, v_tok = tp_attn.decode_project(
-            layer_params["attn"], h, cfg, lens, axis=axis)
+    def attend(li, q, k_tok, v_tok, cache):
         cache = cache.append_decode(li, k_tok, v_tok)
-        if attn_impl in ("kernel", "flash"):
-            from triton_dist_tpu.ops.paged_flash_decode import (
-                paged_flash_decode)
+        return _decode_attend(li, q, cache, attn_impl), cache
 
-            ksc, vsc = cache.layer_scales(li)
-            o = paged_flash_decode(
-                q[:, 0], cache.k_pages, cache.v_pages,
-                cache.block_table, kv_len, layer=li, axis=None,
-                k_scale=ksc, v_scale=vsc)
-        else:
-            kd, vd = cache.dense_layer(li)
-            o = tp_attn.sdpa(q, kd, vd, causal=False, kv_len=kv_len)
-        x = x + tp_attn.decode_output(
-            layer_params["attn"], o.reshape(b, -1), h, mode=dec_mode,
-            axis=axis, ar_ctx=ctxs.ar)
-        h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
-        if ffn_fn is None:
-            mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
-            x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
-                               axis=axis, ag_ctx=ctxs.ag, rs_ctx=ctxs.rs,
-                               ar_ctx=ctxs.ar)
-        else:
-            x = x + ffn_fn(layer_params, h)
+    x, cache = _paged_layers(params, x, cache.lens, cache, cfg, attend,
+                             mode=mode, axis=axis, ctxs=ctxs,
+                             ffn_fn=ffn_fn)
+    return _lm_head(params, x, axis), cache.advance()
 
-    x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    logits_loc = jnp.dot(x, params["lm_head"].T,
-                         preferred_element_type=jnp.float32)
-    logits = jax.lax.all_gather(logits_loc, axis, axis=1, tiled=True)
-    return logits, cache.advance()
+
+def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
+                       cfg: ModelConfig, *, start, wfrom, valid,
+                       mode: str = "xla", axis: str = "tp",
+                       ctxs: FwdContexts = FwdContexts(),
+                       attn_impl: str = "ref",
+                       decode_attn_impl: str = "ref", ffn_fn=None):
+    """One prefill chunk of one slot AND one decode step of the whole
+    batch in ONE program: :func:`prefill_chunk_paged` and
+    :func:`decode_step_paged` on the same pool, with every weight read
+    once for both.
+
+    The chunk's ``C`` rows and the batch's ``S`` decode rows go through
+    embedding, projections, output projection, FFN and final norm as
+    one row-concatenated ``(C + S, d)`` activation; only where the
+    K/V are written and what each query reads differs, and there each
+    half runs the code of the step it replaces: the chunk rows write
+    through ``table_row`` and attend causally by global position, the
+    decode rows append and attend through ``cache.block_table`` at
+    ``cache.lens`` (parked rows, ``live == 0``, write the scratch page;
+    the chunk's own slot is parked in the decode batch until its prompt
+    is resident, so neither half reads what the other writes). The head
+    runs once, over the chunk's last valid row and the decode rows.
+
+    Arguments as the two steps': ``chunk_toks`` (C,), ``token_ids``
+    (S,), scalars ``start``/``wfrom``/``valid`` and the table, lengths
+    and live mask all ride as data — the trace keys on ``C`` alone.
+    ``attn_impl`` is the chunk rows' ("ref" | "flash"),
+    ``decode_attn_impl`` the decode rows' ("ref" | "kernel" | "flash").
+
+    Returns ``(chunk logits (vocab,), decode logits (S, vocab),
+    cache.advance())``.
+    """
+    c = chunk_toks.shape[0]
+    x = params["embed"][jnp.concatenate([chunk_toks, token_ids])]
+    chunk_pos = (jnp.asarray(start, jnp.int32)
+                 + jnp.arange(c, dtype=jnp.int32))
+
+    def attend(li, q, k_tok, v_tok, cache):
+        # Both writes, then both reads: each kernel takes the pool as
+        # the layer's last writer left it, in place.
+        cache = cache.write_chunk(li, k_tok[:c], v_tok[:c], table_row,
+                                  chunk_pos, valid, wfrom)
+        cache = cache.append_decode(li, k_tok[c:], v_tok[c:])
+        o_chunk = _chunk_attend(li, q[:c], cache, table_row, chunk_pos,
+                                start, valid, attn_impl)
+        o_dec = _decode_attend(li, q[c:], cache, decode_attn_impl)
+        return jnp.concatenate(
+            [o_chunk.reshape(c, -1),
+             o_dec.reshape(q.shape[0] - c, -1)]), cache
+
+    x, cache = _paged_layers(
+        params, x, jnp.concatenate([chunk_pos, cache.lens]), cache, cfg,
+        attend, mode=mode, axis=axis, ctxs=ctxs, ffn_fn=ffn_fn)
+    logits = _lm_head(
+        params, jnp.concatenate([_last_valid_row(x[:c], valid), x[c:]]),
+        axis)
+    return logits[0], logits[1:], cache.advance()

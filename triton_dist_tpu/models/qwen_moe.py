@@ -261,6 +261,18 @@ def _sum_counts(counts, cfg: ModelConfig):
     return jnp.zeros((cfg.num_experts,), jnp.int32)
 
 
+def _ar_ffn(cfg: ModelConfig, moe_impl, axis, ep_ctx):
+    """The ``ffn_fn`` hook of the steps whose replicated rows take the
+    MoE FFN in the AR decode regime whatever the decode dispatch's
+    transport is: prefill chunks, verification, a chunk with the decode
+    batch aboard."""
+    import functools
+
+    return functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
+                             axis=axis, ep_ctx=ep_ctx, transport="ar",
+                             counts=None, _layer_cursor=[0])
+
+
 def paged_cache_specs(axis: str = "tp", quantized: bool = False):
     from triton_dist_tpu.models import dense as _dense
 
@@ -280,13 +292,9 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     ``transport``/``replicas``/counts are decode-dispatch knobs the
     verification contract ignores."""
     del transport, replicas, with_expert_counts
-    import functools
-
     from triton_dist_tpu.models import dense as _dense
 
-    ffn = functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
-                            axis=axis, ep_ctx=ep_ctx, transport="ar",
-                            counts=None, _layer_cursor=[0])
+    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
     return _dense.verify_step_paged(params, token_ids, cache, cfg,
                                     budget=budget, mode=mode, axis=axis,
                                     ctxs=ctxs, attn_impl=attn_impl,
@@ -307,13 +315,9 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
     decode-dispatch knobs — prefill chunks ignore them; decode keeps
     its own resolved transport."""
     del transport, replicas, with_expert_counts
-    import functools
-
     from triton_dist_tpu.models import dense as _dense
 
-    ffn = functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
-                            axis=axis, ep_ctx=ep_ctx, transport="ar",
-                            counts=None, _layer_cursor=[0])
+    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
     return _dense.prefill_chunk_paged(params, chunk_toks, cache,
                                       table_row, cfg, start=start,
                                       wfrom=wfrom, valid=valid,
@@ -349,3 +353,26 @@ def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     if not with_expert_counts:
         return out
     return out + (_sum_counts(counts, cfg),)
+
+
+def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
+                       cfg: ModelConfig, *, start, wfrom, valid,
+                       mode: str = "xla", axis: str = "tp",
+                       ctxs: FwdContexts = FwdContexts(),
+                       attn_impl: str = "ref",
+                       decode_attn_impl: str = "ref",
+                       moe_impl: str = "tp", ep_ctx=None):
+    """A prefill chunk and the batch's decode step in one program (see
+    ``dense.chunk_decode_paged``), all ``C + S`` replicated rows through
+    the MoE FFN in the AR decode regime, as the chunk's rows are in
+    :func:`prefill_chunk_paged`. The serving engine builds it only
+    where the decode dispatch has no transport of its own (the TP
+    expert regime): EP decode keeps its program."""
+    from triton_dist_tpu.models import dense as _dense
+
+    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
+    return _dense.chunk_decode_paged(
+        params, chunk_toks, token_ids, cache, table_row, cfg,
+        start=start, wfrom=wfrom, valid=valid, mode=mode, axis=axis,
+        ctxs=ctxs, attn_impl=attn_impl,
+        decode_attn_impl=decode_attn_impl, ffn_fn=ffn)

@@ -54,10 +54,11 @@ _OP_HIST_KINDS = frozenset({
 })
 
 # The fields an annotation carries as stats into a profiler capture:
-# the correlation keys, and the two counts read there (``batch`` of a
-# decode, ``waited_ms`` of an admission).
-_ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "bucket",
-                        "valid", "waited_ms"})
+# the correlation keys, and the counts read there (``batch`` of a
+# decode and whether it rode a chunk program, ``fused`` 0/1;
+# ``waited_ms`` of an admission).
+_ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
+                        "bucket", "valid", "waited_ms"})
 
 
 class _NullSpan:

@@ -8,7 +8,7 @@ composition (``ops/flash_decode.py``); this adds the kernel-level form:
 
 - **Paged KV**: the cache is a page pool ``(num_pages, KV, page, hd)``
   (or every layer's pool whole, ``(L, num_pages, KV, page, hd)``, with a
-  static ``layer``) plus a per-sequence ``block_table (B, P_max)`` of
+  ``layer`` index) plus a per-sequence ``block_table (B, P_max)`` of
   page ids (SMEM) — pages stream through VMEM one at a time via
   dynamic-index DMA, so arbitrary context lengths serve from a fixed
   pool (no dense (B, T) cache materialization, and no layer cut out of
@@ -119,28 +119,33 @@ def _lse_reduce(parts, hd: int):
 
 def _pool_layer(pool, layer):
     """A paged reader's view of its pool: ``(num_pages, KV, page, hd)``
-    as it is, or ``(L, num_pages, KV, page, hd)`` with a static Python
-    ``layer`` (the serving steps unroll their layers). Returns the
-    page-index prefix a kernel puts before the page id — ``()`` or
-    ``(layer,)`` — so a page is ``pool.at[(*prefix, pid)]`` either way
-    and the 5-D pool is never sliced outside the kernel."""
+    as it is, or ``(L, num_pages, KV, page, hd)`` with a ``layer`` (the
+    serving steps unroll their layers). Returns what the kernel takes
+    as its leading operand: ``None`` for the 4-D pool, else the layer as
+    a ``(1,)`` int32 array. The kernel reads it from SMEM and fetches a
+    page as ``pool.at[layer, pid]``, so the 5-D pool is never sliced
+    outside the kernel, and the layer is DATA: every layer of a step
+    program calls the same jitted function with the same shapes, which
+    JAX traces once and lowers to one Mosaic kernel (a layer baked into
+    the kernel made each call a kernel of its own to trace and lower:
+    16 a program with a chunk and a decode batch, most of its set-up
+    time, PERF.md section 6, PR 30)."""
     if pool.ndim == 5:
-        if not isinstance(layer, int):
+        if layer is None:
             raise ValueError(
-                "a 5-D (L, num_pages, KV, page, hd) pool needs a static "
-                f"int layer, got {layer!r}")
-        return (layer,)
+                "a 5-D (L, num_pages, KV, page, hd) pool needs a layer")
+        return jnp.asarray(layer, jnp.int32).reshape(1)
     if layer is not None:
         raise ValueError(
             f"layer={layer!r} given with a {pool.ndim}-D pool: only the "
             "whole 5-D pool is indexed by layer")
-    return ()
+    return None
 
 
 def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
                    kvh: int, rep: int, hd: int, shard_len: int,
                    paged: bool, sim: bool, quantized: bool = False,
-                   prefix: tuple = ()):
+                   layered: bool = False):
     """``axes``: list of (axis_name, n_ax) exchange stages, innermost
     first (1 entry = flat; 2 = hierarchical outer x inner, where the
     flat shard order is outer-major). ``paged=False`` reads a dense
@@ -151,8 +156,12 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     ``quantized=True``: the pools are int8/fp8 and two extra
     (B, P_max, KV) fp32 scale tables ride in VMEM — the dequant fuses
     into each page's compute step (:func:`page_attend`).
-    ``prefix``: :func:`_pool_layer`'s static index before the page id."""
+    ``layered=True``: the first operand is :func:`_pool_layer`'s layer
+    index, read from SMEM and put before the page id."""
     ks_ref = vs_ref = None
+    prefix = ()
+    if layered:
+        prefix, refs = (refs[0][0],), refs[1:]
     if paged and quantized:
         (table_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
          o_ref, part_gather) = refs[:9]
@@ -338,9 +347,31 @@ def _normalize_axes(axis, ctx, sim_ranks):
     return [(axis, n)], n, False
 
 
+def _check_kv_len(kv_len, p_max: int, page: int, n: int, sim: bool):
+    """Concrete lengths beyond the pool's capacity are an error:
+    positions past it would be dropped in silence."""
+    if isinstance(kv_len, jax.core.Tracer):
+        return
+    import numpy as _np
+
+    cap = p_max * page * (1 if sim else n)
+    lens_np = _np.asarray(kv_len)
+    if int(_np.max(lens_np)) > cap:
+        # Name the offending batch slot: a serving layer maps slots
+        # to requests, so "slot s outgrew its row" is actionable
+        # where a bare max() is not.
+        bad = int(_np.argmax(lens_np))
+        layout = (f"sim: local pool only, {p_max} pages x {page}"
+                  if sim else f"{n} ranks x {p_max} pages x {page}")
+        raise ValueError(
+            f"kv_len {int(lens_np[bad])} of batch slot {bad} "
+            f"exceeds one block-table row's capacity {cap} "
+            f"({layout}); the request is longer than its table row")
+
+
 def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
                  page, p_max, paged, sim_ranks=0, k_scale=None,
-                 v_scale=None, prefix=()):
+                 v_scale=None, layer=None):
     """Shared host plumbing for the paged and dense decode kernels."""
     b, h, hd = q.shape
     kvh = k_arr.shape[-3]
@@ -348,27 +379,12 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
     quantized = k_scale is not None
     axes, n, sim = _normalize_axes(axis, ctx, sim_ranks)
     shard_len = p_max * page
-    if not isinstance(kv_len, jax.core.Tracer):
-        import numpy as _np
-
-        cap = shard_len if sim else n * shard_len
-        lens_np = _np.asarray(kv_len)
-        if int(_np.max(lens_np)) > cap:
-            # Name the offending batch slot: a serving layer maps slots
-            # to requests, so "slot s outgrew its row" is actionable
-            # where a bare max() is not.
-            bad = int(_np.argmax(lens_np))
-            layout = (f"sim: local pool only, {p_max} pages x {page}"
-                      if sim else f"{n} ranks x {p_max} pages x {page}")
-            raise ValueError(
-                f"kv_len {int(lens_np[bad])} of batch slot {bad} "
-                f"exceeds one block-table row's capacity {cap} "
-                f"({layout}); the request is longer than its table row")
+    _check_kv_len(kv_len, p_max, page, n, sim)
 
     kernel = functools.partial(
         _decode_kernel, axes=axes, ctx=ctx, page=page, p_max=p_max,
         kvh=kvh, rep=rep, hd=hd, shard_len=shard_len, paged=paged,
-        sim=sim, quantized=quantized, prefix=prefix)
+        sim=sim, quantized=quantized, layered=layer is not None)
 
     n_sem = max(sum(n_ax - 1 for _, n_ax in axes), 1)
     n_slots = max(max(n_ax for _, n_ax in axes), 1)
@@ -393,6 +409,9 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
     if paged:
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.insert(0, block_table.astype(jnp.int32))
+    if layer is not None:
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.insert(0, layer)
 
     out, _ = core_call(
         kernel,
@@ -438,7 +457,7 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
     q: (B, H, hd) replicated along ``axis``;
     k_pages/v_pages: (num_pages, KV, page, hd) — this rank's page pool
     (head-major pages) — or every layer's pool whole, (L, num_pages,
-    KV, page, hd), with ``layer`` a static int: the kernel then fetches
+    KV, page, hd), with ``layer`` an int or int32 scalar: the kernel fetches
     ``pool.at[layer, pid]`` and the caller cuts no layer out of the pool
     (a slice XLA would copy, see :class:`~triton_dist_tpu.serving.blocks.
     PagedKVCache`); int8/fp8 pools additionally REQUIRE
@@ -458,14 +477,23 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
     outer peer crosses the slow link.
     Returns (B, H, hd).
     """
-    prefix = _pool_layer(k_pages, layer)
-    page = k_pages.shape[-2]
-    p_max = block_table.shape[1]
     _require_pool_scales(k_pages, k_scale, reject_spurious=True)
+    _check_kv_len(kv_len, block_table.shape[1], k_pages.shape[-2],
+                  *_normalize_axes(axis, ctx, 0)[1:])
+    return _paged_decode_call(q, k_pages, v_pages, block_table, kv_len,
+                              _pool_layer(k_pages, layer), k_scale,
+                              v_scale, ctx=ctx, axis=axis)
+
+
+@functools.partial(jax.jit, static_argnames=("ctx", "axis"))
+def _paged_decode_call(q, k_pages, v_pages, block_table, kv_len, layer,
+                       k_scale, v_scale, *, ctx, axis):
+    """:func:`paged_flash_decode` behind one jit: the layers of a step
+    program share its trace and its lowering (:func:`_pool_layer`)."""
     return _decode_call(q, k_pages, v_pages, block_table, kv_len,
-                        ctx=ctx, axis=axis, page=page, p_max=p_max,
-                        paged=True, k_scale=k_scale, v_scale=v_scale,
-                        prefix=prefix)
+                        ctx=ctx, axis=axis, page=k_pages.shape[-2],
+                        p_max=block_table.shape[1], paged=True,
+                        k_scale=k_scale, v_scale=v_scale, layer=layer)
 
 
 def paged_flash_decode_ref(q, k_pages, v_pages, block_table, kv_len,

@@ -80,7 +80,8 @@ def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
 
 
 def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
-                   hd: int, cq: int, quantized: bool, prefix: tuple = ()):
+                   hd: int, cq: int, quantized: bool,
+                   layered: bool = False):
     """Grid (B, KV, P_max): slot-major, then one KV head (and its
     ``rep`` query heads) at a time, then that head's page walk with the
     decode kernel's double-buffered prefetch (per-parity semaphores);
@@ -91,9 +92,13 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     against its 16 MB limit. No partial exchange — this is the LOCAL
     (axis=None) form, the layout the serving engine's TP-head-sharded
     pools use (every rank holds the full sequence for its heads).
-    ``prefix``: :func:`~triton_dist_tpu.ops.paged_flash_decode.
-    _pool_layer`'s static index before the page id."""
+    ``layered=True``: the first operand is :func:`~triton_dist_tpu.ops.
+    paged_flash_decode._pool_layer`'s layer index, read from SMEM and
+    put before the page id."""
     ks_ref = vs_ref = None
+    prefix = ()
+    if layered:
+        prefix, refs = (refs[0][0],), refs[1:]
     if quantized:
         (table_ref, end_ref, pos_ref, q_ref, kp_ref, vp_ref, ks_ref,
          vs_ref, o_ref) = refs[:9]
@@ -215,7 +220,7 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
     q: (B, Cq, H, hd) — Cq queries per slot (head-major, this rank's
     heads); k_pages/v_pages: (num_pages, KV, page, hd) — this rank's
     page pool — or every layer's pool whole, (L, num_pages, KV, page,
-    hd), with ``layer`` a static int (the kernel fetches
+    hd), with ``layer`` an int or int32 scalar (the kernel fetches
     ``pool.at[layer, pid]``; no layer is cut out of the pool, which XLA
     would copy) — every attended key already resident (the chunk writer /
     candidate block append runs BEFORE the attend, exactly like the
@@ -235,12 +240,8 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
     cannot hold the key a query asks for).
     Returns (B, Cq, H, hd).
     """
-    b, cq, h, hd = q.shape
-    prefix = _pool_layer(k_pages, layer)
-    kvh, page = k_pages.shape[-3:-1]
+    page = k_pages.shape[-2]
     p_max = block_table.shape[1]
-    rep = h // kvh
-    quantized = k_scale is not None
     _require_pool_scales(k_pages, k_scale, reject_spurious=True)
     positions = jnp.maximum(jnp.asarray(positions, jnp.int32), 0)
     if not isinstance(positions, jax.core.Tracer):
@@ -255,6 +256,20 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
                 f"exceeds one block-table row's capacity {cap} "
                 f"({p_max} pages x {page}); the query asks for a key "
                 "its table row cannot hold")
+    return _qblock_call(q, k_pages, v_pages, block_table, positions,
+                        _pool_layer(k_pages, layer), k_scale, v_scale)
+
+
+@jax.jit
+def _qblock_call(q, k_pages, v_pages, block_table, positions, layer,
+                 k_scale, v_scale):
+    """:func:`paged_flash_qblock` behind one jit: the layers of a step
+    program share its trace and its lowering (``_pool_layer``)."""
+    b, cq, h, hd = q.shape
+    kvh, page = k_pages.shape[-3:-1]
+    p_max = block_table.shape[1]
+    rep = h // kvh
+    quantized = k_scale is not None
     # A Q-block too large for VMEM splits into row blocks that ride the
     # grid as extra slots sharing their slot's table row. Each gets its
     # own page-skip bound, so an early block of a causal chunk also
@@ -272,7 +287,7 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
 
     kernel = functools.partial(
         _qblock_kernel, page=page, p_max=p_max, kvh=kvh, rep=rep,
-        hd=hd, cq=bq, quantized=quantized, prefix=prefix)
+        hd=hd, cq=bq, quantized=quantized, layered=layer is not None)
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),          # block_table
@@ -296,6 +311,9 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale[block_table].astype(jnp.float32),
                      v_scale[block_table].astype(jnp.float32)]
+    if layer is not None:
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.insert(0, layer)
 
     out = core_call(
         kernel,

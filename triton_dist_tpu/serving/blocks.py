@@ -209,7 +209,7 @@ class PagedKVCache:
     keep shape but neither advance nor persist their appends).
 
     The pools keep ONE layout, row-major, inside every paged program:
-    the kernels take them whole with a static ``layer`` (nothing cuts
+    the kernels take them whole with a ``layer`` index (nothing cuts
     ``k_pages[layer]`` out) and the unquantized writers update them in
     place (:func:`_put_rows`, :func:`_merge_pages`). A program that
     asks for the pool in another layout, as a row scatter does, pays a

@@ -47,10 +47,21 @@ class ChunkedPrefill:
     one entry per bucket — :meth:`step` asserts that invariant after
     every dispatch (the prefill half of the serving no-recompilation
     gate).
+
+    ``decode_rows``: how many decode rows every chunk program carries
+    beside its chunk (``decode_attn`` their attention). 0, the default,
+    is the chunk program alone: a disaggregated worker's, whose pool no
+    decoder reads. The in-place serving engine passes its ``num_slots``:
+    each bucket's ONE program is then
+    :func:`models.dense.chunk_decode_paged`, the chunk and the decode
+    batch's step with every weight read once. :meth:`step_decode` gives
+    it a decode batch; :meth:`step` parks all its decode rows. Either
+    way one program a bucket, so the gate above is unchanged.
     """
 
     def __init__(self, engine, cache_shardings, buckets: Sequence[int],
-                 *, attn_impl: str = "ref", telemetry=None):
+                 *, attn_impl: str = "ref", telemetry=None,
+                 decode_rows: int = 0, decode_attn: str = "ref"):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -86,22 +97,50 @@ class ChunkedPrefill:
         kv_spec = model.paged_cache_specs(
             axis, quantized=cache_shardings.k_scale is not None)
 
-        def _chunk(params, toks, cache, table_row, start, wfrom, valid):
-            return model.prefill_chunk_paged(
-                params, toks, cache, table_row, cfg, start=start,
-                wfrom=wfrom, valid=valid, mode=engine.mode, axis=axis,
-                ctxs=engine.ctxs, attn_impl=attn_impl, **mk)
+        self.decode_rows = int(decode_rows)
+        # What :meth:`step` feeds the decode rows (see
+        # :meth:`_parked_rows`), made at its first call.
+        self._parked = None
+        if not self.decode_rows:
+            def _chunk(params, toks, cache, table_row, start, wfrom,
+                       valid):
+                return model.prefill_chunk_paged(
+                    params, toks, cache, table_row, cfg, start=start,
+                    wfrom=wfrom, valid=valid, mode=engine.mode,
+                    axis=axis, ctxs=engine.ctxs, attn_impl=attn_impl,
+                    **mk)
+
+            dec_in = dec_out = dec_sh = ()
+        elif not hasattr(model, "chunk_decode_paged"):
+            raise NotImplementedError(
+                f"model {getattr(model, '__name__', model)!r} has no "
+                "chunk_decode_paged — decode rows ride a chunk program "
+                "through that contract (models.dense / models.qwen_moe)")
+        else:
+            # Under the chunk program's name: one program a bucket,
+            # at the same place in a profile.
+            def _chunk(params, toks, cache, table_row, start, wfrom,
+                       valid, dec_toks):
+                return model.chunk_decode_paged(
+                    params, toks, dec_toks, cache, table_row, cfg,
+                    start=start, wfrom=wfrom, valid=valid,
+                    mode=engine.mode, axis=axis, ctxs=engine.ctxs,
+                    attn_impl=attn_impl, decode_attn_impl=decode_attn,
+                    **mk)
+
+            dec_in, dec_out = (P(None),), (P(None, None),)
+            dec_sh = (NamedSharding(mesh, P(None, None)),)
 
         self._chunk = jax.jit(
             jax.shard_map(
                 _chunk, mesh=mesh,
                 in_specs=(engine._specs, P(None), kv_spec, P(None),
-                          P(), P(), P()),
-                out_specs=(P(None), kv_spec),
+                          P(), P(), P()) + dec_in,
+                out_specs=(P(None),) + dec_out + (kv_spec,),
                 check_vma=False),
             donate_argnums=(2,),
-            out_shardings=(NamedSharding(mesh, P(None)),
-                           cache_shardings))
+            out_shardings=(NamedSharding(mesh, P(None)),) + dec_sh
+            + (cache_shardings,))
 
     def plan(self, n_tokens: int) -> List[Tuple[int, int]]:
         """Deterministic ``[(bucket, valid), ...]`` cover of
@@ -116,15 +155,59 @@ class ChunkedPrefill:
              start: int, wfrom: int, valid: int):
         """Dispatch one chunk; returns ``(logits (vocab,), cache)``.
         ``toks`` is (bucket,) int32 padded; scalars ride as int32 data
-        so the trace signature depends only on the bucket length."""
+        so the trace signature depends only on the bucket length. A
+        program that carries decode rows runs with all of them parked
+        (and returns the pool with no slot live)."""
+        if not self.decode_rows:
+            return self._dispatch(params, toks, cache, table_row, start,
+                                  wfrom, valid)
+        dec_toks, cache = self._parked_rows(cache)
+        logits, _, cache = self._dispatch(params, toks, cache, table_row,
+                                          start, wfrom, valid, dec_toks)
+        return logits, cache
+
+    def step_decode(self, params, toks: np.ndarray, cache, table_row,
+                    start: int, wfrom: int, valid: int, dec_toks):
+        """Dispatch one chunk with a decode batch aboard (a chunker
+        built with ``decode_rows``): ``dec_toks`` (decode_rows,) are the
+        batch's input tokens and ``cache`` carries its block table,
+        lengths and live mask, as the decode dispatch's does. Returns
+        ``(chunk logits (vocab,), decode logits (decode_rows, vocab),
+        cache)`` with the live slots' lengths advanced."""
+        return self._dispatch(params, toks, cache, table_row, start,
+                              wfrom, valid, dec_toks)
+
+    def _parked_rows(self, cache):
+        """``(dec_toks, cache)`` with every decode row parked: scratch
+        table row, length 0, not live. Uploaded as the serving engine
+        uploads a live batch's (plain host arrays), so a parked and a
+        ridden dispatch share one compiled program; the three cache
+        leaves anew at every call, since the pool's donation takes them
+        along."""
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        if self._parked is None:
+            self._parked = (
+                jnp.asarray(np.zeros((self.decode_rows,), np.int32)),
+                np.zeros(cache.block_table.shape, np.int32),
+                np.zeros(cache.lens.shape, np.int32))
+        dec_toks, table, rows = self._parked
+        return dec_toks, dataclasses.replace(
+            cache, block_table=jnp.asarray(table), lens=jnp.asarray(rows),
+            live=jnp.asarray(rows))
+
+    def _dispatch(self, params, toks, cache, table_row, start, wfrom,
+                  valid, *dec_toks):
         import jax.numpy as jnp
 
         tel = self.telemetry
         t0 = tel.now() if tel is not None and tel.enabled else None
-        logits, cache = self._chunk(
+        out = self._chunk(
             params, jnp.asarray(toks, jnp.int32), cache,
             jnp.asarray(table_row, jnp.int32), np.int32(start),
-            np.int32(wfrom), np.int32(valid))
+            np.int32(wfrom), np.int32(valid), *dec_toks)
         if t0 is not None:
             # Host dispatch time (the chunk result is async; the
             # request-level wait is the server's prefill_chunk span) +
@@ -145,7 +228,7 @@ class ChunkedPrefill:
                 f"{len(self.buckets)} buckets {self.buckets} — the "
                 "chunk dispatch re-specialized on something other "
                 "than the bucket length")
-        return logits, cache
+        return out
 
     def cache_size(self) -> int:
         """Jit-cache entries of the chunk dispatch (≤ bucket count) —

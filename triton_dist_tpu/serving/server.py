@@ -423,7 +423,8 @@ class ServingEngine:
         self.cfg = engine.cfg
         self.max_len = engine.max_len
         self.stats_counters = {
-            "decode_dispatches": 0, "tokens_generated": 0,
+            "decode_dispatches": 0, "decode_dispatches_fused": 0,
+            "tokens_generated": 0,
             "prefill_tokens": 0, "prefill_calls": 0, "admit_stalls": 0,
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
             "decode_tokens": 0, "prefill_chunks": 0, "migrated_pages": 0,
@@ -446,6 +447,9 @@ class ServingEngine:
         # at its PrefillWorker, None = monolithic prefill.
         self._prefiller = None
         self.chunker = None
+        # Whether this engine's own chunker carries the decode batch
+        # (set where the layer path builds it; never by the caller).
+        self._rides = False
 
         if self.mega:
             # kv_dtype / spec_k are ENGINE knobs on the megakernel lane
@@ -634,14 +638,6 @@ class ServingEngine:
         # blit, chunk steps, page-migration scatter) must return leaves
         # with EXACTLY these, or the decode dispatch re-specializes.
         self._cache_shardings = shardings
-        if self.prefill_buckets:
-            from triton_dist_tpu.serving.chunked import ChunkedPrefill
-
-            self.chunker = ChunkedPrefill(eng, shardings,
-                                          self.prefill_buckets,
-                                          attn_impl=self.chunk_attn,
-                                          telemetry=self.obs)
-            self._prefiller = self
 
         # EP-MoE decode: resolve the transport knob ONCE (host-side,
         # against the tune cache, with the true decode batch shape) so
@@ -721,6 +717,24 @@ class ServingEngine:
             raise ValueError(
                 "transport/replica_slots are EP-MoE decode knobs; "
                 "this engine serves a non-EP model")
+
+        if self.prefill_buckets:
+            from triton_dist_tpu.serving.chunked import ChunkedPrefill
+
+            # In-place chunked prefill writes the pool the decoders
+            # read, so each bucket's one program carries the decode
+            # batch too (docs/serving.md, "The decode batch rides the
+            # chunk's program"). Not where the decode dispatch is
+            # another program than the plain step: speculation verifies
+            # K tokens a slot, EP decode has its transport, replicas
+            # and expert counts.
+            self._rides = not (self.spec_k or self.ep or self.ep2d)
+            self.chunker = ChunkedPrefill(
+                eng, shardings, self.prefill_buckets,
+                attn_impl=self.chunk_attn, telemetry=self.obs,
+                decode_rows=num_slots if self._rides else 0,
+                decode_attn=self.attn_impl)
+            self._prefiller = self
 
         # Pinned cache out_shardings on the decode dispatch too: every
         # producer of the pool (init device_put, prompt writer, chunk
@@ -884,7 +898,10 @@ class ServingEngine:
     def step(self) -> int:
         """One serving tick: deadlines → admission/prefill → one joint
         decode dispatch → per-slot token handling. Returns how many
-        live slots decoded (0 = idle tick)."""
+        live slots decoded (0 = idle tick). Where the tick has a
+        prefill chunk and this engine's chunker carries the decode
+        batch, the decode step rides the chunk's program instead of a
+        dispatch of its own (:meth:`_fused_tick`)."""
         tick = self.stats_counters["ticks"]
         self.stats_counters["ticks"] = tick + 1
         # The root span: every span and event below carries ``tick``,
@@ -894,7 +911,9 @@ class ServingEngine:
             with self.obs.span("schedule"):
                 self._schedule()
             if self._prefiller is not None:
-                self._advance_chunks()
+                decoded = self._advance_chunks()
+                if decoded is not None:
+                    return decoded
             return self._decode_tick()
 
     def _schedule(self):
@@ -1638,10 +1657,84 @@ class ServingEngine:
     def _advance_chunks(self):
         """One bucketed chunk per prefilling slot per tick — long
         prompts interleave with the decode batch instead of
-        monopolizing the dispatch."""
-        for h in list(self.sched.running()):
+        monopolizing the dispatch. Where this engine's chunker carries
+        the decode batch and the tick has both a chunk and a live
+        decoder, the batch rides the tick's first chunk program
+        (:meth:`_fused_tick`) and the count of sequences that decoded
+        is returned; otherwise None, and the caller runs the decode
+        tick."""
+        prefilling = [h for h in self.sched.running()
+                      if h.status == "prefill"]
+        if self._rides and prefilling:
+            active = [h for h in self.sched.running()
+                      if h.status == "running"]
+            if active:
+                with self.obs.span("decode_prep"):
+                    active, tbl = self._decode_prep(active)
+            if active:
+                return self._fused_tick(prefilling, active, tbl)
+        for h in prefilling:
+            logits = self._advance_chunk(h)
+            if logits is not None:
+                self._finish_prefill(h, logits)
+        return None
+
+    def _fused_tick(self, prefilling, active, tbl) -> int:
+        """A tick whose decode batch rides its first chunk's program:
+        the weights are read once for the chunk and the step. The
+        tick's other chunks (decode rows parked) are enqueued behind
+        that program BEFORE the host waits on it, so the fetch and the
+        sampling run under them; slots whose last chunk ran go live
+        after the decode rows' tokens and decode from the next tick.
+
+        A fault is contained where its scope is: one raised at
+        ``chunked_prefill`` fails the chunk's request alone, as
+        :meth:`_advance_chunk` does, and the decoders (whose lengths
+        never advanced) redo their step next tick; anything else — a
+        drop at ``serving_decode``, a watchdog miss on the joint
+        program — is the decode tick's containment, and the chunk's
+        request goes with it."""
+        import jax.numpy as jnp
+        from triton_dist_tpu.resilience import faults
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
+
+        first = prefilling[0]
+        finished = []          # (handle, its last chunk's logits)
+        t0 = time.perf_counter()
+        try:
+            with self.obs.span(
+                    "decode", step=self.stats_counters["decode_dispatches"],
+                    batch=len(active), fused=1):
+                with self.obs.span("decode_enqueue"):
+                    batch = tuple(jnp.asarray(a) for a in (
+                        self._toks, tbl, self._lens, self._live))
+                chunk_logits, dec, plan = self._enqueue_chunk(first, batch)
+                for h in prefilling[1:]:
+                    logits = self._advance_chunk(h)
+                    if logits is not None:
+                        finished.append((h, logits))
+                with self.obs.span("decode_wait"):
+                    dec = self._wait_decode(dec)
+                with self.obs.span("decode_fetch"):
+                    dec = np.asarray(dec)
+        except (CommTimeoutError, faults.InjectedFault) as e:
+            if getattr(e, "op", None) == "chunked_prefill":
+                self._chunk_failed(first, e)
+            else:
+                self._decode_contain(e)
+                if first.status == "prefill":
+                    self._fail(first, "timeout" if isinstance(
+                        e, CommTimeoutError) else "failed", e)
+            decoded = 0
+        else:
+            if self._chunk_done(first, plan):
+                finished.insert(0, (first, chunk_logits))
+            self.stats_counters["decode_dispatches_fused"] += 1
+            decoded = self._decode_commit(active, dec, t0)
+        for h, logits in finished:
             if h.status == "prefill":
-                self._advance_chunk(h)
+                self._finish_prefill(h, logits)
+        return decoded
 
     def _run_op_with_retry(self, op: str, fn, retry_on=None):
         """Run one retryable serving op under its configured
@@ -1711,6 +1804,36 @@ class ServingEngine:
         return False
 
     def _advance_chunk(self, h: RequestHandle):
+        """Dispatch ``h``'s next chunk and book it; a chunk that fails
+        past its retries fails ``h`` alone. Returns the chunk's logits
+        (still on the device) if it was the prompt's last — the caller
+        owes ``h`` its :meth:`_finish_prefill` — else None."""
+        from triton_dist_tpu.resilience import faults
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
+
+        if h.status != "prefill":
+            # An earlier chunk of this tick took it out of the stream
+            # (a failover requeues every in-flight prefill).
+            return None
+        try:
+            logits, _, plan = self._enqueue_chunk(h)
+        except (CommTimeoutError, faults.InjectedFault) as e:
+            self._chunk_failed(h, e)
+            return None
+        return logits if self._chunk_done(h, plan) else None
+
+    def _enqueue_chunk(self, h: RequestHandle, batch=None):
+        """Dispatch ``h``'s next chunk under the ``chunked_prefill``
+        fault scope and retry policy; raises what outlives the retries.
+        With ``batch`` (the decode batch's uploaded tokens, table,
+        lengths and live mask) the decode step rides the same program:
+        the attempt opens the ``serving_decode`` scope too, either op's
+        policy absorbs a TRANSIENT drop (raised at a scope's entry,
+        before anything is dispatched), and a wedge is not retried, as
+        on the decode dispatch. Returns ``(chunk logits, decode logits
+        or None, (start, bucket, valid))``, all still on the device."""
+        import dataclasses as _dc
+
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import (
             CommTimeoutError, block_until_ready)
@@ -1726,15 +1849,27 @@ class ServingEngine:
             # Replay-idempotent: a retried chunk rewrites the same
             # positions of the same pages with the same bytes
             # (quantized pools re-merge to the identical amax), and
-            # prefix pages stay scratch-routed below ``wfrom``. One
-            # span per ATTEMPT — retries show as repeated chunk spans
-            # interleaved with their retry events.
+            # prefix pages stay scratch-routed below ``wfrom``; the
+            # decode rows aboard append at lengths that advance only
+            # on success. One span per ATTEMPT — retries show as
+            # repeated chunk spans interleaved with their retry events.
             with self.obs.span("prefill_chunk",
                                request_id=h.request.request_id,
                                slot=slot, tenant=h.request.tenant,
                                start=int(start), bucket=int(bucket),
                                valid=int(valid)), \
                     faults.on_op_call("chunked_prefill"):
+                if batch is not None:
+                    dec_toks, tbl, lens, live = batch
+                    with faults.on_op_call("serving_decode"):
+                        logits, dec, p.cache = p.chunker.step_decode(
+                            p.engine.params, toks,
+                            _dc.replace(p.cache, block_table=tbl,
+                                        lens=lens, live=live),
+                            row, start, h.resident, valid, dec_toks)
+                    # The copy is asked for now, behind the program.
+                    dec.copy_to_host_async()
+                    return logits, dec
                 logits, p.cache = p.chunker.step(
                     p.engine.params, toks, p.cache, row, start,
                     h.resident, valid)
@@ -1745,35 +1880,53 @@ class ServingEngine:
                         progress_fn=lambda: {
                             "slot": slot, "chunk_start": start,
                             "chunks": list(h.chunks)})
-            return logits
+            return logits, None
 
         try:
-            logits = self._run_op_with_retry("chunked_prefill",
-                                             _attempt)
-        except (CommTimeoutError, faults.InjectedFault) as e:
-            # Retries exhausted. A dying prefill worker fails over
-            # (this handle requeues with the rest of its in-flight
-            # work); otherwise a wedged / dropped chunk fails THIS
-            # request only (slot and pages released) and the loop
-            # keeps serving.
-            if isinstance(e, CommTimeoutError):
-                self.stats_counters["comm_timeouts"] += 1
-            if self._note_role_failure("prefill", e):
-                return
-            self._fail(h, "timeout" if isinstance(e, CommTimeoutError)
-                       else "failed", e)
-            return
+            if batch is None:
+                logits, dec = self._run_op_with_retry("chunked_prefill",
+                                                      _attempt)
+            else:
+                drops = (faults.InjectedFault,)
+                logits, dec = self._run_op_with_retry(
+                    "serving_decode",
+                    lambda: self._run_op_with_retry(
+                        "chunked_prefill", _attempt, retry_on=drops),
+                    retry_on=drops)
+        except (CommTimeoutError, faults.InjectedFault):
+            raise
         except Exception as e:  # noqa: BLE001 — release, then surface
             self._fail(h, "failed", e)
             raise
+        return logits, dec, (start, bucket, valid)
+
+    def _chunk_failed(self, h: RequestHandle, e):
+        """A chunk was wedged or dropped past its retries. A dying
+        prefill worker fails over (``h`` requeues with the rest of its
+        in-flight work); otherwise it fails THIS request only (slot and
+        pages released) and the loop keeps serving."""
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
+
+        if isinstance(e, CommTimeoutError):
+            self.stats_counters["comm_timeouts"] += 1
+        if self._note_role_failure("prefill", e):
+            return
+        self._fail(h, "timeout" if isinstance(e, CommTimeoutError)
+                   else "failed", e)
+
+    def _chunk_done(self, h: RequestHandle, plan) -> bool:
+        """Book a dispatched chunk: counters and the compute cursor.
+        True once the whole prompt is resident."""
+        start, bucket, valid = plan
         self._note_role_ok("prefill")
         self.stats_counters["prefill_chunks"] += 1
         self.stats_counters["prefill_tokens"] += valid
         h.chunks.append((start, bucket, valid))
         h.prompt_pos = start + valid
-        if h.prompt_pos >= len(seq):
-            self.stats_counters["prefill_calls"] += 1
-            self._finish_prefill(h, logits)
+        if h.prompt_pos < len(h.lane):
+            return False
+        self.stats_counters["prefill_calls"] += 1
+        return True
 
     def _finish_prefill(self, h: RequestHandle, logits):
         """Prompt fully resident: activate the slot (in-place chunked
@@ -2235,6 +2388,7 @@ class ServingEngine:
             return 0
 
         from triton_dist_tpu.resilience import faults
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
         t0 = time.perf_counter()
         try:
@@ -2256,38 +2410,44 @@ class ServingEngine:
                 with self.obs.span(
                         "decode",
                         step=self.stats_counters["decode_dispatches"],
-                        batch=len(active)), \
+                        batch=len(active), fused=0), \
                         faults.on_op_call("serving_decode"):
                     return self._dispatch(tbl)
 
             logits = self._run_op_with_retry(
                 "serving_decode", _attempt,
                 retry_on=(faults.InjectedFault,))
-        except Exception as e:  # noqa: BLE001 — route through policy
-            from triton_dist_tpu.resilience.watchdog import (
-                CommTimeoutError)
-
-            if not isinstance(e, (CommTimeoutError,
-                                  faults.InjectedFault)):
-                raise
-            timed_out = isinstance(e, CommTimeoutError)
-            if timed_out:
-                self.stats_counters["comm_timeouts"] += 1
-                self.obs.event("timeout", op="serving.decode")
-            if self.mega and getattr(self.engine, "states",
-                                     None) is not None:
-                # Hybrid GDN: the recurrent state is NOT position-
-                # addressed, so a retried step would advance survivors'
-                # states twice for one token — no exact recovery
-                # exists. Fail every in-flight request; the server (and
-                # new requests, via reset_slot) stay healthy.
-                victims = list(self.sched.running())
-            else:
-                victims = self.sched.timeout_victims()
-            for victim in victims:
-                self._fail(victim, "timeout" if timed_out else "failed",
-                           e)
+        except (CommTimeoutError, faults.InjectedFault) as e:
+            self._decode_contain(e)
             return 0
+        return self._decode_commit(active, logits, t0)
+
+    def _decode_contain(self, e):
+        """The joint decode was wedged or dropped: fail the victim(s),
+        not the server — the survivors redo the identical step."""
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
+
+        timed_out = isinstance(e, CommTimeoutError)
+        if timed_out:
+            self.stats_counters["comm_timeouts"] += 1
+            self.obs.event("timeout", op="serving.decode")
+        if self.mega and getattr(self.engine, "states",
+                                 None) is not None:
+            # Hybrid GDN: the recurrent state is NOT position-
+            # addressed, so a retried step would advance survivors'
+            # states twice for one token — no exact recovery
+            # exists. Fail every in-flight request; the server (and
+            # new requests, via reset_slot) stay healthy.
+            victims = list(self.sched.running())
+        else:
+            victims = self.sched.timeout_victims()
+        for victim in victims:
+            self._fail(victim, "timeout" if timed_out else "failed", e)
+
+    def _decode_commit(self, active, logits, t0) -> int:
+        """Book a decode step that ran for ``active``: counters, the
+        length mirrors, and each slot's token from its row of the host
+        ``logits``. Returns how many sequences decoded."""
         self.stats_counters["decode_time_s"] += time.perf_counter() - t0
         self.stats_counters["decode_dispatches"] += 1
         self._maybe_rebalance()
@@ -2724,9 +2884,7 @@ class ServingEngine:
         (``decode_wait``), their copy to the host (``decode_fetch``)."""
         import dataclasses as _dc
 
-        import jax
         import jax.numpy as jnp
-        from triton_dist_tpu.resilience.watchdog import block_until_ready
 
         with self.obs.span("decode_enqueue"):
             toks = jnp.asarray(self._toks)
@@ -2753,25 +2911,31 @@ class ServingEngine:
             # inside the watchdog-bounded wait, or a wedged collective
             # would hang the host in the counts conversion below
             # before the deadline ever fires.
-            guarded = (out if ecounts is None else (out, ecounts))
-            if self.timeout_s is None:
-                jax.block_until_ready(guarded)
-            else:
-                guarded = block_until_ready(
-                    guarded, timeout_s=self.timeout_s,
-                    op="serving.decode",
-                    progress_fn=lambda: {
-                        "lens": self._lens.tolist(),
-                        "live": self._live.tolist(),
-                        **{k: self.stats_counters[k] for k in
-                           ("decode_dispatches", "tokens_generated")}})
-                out, ecounts = (guarded if ecounts is not None
-                                else (guarded, None))
+            guarded = self._wait_decode(
+                out if ecounts is None else (out, ecounts))
+            out, ecounts = (guarded if ecounts is not None
+                            else (guarded, None))
         with self.obs.span("decode_fetch"):
             if ecounts is not None:
                 self._note_expert_counts(
                     np.asarray(ecounts).astype(np.int64))
             return np.asarray(out)
+
+    def _wait_decode(self, outputs):
+        """Block until a decode step's ``outputs`` exist, under the
+        watchdog where ``timeout_s`` arms one; returns them."""
+        import jax
+        from triton_dist_tpu.resilience.watchdog import block_until_ready
+
+        if self.timeout_s is None:
+            return jax.block_until_ready(outputs)
+        return block_until_ready(
+            outputs, timeout_s=self.timeout_s, op="serving.decode",
+            progress_fn=lambda: {
+                "lens": self._lens.tolist(),
+                "live": self._live.tolist(),
+                **{k: self.stats_counters[k] for k in
+                   ("decode_dispatches", "tokens_generated")}})
 
     # -- expert-load telemetry + hot-expert rebalancing --------------
 
