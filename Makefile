@@ -12,13 +12,16 @@ PY ?= python
 csrc:
 	$(MAKE) -C csrc
 
-# PYTEST_ARGS lets CI deselect files covered by dedicated jobs
-# (e.g. --ignore=tests/test_multihost.py).
+# Tier-1 as the driver runs it (`commands` in /root/TESTS_LAST_RUN.json):
+# six workers, the `slow` tier left out. Its wall time is what a PR is
+# judged on (docs/testing.md). PYTEST_ARGS lets CI deselect files covered
+# by dedicated jobs (e.g. --ignore=tests/test_multihost.py).
 test: csrc
-	$(PY) -m pytest tests/ -x -q $(PYTEST_ARGS)
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
+	    -p xdist -n 6 --dist load $(PYTEST_ARGS)
 
 # Sub-2-minute smoke tier for iteration (primitives, collectives,
-# low-latency family, tools; the full battery stays the merge gate).
+# low-latency family, tools; tier-1, `make test`, stays the merge gate).
 quick: csrc
 	$(PY) -m pytest tests/test_shmem.py tests/test_tools.py \
 	    tests/test_low_latency.py tests/test_collectives.py -x -q

@@ -7,6 +7,7 @@ simulated-multi-rank harness the reference only has for Ascend
 (``test/ascend/conftest.py:31-44`` run_dist_test).
 """
 
+import faulthandler
 import os
 
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -24,59 +25,42 @@ from triton_dist_tpu.parallel.mesh import MeshContext  # noqa: E402
 
 
 NUM_DEVICES = 8
+_STDERR_FD = 2
 
 
-# The tier-1 command runs six xdist workers under a time limit, and its
-# wall time was set by which worker drew a minutes-long test last: each of
-# these holds one core for 90 to 300 s while the other workers idle, and
-# the run ended within a minute of its limit (PR 26 read them off
-# ``--durations`` under six workers). Longest first.
-_LONG_TESTS = (
-    "test_qwen3_next_hf.py::test_hybrid_checkpoint_engine_serve",
-    "test_qwen_next.py::test_moe_ffn_forward_fused_matches_xla",
-    "test_qwen_next.py::test_decode_fused_matches_xla",
-    "test_resilience.py::test_signal_faults_ag_gemm_terminate[dropped_signal]",
-    "test_chaos.py::test_soak_megakernel_with_restore",
-    "test_qwen_moe.py::test_moe_model_fused_vs_xla",
-    "test_megakernel.py::test_megakernel_dynamic_token_exact_all_families",
-    "test_chaos.py::test_soak_megakernel_quantized",
-    "test_qwen_next.py::test_forward_fused_matches_xla",
-    "test_mk_chunked_prefill.py::"
-    "test_mk_chunked_token_exact_bucket_edges_vs_lane_and_layer",
-    "test_e2e_dense.py::test_decode_fused_matches_xla",
-    "test_kv_quant.py::test_megakernel_quant_decode_token_agreement[fp8-0.5]",
-    "test_paged_qblock.py::test_no_recompile_gates_with_flash",
-    "test_spec_decode.py::test_megakernel_spec_token_exact_vs_nonspec",
-)
+# Seconds one test may take, fixtures and teardown included: about three
+# times the slowest test of the suite on the slower of the two machines
+# that run it (docs/testing.md).
+TEST_LIMIT_S = 180.0
 
 
-def pytest_collection_modifyitems(config, items):
-    """Under xdist, start the minutes-long tests first, one to a worker.
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    """Every test has a limit of its own.
 
-    ``--dist load`` hands every worker a contiguous chunk of the collection
-    first (a quarter of the tests over the workers) and deals the rest out
-    as workers come free, so a long test late in the alphabet starts late
-    and the run waits for it alone. The long tests move to the front, a
-    chunk apart. Every worker computes the same order; a run without
-    workers keeps the collection's own."""
-    workers = getattr(config, "workerinput", {}).get("workercount")
-    if not workers:
-        return
-    rank = {}
-    for it in items:
-        for k, name in enumerate(_LONG_TESTS):
-            if it.nodeid.endswith(name):
-                rank[it] = k
-    long = sorted(rank, key=rank.get)
-    rest = [it for it in items if it not in rank]
-    gap = max(len(items) // (4 * workers), 2) - 1
-    order = []
-    for k, it in enumerate(long):
-        order += [it] + rest[k * gap:(k + 1) * gap]
-    items[:] = order + rest[len(long) * gap:]
+    The Pallas interpreter's deadlocks (docs/testing.md) block the main
+    thread in native code, where no signal handler runs. ``faulthandler``
+    watches from a thread of its own: at the limit it writes every
+    thread's stack to stderr and exits the process. Under xdist that takes
+    down the one worker, which is reported as the failure of the test it
+    was running (``worker 'gwN' crashed while running '<node id>'``), and
+    a new worker takes the rest. Without workers the run ends there.
+    Tests marked ``slow`` are long by design and run unwatched."""
+    if item.get_closest_marker("slow"):
+        return (yield)
+    faulthandler.dump_traceback_later(TEST_LIMIT_S, exit=True,
+                                      file=_STDERR_FD)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_configure(config):
+    # Capturing is suspended here, so this is the terminal's stderr and
+    # not the file a test's output is captured into.
+    global _STDERR_FD
+    _STDERR_FD = os.dup(2)
     config.addinivalue_line(
         "markers",
         "slow: long-running fault plans (subprocess deadlock harness); "
@@ -96,6 +80,19 @@ def tp8_mesh():
 @pytest.fixture(scope="session")
 def tp8_ctx(tp8_mesh):
     return MeshContext.from_mesh(tp8_mesh)
+
+
+@pytest.fixture(scope="session")
+def tp4_mesh():
+    """1D mesh of four devices: the model-level parity tests. What they
+    prove is how the layers compose; the 8-rank ring of each fused op is
+    held by the op tests on ``tp8_mesh``."""
+    return Mesh(np.array(jax.devices()[:4]), ("tp",))
+
+
+@pytest.fixture(scope="session")
+def tp4_ctx(tp4_mesh):
+    return MeshContext.from_mesh(tp4_mesh)
 
 
 @pytest.fixture(scope="session")

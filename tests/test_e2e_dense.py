@@ -18,9 +18,24 @@ CFG = ModelConfig.tiny()
 B, S = 2, 32
 
 
-def _engine(mesh, mode, **kw):
+# Four ranks, and blocks of one rank's share of the rows and columns:
+# every ring step is one tile. The 8-rank rings and the multi-tile grids
+# of ag_gemm / gemm_rs / gemm_ar are held at the ops (test_fused_gemm.py,
+# test_overlap.py, test_stress.py). Both engines serve the whole module:
+# ``prefill`` and ``serve`` make their cache anew.
+def _engine(mesh, mode):
     return Engine(CFG, mesh, mode=mode, max_len=64, seed=3,
-                  block_m=8, block_n=8, block_k=32, **kw)
+                  block_m=B * S // 4, block_n=16, block_k=32)
+
+
+@pytest.fixture(scope="module")
+def e_xla(tp4_mesh):
+    return _engine(tp4_mesh, "xla")
+
+
+@pytest.fixture(scope="module")
+def e_fused(tp4_mesh):
+    return _engine(tp4_mesh, "fused")
 
 
 @pytest.fixture(scope="module")
@@ -29,37 +44,34 @@ def ids():
                               CFG.vocab_size)
 
 
-def test_prefill_fused_matches_xla(tp8_mesh, ids):
-    e_xla = _engine(tp8_mesh, "xla")
-    e_fused = _engine(tp8_mesh, "fused")
+def test_prefill_fused_matches_xla(e_xla, e_fused, ids):
     logits_xla, cache_xla = e_xla.prefill(ids)
     logits_fused, cache_fused = e_fused.prefill(ids)
     assert_allclose(logits_fused, logits_xla, rtol=2e-3, atol=2e-3)
     assert_allclose(cache_fused.k, cache_xla.k, rtol=2e-3, atol=2e-3)
 
 
-def test_decode_fused_matches_xla(tp8_mesh, ids):
-    e_xla = _engine(tp8_mesh, "xla")
-    e_fused = _engine(tp8_mesh, "fused")
-    toks_xla = np.asarray(e_xla.serve(ids, gen_len=4))
-    toks_fused = np.asarray(e_fused.serve(ids, gen_len=4))
+def test_decode_fused_matches_xla(e_xla, e_fused, ids):
+    # Two tokens: the first from the prefill's logits, the second from a
+    # decode step on the cache the prefill left.
+    toks_xla = np.asarray(e_xla.serve(ids, gen_len=2))
+    toks_fused = np.asarray(e_fused.serve(ids, gen_len=2))
     np.testing.assert_array_equal(toks_fused, toks_xla)
-    assert toks_xla.shape == (B, 4)
+    assert toks_xla.shape == (B, 2)
 
 
-def test_cache_length_advances(tp8_mesh, ids):
-    e = _engine(tp8_mesh, "xla")
-    logits, cache = e.prefill(ids)
+def test_cache_length_advances(e_xla, ids):
+    logits, cache = e_xla.prefill(ids)
     assert int(np.asarray(cache.length)) == S
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    _, cache2 = e.decode(tok, cache)
+    _, cache2 = e_xla.decode(tok, cache)
     assert int(np.asarray(cache2.length)) == S + 1
 
 
-def test_serve_sampling(tp8_mesh, ids):
+def test_serve_sampling(e_xla, ids):
     """Sampling decode: deterministic per seed, different across seeds,
     and temperature→0 converges to greedy. top_k=1 IS greedy."""
-    eng = _engine(tp8_mesh, "xla")
+    eng = e_xla
     greedy = np.asarray(eng.serve(ids, gen_len=4))
 
     s1 = np.asarray(eng.serve(ids, gen_len=4, temperature=0.8, seed=1))
@@ -84,7 +96,7 @@ def test_engine_rejects_moe_impl_on_dense_model(tp8_mesh):
         Engine(ModelConfig.tiny(), tp8_mesh, moe_impl="ep")
 
 
-def test_dense_attention_bias_seed_oss_shape(tp8_mesh, tp8_ctx):
+def test_dense_attention_bias_seed_oss_shape(tp4_mesh):
     """Seed-OSS-class dense models (attention biases, NO per-head q/k
     norm — reference serves ByteDance-Seed/Seed-OSS-36B-Instruct
     through the same DenseLLM, models/__init__.py:42): fused modes must
@@ -113,7 +125,7 @@ def test_dense_attention_bias_seed_oss_shape(tp8_mesh, tp8_ctx):
     from triton_dist_tpu.utils.testing import spmd
     specs = dense_mod.param_specs(cfg)
     params0 = dense_mod.init_params(jax.random.PRNGKey(1), cfg)
-    f = spmd(tp8_mesh,
+    f = spmd(tp4_mesh,
              lambda p, i: dense_mod.prefill(p, i, cfg, max_len=16)[0],
              (specs, P(None, None)), P(None, None))
     lg_b = np.asarray(f(params, ids))
@@ -122,8 +134,8 @@ def test_dense_attention_bias_seed_oss_shape(tp8_mesh, tp8_ctx):
 
     outs = {}
     for mode in ("xla", "fused"):
-        eng = Engine(cfg, tp8_mesh, mode=mode, params=params)
-        outs[mode] = np.asarray(eng.serve(ids, gen_len=4))
+        eng = Engine(cfg, tp4_mesh, mode=mode, params=params)
+        outs[mode] = np.asarray(eng.serve(ids, gen_len=2))
     np.testing.assert_array_equal(outs["xla"], outs["fused"])
 
 

@@ -208,6 +208,9 @@ def test_auto_transport_tune_roundtrip(mesh, tmp_path, monkeypatch):
 # megakernel engine: skewed routing + expert-load claim priority
 # ---------------------------------------------------------------------------
 
+MK_GEN = 2     # the prefill lane's token and one decode step; a step is ~1 s
+
+
 def _mk_engine(cfg, params=None, **kw):
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
 
@@ -218,59 +221,64 @@ def _mk_engine(cfg, params=None, **kw):
 
 @pytest.fixture(scope="module")
 def mk_cfg_params():
-    cfg = ModelConfig.tiny_moe(vocab_size=128, num_experts=8)
+    # Four experts hold the skew (top-1 on expert 0 or 1, the tie on 2);
+    # an interpreted step costs in proportion to its queue, and every
+    # expert and every head adds its tasks to it.
+    cfg = ModelConfig.tiny_moe(vocab_size=128, num_experts=4,
+                               num_attention_heads=4,
+                               num_key_value_heads=2)
     params = _skewed(qwen_moe.init_params(jax.random.PRNGKey(0), cfg))
     return cfg, params
 
 
+@pytest.fixture(scope="module")
+def mk_solo_tokens(mk_cfg_params):
+    """Each prompt alone on the static-schedule engine: the oracle of the
+    serving tests below. One engine serves both prompts: a dense cache's
+    stale rows are masked beyond the length."""
+    cfg, params = mk_cfg_params
+    e = _mk_engine(cfg, params=params)
+
+    def solo(prompt):
+        tiled = jnp.asarray(np.tile(np.asarray([prompt], np.int32),
+                                    (2, 1)))
+        seed = e.prefill_chain(tiled)
+        return np.asarray(e.generate(
+            seed, steps=MK_GEN, start_pos=len(prompt) - 1))[0].tolist()
+
+    return [solo(p) for p in PROMPTS]
+
+
 @pytest.mark.parametrize("transport", ["ragged", "ll"])
-def test_megakernel_skew_serving_token_exact(mk_cfg_params, transport):
+def test_megakernel_skew_serving_token_exact(mk_cfg_params, mk_solo_tokens,
+                                             transport):
     """Megakernel serving under adversarial skew: the transport knob is
     accepted (experts are served in-kernel, TP regime — stats say so),
     tokens match solo runs, and the in-kernel router counters surface
     the hot experts."""
     cfg, params = mk_cfg_params
-
-    def solo(prompt):
-        e = _mk_engine(cfg, params=params)
-        tiled = jnp.asarray(np.tile(np.asarray([prompt], np.int32),
-                                    (2, 1)))
-        seed = e.prefill_chain(tiled)
-        return np.asarray(e.generate(
-            seed, steps=GEN, start_pos=len(prompt) - 1))[0].tolist()
-
-    want = [solo(p) for p in PROMPTS]
     mk = _mk_engine(cfg, params=params)
     srv = ServingEngine(mk, transport=transport)
-    h = [srv.submit(p, max_new_tokens=GEN) for p in PROMPTS]
+    h = [srv.submit(p, max_new_tokens=MK_GEN) for p in PROMPTS]
     srv.run()
-    assert [x.tokens for x in h] == want
+    assert [x.tokens for x in h] == mk_solo_tokens
     st = srv.stats()
     assert st["dispatch_transport"] == "in-kernel-tp"
     tot = np.asarray(st["expert_totals"], np.float64)
     assert tot.sum() > 0 and tot[:3].sum() == tot.sum()
 
 
-def test_megakernel_dynamic_rebalance_token_exact(mk_cfg_params):
+def test_megakernel_dynamic_rebalance_token_exact(mk_cfg_params,
+                                                  mk_solo_tokens):
     """schedule="dynamic" + rebalance: the serving loop feeds the load
     EWMA into the scoreboard (claim tables rebuilt mid-serve) and the
     tokens still match the static-schedule solo baseline."""
     cfg, params = mk_cfg_params
-
-    def solo(prompt):
-        e = _mk_engine(cfg, params=params)          # static baseline
-        tiled = jnp.asarray(np.tile(np.asarray([prompt], np.int32),
-                                    (2, 1)))
-        seed = e.prefill_chain(tiled)
-        return np.asarray(e.generate(
-            seed, steps=GEN, start_pos=len(prompt) - 1))[0].tolist()
-
-    want = [solo(p) for p in PROMPTS]
     mk = _mk_engine(cfg, params=params, schedule="dynamic")
     srv = ServingEngine(mk, rebalance_every=2, hot_expert_factor=0.0)
-    h = [srv.submit(p, max_new_tokens=GEN) for p in PROMPTS]
+    h = [srv.submit(p, max_new_tokens=MK_GEN) for p in PROMPTS]
     srv.run()
-    assert [x.tokens for x in h] == want
+    assert [x.tokens for x in h] == mk_solo_tokens
     assert srv._mk_load_sig is not None, "rebalance never applied"
     assert mk.builder.expert_load is not None
 

@@ -589,10 +589,9 @@ def _mk_serving(kv_dtype="bf16"):
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
 
     if kv_dtype not in _MK_ENGINES:
-        cfg = ModelConfig.tiny(vocab_size=128)
         mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
         _MK_ENGINES[kv_dtype] = MegaKernelEngine(
-            cfg, mesh, batch=2, max_len=32, tile_w=16, t_tile=16,
+            TINY, mesh, batch=2, max_len=32, tile_w=16, t_tile=16,
             paged=True, page=16, num_pages=5, kv_dtype=kv_dtype)
     return ServingEngine(_MK_ENGINES[kv_dtype], kv_dtype=kv_dtype)
 
@@ -605,12 +604,12 @@ def test_megakernel_checkpoint_restore_token_exact(kvd):
     — bit-exact pools at bf16 AND int8. A mid-prefill-LANE request
     snapshots as queued and re-prefills deterministically."""
     prompts = [[5, 6, 7], [3, 4]]
-    want = _mk_serving(kvd).generate(prompts, max_new_tokens=6)
+    want = _mk_serving(kvd).generate(prompts, max_new_tokens=4)
     srv = _mk_serving(kvd)
-    h0 = srv.submit(prompts[0], max_new_tokens=6)
-    for _ in range(6):       # h0 mid-decode
+    h0 = srv.submit(prompts[0], max_new_tokens=4)
+    for _ in range(4):       # h0 mid-decode: its prompt, and one step on
         srv.step()
-    h1 = srv.submit(prompts[1], max_new_tokens=6)
+    h1 = srv.submit(prompts[1], max_new_tokens=4)
     srv.step()               # h1 mid-prefill-lane
     assert h0.status == "running" and h0.tokens
     snap = srv.checkpoint()

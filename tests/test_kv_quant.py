@@ -210,7 +210,14 @@ def test_quantized_spec_composes(engine):
 
 
 def _mk_cfg():
-    return ModelConfig.tiny(vocab_size=128)
+    # The megakernel tests' micro config: an interpreted step costs in
+    # proportion to the tasks in its queue, and the heads and the FFN's
+    # width add theirs (77 tasks a step at ``tiny(vocab_size=128)``, 49
+    # here).
+    return ModelConfig.tiny(vocab_size=64, hidden_size=32,
+                            intermediate_size=32, num_hidden_layers=2,
+                            num_attention_heads=4, num_key_value_heads=2,
+                            head_dim=8)
 
 
 # One megakernel engine per kv_dtype for the whole module: engine
@@ -236,36 +243,41 @@ def _mk_engine(**kw):
 MK_PROMPTS = [[5, 6, 7], [3, 4], [9, 10, 11, 12], [1]]
 
 
-def test_megakernel_bf16_still_bit_identical():
+@pytest.fixture(scope="module")
+def mk_want():
+    """The unquantized lane's tokens, once a module (a fresh
+    ServingEngine; the engine's pool is rewritten by whoever comes
+    next)."""
+    return ServingEngine(_mk_engine()).generate(MK_PROMPTS,
+                                                max_new_tokens=6)
+
+
+def test_megakernel_bf16_still_bit_identical(mk_want):
     """The quantization machinery existing must not perturb the
     unquantized persistent lane: kv_dtype='bf16' serving tokens equal
     solo runs on a fresh engine (the pre-existing mk contract), and
     the jitted step count stays flat after warmup."""
-    want = ServingEngine(_mk_engine()).generate(MK_PROMPTS,
-                                                max_new_tokens=6)
     srv = ServingEngine(_mk_engine(), kv_dtype="bf16")
     assert srv.engine.k_scale is None     # bf16 = no scale tables
     got = srv.generate(MK_PROMPTS, max_new_tokens=6)
-    assert got == want
+    assert got == mk_want
     n = srv.decode_cache_size()
     srv.generate([[2, 4]], max_new_tokens=3)
     assert srv.decode_cache_size() == n, "mk decode step re-specialized"
 
 
 @pytest.mark.parametrize("kvd,min_agree", [("int8", 0.7), ("fp8", 0.5)])
-def test_megakernel_quant_decode_token_agreement(kvd, min_agree):
+def test_megakernel_quant_decode_token_agreement(kvd, min_agree, mk_want):
     """The converted mk-reject: int8/fp8 pools on the persistent lane
     decode token-AGREEING with the layer-path quantized contract's
     bar (fused quantize-on-write / dequantize-on-read vs the fp32
     pools), surfaced via compare_greedy, with the jit cache flat."""
-    want = ServingEngine(_mk_engine()).generate(MK_PROMPTS,
-                                                max_new_tokens=6)
     srv = ServingEngine(_mk_engine(kv_dtype=kvd), kv_dtype=kvd)
     got = srv.generate(MK_PROMPTS, max_new_tokens=6)
-    agree = srv.compare_greedy(zip(got, want))
+    agree = srv.compare_greedy(zip(got, mk_want))
     st = srv.stats()
     assert st["greedy_agreement"] == agree
-    assert agree >= min_agree, (kvd, agree, got, want)
+    assert agree >= min_agree, (kvd, agree, got, mk_want)
     assert st["kv_dtype"] == kvd
     assert st["mk_kv_dtype"] == kvd
     n = srv.decode_cache_size()

@@ -496,8 +496,12 @@ def test_megakernel_hybrid_engine_matches_layer_engine(tp2_mesh):
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
     from triton_dist_tpu.models import Engine, qwen_next
 
+    # One GDN and one attention layer (two of each, one step:
+    # ..._hybrid_gdn_decode_vs_layers above), a 4-token prompt and three
+    # generated tokens: the state and the cache carry the prefix across
+    # the prefill-to-decode handoff and two decode steps after it.
     hcfg = ModelConfig.tiny_next(vocab_size=64, hidden_size=32,
-                                 num_hidden_layers=4,
+                                 num_hidden_layers=2,
                                  num_attention_heads=4,
                                  num_key_value_heads=2, head_dim=8,
                                  gdn_num_heads=8, gdn_head_dim_k=8,
@@ -506,14 +510,14 @@ def test_megakernel_hybrid_engine_matches_layer_engine(tp2_mesh):
     mk = MegaKernelEngine(hcfg, tp2_mesh, batch=2, max_len=32,
                           tile_w=16, t_tile=16, params=params)
     prompts = jnp.asarray(
-        np.random.RandomState(5).randint(0, hcfg.vocab_size, (2, 8)),
+        np.random.RandomState(5).randint(0, hcfg.vocab_size, (2, 4)),
         jnp.int32)
     seed_tok = mk.prefill_chain(prompts)
-    mk_toks = np.asarray(mk.generate(seed_tok, steps=5, start_pos=7))
+    mk_toks = np.asarray(mk.generate(seed_tok, steps=3, start_pos=3))
 
     eng = Engine(hcfg, tp2_mesh, mode="xla", max_len=32,
                  model=qwen_next, params=params)
-    eng_toks = np.asarray(eng.serve(prompts, gen_len=5))
+    eng_toks = np.asarray(eng.serve(prompts, gen_len=3))
     np.testing.assert_array_equal(mk_toks, eng_toks)
 
 
@@ -533,18 +537,19 @@ def test_megakernel_hybrid_reset_states(tp2_mesh):
     params = qwen_next.init_params(jax.random.PRNGKey(30), hcfg)
     eng = MegaKernelEngine(hcfg, tp2_mesh, batch=2, max_len=32,
                            tile_w=16, t_tile=16, params=params)
-    p1 = jnp.asarray([[3, 9, 27, 17], [5, 25, 61, 41]], jnp.int32)
-    p2 = jnp.asarray([[8, 16, 32, 60], [7, 49, 23, 11]], jnp.int32)
-    eng.generate(eng.prefill_chain(p1), steps=3, start_pos=3)
+    p1 = jnp.asarray([[3, 9, 27], [5, 25, 61]], jnp.int32)
+    p2 = jnp.asarray([[8, 16, 32], [7, 49, 23]], jnp.int32)
+    # The first prompt only has to leave its state behind.
+    eng.generate(eng.prefill_chain(p1), steps=1, start_pos=2)
 
     eng.reset_states()
     t2_reused = np.asarray(
-        eng.generate(eng.prefill_chain(p2), steps=3, start_pos=3))
+        eng.generate(eng.prefill_chain(p2), steps=2, start_pos=2))
 
     fresh = MegaKernelEngine(hcfg, tp2_mesh, batch=2, max_len=32,
                              tile_w=16, t_tile=16, params=params)
     t2_fresh = np.asarray(
-        fresh.generate(fresh.prefill_chain(p2), steps=3, start_pos=3))
+        fresh.generate(fresh.prefill_chain(p2), steps=2, start_pos=2))
     np.testing.assert_array_equal(t2_reused, t2_fresh)
 
 
@@ -834,42 +839,41 @@ def test_dynamic_fewer_idle_steps_interpret_counter(tp2_mesh):
     assert noops["dynamic"] < noops["static"], noops
 
 
-def test_megakernel_dynamic_token_exact_all_families(tp2_mesh):
+@pytest.mark.parametrize("family", ["dense", "moe", "hybrid"])
+def test_megakernel_dynamic_token_exact_all_families(tp2_mesh, family):
     """Acceptance: schedule="dynamic" produces token-exact greedy
     output vs static on the dense, MoE, and hybrid-GDN families."""
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
     from triton_dist_tpu.models import qwen_moe, qwen_next
 
-    mcfg = ModelConfig.tiny_moe(vocab_size=64, hidden_size=32,
-                                num_hidden_layers=2,
-                                num_attention_heads=4,
-                                num_key_value_heads=2, head_dim=8,
-                                num_experts=4, num_experts_per_tok=2,
-                                moe_intermediate_size=32)
-    hcfg = ModelConfig.tiny_next(vocab_size=64, hidden_size=32,
-                                 num_hidden_layers=4,
-                                 num_attention_heads=4,
-                                 num_key_value_heads=2, head_dim=8,
-                                 gdn_num_heads=8, gdn_head_dim_k=8,
-                                 gdn_head_dim_v=8, full_attn_interval=2)
-    fams = [("dense", CFG, None),
-            ("moe", mcfg, qwen_moe),
-            ("hybrid", hcfg, qwen_next)]
-    for name, cfg, model in fams:
-        params = (model.init_params(jax.random.PRNGKey(21), cfg)
-                  if model is not None
-                  else dense.init_params(jax.random.PRNGKey(21), cfg))
-        toks = {}
-        for schedule in ("static", "dynamic"):
-            eng = MegaKernelEngine(cfg, tp2_mesh, batch=B, max_len=32,
-                                   tile_w=16, t_tile=16, params=params,
-                                   num_cores=2, strategy="cost_lpt",
-                                   schedule=schedule)
-            toks[schedule] = np.asarray(
-                eng.generate(jnp.asarray([3, 7], jnp.int32), steps=4))
-        np.testing.assert_array_equal(
-            toks["static"], toks["dynamic"],
-            err_msg=f"dynamic schedule diverged on {name}")
+    if family == "dense":
+        cfg, model = CFG, dense
+    elif family == "moe":
+        cfg, model = ModelConfig.tiny_moe(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            num_experts=4, num_experts_per_tok=2,
+            moe_intermediate_size=32), qwen_moe
+    else:
+        # One GDN and one attention layer: every task type of the family
+        # is in the queue (two of each: ..._hybrid_gdn_decode_vs_layers).
+        cfg, model = ModelConfig.tiny_next(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            gdn_num_heads=8, gdn_head_dim_k=8, gdn_head_dim_v=8,
+            full_attn_interval=2), qwen_next
+    params = model.init_params(jax.random.PRNGKey(21), cfg)
+    toks = {}
+    for schedule in ("static", "dynamic"):
+        eng = MegaKernelEngine(cfg, tp2_mesh, batch=B, max_len=32,
+                               tile_w=16, t_tile=16, params=params,
+                               num_cores=2, strategy="cost_lpt",
+                               schedule=schedule)
+        # Three steps: from the empty cache, and twice on what the step
+        # before wrote.
+        toks[schedule] = np.asarray(
+            eng.generate(jnp.asarray([3, 7], jnp.int32), steps=3))
+    np.testing.assert_array_equal(toks["static"], toks["dynamic"])
 
 
 def test_dynamic_dropped_edge_terminates_or_raises(tp2_mesh):
@@ -956,7 +960,8 @@ def test_tune_schedule_persists_and_auto_resolves(tp2_mesh, tmp_path,
     assert eng.schedule == winner
 
 
-def test_megakernel_serves_real_checkpoints(tp2_mesh):
+@pytest.mark.parametrize("fixture", ["qwen3_tiny", "qwen3_moe_tiny"])
+def test_megakernel_serves_real_checkpoints(tp2_mesh, fixture):
     """The dense and MoE megakernel families serve the committed
     REAL-format HF fixtures token-exactly against the layer Engine —
     checkpoint weights, not synthetic init (the reference megakernel's
@@ -968,24 +973,20 @@ def test_megakernel_serves_real_checkpoints(tp2_mesh):
     from triton_dist_tpu.models.hf_loader import load_hf_checkpoint
 
     here = os.path.dirname(os.path.abspath(__file__))
-    for fixture, model in (("qwen3_tiny", None),
-                           ("qwen3_moe_tiny", qwen_moe)):
-        cfg, params = load_hf_checkpoint(
-            os.path.join(here, "fixtures", fixture), dtype=jnp.float32)
-        mk = MegaKernelEngine(cfg, tp2_mesh, batch=B, max_len=MAXLEN,
-                              tile_w=16, t_tile=16, params=params,
-                              keep_params=True)
-        toks = np.asarray(
-            mk.generate(jnp.asarray([3, 7], jnp.int32), steps=4))
+    cfg, params = load_hf_checkpoint(
+        os.path.join(here, "fixtures", fixture), dtype=jnp.float32)
+    mk = MegaKernelEngine(cfg, tp2_mesh, batch=B, max_len=MAXLEN,
+                          tile_w=16, t_tile=16, params=params,
+                          keep_params=True)
+    toks = np.asarray(
+        mk.generate(jnp.asarray([3, 7], jnp.int32), steps=4))
 
-        ekw = {"model": model} if model is not None else {}
-        e2 = Engine(cfg, tp2_mesh, mode="xla", max_len=MAXLEN,
-                    params=params, **ekw)
-        ref = _layer_engine_greedy(e2, cfg,
-                                   jnp.asarray([3, 7], jnp.int32), 4)
-        np.testing.assert_array_equal(
-            toks, ref,
-            err_msg=f"megakernel vs layer engine diverged on {fixture}")
+    ekw = {"model": qwen_moe} if cfg.is_moe else {}
+    e2 = Engine(cfg, tp2_mesh, mode="xla", max_len=MAXLEN,
+                params=params, **ekw)
+    ref = _layer_engine_greedy(e2, cfg,
+                               jnp.asarray([3, 7], jnp.int32), 4)
+    np.testing.assert_array_equal(toks, ref)
 
 
 # ---------------------------------------------------------------------------

@@ -242,11 +242,12 @@ def test_chunk_boundary_token_exact_flash(engine):
     rng = np.random.RandomState(0)
     prompts = [[int(t) for t in rng.randint(0, CFG.vocab_size, n)]
                for n in (bucket - 1, bucket, bucket + 1)]
-    want = [_baseline(engine, p, 8) for p in prompts]
+    # The boundaries are the prompt's: four tokens after it are enough.
+    want = [_baseline(engine, p, 4) for p in prompts]
     srv = ServingEngine(engine, num_slots=2, page=SRV_PAGE,
                         prefill_buckets=(4, bucket),
                         attn_impl="flash")
-    got = srv.generate(prompts, max_new_tokens=8)
+    got = srv.generate(prompts, max_new_tokens=4)
     assert got == want
     assert srv.stats()["chunk_attn"] == "flash"
 
@@ -256,11 +257,11 @@ def test_spec_rollback_token_exact_flash(engine):
     rejected draft suffixes roll back page accounting and greedy
     outputs stay bit-identical to Engine.serve — acceptance is data,
     whichever kernel scored it."""
-    prompts = [[1, 2, 3, 1, 2, 3], [4, 5], [6, 7, 8, 9], [5, 5, 5]]
-    want = [_baseline(engine, p, 10) for p in prompts]
+    prompts = [[1, 2, 3, 1, 2, 3], [4, 5], [5, 5, 5]]
+    want = [_baseline(engine, p, 6) for p in prompts]
     srv = ServingEngine(engine, num_slots=2, page=SRV_PAGE, spec_k=4,
                         chunk_attn="flash")
-    got = srv.generate(prompts, max_new_tokens=10)
+    got = srv.generate(prompts, max_new_tokens=6)
     assert got == want
     st = srv.stats()
     # Mixed accept/reject actually exercised the rollback path.
@@ -278,9 +279,9 @@ def test_flash_matches_ref_tokens_quantized(engine):
     kw = dict(num_slots=2, page=SRV_PAGE, prefill_buckets=(4, 8),
               spec_k=3, kv_dtype="int8")
     got_f = ServingEngine(engine, attn_impl="flash", **kw).generate(
-        prompts, max_new_tokens=8)
+        prompts, max_new_tokens=4)
     got_r = ServingEngine(engine, attn_impl="ref", **kw).generate(
-        prompts, max_new_tokens=8)
+        prompts, max_new_tokens=4)
     assert got_f == got_r
 
 
@@ -293,14 +294,17 @@ def test_no_recompile_gates_with_flash(engine):
     srv = ServingEngine(engine, num_slots=2, page=SRV_PAGE,
                         prefill_buckets=(4, 8), spec_k=4,
                         attn_impl="flash")
+    # The warm-up meets both buckets (3 -> 4; 9 -> 8 + 4; 13 -> 8 + 8),
+    # the verify program and the decode program; the lengths after it
+    # are new to all of them.
     prompts = [[int(t) for t in rng.randint(0, CFG.vocab_size, n)]
-               for n in (3, 5, 7, 9, 11, 13)]    # unseen lengths
-    srv.generate(prompts, max_new_tokens=6)
+               for n in (3, 9, 13)]
+    srv.generate(prompts, max_new_tokens=4)
     assert srv.decode_cache_size() == 1, srv.decode_cache_size()
     assert srv.prefill_cache_size() <= 2
     more = [[int(t) for t in rng.randint(0, CFG.vocab_size, n)]
-            for n in (2, 6, 10)]
-    srv.generate(more, max_new_tokens=4)
+            for n in (2, 10)]                    # unseen lengths
+    srv.generate(more, max_new_tokens=2)
     assert srv.decode_cache_size() == 1
     assert srv.prefill_cache_size() <= 2
 
@@ -421,7 +425,8 @@ def test_fused_step_equals_chunk_then_decode(fused_engines, family, attn,
     assert fused.cache_size() <= len(FUSED_BUCKETS)
 
 
-# The interpreted kernels take seconds a dispatch: fewer, shorter prompts.
+# The interpreted kernels take seconds a dispatch: fewer, shorter prompts
+# and fewer tokens after them.
 @pytest.mark.parametrize("attn,lens", [
     ("ref", (13, 3, 21, 8, 5, 17, 2, 11)), ("flash", (9, 3, 6))])
 def test_decode_rides_chunks_token_exact(engine, attn, lens):
@@ -432,11 +437,12 @@ def test_decode_rides_chunks_token_exact(engine, attn, lens):
     rng = np.random.RandomState(5)
     prompts = [[int(t) for t in rng.randint(0, CFG.vocab_size, n)]
                for n in lens]
-    want = [_baseline(engine, p, 6) for p in prompts]
+    gen = 6 if attn == "ref" else 4
+    want = [_baseline(engine, p, gen) for p in prompts]
     srv = ServingEngine(engine, num_slots=3 if attn == "ref" else 2,
                         page=SRV_PAGE, prefill_buckets=(4, 8),
                         attn_impl=attn)
-    hs = [srv.submit(p, max_new_tokens=6) for p in prompts]
+    hs = [srv.submit(p, max_new_tokens=gen) for p in prompts]
     decoded = 0
     for _ in range(400):
         if srv.sched.idle:

@@ -317,22 +317,32 @@ def test_hybrid_checkpoint_prefill_decode_parity(tp8_mesh):
             err_msg=f"decode step {t}")
 
 
-def test_hybrid_checkpoint_engine_serve(tp8_mesh):
+def test_hybrid_checkpoint_engine_serve():
     """Engine.serve on the real-format checkpoint: greedy tokens agree
-    between the XLA oracle and the fused path."""
+    between the XLA oracle and the fused path.
+
+    What the checkpoint adds to test_qwen_next.py's fused-against-xla
+    tests (4 ranks, synthetic weights) is each rank's own arithmetic on
+    the real layout: conv kernel, gated attention, shared expert, partial
+    rotary, key and value heads that differ. Its four layers cannot be
+    cut, so the mesh is: two ranks, and two tokens (the prefill's, and one
+    decode step on the state the prefill left)."""
+    from jax.sharding import Mesh
+
     from triton_dist_tpu.models import Engine, qwen_next
     from triton_dist_tpu.models.hf_loader import load_hf_checkpoint
 
     cfg, params = load_hf_checkpoint(FIXTURE, dtype=jnp.float32)
     ids = jax.random.randint(jax.random.PRNGKey(2), (2, 8), 0,
                              cfg.vocab_size)
+    mesh2 = Mesh(np.array(jax.devices()[:2]), ("tp",))
     outs = {}
     for mode in ("xla", "fused"):
-        eng = Engine(cfg, tp8_mesh, mode=mode, max_len=32,
+        eng = Engine(cfg, mesh2, mode=mode, max_len=32,
                      params=params, model=qwen_next,
                      block_m=8, block_n=8, block_k=32)
-        outs[mode] = np.asarray(eng.serve(ids, gen_len=4))
-    assert outs["xla"].shape == (2, 4)
+        outs[mode] = np.asarray(eng.serve(ids, gen_len=2))
+    assert outs["xla"].shape == (2, 2)
     np.testing.assert_array_equal(outs["xla"], outs["fused"])
 
 

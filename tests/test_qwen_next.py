@@ -9,6 +9,7 @@ equivalence the dense tests establish for the KV cache).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models import Engine, qwen_next
@@ -16,13 +17,26 @@ from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import make_fwd_contexts
 from triton_dist_tpu.utils.testing import spmd, assert_allclose
 
-CFG = ModelConfig.tiny_next()
-B, S = 2, 32
+# One GDN and one attention layer: every layer kind, and the handoff
+# between the two, once. Four ranks; the blocks are one rank's share of
+# the rows and columns, so every ring step is one tile (the 8-rank rings
+# and the multi-tile grids are the op tests': test_fused_gemm.py,
+# test_gdn.py, test_overlap.py).
+CFG = ModelConfig.tiny_next(num_hidden_layers=2)
+B, S = 2, 16
+BLOCKS = dict(block_m=B * S // 4, block_n=16, block_k=32)
 
 
 def _engine(mesh, mode):
-    return Engine(CFG, mesh, mode=mode, max_len=64, seed=3,
-                  block_m=8, block_n=8, block_k=32, model=qwen_next)
+    return Engine(CFG, mesh, mode=mode, max_len=64, seed=3, model=qwen_next,
+                  **BLOCKS)
+
+
+@pytest.fixture(scope="module")
+def xla_engine(tp4_mesh):
+    """The oracle of the module. ``serve`` and ``prefill`` make their
+    cache anew, so a test sees nothing of the one before."""
+    return _engine(tp4_mesh, "xla")
 
 
 def _ids(seed=1, s=S):
@@ -30,120 +44,101 @@ def _ids(seed=1, s=S):
                               CFG.vocab_size)
 
 
+def _forward(mesh, ctx, cfg, params, ids, mode="xla"):
+    ctxs = make_fwd_contexts(ctx, "tp", **BLOCKS)
+    return spmd(
+        mesh,
+        lambda p, i: qwen_next.forward_tokens(p, i, cfg, mode=mode,
+                                              ctxs=ctxs),
+        (qwen_next.param_specs(cfg), P(None, None)),
+        P(None, None, None))(params, ids)
+
+
 def test_layer_schedule():
-    kinds, n_attn, n_gdn = qwen_next._layer_kinds(CFG)
+    cfg = ModelConfig.tiny_next()
+    kinds, n_attn, n_gdn = qwen_next._layer_kinds(cfg)
     # interval=2 over 4 layers → gdn, attn, gdn, attn.
     assert [k for k, _ in kinds] == ["gdn", "attn", "gdn", "attn"]
     assert (n_attn, n_gdn) == (2, 2)
-    assert CFG.is_hybrid
+    assert cfg.is_hybrid
+    assert [k for k, _ in qwen_next._layer_kinds(CFG)[0]] == ["gdn", "attn"]
 
 
-def test_forward_fused_matches_xla(tp8_mesh, tp8_ctx):
+def test_forward_fused_matches_xla(tp4_mesh, tp4_ctx):
     params = qwen_next.init_params(jax.random.PRNGKey(0), CFG)
     ids = _ids()
-    ctxs = make_fwd_contexts(tp8_ctx, "tp", block_m=8, block_n=8,
-                             block_k=32)
-
-    def run(mode):
-        return spmd(
-            tp8_mesh,
-            lambda p, i: qwen_next.forward_tokens(p, i, CFG, mode=mode,
-                                                  ctxs=ctxs),
-            (qwen_next.param_specs(CFG), P(None, None)),
-            P(None, None, None))(params, ids)
-
-    logits_xla = run("xla")
+    logits_xla = _forward(tp4_mesh, tp4_ctx, CFG, params, ids)
     assert logits_xla.shape == (B, S, CFG.vocab_size)
-    assert_allclose(run("fused"), logits_xla, rtol=2e-3, atol=2e-3)
+    assert_allclose(_forward(tp4_mesh, tp4_ctx, CFG, params, ids, "fused"),
+                    logits_xla, rtol=2e-3, atol=2e-3)
 
 
-def test_prefill_decode_matches_forward(tp8_mesh, tp8_ctx):
+def _assert_chain_matches_forward(eng, cfg, mesh, ctx, ids, gen=4):
+    """Greedy tokens of (prefill → decode chain) against the all-tokens
+    forward, teacher-forced on the same tokens."""
+    s = ids.shape[1]
+    chain = np.asarray(eng.serve(ids, gen_len=gen))        # (B, gen)
+    full = jnp.concatenate([ids, jnp.asarray(chain)], axis=1)
+    fwd = _forward(mesh, ctx, cfg, jax.tree.map(np.asarray, eng.params),
+                   full)
+    want = np.asarray(jnp.argmax(fwd, -1))[:, s - 1:s - 1 + gen]
+    np.testing.assert_array_equal(chain, want)
+
+
+def test_prefill_decode_matches_forward(xla_engine, tp4_mesh, tp4_ctx):
     """Greedy continuation from (prefill → decode chain) must equal the
     all-tokens forward teacher-forced on the same tokens — proving the
     GDN recurrent state and the KV cache carry exactly the prefix
     information."""
-    eng = _engine(tp8_mesh, "xla")
-    ids = _ids(seed=2, s=16)
-    gen = 4
-    chain = np.asarray(eng.serve(ids, gen_len=gen))        # (B, gen)
-
-    full = jnp.concatenate([ids, jnp.asarray(chain)], axis=1)
-    ctxs = make_fwd_contexts(tp8_ctx, "tp", block_m=8, block_n=8,
-                             block_k=32)
-    fwd = spmd(tp8_mesh,
-               lambda p, i: qwen_next.forward_tokens(p, i, CFG,
-                                                     ctxs=ctxs),
-               (qwen_next.param_specs(CFG), P(None, None)),
-               P(None, None, None))(
-        jax.tree.map(np.asarray, eng.params), full)
-    want = np.asarray(jnp.argmax(fwd, -1))[:, 15:15 + gen]
-    np.testing.assert_array_equal(chain, want)
+    _assert_chain_matches_forward(xla_engine, CFG, tp4_mesh, tp4_ctx,
+                                  _ids(seed=2))
 
 
-def test_decode_fused_matches_xla(tp8_mesh):
-    ids = _ids(seed=3, s=16)
-    toks_xla = np.asarray(_engine(tp8_mesh, "xla").serve(ids, gen_len=4))
+def test_decode_fused_matches_xla(xla_engine, tp4_mesh):
+    # Two tokens: the first comes from the prefill's logits, the second
+    # from a decode step on the state and the cache the prefill left.
+    ids = _ids(seed=3)
+    toks_xla = np.asarray(xla_engine.serve(ids, gen_len=2))
     toks_fused = np.asarray(
-        _engine(tp8_mesh, "fused").serve(ids, gen_len=4))
+        _engine(tp4_mesh, "fused").serve(ids, gen_len=2))
     np.testing.assert_array_equal(toks_fused, toks_xla)
-    assert toks_xla.shape == (B, 4)
+    assert toks_xla.shape == (B, 2)
 
 
-MOE_CFG = ModelConfig.tiny_next(num_experts=8, num_experts_per_tok=2,
+MOE_CFG = ModelConfig.tiny_next(num_hidden_layers=2, num_experts=4,
+                                num_experts_per_tok=2,
                                 moe_intermediate_size=32)
 
 
-def test_moe_ffn_forward_fused_matches_xla(tp8_mesh, tp8_ctx):
+def test_moe_ffn_forward_fused_matches_xla(tp4_mesh, tp4_ctx):
     """MoE hybrid configs must actually run the MoE FFN (r2 advisor:
     cfg.is_moe was silently ignored) and the fused pipeline must match
     the XLA oracle."""
     params = qwen_next.init_params(jax.random.PRNGKey(7), MOE_CFG)
     # MoE param set, not a dense MLP: router + per-expert weights.
     assert "router" in params["layers"][0]["mlp"]
-    assert params["layers"][0]["mlp"]["w_gate"].shape[0] == 8
+    assert params["layers"][0]["mlp"]["w_gate"].shape[0] == 4
     ids = _ids(seed=8)
-    ctxs = make_fwd_contexts(tp8_ctx, "tp", block_m=8, block_n=8,
-                             block_k=32)
-
-    def run(mode):
-        return spmd(
-            tp8_mesh,
-            lambda p, i: qwen_next.forward_tokens(p, i, MOE_CFG,
-                                                  mode=mode, ctxs=ctxs),
-            (qwen_next.param_specs(MOE_CFG), P(None, None)),
-            P(None, None, None))(params, ids)
-
-    logits_xla = run("xla")
+    logits_xla = _forward(tp4_mesh, tp4_ctx, MOE_CFG, params, ids)
     assert logits_xla.shape == (B, S, MOE_CFG.vocab_size)
-    assert_allclose(run("fused"), logits_xla, rtol=2e-3, atol=2e-3)
+    assert_allclose(
+        _forward(tp4_mesh, tp4_ctx, MOE_CFG, params, ids, "fused"),
+        logits_xla, rtol=2e-3, atol=2e-3)
 
 
-def test_moe_prefill_decode_matches_forward(tp8_mesh, tp8_ctx):
+def test_moe_prefill_decode_matches_forward(tp4_mesh, tp4_ctx):
     """The MoE FFN decode path (replicated rows + AR) must agree with
     the token-sharded prefill path token-for-token."""
-    eng = Engine(MOE_CFG, tp8_mesh, mode="xla", max_len=64, seed=9,
-                 block_m=8, block_n=8, block_k=32, model=qwen_next)
-    ids = _ids(seed=10, s=16)
-    gen = 4
-    chain = np.asarray(eng.serve(ids, gen_len=gen))
-
-    full = jnp.concatenate([ids, jnp.asarray(chain)], axis=1)
-    ctxs = make_fwd_contexts(tp8_ctx, "tp", block_m=8, block_n=8,
-                             block_k=32)
-    fwd = spmd(tp8_mesh,
-               lambda p, i: qwen_next.forward_tokens(p, i, MOE_CFG,
-                                                     ctxs=ctxs),
-               (qwen_next.param_specs(MOE_CFG), P(None, None)),
-               P(None, None, None))(
-        jax.tree.map(np.asarray, eng.params), full)
-    want = np.asarray(jnp.argmax(fwd, -1))[:, 15:15 + gen]
-    np.testing.assert_array_equal(chain, want)
+    eng = Engine(MOE_CFG, tp4_mesh, mode="xla", max_len=64, seed=9,
+                 model=qwen_next, **BLOCKS)
+    _assert_chain_matches_forward(eng, MOE_CFG, tp4_mesh, tp4_ctx,
+                                  _ids(seed=10))
 
 
-def test_state_is_constant_memory(tp8_mesh, tp8_ctx):
+def test_state_is_constant_memory(xla_engine):
     """The GDN cache does not grow with sequence length (the point of
     the hybrid architecture for long context)."""
-    eng = _engine(tp8_mesh, "xla")
+    eng = xla_engine
     _, c16 = eng.prefill(_ids(seed=4, s=16))
     _, c32 = eng.prefill(_ids(seed=5, s=32))
     assert c16.states.shape == c32.states.shape

@@ -8,6 +8,7 @@ never a hang. Deadlock-prone plans run through the subprocess harness
 (``resilience.harness``), whose deadline is the no-hang guarantee.
 """
 
+import time
 import warnings
 
 import jax
@@ -28,6 +29,22 @@ from triton_dist_tpu.utils.testing import assert_allclose, spmd
 # with margin; the deadline only has to FIRE for genuinely wedged
 # schedules (blocking interpreter backends).
 SUBPROC_DEADLINE_S = 240.0
+
+
+@pytest.fixture(scope="module")
+def ag_gemm_deadline_s():
+    """Deadline for the ag_gemm child, reckoned from a healthy one.
+
+    A wedged child is waited for until the deadline, and nothing is
+    learned after its last progress marker: the wait is as long as the
+    child needs when nothing is wrong (a tolerated plan runs the same
+    program to its end, under the load of the moment), twice over, and
+    never under 20 s."""
+    t0 = time.monotonic()
+    verdict, _ = harness.run_plan("skewed_barrier", "ag_gemm", rank=1,
+                                  iters=1, deadline_s=SUBPROC_DEADLINE_S)
+    assert verdict == "ok"
+    return max(20.0, 2.0 * (time.monotonic() - t0))
 
 
 def _run_ag_gemm(mesh, ctx8, plan=None):
@@ -93,14 +110,14 @@ def test_no_plan_is_free_and_correct(tp8_mesh, tp8_ctx):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("plan", ["dropped_signal", "dup_signal"])
-def test_signal_faults_ag_gemm_terminate(plan):
+def test_signal_faults_ag_gemm_terminate(plan, ag_gemm_deadline_s):
     try:
         verdict, _ = harness.run_plan(plan, "ag_gemm", rank=1, k=0,
-                                      deadline_s=SUBPROC_DEADLINE_S)
+                                      deadline_s=ag_gemm_deadline_s)
     except CommTimeoutError as e:
         # Detected: the structured error must attribute the hang.
         assert e.op == "ag_gemm"
-        assert e.timeout_s == SUBPROC_DEADLINE_S
+        assert e.timeout_s == ag_gemm_deadline_s
         assert e.progress is not None, "no progress marker recorded"
         return
     assert verdict == "ok"   # tolerated: bit-correct output
@@ -145,8 +162,6 @@ def test_fail_kth_call_raises_structured():
 # ---------------------------------------------------------------------------
 
 def test_watchdog_timeout_structured():
-    import time
-
     wd = Watchdog(0.2, op="unit.slow",
                   progress_fn=lambda: {"step": 7})
     with pytest.raises(CommTimeoutError) as ei:

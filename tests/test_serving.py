@@ -399,6 +399,14 @@ def test_prefix_reuse_serving(engine):
 # megakernel path (prefill lane + live slot mask)
 # ---------------------------------------------------------------------------
 
+# An interpreted step costs in proportion to the tasks in its queue, and
+# the heads and the FFN's width add theirs: tests/test_megakernel.py's
+# micro config.
+MK_CFG = ModelConfig.tiny(vocab_size=64, hidden_size=32,
+                          intermediate_size=32, num_attention_heads=4,
+                          num_key_value_heads=2, head_dim=8)
+
+
 def test_megakernel_paged_serving_token_exact():
     """PAGED megakernel serving: the manager's block table is installed
     on the engine each tick (parked rows hit the scratch page), and
@@ -406,15 +414,15 @@ def test_megakernel_paged_serving_token_exact():
     on the identity-table engine."""
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
 
-    cfg = ModelConfig.tiny(vocab_size=128)
     mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
     kw = dict(batch=2, max_len=32, tile_w=16, t_tile=16, paged=True,
               page=16)
     prompts = [[5, 6, 7], [3, 4]]
     gen = 3
 
+    e = MegaKernelEngine(MK_CFG, mesh, **kw)    # stale rows are masked
+
     def solo(prompt):
-        e = MegaKernelEngine(cfg, mesh, **kw)
         tiled = jnp.asarray(np.tile(np.asarray([prompt], np.int32),
                                     (2, 1)))
         seed = e.prefill_chain(tiled)
@@ -422,7 +430,7 @@ def test_megakernel_paged_serving_token_exact():
             seed, steps=gen, start_pos=len(prompt) - 1))[0].tolist()
 
     want = [solo(p) for p in prompts]
-    mk = MegaKernelEngine(cfg, mesh, num_pages=2 * 2 + 1, **kw)
+    mk = MegaKernelEngine(MK_CFG, mesh, num_pages=2 * 2 + 1, **kw)
     srv = ServingEngine(mk)
     assert srv.manager is not None
     h0 = srv.submit(prompts[0], max_new_tokens=gen)
@@ -478,23 +486,24 @@ def test_megakernel_serving_token_exact():
     requests through the prefill lane match solo runs."""
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
 
-    cfg = ModelConfig.tiny(vocab_size=128)
     mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
     prompts = [[5, 6, 7], [3], [11, 12]]
     gen = 3
 
+    # One engine for the solo runs and the serving run: a slot's stale
+    # rows are masked beyond its length, which is what recycling a slot
+    # relies on.
+    mk = MegaKernelEngine(MK_CFG, mesh, batch=2, max_len=16,
+                          tile_w=16, t_tile=16)
+
     def solo(prompt):
-        e = MegaKernelEngine(cfg, mesh, batch=2, max_len=16,
-                             tile_w=16, t_tile=16)
         tiled = jnp.asarray(np.tile(np.asarray([prompt], np.int32),
                                     (2, 1)))
-        seed = e.prefill_chain(tiled)
-        return np.asarray(e.generate(
+        seed = mk.prefill_chain(tiled)
+        return np.asarray(mk.generate(
             seed, steps=gen, start_pos=len(prompt) - 1))[0].tolist()
 
     want = [solo(p) for p in prompts]
-    mk = MegaKernelEngine(cfg, mesh, batch=2, max_len=16,
-                          tile_w=16, t_tile=16)
     srv = ServingEngine(mk)
     h0 = srv.submit(prompts[0], max_new_tokens=gen)
     srv.step()                       # slot 0 mid-prefill-lane

@@ -212,6 +212,14 @@ def test_spec_dropped_verification_fails_one_request(engine):
 # slot-recycling contract (positions rewrite, lengths mask).
 _MK_CACHE: dict = {}
 
+# The megakernel tests' micro config: an interpreted step costs in
+# proportion to the tasks in its queue, and the heads and the FFN's width
+# add theirs (77 tasks a step at ``tiny(vocab_size=128)``, 49 here).
+MK_CFG = ModelConfig.tiny(vocab_size=64, hidden_size=32,
+                          intermediate_size=32, num_hidden_layers=2,
+                          num_attention_heads=4, num_key_value_heads=2,
+                          head_dim=8)
+
 
 def _mk_engine(**kw):
     from triton_dist_tpu.megakernel.engine import MegaKernelEngine
@@ -219,11 +227,10 @@ def _mk_engine(**kw):
     key = tuple(sorted(kw.items()))
     if key not in _MK_CACHE:
         mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
-        base = dict(batch=2, max_len=64, tile_w=16, t_tile=16,
-                    paged=True, page=16, num_pages=9)
+        base = dict(batch=2, max_len=32, tile_w=16, t_tile=16,
+                    paged=True, page=16, num_pages=5)
         base.update(kw)
-        _MK_CACHE[key] = MegaKernelEngine(
-            ModelConfig.tiny(vocab_size=128), mesh, **base)
+        _MK_CACHE[key] = MegaKernelEngine(MK_CFG, mesh, **base)
     return _MK_CACHE[key]
 
 
@@ -234,11 +241,11 @@ def test_megakernel_spec_token_exact_vs_nonspec():
     the Q-block verification rows' logits are bit-identical to the
     sequential decode body's, so greedy acceptance commits exactly
     the sequential tokens — with > 1 tokens per dispatch measured."""
-    rep = [[1, 2, 3, 1, 2, 3, 1, 2], [7, 8, 7, 8, 7, 8]]
-    want = ServingEngine(_mk_engine()).generate(rep, max_new_tokens=16)
+    rep = [[1, 2, 1, 2], [7, 8, 7]]
+    want = ServingEngine(_mk_engine()).generate(rep, max_new_tokens=8)
     srv = ServingEngine(_mk_engine(spec_k=2, schedule="dynamic"),
                         spec_k=2)
-    got = srv.generate(rep, max_new_tokens=16)
+    got = srv.generate(rep, max_new_tokens=8)
     assert got == want
     st = srv.stats()
     assert st["spec"]["k"] == 2
@@ -248,34 +255,30 @@ def test_megakernel_spec_token_exact_vs_nonspec():
     # joining/leaving, acceptance patterns, and budget-clamped tails
     # are all data.
     n = srv.decode_cache_size()
-    srv.generate([[4, 4, 4]], max_new_tokens=4)
+    srv.generate([[4, 4]], max_new_tokens=2)
     assert srv.decode_cache_size() == n, "mk verify re-specialized"
 
 
-def test_megakernel_spec_eos_budget_and_sampled():
+@pytest.mark.parametrize("case", ["budget", "eos", "sampled"])
+def test_megakernel_spec_eos_budget_and_sampled(case):
     """EOS mid-block, a max_new budget smaller than K (over-budget
     rows MASKED in-kernel, never touching real pages), and sampled
     requests (one exact token per dispatch) all match the non-spec
     megakernel run."""
-    want = ServingEngine(_mk_engine()).generate([[1, 2, 3]],
-                                                max_new_tokens=3)[0]
-    srv = ServingEngine(_mk_engine(spec_k=4), spec_k=4)
-    h = srv.submit([1, 2, 3], max_new_tokens=3)     # budget < K
-    srv.run()
-    assert h.tokens == want
-    eos = want[1]
-    srv2 = ServingEngine(_mk_engine(spec_k=4), spec_k=4)
-    h2 = srv2.submit([1, 2, 3], max_new_tokens=10, eos_id=eos)
-    srv2.run()
-    assert h2.tokens == want[:want.index(eos) + 1]
-    req = dict(max_new_tokens=5, temperature=0.8, top_k=4, seed=11)
+    req = dict(max_new_tokens=3)                    # budget < K
+    if case == "sampled":
+        req.update(temperature=0.8, top_k=4, seed=11)
     base = ServingEngine(_mk_engine())
-    hb = base.submit([3, 1, 4], **req)
+    hb = base.submit([1, 2], **req)
     base.run()
+    want = hb.tokens
+    if case == "eos":
+        req.update(max_new_tokens=10, eos_id=want[1])
+        want = want[:want.index(want[1]) + 1]
     spec = ServingEngine(_mk_engine(spec_k=4), spec_k=4)
-    hs = spec.submit([3, 1, 4], **req)
+    hs = spec.submit([1, 2], **req)
     spec.run()
-    assert hs.tokens == hb.tokens
+    assert hs.tokens == want
 
 
 def test_megakernel_spec_knob_validation():
