@@ -309,6 +309,38 @@ def test_dropped_fused_dispatch_contained_by_scope(tiny_engine, op,
     chaos.check_invariants(srv)
 
 
+def test_dropped_second_chunk_of_a_riding_tick_fails_its_request_alone(
+        tiny_engine):
+    """A riding tick runs the oldest prompt's consecutive chunks: a
+    drop at the second of them (its decode rows parked) fails that
+    request alone, the decoder's step stands, and the tick's rows go
+    on to the next-oldest prompt."""
+    srv = ServingEngine(tiny_engine, num_slots=3, page=PAGE,
+                        prefill_buckets=BUCKETS)
+    ok = srv.submit([1, 2, 3], max_new_tokens=6)
+    srv.step()
+    assert ok.status == "running"
+    doomed = srv.submit(list(range(4, 15)), max_new_tokens=3)  # 4+4+3
+    nxt = srv.submit(list(range(20, 26)), max_new_tokens=3)    # 4+2
+    lens, told = int(srv._lens[ok.slot]), len(ok.tokens)
+    with faults.inject(faults.get_plan("fail_kth_call",
+                                       op="chunked_prefill", k=1)):
+        assert srv.step() == 1
+    assert doomed.status == "failed"
+    assert isinstance(doomed.error, faults.InjectedFault)
+    assert doomed.chunks == [(0, 4, 4)], "its first chunk carried the batch"
+    # As after a sound tick: one token more, one position further.
+    assert (int(srv._lens[ok.slot]), len(ok.tokens)) == (lens + 1, told + 1)
+    assert nxt.chunks == [(0, 4, 4), (4, 4, 2)] and nxt.status == "running"
+    srv.run()
+    assert ok.tokens == _baseline(tiny_engine, [1, 2, 3], 6)
+    assert nxt.tokens == _baseline(tiny_engine, list(range(20, 26)), 3)
+    st = srv.stats()
+    assert st["decode_dispatches_fused"] == 1 and st["retries"] == 0
+    assert st["pool"]["used_pages"] == 0, "pages leaked"
+    chaos.check_invariants(srv)
+
+
 # ---------------------------------------------------------------------------
 # Prefill-worker failover
 # ---------------------------------------------------------------------------

@@ -426,10 +426,13 @@ def test_fused_step_equals_chunk_then_decode(fused_engines, family, attn,
 
 
 # The interpreted kernels take seconds a dispatch: fewer, shorter prompts
-# and fewer tokens after them.
-@pytest.mark.parametrize("attn,lens", [
-    ("ref", (13, 3, 21, 8, 5, 17, 2, 11)), ("flash", (9, 3, 6))])
-def test_decode_rides_chunks_token_exact(engine, attn, lens):
+# and fewer tokens after them. ``rode``: the least share of decode
+# dispatches aboard a chunk program. Here 10 of 20 (one chunk a slot a
+# tick: 10 of 22), and 2 of 7 (3 of 8: the two-chunk prompt's chunks now
+# share a tick, which is one tick and one ride fewer).
+@pytest.mark.parametrize("attn,lens,rode", [
+    ("ref", (13, 3, 21, 8, 5, 17, 2, 11), 0.5), ("flash", (9, 3, 6), 0.25)])
+def test_decode_rides_chunks_token_exact(engine, attn, lens, rode):
     """Mixed lengths over few slots, so that ticks hold a chunk and
     live decoders: the tokens are ``Engine.serve``'s, what ``step()``
     returns adds up to ``decode_tokens``, some decode dispatches rode a
@@ -454,6 +457,90 @@ def test_decode_rides_chunks_token_exact(engine, attn, lens):
     assert 0 < st["decode_dispatches_fused"] <= st["decode_dispatches"]
     assert st["decode_dispatches_fused"] < st["decode_dispatches"], (
         "ticks with no chunk run the decode program")
+    assert st["decode_dispatches_fused"] >= rode * st["decode_dispatches"]
+    assert 0 < st["chunk_dispatches_parked"] < st["prefill_chunks"]
     assert srv.prefill_cache_size() <= 2
     assert srv.decode_cache_size() == 1
+    assert st["pool"]["used_pages"] == 0
+
+
+# Buckets (4, 16): a riding tick holds 16 bucket rows. A decoder (3
+# tokens) is live when A (27 = 16 + 4 + 4 + 3), B (11 = 4 + 4 + 3) and
+# C (21 = 16 + 4 + 1) are admitted together, in that order.
+TICK_BUCKETS = (4, 16)
+TICK_PROMPTS = {"D": 3, "A": 27, "B": 11, "C": 21}
+ONE_CHUNK_A_SLOT = [["A16", "B4", "C16"], ["A4", "B4", "C4"],
+                    ["A4", "B4", "C4"], ["A4"]]
+# case: (engine arguments, a decoder live before A, B and C arrive, the
+# decode batch rides, the chunks of each tick from then on)
+TICK_CASES = {
+    # Oldest first, a prompt's tail chunks in one tick, the next
+    # prompt's behind them while the tick's rows stay within 16.
+    "riding": ({}, True, True, [["A16"], ["A4", "A4", "A4", "B4"],
+                                ["B4", "B4"], ["C16"], ["C4", "C4"]]),
+    # The same engine held to the old rule: the schedule whose tokens
+    # the riding one must equal.
+    "one_chunk_a_slot": ({}, True, False, ONE_CHUNK_A_SLOT),
+    # Speculation's decode dispatch is not the plain step: nothing
+    # rides, so nothing is saved by waiting.
+    "speculation": ({"spec_k": 1}, True, False, ONE_CHUNK_A_SLOT),
+    # No decoder live in the first tick: its chunk programs pipeline
+    # back to back, one a slot. From the next tick on D decodes.
+    "no_live_decoder": ({}, False, True, [
+        ["D4", "A16", "B4", "C16"], ["A4", "A4", "A4", "B4"],
+        ["B4", "C4", "C4"]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TICK_CASES))
+def test_riding_tick_prefills_one_programs_worth(engine, case):
+    """Which chunks each tick runs, read from the ``prefill_chunk``
+    spans: a tick whose decode batch rides holds at most
+    ``max(prefill_buckets)`` bucket rows, oldest prompt first; an
+    engine that does not ride, and a tick with no live decoder, keep
+    one chunk a prefilling slot. Every schedule serves
+    ``Engine.serve``'s tokens from the same two chunk programs."""
+    kw, decoder_first, rides, want_ticks = TICK_CASES[case]
+    rng = np.random.RandomState(9)
+    prompts = {k: [int(t) for t in rng.randint(0, CFG.vocab_size, n)]
+               for k, n in TICK_PROMPTS.items()}
+    gen = {"D": 12, "A": 3, "B": 3, "C": 3}
+    srv = ServingEngine(engine, num_slots=4, page=SRV_PAGE,
+                        prefill_buckets=TICK_BUCKETS, telemetry="spans",
+                        **kw)
+    if not rides:
+        srv._rides = False       # parked chunks, the decode tick apart
+    hs = {"D": srv.submit(prompts["D"], max_new_tokens=gen["D"])}
+    if decoder_first:
+        srv.step()
+        assert hs["D"].status == "running"
+    for k in "ABC":
+        hs[k] = srv.submit(prompts[k], max_new_tokens=gen[k])
+    srv.run()
+    for k, h in hs.items():
+        assert h.tokens == _baseline(engine, prompts[k], gen[k]), k
+    name = {h.request.request_id: k for k, h in hs.items()}
+    spans = srv.obs.log.spans()
+    ticks = {}
+    for s in spans:
+        if s.kind == "prefill_chunk":
+            ticks.setdefault(s.attrs["tick"], []).append(
+                f"{name[s.request_id]}{s.attrs['bucket']}")
+    got = [ticks[t] for t in sorted(ticks)]
+    if decoder_first:
+        assert got[0] == ["D4"]
+        got = got[1:]
+    assert got == want_ticks
+    rode = {s.attrs["tick"] for s in spans
+            if s.kind == "decode" and s.attrs["fused"]}
+    for t in rode:
+        assert sum(int(c[1:]) for c in ticks[t]) <= max(TICK_BUCKETS)
+    st = srv.stats()
+    assert bool(rode) == rides
+    assert st["decode_dispatches_fused"] == len(rode)
+    assert st["prefill_chunks"] == sum(len(t) for t in ticks.values())
+    # Every chunk program but the one a tick's batch rode ran parked.
+    assert st["chunk_dispatches_parked"] == (
+        st["prefill_chunks"] - len(rode) if rides else 0)
+    assert srv.chunker.cache_size() <= len(TICK_BUCKETS)
     assert st["pool"]["used_pages"] == 0

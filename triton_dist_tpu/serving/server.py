@@ -187,7 +187,8 @@ class ServingEngine:
         ``prefill_buckets`` (layer path): switch prefill from the
         monolithic per-length dispatch to FIXED-SHAPE chunked prefill —
         prompts stream into the page pool in bucketed chunks (padded to
-        bucket), one chunk per serving tick, interleaved with decode.
+        bucket), a few chunks per serving tick (:meth:`_advance_chunks`),
+        interleaved with decode.
         The prefill jit cache is then bounded by the bucket count
         (:meth:`prefill_cache_size`) instead of growing per distinct
         prompt/resume length, and a long prompt no longer monopolizes
@@ -427,7 +428,8 @@ class ServingEngine:
             "tokens_generated": 0,
             "prefill_tokens": 0, "prefill_calls": 0, "admit_stalls": 0,
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
-            "decode_tokens": 0, "prefill_chunks": 0, "migrated_pages": 0,
+            "decode_tokens": 0, "prefill_chunks": 0,
+            "chunk_dispatches_parked": 0, "migrated_pages": 0,
             "spec_drafted": 0, "spec_accepted": 0,
             "spec_sampled_fallbacks": 0,
             "greedy_agree_tokens": 0, "greedy_ref_tokens": 0,
@@ -1616,8 +1618,8 @@ class ServingEngine:
         """Admit into the chunk stream: allocate the slot's pages in
         the prefiller's pool now (backpressure = the same requeue as
         monolithic admission), then leave the handle in ``"prefill"``
-        status — :meth:`_advance_chunks` streams one bucketed chunk
-        per tick, interleaved with decode, until the prompt is
+        status — :meth:`_advance_chunks` streams its bucketed chunks
+        tick by tick, interleaved with decode, until the prompt is
         resident. Prefix hits skip straight past already-resident
         pages: the compute cursor starts at the first non-shared page
         (clamped so the last prompt token always runs — its logits
@@ -1655,14 +1657,14 @@ class ServingEngine:
         self._toks[slot] = 0
 
     def _advance_chunks(self):
-        """One bucketed chunk per prefilling slot per tick — long
-        prompts interleave with the decode batch instead of
-        monopolizing the dispatch. Where this engine's chunker carries
-        the decode batch and the tick has both a chunk and a live
-        decoder, the batch rides the tick's first chunk program
-        (:meth:`_fused_tick`) and the count of sequences that decoded
-        is returned; otherwise None, and the caller runs the decode
-        tick."""
+        """The tick's prefill chunks. Where this engine's chunker
+        carries the decode batch and a decoder is live, the tick is one
+        program's worth of prefill rows, oldest prompt first, with the
+        batch aboard the first of them (:meth:`_fused_tick`), and the
+        count of sequences that decoded is returned. Otherwise one
+        bucketed chunk per prefilling slot (nothing rides, so waiting
+        saves no weight read), None is returned, and the caller runs
+        the decode tick."""
         prefilling = [h for h in self.sched.running()
                       if h.status == "prefill"]
         if self._rides and prefilling:
@@ -1679,26 +1681,47 @@ class ServingEngine:
                 self._finish_prefill(h, logits)
         return None
 
+    def _riding_chunks(self, prefilling):
+        """The handles whose next chunk a riding tick runs, one at a
+        time as each is dispatched (so a request's cursor is where its
+        last chunk left it): prompts in admission order, a prompt's
+        consecutive chunks included, while the tick's bucket rows stay
+        within the largest bucket. It stops at the first chunk that
+        does not fit; the oldest prompt's chunk always does, so no
+        prompt starves, and a decoder never waits longer than one
+        chunk program's worth of rows for its next token."""
+        chunker = self._prefiller.chunker
+        room = max(chunker.buckets)
+        for h in sorted(prefilling, key=lambda h: (h.started_at, h.slot)):
+            while h.status == "prefill" and h.prompt_pos < len(h.lane):
+                bucket, _ = chunker.next_chunk(len(h.lane) - h.prompt_pos)
+                if bucket > room:
+                    return
+                room -= bucket
+                yield h
+
     def _fused_tick(self, prefilling, active, tbl) -> int:
         """A tick whose decode batch rides its first chunk's program:
         the weights are read once for the chunk and the step. The
-        tick's other chunks (decode rows parked) are enqueued behind
-        that program BEFORE the host waits on it, so the fetch and the
-        sampling run under them; slots whose last chunk ran go live
-        after the decode rows' tokens and decode from the next tick.
+        tick's other chunks (:meth:`_riding_chunks`; decode rows
+        parked) are enqueued behind that program BEFORE the host waits
+        on it, so the fetch and the sampling run under them; slots
+        whose last chunk ran go live after the decode rows' tokens and
+        decode from the next tick.
 
         A fault is contained where its scope is: one raised at
         ``chunked_prefill`` fails the chunk's request alone, as
         :meth:`_advance_chunk` does, and the decoders (whose lengths
         never advanced) redo their step next tick; anything else — a
         drop at ``serving_decode``, a watchdog miss on the joint
-        program — is the decode tick's containment, and the chunk's
-        request goes with it."""
+        program — is the decode tick's containment, and the first
+        chunk's request goes with it."""
         import jax.numpy as jnp
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
-        first = prefilling[0]
+        chunks = self._riding_chunks(prefilling)
+        first = next(chunks)
         finished = []          # (handle, its last chunk's logits)
         t0 = time.perf_counter()
         try:
@@ -1708,8 +1731,12 @@ class ServingEngine:
                 with self.obs.span("decode_enqueue"):
                     batch = tuple(jnp.asarray(a) for a in (
                         self._toks, tbl, self._lens, self._live))
-                chunk_logits, dec, plan = self._enqueue_chunk(first, batch)
-                for h in prefilling[1:]:
+                logits, dec, plan = self._enqueue_chunk(first, batch)
+                # Booked at enqueue: the prompt's next chunk may run
+                # in this tick.
+                if self._chunk_done(first, plan):
+                    finished.append((first, logits))
+                for h in chunks:
                     logits = self._advance_chunk(h)
                     if logits is not None:
                         finished.append((h, logits))
@@ -1727,8 +1754,6 @@ class ServingEngine:
                         e, CommTimeoutError) else "failed", e)
             decoded = 0
         else:
-            if self._chunk_done(first, plan):
-                finished.insert(0, (first, chunk_logits))
             self.stats_counters["decode_dispatches_fused"] += 1
             decoded = self._decode_commit(active, dec, t0)
         for h, logits in finished:
@@ -1807,7 +1832,8 @@ class ServingEngine:
         """Dispatch ``h``'s next chunk and book it; a chunk that fails
         past its retries fails ``h`` alone. Returns the chunk's logits
         (still on the device) if it was the prompt's last — the caller
-        owes ``h`` its :meth:`_finish_prefill` — else None."""
+        owes ``h`` its :meth:`_finish_prefill` — else None. A program
+        that carries decode rows runs here with all of them parked."""
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
@@ -1820,6 +1846,8 @@ class ServingEngine:
         except (CommTimeoutError, faults.InjectedFault) as e:
             self._chunk_failed(h, e)
             return None
+        if self._rides:
+            self.stats_counters["chunk_dispatches_parked"] += 1
         return logits if self._chunk_done(h, plan) else None
 
     def _enqueue_chunk(self, h: RequestHandle, batch=None):
