@@ -353,6 +353,10 @@ def test_disagg_token_exact_vs_solo(role_engines):
         -(-len(p) // PAGE) for p in prompts)
     assert st["pool"]["used_pages"] == 0
     assert st["prefill_pool"]["used_pages"] == 0, "staging leaked"
+    # A prompt's token is its last chunk's own pick, read on the decode
+    # side after the migration; a step's is the decode program's.
+    assert st["tokens_picked_on_device"] == st["tokens_generated"] == (
+        4 * len(prompts))
 
 
 def test_disagg_migration_bit_exact_rewritten_tables(role_engines):

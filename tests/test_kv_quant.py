@@ -77,7 +77,7 @@ def test_quantized_logit_divergence_bounded(engine):
         srv.manager.append(0, int(srv._lens[0]))
         tbl = np.zeros((srv.num_slots, srv.p_max), np.int32)
         tbl[0] = srv.manager.table_row(0)
-        return srv._dispatch(tbl)[0]
+        return np.asarray(srv._dispatch(tbl, False)[0])
 
     base = first_decode_logits("bf16")
     # Thresholds: the CPU battery's empirical bound with ~5x margin

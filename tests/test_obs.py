@@ -631,6 +631,39 @@ def test_fused_tick_holds_every_leaf_kind(engine):
     assert full >= 1, "no fused tick held every leaf kind"
 
 
+def test_greedy_tick_fetches_tokens_and_keeps_its_spans(engine):
+    """What the benchmark's fetch and sample metrics read of a greedy
+    chunked run: ``decode_fetch``, ``prefill_fetch``, ``sample`` and
+    ``emit`` all occur, every ``sample`` says the device picked its
+    token, and every array the tick copies from a program is the
+    picked tokens, never a logits row (counted at ``_read``, the one
+    place the engine reads a program's output)."""
+    srv = ServingEngine(engine, num_slots=3, page=PAGE,
+                        prefill_buckets=(4, 8), telemetry="spans")
+    read, real = [], srv._read
+
+    def counting(out):
+        read.append(real(out))
+        return read[-1]
+
+    srv._read = counting
+    # One chunk, two, three: ticks that ride, parked last chunks and
+    # decode-only ticks (test_fused_tick_holds_every_leaf_kind).
+    srv.generate([[7, 8], list(range(1, 11)), list(range(1, 20))],
+                 max_new_tokens=4)
+    spans = srv.obs.log.spans()
+    samples = [s for s in spans if s.kind == "sample"]
+    assert {"decode_fetch", "prefill_fetch", "sample", "emit"} <= {
+        s.kind for s in spans}
+    assert samples and all(s.attrs["device"] == 1 for s in samples)
+    st = srv.stats()
+    assert (st["tokens_picked_on_device"] == st["tokens_generated"]
+            == len(samples) == 12)
+    assert len(read) >= st["decode_dispatches"]
+    assert all(a.dtype == np.int32 and a.nbytes <= 4 * (1 + srv.num_slots)
+               for a in read), [(a.dtype, a.shape) for a in read]
+
+
 def test_spans_reach_a_profiler_capture(engine, tmp_path):
     """A capture started by any means holds the spans, on its clock and
     with their keys as stats (on the chip: beside the device's ops)."""

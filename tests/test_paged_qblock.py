@@ -396,20 +396,24 @@ def test_fused_step_equals_chunk_then_decode(fused_engines, family, attn,
             c, block_table=jnp.asarray(tbl), lens=jnp.asarray(lens),
             live=jnp.asarray(live))
 
-    chunk_ref, c = plain.step(eng.params, toks, pool(), row, start,
-                              wfrom, valid)
-    dec_ref, c_ref = srv._decode(eng.params, jnp.asarray(dec_toks),
-                                 batch(c))
+    _, chunk_ref, c = plain.step(eng.params, toks, pool(), row, start,
+                                 wfrom, valid)
+    _, dec_ref, c_ref = srv._decode(eng.params, jnp.asarray(dec_toks),
+                                    batch(c))
     if live.any():
-        chunk_got, dec_got, c_got = fused.step_decode(
+        picked, chunk_got, dec_got, c_got = fused.step_decode(
             eng.params, toks, batch(pool()), row, start, wfrom, valid,
             jnp.asarray(dec_toks))
         np.testing.assert_allclose(np.asarray(dec_got)[live == 1],
                                    np.asarray(dec_ref)[live == 1],
                                    rtol=1e-5, atol=1e-5)
+        # The program's own picks are its own rows' arg-maxes.
+        np.testing.assert_array_equal(
+            np.asarray(picked)[1:], np.argmax(np.asarray(dec_got), -1))
     else:
-        chunk_got, c_got = fused.step(eng.params, toks, pool(), row,
-                                      start, wfrom, valid)
+        picked, chunk_got, c_got = fused.step(
+            eng.params, toks, pool(), row, start, wfrom, valid)
+    assert np.asarray(picked)[0] == np.argmax(np.asarray(chunk_got))
     np.testing.assert_allclose(np.asarray(chunk_got),
                                np.asarray(chunk_ref), rtol=1e-5,
                                atol=1e-5)
@@ -543,4 +547,127 @@ def test_riding_tick_prefills_one_programs_worth(engine, case):
     assert st["chunk_dispatches_parked"] == (
         st["prefill_chunks"] - len(rode) if rides else 0)
     assert srv.chunker.cache_size() <= len(TICK_BUCKETS)
+    assert st["pool"]["used_pages"] == 0
+
+
+class _Recorded:
+    """A jitted step program that notes, at every call, the tick, the
+    tokens it picked and the ``np.argmax`` of the logits it returned
+    beside them (``n_logits`` outputs after the first: the chunk's row,
+    then the decode rows)."""
+
+    def __init__(self, srv, fn, kind, n_logits, calls):
+        self.srv, self.fn, self.kind = srv, fn, kind
+        self.n_logits, self.calls = n_logits, calls
+
+    def __call__(self, *args):
+        out = self.fn(*args)
+        want = np.concatenate([
+            np.argmax(np.atleast_2d(np.asarray(rows)), axis=-1)
+            for rows in out[1:1 + self.n_logits]])
+        self.calls.append((self.srv.stats_counters["ticks"] - 1,
+                           self.kind, np.asarray(out[0]), want))
+        return out
+
+    def _cache_size(self):
+        return self.fn._cache_size()
+
+
+# Buckets (4, 16) for the cell's (128, 512), 8 slots. A decoder D is
+# live when A arrives, or nothing is. case: (A's prompt length, D
+# first, which program A's last chunk is, by its place among the
+# chunks of a tick whose batch rides: 0 the one the batch is aboard).
+PICK_CASES = {
+    # A16 carries the batch: A's first token comes in the batch's array.
+    "riding_tick": (16, True, 0),
+    # A4 carries the batch, A's last chunk A4 runs behind it, parked:
+    # the token is that program's own 4-byte read.
+    "parked_program": (8, True, 1),
+    # No decoder: A's chunk runs parked, then every token is the decode
+    # program's.
+    "decode_only": (16, False, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PICK_CASES))
+def test_greedy_tokens_are_the_programs_own_picks(engine, case):
+    """A greedy run emits, token for token, the ``np.argmax`` of the
+    logits the same programs return, without copying a logits row:
+    every program's picked tokens are its rows' arg-maxes, every array
+    the tick reads is a program's picked tokens, and each served token
+    is the pick of the row that produced it (``Engine.serve``'s, too).
+    Every token counts as picked on the device; the programs are the
+    same three."""
+    n_a, decoder_first, last_place = PICK_CASES[case]
+    rng = np.random.RandomState(13)
+    prompts = {"D": [int(t) for t in rng.randint(0, CFG.vocab_size, 3)],
+               "A": [int(t) for t in rng.randint(0, CFG.vocab_size, n_a)]}
+    gen = {"D": 8, "A": 4}
+    srv = ServingEngine(engine, num_slots=8, page=SRV_PAGE,
+                        prefill_buckets=TICK_BUCKETS, telemetry="spans")
+    calls, read, real = [], [], srv._read
+    srv._decode = _Recorded(srv, srv._decode, "decode", 1, calls)
+    srv.chunker._chunk = _Recorded(srv, srv.chunker._chunk, "chunk", 2,
+                                   calls)
+
+    def counting(out):
+        read.append(real(out))
+        return read[-1]
+
+    srv._read = counting
+    hs = {}
+    if decoder_first:
+        hs["D"] = srv.submit(prompts["D"], max_new_tokens=gen["D"])
+        srv.step()
+        assert hs["D"].status == "running"
+    hs["A"] = srv.submit(prompts["A"], max_new_tokens=gen["A"])
+    srv.run()
+
+    for k, h in hs.items():
+        assert h.tokens == _baseline(engine, prompts[k], gen[k]), k
+    for _, kind, picked, want in calls:
+        np.testing.assert_array_equal(picked, want, err_msg=kind)
+    picks = {picked.tobytes() for _, _, picked, _ in calls}
+    assert read and all(a.dtype == np.int32 and a.tobytes() in picks
+                        for a in read), "a tick read more than tokens"
+
+    # Each served token, from the row of the program that produced it.
+    spans = srv.obs.log.spans()
+    chunk_spans = [s for s in spans if s.kind == "prefill_chunk"]
+    chunk_calls = [c for c in calls if c[1] == "chunk"]
+    assert len(chunk_spans) == len(chunk_calls)
+    served = {h.request.request_id: [] for h in hs.values()}
+    for s in (s for s in spans if s.kind == "sample"):
+        assert s.attrs["device"] == 1
+        tick, toks = s.attrs["tick"], served[s.request_id]
+        if not toks:     # the prompt's token: its last chunk's row 0
+            i = max(i for i, c in enumerate(chunk_spans)
+                    if c.request_id == s.request_id)
+            toks.append(int(chunk_calls[i][3][0]))
+            continue
+        of_tick = [c for c in calls if c[0] == tick]
+        dec = [c for c in of_tick if c[1] == "decode"]
+        toks.append(int(dec[0][3][s.slot] if dec
+                        else of_tick[0][3][1 + s.slot]))
+    for h in hs.values():
+        assert h.tokens == served[h.request.request_id]
+
+    # The path the case is named for.
+    a_id = hs["A"].request.request_id
+    last = [s for s in chunk_spans if s.request_id == a_id][-1]
+    in_tick = [s for s in chunk_spans
+               if s.attrs["tick"] == last.attrs["tick"]]
+    rode = [s for s in spans if s.kind == "decode" and s.attrs["fused"]
+            and s.attrs["tick"] == last.attrs["tick"]]
+    if last_place is None:
+        assert not rode
+        assert any(s.kind == "decode" and not s.attrs["fused"]
+                   for s in spans)
+    else:
+        assert rode and in_tick.index(last) == last_place
+    st = srv.stats()
+    assert st["tokens_picked_on_device"] == st["tokens_generated"] == sum(
+        len(h.tokens) for h in hs.values())
+    assert srv.decode_cache_size() == 1
+    assert srv.prefill_cache_size() <= len(TICK_BUCKETS)
     assert st["pool"]["used_pages"] == 0

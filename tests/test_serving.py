@@ -396,6 +396,92 @@ def test_prefix_reuse_serving(engine):
 
 
 # ---------------------------------------------------------------------------
+# the token picked inside the step program
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", ["tied_maxima", "nan_row"])
+def test_greedy_tokens_first_index_rule(rows):
+    """The program's pick and ``np.argmax`` agree where the maximum is
+    not alone: the FIRST index of the maximum, a NaN counting as the
+    maximum. Pinned on both sides, so neither may drift."""
+    from triton_dist_tpu.serving.chunked import greedy_tokens
+
+    v = 257
+    x = np.random.RandomState(3).randn(6, v).astype(np.float32)
+    if rows == "tied_maxima":
+        x[0, [5, 200]] = 9.0               # two maxima
+        x[1, :] = 0.25                     # every entry the maximum
+        x[2, :] = -np.inf
+        x[3, [0, v - 1]] = np.inf
+        x[4, [v - 2, v - 1]] = 7.0         # the tie at the row's end
+        want = [5, 0, 0, 0, v - 2]
+    else:
+        x[0, 17] = np.nan                  # a NaN below a larger value
+        x[0, 3] = 50.0
+        x[1, [40, 41, 250]] = np.nan       # several: the first
+        x[2, :] = np.nan
+        x[3, 9] = np.nan
+        x[3, 2] = np.inf                   # NaN outranks +inf
+        x[4, v - 1] = np.nan
+        want = [17, 40, 0, 9, v - 1]
+    got = np.asarray(jax.jit(greedy_tokens)(jnp.asarray(x)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, np.argmax(x, axis=-1))
+    assert got[:5].tolist() == want
+
+
+@pytest.mark.parametrize("buckets", [None, (4, 8)],
+                         ids=["monolithic", "chunked"])
+def test_sampled_request_among_greedy_rows(engine, buckets):
+    """One sampled request in a batch of greedy ones: it samples the
+    tokens it samples served alone, the greedy rows take the device's
+    token (monolithic admission picks a prompt's token on the host, as
+    ever), and only ticks with the sampled row aboard copy logits."""
+    req = dict(max_new_tokens=3, temperature=0.7, top_k=40, seed=5)
+    sampled = [9, 1, 4, 7, 2]
+    alone = ServingEngine(engine, num_slots=3, page=PAGE,
+                          prefill_buckets=buckets)
+    ha = alone.submit(sampled, **req)
+    alone.run()
+    greedy = [[1, 2, 3], list(range(20, 31))]
+    want = [_baseline(engine, p, 8) for p in greedy]
+
+    srv = ServingEngine(engine, num_slots=3, page=PAGE,
+                        prefill_buckets=buckets)
+    read, real = [], srv._read
+
+    def counting(out):
+        read.append((srv.stats_counters["ticks"] - 1, real(out)))
+        return read[-1][1]
+
+    srv._read = counting
+    hg = [srv.submit(p, max_new_tokens=8) for p in greedy]
+    hs = srv.submit(sampled, **req)
+    aboard = set()           # ticks that began with the sampled row live
+    while not srv.sched.idle:
+        if hs.status == "running":
+            aboard.add(srv.stats_counters["ticks"])
+        srv.step()
+    assert hs.tokens == ha.tokens and len(hs.tokens) == 3
+    assert [h.tokens for h in hg] == want
+    on_host = 0 if buckets else len(greedy)    # admission's own picks
+    st = srv.stats()
+    assert st["tokens_picked_on_device"] == 16 - on_host
+    assert st["tokens_generated"] == 16 + 3
+    wide = {t for t, a in read if a.dtype != np.int32}
+    assert wide and wide <= aboard | {min(aboard) - 1}, (wide, aboard)
+    assert aboard <= wide, "a sampled row's tick copied no logits"
+    assert max(t for t, _ in read) > max(wide), (
+        "the greedy ticks after it copied tokens alone")
+    for _, a in read:
+        if a.dtype == np.int32:
+            assert a.nbytes <= 4 * (1 + srv.num_slots)
+        else:
+            assert a.shape[-1] == VOCAB
+    assert srv.decode_cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
 # megakernel path (prefill lane + live slot mask)
 # ---------------------------------------------------------------------------
 
@@ -511,3 +597,6 @@ def test_megakernel_serving_token_exact():
     h2 = srv.submit(prompts[2], max_new_tokens=gen)
     srv.run()
     assert [h0.tokens, h1.tokens, h2.tokens] == want
+    # This lane's step returns host rows: every token is _pick's.
+    st = srv.stats()
+    assert st["tokens_picked_on_device"] == 0 < st["tokens_generated"]

@@ -38,10 +38,13 @@ SPAN_KINDS = (
     "schedule",          # span: deadlines, SLO pump, admission, requeue
     "decode_prep",       # span: token gather, page growth, block table
     "decode_enqueue",    # span: uploads, the jitted call, copy request
-    "decode_wait",       # span: host blocked until the logits exist
-    "decode_fetch",      # span: logits copied to the host
-    "prefill_fetch",     # span: wait for + copy of the last chunk's row
-    "sample",            # span: one slot's _pick
+    "decode_wait",       # span: host blocked until the step's picks exist
+    "decode_fetch",      # span: picked tokens copied to the host (the
+                         # logits too where a row of the batch samples)
+    "prefill_fetch",     # span: wait for + copy of the last chunk's
+                         # token (its logits row for a sampled request)
+    "sample",            # span: one slot's token (attrs: device = 1 the
+                         # program's own pick, 0 _pick on the host's row)
     "emit",              # span: one slot's _emit (callbacks, retire)
     # request lifecycle
     "submit",            # span: submit()'s own work (validate, enqueue)

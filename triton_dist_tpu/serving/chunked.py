@@ -26,11 +26,22 @@ import numpy as np
 
 from triton_dist_tpu.ops.chunked_prefill import plan_chunks
 
-__all__ = ["ChunkedPrefill", "MegaChunkedPrefill", "DEFAULT_BUCKETS"]
+__all__ = ["ChunkedPrefill", "MegaChunkedPrefill", "DEFAULT_BUCKETS",
+           "greedy_tokens"]
 
 # Production default (the e.g. of ROADMAP Open item 1); tests and tiny
 # models pass their own. Sizing guidance in docs/serving.md.
 DEFAULT_BUCKETS = (128, 512, 2048)
+
+
+def greedy_tokens(logits):
+    """The greedy token of each logits row, picked inside the step
+    program: ``np.argmax``'s rule (the first index of the maximum, a
+    NaN counting as one), so the host reads the integers it would have
+    computed from the rows."""
+    import jax.numpy as jnp
+
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class ChunkedPrefill:
@@ -57,12 +68,19 @@ class ChunkedPrefill:
     batch's step with every weight read once. :meth:`step_decode` gives
     it a decode batch; :meth:`step` parks all its decode rows. Either
     way one program a bucket, so the gate above is unchanged.
+
+    Every program's first output is its rows' greedy tokens
+    (:func:`greedy_tokens`), int32 ``(1 + decode_rows,)``: row 0 the
+    chunk's last valid row, rows 1.. the decode rows. A greedy request's
+    token is read from there; the logits stay outputs, on the device,
+    for a request that samples.
     """
 
     def __init__(self, engine, cache_shardings, buckets: Sequence[int],
                  *, attn_impl: str = "ref", telemetry=None,
                  decode_rows: int = 0, decode_attn: str = "ref"):
         import jax
+        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         buckets = tuple(sorted(set(int(b) for b in buckets)))
@@ -104,11 +122,12 @@ class ChunkedPrefill:
         if not self.decode_rows:
             def _chunk(params, toks, cache, table_row, start, wfrom,
                        valid):
-                return model.prefill_chunk_paged(
+                logits, cache = model.prefill_chunk_paged(
                     params, toks, cache, table_row, cfg, start=start,
                     wfrom=wfrom, valid=valid, mode=engine.mode,
                     axis=axis, ctxs=engine.ctxs, attn_impl=attn_impl,
                     **mk)
+                return greedy_tokens(logits[None]), logits, cache
 
             dec_in = dec_out = dec_sh = ()
         elif not hasattr(model, "chunk_decode_paged"):
@@ -121,12 +140,15 @@ class ChunkedPrefill:
             # at the same place in a profile.
             def _chunk(params, toks, cache, table_row, start, wfrom,
                        valid, dec_toks):
-                return model.chunk_decode_paged(
+                logits, dec, cache = model.chunk_decode_paged(
                     params, toks, dec_toks, cache, table_row, cfg,
                     start=start, wfrom=wfrom, valid=valid,
                     mode=engine.mode, axis=axis, ctxs=engine.ctxs,
                     attn_impl=attn_impl, decode_attn_impl=decode_attn,
                     **mk)
+                picked = jnp.concatenate(
+                    [greedy_tokens(logits[None]), greedy_tokens(dec)])
+                return picked, logits, dec, cache
 
             dec_in, dec_out = (P(None),), (P(None, None),)
             dec_sh = (NamedSharding(mesh, P(None, None)),)
@@ -136,10 +158,10 @@ class ChunkedPrefill:
                 _chunk, mesh=mesh,
                 in_specs=(engine._specs, P(None), kv_spec, P(None),
                           P(), P(), P()) + dec_in,
-                out_specs=(P(None),) + dec_out + (kv_spec,),
+                out_specs=(P(None), P(None)) + dec_out + (kv_spec,),
                 check_vma=False),
             donate_argnums=(2,),
-            out_shardings=(NamedSharding(mesh, P(None)),) + dec_sh
+            out_shardings=(NamedSharding(mesh, P(None)),) * 2 + dec_sh
             + (cache_shardings,))
 
     def plan(self, n_tokens: int) -> List[Tuple[int, int]]:
@@ -153,18 +175,19 @@ class ChunkedPrefill:
 
     def step(self, params, toks: np.ndarray, cache, table_row,
              start: int, wfrom: int, valid: int):
-        """Dispatch one chunk; returns ``(logits (vocab,), cache)``.
-        ``toks`` is (bucket,) int32 padded; scalars ride as int32 data
-        so the trace signature depends only on the bucket length. A
-        program that carries decode rows runs with all of them parked
-        (and returns the pool with no slot live)."""
+        """Dispatch one chunk; returns ``(picked (1 + decode_rows,),
+        logits (vocab,), cache)``, ``picked[0]`` the greedy token of
+        ``logits``. ``toks`` is (bucket,) int32 padded; scalars ride as
+        int32 data so the trace signature depends only on the bucket
+        length. A program that carries decode rows runs with all of
+        them parked (and returns the pool with no slot live)."""
         if not self.decode_rows:
             return self._dispatch(params, toks, cache, table_row, start,
                                   wfrom, valid)
         dec_toks, cache = self._parked_rows(cache)
-        logits, _, cache = self._dispatch(params, toks, cache, table_row,
-                                          start, wfrom, valid, dec_toks)
-        return logits, cache
+        picked, logits, _, cache = self._dispatch(
+            params, toks, cache, table_row, start, wfrom, valid, dec_toks)
+        return picked, logits, cache
 
     def step_decode(self, params, toks: np.ndarray, cache, table_row,
                     start: int, wfrom: int, valid: int, dec_toks):
@@ -172,8 +195,10 @@ class ChunkedPrefill:
         built with ``decode_rows``): ``dec_toks`` (decode_rows,) are the
         batch's input tokens and ``cache`` carries its block table,
         lengths and live mask, as the decode dispatch's does. Returns
-        ``(chunk logits (vocab,), decode logits (decode_rows, vocab),
-        cache)`` with the live slots' lengths advanced."""
+        ``(picked (1 + decode_rows,), chunk logits (vocab,), decode
+        logits (decode_rows, vocab), cache)`` with the live slots'
+        lengths advanced; ``picked[1 + slot]`` is the greedy token of
+        decode row ``slot``."""
         return self._dispatch(params, toks, cache, table_row, start,
                               wfrom, valid, dec_toks)
 
@@ -274,9 +299,10 @@ class MegaChunkedPrefill:
     def step(self, params, toks: np.ndarray, cache, table_row,
              start: int, wfrom: int, valid: int):
         """Dispatch one chunk through the megakernel chunk task pair;
-        returns ``(logits (vocab,), cache)`` — the last VALID row's
-        logits, bit-identical to the one-token prefill lane's at that
-        position."""
+        returns ``(None, logits (vocab,), cache)`` — the last VALID
+        row's logits, bit-identical to the one-token prefill lane's at
+        that position; this lane's programs pick no token, so the
+        caller picks from the row."""
         from triton_dist_tpu.ops.chunked_prefill import chunk_row_codes
 
         tel = self.telemetry
@@ -286,7 +312,7 @@ class MegaChunkedPrefill:
         if t0 is not None:
             tel.observe("chunk_dispatch", tel.now() - t0)
             tel.count(f"chunk_bucket_{len(toks)}")
-        return logits[int(valid) - 1], cache
+        return None, logits[int(valid) - 1], cache
 
     def cache_size(self) -> int:
         """Jit-cache entries across the per-bucket chunk steps (≤

@@ -416,15 +416,17 @@ class DisaggServingEngine(ServingEngine):
 
     # -- handoff: allocate decode pages, migrate, activate -----------
 
-    def _finish_prefill(self, h: RequestHandle, logits):
+    def _finish_prefill(self, h: RequestHandle, last):
         """Final chunk done: claim decode-side pages, issue the page
         extract (async — collected next tick so the transfer overlaps
         whatever dispatches next), and park the handle as
-        ``"migrating"``. After a failover onto the decode engine's
-        in-place path there is nothing to migrate — the chunks wrote
-        the serving pool directly and the base activation applies."""
+        ``"migrating"``; ``last``, that chunk's picked token and logits,
+        waits with it for :meth:`_activate`. After a failover onto the
+        decode engine's in-place path there is nothing to migrate — the
+        chunks wrote the serving pool directly and the base activation
+        applies."""
         if self._prefiller is self:
-            return super()._finish_prefill(h, logits)
+            return super()._finish_prefill(h, last)
         pw = self._prefiller
         slot, seq = h.slot, h.lane
         # The staging pool's pages are fully written — publish them to
@@ -472,7 +474,7 @@ class DisaggServingEngine(ServingEngine):
 
         digest = payload_digest(payload)
         h.status = "migrating"
-        self._pending.append((h, logits, payload, dst_ids,
+        self._pending.append((h, last, payload, dst_ids,
                               len(pages) - hits, pw, digest))
 
     def step(self) -> int:
@@ -500,7 +502,7 @@ class DisaggServingEngine(ServingEngine):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         pending, self._pending = self._pending, []
-        for h, logits, payload, dst_ids, n_mig, pw, digest in pending:
+        for h, last, payload, dst_ids, n_mig, pw, digest in pending:
             if h.status != "migrating":
                 continue    # failed/requeued meanwhile (deadline,
                             # worker failover)
@@ -598,7 +600,7 @@ class DisaggServingEngine(ServingEngine):
             pw.release(slot)
             self._note_role_ok("prefill")
             self.stats_counters["migrated_pages"] += n_mig
-            self._activate(h, logits)
+            self._activate(h, last)
 
     def _requeue_corrupt_migration(self, h, pw) -> None:
         """A migration payload failed its digest past retries: requeue

@@ -58,6 +58,50 @@ __all__ = ["ServingEngine", "save_checkpoint", "load_checkpoint"]
 CKPT_FILE_FORMAT = "tdt-serving-ckpt-file-v2"
 
 
+def _samples(handles) -> bool:
+    """Whether a request among ``handles`` samples (temperature > 0):
+    its step's logits then go to the host; an all-greedy batch's stay
+    on the device and only the picked tokens are fetched."""
+    return any(h.request.temperature > 0.0 for h in handles)
+
+
+class _Row:
+    """One logits row of a step program with ``token``, the program's
+    own greedy pick from it. The floats stay on the device unless the
+    tick fetched them (``host``: a row of its batch samples); read as an
+    array (``np.asarray``: a sampled request, a tap standing in for
+    :meth:`ServingEngine._pick`) the row is copied then."""
+
+    __slots__ = ("token", "_logits", "_index", "_host")
+
+    def __init__(self, token, logits, index=None, host=None):
+        self.token, self._logits = int(token), logits
+        self._index, self._host = index, host
+
+    def __len__(self) -> int:
+        return self._logits.shape[-1]
+
+    def __array__(self, dtype=None, copy=None):
+        if self._host is None:
+            self._host = np.asarray(
+                self._logits if self._index is None
+                else self._logits[self._index])
+        return self._host if dtype is None else self._host.astype(dtype)
+
+
+class _Rows:
+    """A step's rows by slot, as :class:`_Row`: ``picked`` the
+    program's tokens on the host, ``logits`` its rows on the device,
+    ``host`` those rows on the host where the tick fetched them."""
+
+    def __init__(self, picked, logits, host=None):
+        self.picked, self.logits, self.host = picked, logits, host
+
+    def __getitem__(self, slot) -> _Row:
+        return _Row(self.picked[slot], self.logits, slot,
+                    None if self.host is None else self.host[slot])
+
+
 def save_checkpoint(snap: dict, path: str) -> str:
     """Persist a :meth:`ServingEngine.checkpoint` snapshot to ``path``
     (pickle; numpy pools incl. ml_dtypes fp8 round-trip bit-exact).
@@ -425,7 +469,7 @@ class ServingEngine:
         self.max_len = engine.max_len
         self.stats_counters = {
             "decode_dispatches": 0, "decode_dispatches_fused": 0,
-            "tokens_generated": 0,
+            "tokens_generated": 0, "tokens_picked_on_device": 0,
             "prefill_tokens": 0, "prefill_calls": 0, "admit_stalls": 0,
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
             "decode_tokens": 0, "prefill_chunks": 0,
@@ -744,36 +788,45 @@ class ServingEngine:
         # sharding spelling, or each producer pair costs a jit entry in
         # every consumer (PartitionSpec() and PartitionSpec(None, None)
         # place identically but key differently).
+        # The step's first output is each row's greedy token, picked
+        # behind the head (at TP > 1 every shard holds the gathered
+        # row, so the pick is replicated): a tick of greedy rows
+        # fetches those integers and leaves the logits on the device.
+        from triton_dist_tpu.serving.chunked import greedy_tokens
+
+        row_sh = NamedSharding(mesh, P(None))
         logits_sh = NamedSharding(mesh, P(None, None))
-        counts_sh = NamedSharding(mesh, P(None))
+        out_specs = (P(None), P(None, None), kv_spec)
+        out_sh = (row_sh, logits_sh, shardings)
+        if self.ep:
+            out_specs += (P(None),)
+            out_sh += (row_sh,)            # the expert counts, last
         if self.ep and self.replicas is not None:
             def _decode(params, toks, c, reps):
-                return model.decode_step_paged(
+                out = model.decode_step_paged(
                     params, toks, c, cfg, mode=eng.mode, axis=axis,
                     ctxs=eng.ctxs, attn_impl=self.attn_impl,
                     replicas=reps, **mk)
+                return (greedy_tokens(out[0]), *out)
 
             self._decode = jax.jit(jax.shard_map(
                 _decode, mesh=mesh,
                 in_specs=(eng._specs, P(None), kv_spec,
                           _ep_moe.replica_specs()),
-                out_specs=(P(None, None), kv_spec, P(None)),
-                check_vma=False), donate_argnums=(2,),
-                out_shardings=(logits_sh, shardings, counts_sh))
+                out_specs=out_specs, check_vma=False),
+                donate_argnums=(2,), out_shardings=out_sh)
         else:
             def _decode(params, toks, c):
-                return model.decode_step_paged(
+                out = model.decode_step_paged(
                     params, toks, c, cfg, mode=eng.mode, axis=axis,
                     ctxs=eng.ctxs, attn_impl=self.attn_impl, **mk)
+                return (greedy_tokens(out[0]), *out)
 
             self._decode = jax.jit(jax.shard_map(
                 _decode, mesh=mesh,
                 in_specs=(eng._specs, P(None), kv_spec),
-                out_specs=((P(None, None), kv_spec, P(None))
-                           if self.ep else (P(None, None), kv_spec)),
-                check_vma=False), donate_argnums=(2,),
-                out_shardings=((logits_sh, shardings, counts_sh)
-                               if self.ep else (logits_sh, shardings)))
+                out_specs=out_specs, check_vma=False),
+                donate_argnums=(2,), out_shardings=out_sh)
         # Pinned out_shardings: the writer's output must land with the
         # exact shardings the decode dispatch was compiled for, or the
         # first post-admit step would re-specialize the jit cache.
@@ -1676,9 +1729,9 @@ class ServingEngine:
             if active:
                 return self._fused_tick(prefilling, active, tbl)
         for h in prefilling:
-            logits = self._advance_chunk(h)
-            if logits is not None:
-                self._finish_prefill(h, logits)
+            last = self._advance_chunk(h)
+            if last is not None:
+                self._finish_prefill(h, last)
         return None
 
     def _riding_chunks(self, prefilling):
@@ -1722,7 +1775,8 @@ class ServingEngine:
 
         chunks = self._riding_chunks(prefilling)
         first = next(chunks)
-        finished = []          # (handle, its last chunk's logits)
+        finished = []          # (handle, its last chunk's result)
+        sampled = _samples(active)
         t0 = time.perf_counter()
         try:
             with self.obs.span(
@@ -1731,19 +1785,25 @@ class ServingEngine:
                 with self.obs.span("decode_enqueue"):
                     batch = tuple(jnp.asarray(a) for a in (
                         self._toks, tbl, self._lens, self._live))
-                logits, dec, plan = self._enqueue_chunk(first, batch)
+                picked, logits, dec, plan = self._enqueue_chunk(
+                    first, batch, rows=sampled)
                 # Booked at enqueue: the prompt's next chunk may run
                 # in this tick.
-                if self._chunk_done(first, plan):
-                    finished.append((first, logits))
+                first_done = self._chunk_done(first, plan)
                 for h in chunks:
-                    logits = self._advance_chunk(h)
-                    if logits is not None:
-                        finished.append((h, logits))
+                    last = self._advance_chunk(h)
+                    if last is not None:
+                        finished.append((h, last))
                 with self.obs.span("decode_wait"):
-                    dec = self._wait_decode(dec)
+                    picked = self._wait_decode(picked)
                 with self.obs.span("decode_fetch"):
-                    dec = np.asarray(dec)
+                    picked = self._read(picked)
+                    rows = _Rows(picked[1:], dec,
+                                 self._read(dec) if sampled else None)
+                if first_done:
+                    # Its token came with the batch's: row 0 of the
+                    # one array, already on the host.
+                    finished.insert(0, (first, (picked, logits)))
         except (CommTimeoutError, faults.InjectedFault) as e:
             if getattr(e, "op", None) == "chunked_prefill":
                 self._chunk_failed(first, e)
@@ -1755,10 +1815,10 @@ class ServingEngine:
             decoded = 0
         else:
             self.stats_counters["decode_dispatches_fused"] += 1
-            decoded = self._decode_commit(active, dec, t0)
-        for h, logits in finished:
+            decoded = self._decode_commit(active, rows, t0)
+        for h, last in finished:
             if h.status == "prefill":
-                self._finish_prefill(h, logits)
+                self._finish_prefill(h, last)
         return decoded
 
     def _run_op_with_retry(self, op: str, fn, retry_on=None):
@@ -1830,10 +1890,11 @@ class ServingEngine:
 
     def _advance_chunk(self, h: RequestHandle):
         """Dispatch ``h``'s next chunk and book it; a chunk that fails
-        past its retries fails ``h`` alone. Returns the chunk's logits
-        (still on the device) if it was the prompt's last — the caller
-        owes ``h`` its :meth:`_finish_prefill` — else None. A program
-        that carries decode rows runs here with all of them parked."""
+        past its retries fails ``h`` alone. Returns the chunk's result
+        ``(picked tokens, logits)``, still on the device, if it was the
+        prompt's last — the caller owes ``h`` its
+        :meth:`_finish_prefill` — else None. A program that carries
+        decode rows runs here with all of them parked."""
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
@@ -1842,15 +1903,15 @@ class ServingEngine:
             # (a failover requeues every in-flight prefill).
             return None
         try:
-            logits, _, plan = self._enqueue_chunk(h)
+            picked, logits, _, plan = self._enqueue_chunk(h)
         except (CommTimeoutError, faults.InjectedFault) as e:
             self._chunk_failed(h, e)
             return None
         if self._rides:
             self.stats_counters["chunk_dispatches_parked"] += 1
-        return logits if self._chunk_done(h, plan) else None
+        return (picked, logits) if self._chunk_done(h, plan) else None
 
-    def _enqueue_chunk(self, h: RequestHandle, batch=None):
+    def _enqueue_chunk(self, h: RequestHandle, batch=None, rows=False):
         """Dispatch ``h``'s next chunk under the ``chunked_prefill``
         fault scope and retry policy; raises what outlives the retries.
         With ``batch`` (the decode batch's uploaded tokens, table,
@@ -1858,8 +1919,12 @@ class ServingEngine:
         the attempt opens the ``serving_decode`` scope too, either op's
         policy absorbs a TRANSIENT drop (raised at a scope's entry,
         before anything is dispatched), and a wedge is not retried, as
-        on the decode dispatch. Returns ``(chunk logits, decode logits
-        or None, (start, bucket, valid))``, all still on the device."""
+        on the decode dispatch; ``rows`` says that a row of the batch
+        samples, so the decode logits are copied to the host too.
+        Returns ``(picked tokens, chunk logits, decode logits or None,
+        (start, bucket, valid))``, all still on the device; the picked
+        tokens (None from the megakernel's chunker) are row 0 the
+        chunk's and rows 1.. the decode rows'."""
         import dataclasses as _dc
 
         from triton_dist_tpu.resilience import faults
@@ -1890,17 +1955,22 @@ class ServingEngine:
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
                     with faults.on_op_call("serving_decode"):
-                        logits, dec, p.cache = p.chunker.step_decode(
-                            p.engine.params, toks,
-                            _dc.replace(p.cache, block_table=tbl,
-                                        lens=lens, live=live),
-                            row, start, h.resident, valid, dec_toks)
-                    # The copy is asked for now, behind the program.
-                    dec.copy_to_host_async()
-                    return logits, dec
-                logits, p.cache = p.chunker.step(
+                        picked, logits, dec, p.cache = (
+                            p.chunker.step_decode(
+                                p.engine.params, toks,
+                                _dc.replace(p.cache, block_table=tbl,
+                                            lens=lens, live=live),
+                                row, start, h.resident, valid, dec_toks))
+                    # The copies are asked for now, behind the program.
+                    picked.copy_to_host_async()
+                    if rows:
+                        dec.copy_to_host_async()
+                    return picked, logits, dec
+                picked, logits, p.cache = p.chunker.step(
                     p.engine.params, toks, p.cache, row, start,
                     h.resident, valid)
+                if picked is not None and start + valid >= len(seq):
+                    picked.copy_to_host_async()   # the prompt's token
                 if self.timeout_s is not None:
                     logits = block_until_ready(
                         logits, timeout_s=self.timeout_s,
@@ -1908,15 +1978,15 @@ class ServingEngine:
                         progress_fn=lambda: {
                             "slot": slot, "chunk_start": start,
                             "chunks": list(h.chunks)})
-            return logits, None
+            return picked, logits, None
 
         try:
             if batch is None:
-                logits, dec = self._run_op_with_retry("chunked_prefill",
-                                                      _attempt)
+                picked, logits, dec = self._run_op_with_retry(
+                    "chunked_prefill", _attempt)
             else:
                 drops = (faults.InjectedFault,)
-                logits, dec = self._run_op_with_retry(
+                picked, logits, dec = self._run_op_with_retry(
                     "serving_decode",
                     lambda: self._run_op_with_retry(
                         "chunked_prefill", _attempt, retry_on=drops),
@@ -1926,7 +1996,7 @@ class ServingEngine:
         except Exception as e:  # noqa: BLE001 — release, then surface
             self._fail(h, "failed", e)
             raise
-        return logits, dec, (start, bucket, valid)
+        return picked, logits, dec, (start, bucket, valid)
 
     def _chunk_failed(self, h: RequestHandle, e):
         """A chunk was wedged or dropped past its retries. A dying
@@ -1956,15 +2026,18 @@ class ServingEngine:
         self.stats_counters["prefill_calls"] += 1
         return True
 
-    def _finish_prefill(self, h: RequestHandle, logits):
+    def _finish_prefill(self, h: RequestHandle, last):
         """Prompt fully resident: activate the slot (in-place chunked
         mode — the disaggregated subclass migrates pages first)."""
-        self._activate(h, logits)
+        self._activate(h, last)
 
-    def _activate(self, h: RequestHandle, logits):
+    def _activate(self, h: RequestHandle, last):
         """Flip a fully-prefilled slot live; seed the first generated
-        token from the final chunk's last-valid-token logits (resumed
-        requests already know their next token)."""
+        token from the final chunk's result ``last``, ``(picked tokens,
+        last-valid-token logits)``: a greedy request's from row 0 of the
+        picked tokens, a sampled one's (and the megakernel lane's,
+        whose chunks pick none) from the logits row. Resumed requests
+        already know their next token."""
         slot = h.slot
         # Every page's content is resident in THIS engine's pool (the
         # last chunk just landed — or, disaggregated, the migration
@@ -1976,17 +2049,34 @@ class ServingEngine:
         h.status = "running"
         self._close_resume_span(h, path="reprefill")
         if not h.tokens:
+            picked, logits = last
             with self.obs.span("prefill_fetch", slot=slot,
                                request_id=h.request.request_id):
-                row = np.asarray(logits)
+                if picked is None or h.request.temperature > 0.0:
+                    row = self._read(logits)
+                else:
+                    row = _Row(self._read(picked)[0], logits)
             self._sample_emit(h, row)
 
-    def _sample_emit(self, h: RequestHandle, logits_row: np.ndarray):
-        """One slot's token: picked from its logits row, then emitted
-        (stream callback, retirement), each under its own span."""
+    def _read(self, out) -> np.ndarray:
+        """A step program's output on the host: the one place the layer
+        path's tick copies one, so what a tick fetches is counted here
+        (a greedy tick: the picked tokens, ``(1 + num_slots)`` int32)."""
+        return np.asarray(out)
+
+    def _sample_emit(self, h: RequestHandle, row):
+        """One slot's token, picked from its logits ``row`` (a
+        :class:`_Row`: a greedy request takes the token its step
+        program picked on the device, stat ``device`` 1; or the floats
+        on the host), then its emission (stream callback, retirement),
+        each under its own span."""
         rid = h.request.request_id
-        with self.obs.span("sample", slot=h.slot, request_id=rid):
-            tok = self._pick(logits_row, h.request, len(h.tokens))
+        device = int(isinstance(row, _Row)
+                     and h.request.temperature <= 0.0)
+        with self.obs.span("sample", slot=h.slot, request_id=rid,
+                           device=device):
+            tok = self._pick(row, h.request, len(h.tokens))
+            self.stats_counters["tokens_picked_on_device"] += device
         with self.obs.span("emit", slot=h.slot, request_id=rid):
             self._emit(h, tok)
 
@@ -2440,15 +2530,15 @@ class ServingEngine:
                         step=self.stats_counters["decode_dispatches"],
                         batch=len(active), fused=0), \
                         faults.on_op_call("serving_decode"):
-                    return self._dispatch(tbl)
+                    return self._dispatch(tbl, _samples(active))
 
-            logits = self._run_op_with_retry(
+            rows = self._run_op_with_retry(
                 "serving_decode", _attempt,
                 retry_on=(faults.InjectedFault,))
         except (CommTimeoutError, faults.InjectedFault) as e:
             self._decode_contain(e)
             return 0
-        return self._decode_commit(active, logits, t0)
+        return self._decode_commit(active, rows, t0)
 
     def _decode_contain(self, e):
         """The joint decode was wedged or dropped: fail the victim(s),
@@ -2472,10 +2562,12 @@ class ServingEngine:
         for victim in victims:
             self._fail(victim, "timeout" if timed_out else "failed", e)
 
-    def _decode_commit(self, active, logits, t0) -> int:
+    def _decode_commit(self, active, rows, t0) -> int:
         """Book a decode step that ran for ``active``: counters, the
-        length mirrors, and each slot's token from its row of the host
-        ``logits``. Returns how many sequences decoded."""
+        length mirrors, and each slot's token from its row of ``rows``
+        (the layer path's :class:`_Rows`, each with its program's own
+        pick; the megakernel lane's host logits). Returns how many
+        sequences decoded."""
         self.stats_counters["decode_time_s"] += time.perf_counter() - t0
         self.stats_counters["decode_dispatches"] += 1
         self._maybe_rebalance()
@@ -2498,7 +2590,7 @@ class ServingEngine:
                     continue
             h.decode_steps += 1
             self.stats_counters["decode_tokens"] += 1
-            self._sample_emit(h, logits[slot])
+            self._sample_emit(h, rows[slot])
         return len(active)
 
     def _decode_prep(self, active):
@@ -2865,13 +2957,16 @@ class ServingEngine:
                 self._emit(h, tok)
         return len(active)
 
-    def _dispatch(self, tbl: np.ndarray) -> np.ndarray:
+    def _dispatch(self, tbl: np.ndarray, sampled: bool):
         """Run the joint decode under the (optional) watchdog; returns
-        host logits (num_slots, vocab)."""
+        its rows by slot, as :meth:`_decode_commit` takes them: the
+        layer path's :class:`_Rows` (the picked tokens on the host, the
+        logits too where a row of the batch samples, ``sampled``), the
+        megakernel lane's host logits (num_slots, vocab)."""
         import jax.numpy as jnp
 
         if not self.mega:
-            return self._dispatch_layers(tbl)
+            return self._dispatch_layers(tbl, sampled)
         lens = jnp.asarray(self._lens)
         toks = jnp.asarray(self._toks)
         if self.manager is not None:
@@ -2905,11 +3000,12 @@ class ServingEngine:
             self._mk_counts_base = total
         return np.asarray(out)
 
-    def _dispatch_layers(self, tbl: np.ndarray) -> np.ndarray:
+    def _dispatch_layers(self, tbl: np.ndarray, sampled: bool):
         """The layer path's joint decode in the three parts a device
         idle gap can fall under: uploads and the jitted call returning
-        (``decode_enqueue``), the host blocked until the logits exist
-        (``decode_wait``), their copy to the host (``decode_fetch``)."""
+        (``decode_enqueue``), the host blocked until the picked tokens
+        exist (``decode_wait``), their copy to the host, and the
+        logits' where a row samples (``decode_fetch``)."""
         import dataclasses as _dc
 
         import jax.numpy as jnp
@@ -2921,33 +3017,36 @@ class ServingEngine:
                                 lens=jnp.asarray(self._lens),
                                 live=jnp.asarray(self._live))
             if self.ep and self.replicas is not None:
-                out, self.cache, ecounts = self._decode(
+                picked, logits, self.cache, ecounts = self._decode(
                     self.engine.params, toks, cache, self.replicas)
             elif self.ep:
-                out, self.cache, ecounts = self._decode(
+                picked, logits, self.cache, ecounts = self._decode(
                     self.engine.params, toks, cache)
             else:
                 ecounts = None
-                out, self.cache = self._decode(self.engine.params,
-                                               toks, cache)
-            # Ask for the copy now, behind the program: the explicit
+                picked, logits, self.cache = self._decode(
+                    self.engine.params, toks, cache)
+            # Ask for the copies now, behind the program: the explicit
             # wait below then puts no host round trip between the
-            # program's end and the copy's start.
-            out.copy_to_host_async()
+            # program's end and a copy's start.
+            picked.copy_to_host_async()
+            if sampled:
+                logits.copy_to_host_async()
         with self.obs.span("decode_wait"):
             # The counts output rides the SAME dispatch: it must sit
             # inside the watchdog-bounded wait, or a wedged collective
             # would hang the host in the counts conversion below
             # before the deadline ever fires.
             guarded = self._wait_decode(
-                out if ecounts is None else (out, ecounts))
-            out, ecounts = (guarded if ecounts is not None
-                            else (guarded, None))
+                picked if ecounts is None else (picked, ecounts))
+            picked, ecounts = (guarded if ecounts is not None
+                               else (guarded, None))
         with self.obs.span("decode_fetch"):
             if ecounts is not None:
                 self._note_expert_counts(
-                    np.asarray(ecounts).astype(np.int64))
-            return np.asarray(out)
+                    self._read(ecounts).astype(np.int64))
+            return _Rows(self._read(picked), logits,
+                         self._read(logits) if sampled else None)
 
     def _wait_decode(self, outputs):
         """Block until a decode step's ``outputs`` exist, under the
@@ -3108,14 +3207,20 @@ class ServingEngine:
 
     # -- per-request token handling ---------------------------------
 
-    def _pick(self, logits_row: np.ndarray, req: Request,
-              step: int) -> int:
+    def _pick(self, logits_row, req: Request, step: int) -> int:
+        """One slot's token from its logits row. Greedy: the row's
+        arg-max, which a :class:`_Row` brings with it from its step
+        program (the same float32 row, the same first-index rule), so
+        no float leaves the device for it."""
         if req.temperature <= 0.0:
+            if isinstance(logits_row, _Row):
+                return logits_row.token
             return int(np.argmax(logits_row))
         import jax
         import jax.numpy as jnp
 
-        lg = jnp.asarray(logits_row, jnp.float32) / req.temperature
+        lg = jnp.asarray(np.asarray(logits_row), jnp.float32) \
+            / req.temperature
         if req.top_k > 0:
             kth = jax.lax.top_k(lg, req.top_k)[0][-1]
             lg = jnp.where(lg < kth, -jnp.inf, lg)
