@@ -1,0 +1,452 @@
+"""``models.latent_moe`` on the CPU at a tiny size: the latent paged pool,
+the two attention paths, the held-experts layer, and the server around
+them, against the benchmark family's plain reference
+(``benchmark/families/mla_moe.py``, which imports nothing of the
+program) on seeded weights.
+
+The preset is the real block small: d 64, 4 heads of 8 + 8 and 16, r_q
+32, r_kv 16, 16 experts of which 4 a token and 4 held, 2 layers, YaRN
+with an original length of 16 so that a 40-token sequence crosses it
+twice (the blend of frequencies and the query's scale by position are
+both away from the identity).
+"""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+import triton_dist_tpu as tdt
+from benchmark.harness import loader, reference, weights as W
+from triton_dist_tpu.layers import ep_moe
+from triton_dist_tpu.layers.rope import rope_freqs, yarn_freqs
+from triton_dist_tpu.models import Engine, ModelConfig, latent_moe
+from triton_dist_tpu.serving.blocks import LatentPagedCache
+
+DATA = os.path.join(os.path.dirname(__file__), "benchmark", "data")
+F = loader.load_family("mla_moe", [loader.DATA_ROOT])
+SYS = loader.sibling(F.__file__, "mla_moe_system")
+SEED = 11
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """(config file, dims, ModelConfig, mesh, seeded params)."""
+    with open(os.path.join(DATA, "configs", "tiny-mla.json")) as f:
+        config = json.load(f)
+    mesh = tdt.make_mesh(tp=1, devices=jax.devices()[:1])
+    return (config, F.dims(config), SYS.model_config(config), mesh,
+            SYS.make_params(config, mesh, SEED))
+
+
+def _on_mesh(mesh, fn, in_specs, out_specs):
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
+
+
+def _empty(cfg, *, pages=9, page=8, slots=2, p_max=8):
+    return LatentPagedCache.empty(
+        cfg.num_hidden_layers, pages, page, latent_moe.cache_width(cfg),
+        num_slots=slots, p_max=p_max, dtype=jnp.float32)
+
+
+def test_config_reads_the_published_keys(tiny):
+    config, dims, cfg, _, _ = tiny
+    assert cfg.is_latent and (cfg.q_lora_rank, cfg.kv_lora_rank) == (32, 16)
+    assert (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim) == (8, 8, 16)
+    assert cfg.num_experts == 16 and cfg.held_experts == 4
+    assert cfg.shared_expert_intermediate_size == 32
+    assert (cfg.rope_factor, cfg.rope_original_max_position,
+            cfg.rope_query_scale_beta) == (8, 16, 0.1)
+    assert cfg.rope_theta == 10000
+    # The program's frequencies and scale are the reference's own.
+    np.testing.assert_allclose(
+        yarn_freqs(8, 10000.0, factor=8.0, original=16, beta_fast=4.0,
+                   beta_slow=1.0), F.yarn_inv_freq(dims), rtol=1e-6)
+    assert latent_moe.softmax_scale(cfg) == pytest.approx(
+        F.softmax_scale(dims))
+    np.testing.assert_array_equal(
+        yarn_freqs(8, 10000.0, factor=1.0, original=16),
+        rope_freqs(8, 10000.0))
+
+
+@pytest.mark.parametrize("scaling", ["linear", "llama3", "longrope"])
+def test_a_rope_scaling_that_is_not_computed_is_refused(tiny, scaling):
+    config = tiny[0]
+    rope = dict(config["rope_parameters"], rope_type=scaling, type=scaling)
+    with pytest.raises(NotImplementedError, match=scaling):
+        ModelConfig.from_hf_config(dict(config, rope_parameters=rope))
+    plain = {k: v for k, v in config.items()
+             if k not in ("kv_lora_rank", "rope_parameters")}
+    with pytest.raises(NotImplementedError, match=scaling):
+        ModelConfig.from_hf_config(dict(plain, rope_scaling=rope))
+    assert ModelConfig.from_hf_config(
+        dict(plain, rope_scaling=None)).rope_factor == 1.0
+
+
+def test_the_pool_plan_is_one_latent_a_token(tiny):
+    cfg = tiny[2]
+    plan = cfg.kv_cache_plan(max_len=64, page=8, num_slots=3,
+                             dtype_bytes=2)
+    assert plan["bytes_per_token"] == 2 * (16 + 8) * 2     # layers x width
+    assert plan["pool_bytes_per_rank"] == plan["page_bytes_per_rank"] * 25
+    with pytest.raises(ValueError, match="not quantized"):
+        cfg.kv_cache_plan(max_len=64, page=8, num_slots=3, kv_dtype="int8")
+    pool, per_token = latent_moe.paged_pool(cfg)
+    assert pool is LatentPagedCache and per_token == (24,)
+
+
+def test_the_pool_writers_put_a_token_in_its_column(tiny):
+    cfg = tiny[2]
+    rng = np.random.default_rng(0)
+    cache = dataclasses.replace(
+        _empty(cfg), block_table=jnp.asarray([[3, 5, 0, 0, 0, 0, 0, 0],
+                                              [0] * 8], jnp.int32),
+        lens=jnp.asarray([11, 0], jnp.int32),
+        live=jnp.asarray([1, 0], jnp.int32))
+    rows = rng.normal(size=(2, 24)).astype(np.float32)
+    got = np.asarray(cache.append_decode(1, jnp.asarray(rows)).pages)
+    want = np.zeros_like(got)
+    want[1, 5, :, 3] = rows[0]           # position 11: page 1, offset 3
+    want[1, 0, :, 0] = rows[1]           # parked: the scratch page
+    np.testing.assert_array_equal(got, want)
+    # A chunk of 16 rows from position 6, 10 of them valid, positions
+    # below 8 resident: pages 1 and 2 of the row take positions 8..15.
+    chunk = rng.normal(size=(16, 24)).astype(np.float32)
+    row = jnp.asarray([4, 7, 2, 0, 0, 0, 0, 0], jnp.int32)
+    got = np.asarray(cache.write_chunk(
+        0, jnp.asarray(chunk), row, 6 + jnp.arange(16), 10, 8).pages)
+    want = np.zeros_like(got)
+    want[0, 7, :, :] = chunk[2:10].T
+    np.testing.assert_array_equal(got, want)
+    assert cache.advance().lens.tolist() == [12, 0]
+
+
+def test_shared_expert_is_gated_by_what_the_parameters_hold():
+    rng = np.random.default_rng(1)
+    d, f = 16, 8
+    p = {k: jnp.asarray(rng.normal(size=s), jnp.float32) for k, s in (
+        ("w_shared_gate", (d, f)), ("w_shared_up", (d, f)),
+        ("w_shared_down", (f, d)))}
+    x = jnp.asarray(rng.normal(size=(5, d)), jnp.float32)
+    plain = ep_moe.shared_expert_out(p, x)
+    want = (jax.nn.silu(x @ p["w_shared_gate"])
+            * (x @ p["w_shared_up"])) @ p["w_shared_down"]
+    np.testing.assert_allclose(plain, want, rtol=1e-5, atol=1e-5)
+    gate = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
+    gated = ep_moe.shared_expert_out(dict(p, shared_gate=gate), x)
+    np.testing.assert_allclose(
+        gated, want * jax.nn.sigmoid(x @ gate)[:, None], rtol=1e-5,
+        atol=1e-5)
+    assert ep_moe.shared_expert_out({}, x) is None
+
+
+def test_the_shares_add_up(tiny, monkeypatch):
+    """The routed parts the four chips of the deployment compute, plus
+    the shared expert counted once, are the uncut layer: the program's
+    held-experts layer share by share against the reference with all 16
+    experts (and the reference's own shares against itself)."""
+    _, dims, cfg, _, _ = tiny
+    whole = dataclasses.replace(dims, held=16, first_held=0)
+    w = {k: v.astype(jnp.float32) for k, v in W.make_layer(
+        W.root_key(SEED), 0, F.layer_leaves(whole), F.LEAF_IDS,
+        jnp.float32).items()}
+    x = jax.random.normal(jax.random.PRNGKey(3), (48, dims.d), jnp.float32)
+    want = F.experts(x, w, whole, reference._dot) - x
+    y = reference.rms(x, w["ln_mlp"], dims.eps)
+    shared = {"w_shared_" + k: w["shared_" + k]
+              for k in ("gate", "up", "down")}
+    once = ep_moe.shared_expert_out(shared, y)
+    total, ref_total, pairs = once, once, 0
+    for first in range(0, 16, 4):
+        held = slice(first, first + 4)
+        moe = dict(shared, router=w["router"],
+                   w_gate=w["experts_gate"][held],
+                   w_up=w["experts_up"][held],
+                   w_down=w["experts_down"][held])
+        out, stats = ep_moe.fwd_held(moe, y, topk=cfg.num_experts_per_tok,
+                                     first=first)
+        total = total + (out - once)
+        pairs += int(stats[0])
+        assert 0 < int(stats[1]) <= int(stats[0])
+        share = dataclasses.replace(dims, held=4, first_held=first)
+        ws = dict(w, **{k: w[k][held] for k in (
+            "experts_gate", "experts_up", "experts_down")})
+        ref_total = ref_total + (
+            F.experts(x, ws, share, reference._dot) - x - once)
+    assert pairs == 48 * 4               # every pair fell to one share
+    # The reference gathers an expert's rows a fixed number a pass: with
+    # room for the even share only (12 rows), the fuller experts take
+    # further passes and the result is the same.
+    monkeypatch.setattr(F, "CAPACITY_FACTOR", 1)
+    assert F.expert_capacity(whole, 48) == 12
+    np.testing.assert_allclose(
+        F.experts(x, w, whole, reference._dot) - x, want, rtol=1e-5,
+        atol=1e-6)
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(ref_total, want, rtol=1e-4, atol=1e-5)
+
+
+def test_absorbed_and_expanded_attention_agree_on_the_same_cache(tiny):
+    _, _, cfg, _, params = tiny
+    attn = params["layers"][1]["attn"]
+    rng = np.random.default_rng(2)
+    n, page = 40, 8
+    cache = _empty(cfg, slots=1)
+    row = jnp.asarray([2, 6, 1, 8, 3, 0, 0, 0], jnp.int32)
+    cache = dataclasses.replace(
+        cache.write_chunk(1, jnp.asarray(rng.normal(size=(n, 24)),
+                                         jnp.float32),
+                          row, jnp.arange(n), n, 0),
+        block_table=row[None], lens=jnp.asarray([n], jnp.int32),
+        live=jnp.asarray([1], jnp.int32))
+    q = jnp.asarray(rng.normal(size=(n, 4, 16)), jnp.float32)
+    qpos = jnp.arange(n, dtype=jnp.int32)
+    expanded = latent_moe._attend_expanded(attn, q, cache, 1, row, qpos, cfg)
+    absorbed = latent_moe._attend_absorbed(attn, q[None], cache, 1,
+                                           qpos[None], cfg)
+    assert expanded.shape == absorbed.shape == (n, 4 * 16)
+    np.testing.assert_allclose(absorbed, expanded, rtol=1e-4, atol=1e-5)
+    # A table row that is no whole number of blocks walks to its end.
+    table, ppb = latent_moe._blocked_table(jnp.zeros((3, 129), jnp.int32),
+                                           128)
+    assert (table.shape, ppb) == ((3, 130), 10)
+    assert latent_moe._blocked_table(row, page)[1] == 8
+
+
+def test_chunks_then_decode_through_the_pool_equal_the_reference(tiny):
+    """A 40-token sequence: 24 tokens prefilled as chunks of 16 and 8 (in
+    a bucket of 16, padded), then 16 decode steps fed the sequence's own
+    tokens. The logits after the prompt and after each decoded token
+    equal the reference's full forward at those positions. float32 on
+    both sides; 1e-4 absolute on logits of std ~0.17 is what the orders
+    of summation differ by (a running softmax over blocks of pages
+    against one whole, grouped products against gathered ones), three
+    orders under what a wrong rope, scale or expert moves."""
+    config, dims, cfg, mesh, params = tiny
+    specs = latent_moe.param_specs(cfg, "tp")
+    kv = latent_moe.paged_cache_specs("tp")
+    chunk = _on_mesh(
+        mesh, lambda p, t, c, row, start, valid:
+        latent_moe.prefill_chunk_paged(p, t, c, row, cfg, start=start,
+                                       wfrom=0, valid=valid)[:2],
+        (specs, P(None), kv, P(None), P(), P()), (P(None), kv))
+    decode = _on_mesh(
+        mesh, lambda p, t, c: latent_moe.decode_step_paged(p, t, c, cfg),
+        (specs, P(None), kv), (P(None, None), kv, P(None)))
+    seq = np.random.default_rng(5).integers(0, dims.vocab, size=40)
+    row = jnp.asarray([4, 2, 7, 1, 5, 0, 0, 0], jnp.int32)
+    cache = _empty(cfg)
+    _, cache = chunk(params, jnp.asarray(seq[:16], jnp.int32), cache, row,
+                     0, 16)
+    toks = np.zeros(16, np.int32)
+    toks[:8] = seq[16:24]
+    logits, cache = chunk(params, jnp.asarray(toks), cache, row, 16, 8)
+    got = [np.asarray(logits)]
+    cache = dataclasses.replace(
+        cache, block_table=jnp.stack([jnp.zeros_like(row), row]),
+        lens=jnp.asarray([0, 24], jnp.int32),
+        live=jnp.asarray([0, 1], jnp.int32))
+    for t in seq[24:39]:
+        logits, cache, stats = decode(
+            params, jnp.asarray([0, t], jnp.int32), cache)
+        got.append(np.asarray(logits)[1])
+        assert 0 <= int(stats[1]) <= int(stats[0]) <= 2 * 4 * 2
+    assert cache.lens.tolist() == [0, 39]
+    want = reference.logits_at(SEED, F, dims, jnp.float32, [seq.tolist()],
+                               [list(range(23, 39))])[0]
+    assert want.std() > 0.1
+    np.testing.assert_allclose(np.stack(got), want, rtol=0, atol=1e-4)
+
+
+def test_verification_sees_what_sequential_decode_sees(tiny):
+    """Three candidates a slot through ``verify_step_paged`` give, row
+    by row, the logits of three decode steps fed the same tokens: the
+    absorbed walk with a position a query, against one query a slot."""
+    _, dims, cfg, mesh, params = tiny
+    specs = latent_moe.param_specs(cfg, "tp")
+    kv = latent_moe.paged_cache_specs("tp")
+    chunk = _on_mesh(
+        mesh, lambda p, t, c, row: latent_moe.prefill_chunk_paged(
+            p, t, c, row, cfg, start=0, wfrom=0, valid=16)[1],
+        (specs, P(None), kv, P(None)), kv)
+    decode = _on_mesh(
+        mesh, lambda p, t, c: latent_moe.decode_step_paged(p, t, c, cfg)[:2],
+        (specs, P(None), kv), (P(None, None), kv))
+    verify = _on_mesh(
+        mesh, lambda p, t, b, c: latent_moe.verify_step_paged(
+            p, t, c, cfg, budget=b)[0],
+        (specs, P(None, None), P(None), kv), P(None, None, None))
+    rng = np.random.default_rng(6)
+    row = jnp.asarray([3, 1, 6, 0, 0, 0, 0, 0], jnp.int32)
+    cache = chunk(params, jnp.asarray(rng.integers(0, 256, 16), jnp.int32),
+                  _empty(cfg), row)
+    cache = dataclasses.replace(
+        cache, block_table=jnp.stack([row, jnp.zeros_like(row)]),
+        lens=jnp.asarray([16, 0], jnp.int32),
+        live=jnp.asarray([1, 0], jnp.int32))
+    cands = rng.integers(0, 256, size=(2, 3)).astype(np.int32)
+    got = np.asarray(verify(params, jnp.asarray(cands),
+                            jnp.asarray([3, 3], jnp.int32), cache))[0]
+    want = []
+    for j in range(3):
+        logits, cache = decode(params, jnp.asarray(cands[:, j]), cache)
+        want.append(np.asarray(logits)[0])
+    np.testing.assert_allclose(got, np.stack(want), rtol=0, atol=1e-4)
+
+
+def _serve(cfg, mesh, params, prompts, **kw):
+    eng = Engine(cfg, mesh, model=latent_moe, mode="xla",
+                 dtype=jnp.float32, max_len=64, params=params)
+    srv = eng.serving(num_slots=3, page=8, prefill_buckets=(8, 16),
+                      telemetry="spans", **kw)
+    return srv, srv.generate(prompts, max_new_tokens=12)
+
+
+def test_the_server_serves_it_with_the_pool_under_pressure(tiny):
+    """Chunked prefill, the decode batch riding the chunk programs, the
+    token picked on the chip, a slot preempted when the pool runs dry
+    and its request resumed: the tokens are those of a roomy pool, and
+    ``stats()`` carries the held experts' counters."""
+    _, _, cfg, mesh, params = tiny
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 256, size=n).tolist()
+               for n in (21, 37, 9, 30, 26)]
+    roomy, want = _serve(cfg, mesh, params, prompts)
+    tight, got = _serve(cfg, mesh, params, prompts, num_pages=12)
+    assert got == want
+    st, rs = tight.stats(), roomy.stats()
+    assert st["preemptions"] > 0 and rs["preemptions"] == 0
+    for s in (st, rs):
+        assert s["decode_dispatches_fused"] > 0
+        assert s["tokens_picked_on_device"] == s["tokens_generated"] == 60
+        assert s["kv_bytes_per_token"] == 2 * 24 * 4
+        assert s["expert_pairs_routed"] > s["expert_pairs_held"] > 0
+        assert 0.1 < s["expert_held_share"] < 0.45      # 4 of 16 held
+        assert s["expert_load_imbalance"] >= 1.0
+        assert s["expert_rows_mean"] == pytest.approx(
+            s["expert_pairs_held"] / (4 * 2 * s["expert_steps"]))
+        assert s["prefill_cache_size"] <= 2
+    events = [e for e in tight.obs.log.spans()
+              if e.kind == "expert_load"]
+    assert events and all(
+        e.attrs["held_pairs"] <= e.attrs["routed_pairs"]
+        == e.attrs["rows"] * 4 * 2 for e in events)
+    assert tight.decode_cache_size() == 1
+    assert isinstance(tight.cache, LatentPagedCache)
+    assert tight.cache.pages.shape == (2, 12, 24, 8)
+
+
+def test_what_the_latent_pool_does_not_do_is_refused(tiny):
+    _, _, cfg, mesh, params = tiny
+    eng = Engine(cfg, mesh, model=latent_moe, mode="xla",
+                 dtype=jnp.float32, max_len=64, params=params)
+    with pytest.raises(NotImplementedError, match="chunked prefill"):
+        eng.serving(num_slots=2, page=8)
+    with pytest.raises(NotImplementedError, match="tiered"):
+        eng.serving(num_slots=2, page=8, prefill_buckets=(8,),
+                    kv_tiers=True)
+    with pytest.raises(ValueError, match="not quantized"):
+        eng.serving(num_slots=2, page=8, prefill_buckets=(8,),
+                    kv_dtype="int8")
+    with pytest.raises(NotImplementedError, match="paged latent pool"):
+        eng.serve(np.zeros((1, 4), np.int32), gen_len=2)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """libtpu's description of a v5e:2x2 (nothing attached, nothing
+    run), or skip."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — libtpu says why
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "fused-128",
+                                     "fused-512"])
+def test_compiled_step_keeps_the_latent_pool_in_place(v5e, program):
+    """Each paged step of ``models.latent_moe``, lowered for one v5e chip
+    as the serving engine jits it (pool donated, output shardings
+    pinned): the entry computation neither relayouts the latent pool nor
+    cuts a layer out of it (writes by ``lax.dynamic_update_slice``, one
+    layout; the walks over pages carry it through and only read), and
+    the temporaries are smaller than one layer of it. The test of
+    ``models.dense``'s pool (tests/test_paged_decode.py), for the pool
+    this model states. Compile only."""
+    from jax.sharding import NamedSharding
+    from triton_dist_tpu.serving.blocks import pool_shardings
+    from triton_dist_tpu.utils.testing import pool_copies
+
+    cfg = ModelConfig.tiny_latent_moe(
+        vocab_size=1024, hidden_size=512, num_attention_heads=8,
+        q_lora_rank=256, kv_lora_rank=256, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=128, moe_intermediate_size=256,
+        shared_expert_intermediate_size=256, rope_factor=128.0,
+        rope_original_max_position=8192, rope_beta_fast=32.0)
+    # ONE LAYER of the pool larger than the chip's 128 MiB of VMEM: the
+    # walks read ``pages[layer]``, and a layer that fits XLA prefetches
+    # there whole, which no serving pool gives it room for.
+    pages, page, slots, p_max, spec_k = 2049, 128, 4, 8, 4
+    mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
+    axis, dt = "tp", jnp.bfloat16
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=(
+                    s if isinstance(s, NamedSharding)
+                    else NamedSharding(mesh, s))),
+            tree, specs, is_leaf=lambda s: isinstance(s, P))
+
+    specs = latent_moe.param_specs(cfg, axis)
+    params = on_mesh(jax.eval_shape(lambda: latent_moe.init_params(
+        jax.random.PRNGKey(0), cfg, dt)), specs)
+    kv_spec = latent_moe.paged_cache_specs(axis)
+    kv_sh = pool_shardings(mesh, kv_spec)
+    cache = on_mesh(jax.eval_shape(lambda: LatentPagedCache.empty(
+        cfg.num_hidden_layers, pages, page, latent_moe.cache_width(cfg),
+        num_slots=slots, p_max=p_max, dtype=dt)), kv_sh)
+    ints = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    if program == "decode":
+        step = lambda p, t, c: latent_moe.decode_step_paged(p, t, c, cfg)
+        in_specs = (specs, P(None), kv_spec)
+        out_specs = (P(None, None), kv_spec, P(None))
+        args = (params, ints(slots), cache)
+    elif program == "verify":
+        step = lambda p, t, b, c: latent_moe.verify_step_paged(
+            p, t, c, cfg, budget=b)
+        in_specs = (specs, P(None, None), P(None), kv_spec)
+        out_specs = (P(None, None, None), kv_spec)
+        args = (params, ints(slots, spec_k), ints(slots), cache)
+    else:
+        step = lambda p, t, c, row, start, wfrom, valid, d: (
+            latent_moe.chunk_decode_paged(
+                p, t, d, c, row, cfg, start=start, wfrom=wfrom,
+                valid=valid))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P(),
+                    P(None))
+        out_specs = (P(None), P(None, None), kv_spec, P(None))
+        args = (params, ints(int(program[6:])), cache, ints(p_max),
+                ints(), ints(), ints(), ints(slots))
+    donate = args.index(cache)
+    compiled = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=(donate,),
+        out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
+                            for s in out_specs)).lower(*args).compile()
+    pool_shape = cache.pages.shape
+    assert pool_shape == (2, 2049, 320, 128)
+    assert pool_copies(compiled.as_text(), pool_shape) == []
+    layer_bytes = 2 * int(np.prod(pool_shape[1:]))
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

@@ -94,7 +94,10 @@ def param_specs(axis: str = "ep", cfg=None) -> Dict:
 def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
     """Qwen3-MoE router: softmax over experts then top-k, weights
     renormalized (reference ``models/qwen_moe.py``)."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32))
+    # Full float32 products: on the TPU a float32 dot is otherwise one
+    # bfloat16 pass, and near-ties among the k-th choices flip on it.
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     topk_w, topk_ids = jax.lax.top_k(probs, topk)
     if norm_topk_prob:
@@ -103,12 +106,14 @@ def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
 
 
 def shared_expert_out(params, x):
-    """Sigmoid-gated dense SwiGLU branch (qwen3_next shared expert);
-    None when the layer has no shared expert. Under TP ffn-sharded
-    weights the result is a PARTIAL sum (the caller's reduce completes
-    it — the sigmoid gate uses the replicated ``shared_gate`` vector so
-    every rank scales by the same factor); under replicated weights
-    (EP) it is the full contribution."""
+    """The dense SwiGLU branch every token takes; None when the layer
+    has none. Sigmoid-gated where the parameters hold a ``shared_gate``
+    vector (qwen3_next), plain where they do not (the latent-attention
+    family). Under TP ffn-sharded weights the result is a PARTIAL sum
+    (the caller's reduce completes it — the sigmoid gate uses the
+    replicated ``shared_gate`` vector so every rank scales by the same
+    factor); under replicated weights (EP) it is the full
+    contribution."""
     if "w_shared_gate" not in params:
         return None
     g = jnp.dot(x, params["w_shared_gate"])
@@ -117,10 +122,50 @@ def shared_expert_out(params, x):
            * u.astype(jnp.float32)).astype(x.dtype)
     out = jnp.dot(act, params["w_shared_down"],
                   preferred_element_type=jnp.float32)
+    if "shared_gate" not in params:
+        return out
     gate = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                   params["shared_gate"]
                                   .astype(jnp.float32)))
     return out * gate[:, None]
+
+
+def fwd_held(params, x, *, topk: int, first: int = 0,
+             norm_topk_prob: bool = True, routed_scale: float = 1.0):
+    """One chip's share of an expert-parallel layer, with no peer here
+    and no exchange: the router is as wide as the deployment's
+    (``params["router"]``: every expert), the weights are those of the
+    ``E_held`` experts from ``first`` on, and of a token's ``topk``
+    choices the ones that fell to a held expert are computed. What the
+    absent experts would add is left out (their chips would add it in
+    the combine this chip does not take part in); the shared expert,
+    which every chip computes alike, is added whole.
+
+    x: (T, d). Returns ``(out (T, d) float32, stats (2,) int32)``:
+    ``stats[0]`` the token-expert pairs that fell to held experts,
+    ``stats[1]`` the most rows one held expert was given."""
+    t, d = x.shape
+    n_held = params["w_gate"].shape[0]
+    topk_ids, topk_w = route(params["router"], x, topk,
+                             norm_topk_prob=norm_topk_prob)
+    local = topk_ids - first
+    held = (local >= 0) & (local < n_held)
+    flat = jnp.where(held, local, -1).reshape(-1)
+    sorted_tok, group_sizes, inv = sort_by_expert(
+        jnp.repeat(x, topk, axis=0), flat, n_held)
+    out = grouped_swiglu(sorted_tok, params["w_gate"], params["w_up"],
+                         params["w_down"], group_sizes)[inv]
+    # Rows past the last group are whatever the grouped product left
+    # there: selected away, not multiplied by zero.
+    w = (topk_w * routed_scale)[..., None]
+    out = jnp.sum(jnp.where(held[..., None],
+                            out.reshape(t, topk, d).astype(jnp.float32)
+                            * w, 0.0), axis=1)
+    shared = shared_expert_out(params, x)
+    if shared is not None:
+        out = out + shared
+    stats = jnp.stack([jnp.sum(group_sizes), jnp.max(group_sizes)])
+    return out, stats.astype(jnp.int32)
 
 
 def fwd(params, x, ep_ctx: EPContext, *, topk: int,

@@ -64,6 +64,35 @@ class ModelConfig:
     # Runtime layers always compute standard w·x̂ — the HF mapper folds
     # the +1 into the stored weights at load time under this flag.
     norm_zero_centered: bool = False
+    # Latent attention (``kv_lora_rank`` > 0; models/latent_moe.py):
+    # queries through a bottleneck of ``q_lora_rank``, keys and values
+    # expanded from one cached latent of ``kv_lora_rank`` values beside
+    # one roped key of ``qk_rope_head_dim`` that every head shares.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope (``rope_factor`` > 1; layers/rope.py): frequencies
+    # blended between ``f`` and ``f / rope_factor`` by how often a
+    # wavelength fits ``rope_original_max_position``; the softmax scale
+    # carries ``mscale_all_dim``'s factor squared; a query is scaled by
+    # ``1 + rope_query_scale_beta * ln(1 + pos // original)``.
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    rope_query_scale_beta: float = 0.0
+    routed_scaling_factor: float = 1.0
+    # One chip's share of an expert-parallel deployment, with no peer
+    # here: the router is ``num_experts`` wide, the weights of
+    # ``num_held_experts`` of them, from ``first_held_expert`` on, are
+    # held (0 = all), and the others' part of the result is left out
+    # (layers/ep_moe.py ``fwd_held``).
+    first_held_expert: int = 0
+    num_held_experts: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -72,6 +101,14 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         return self.gdn_num_heads > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_held_experts or self.num_experts
 
     @property
     def gdn_num_kh(self) -> int:
@@ -106,6 +143,24 @@ class ModelConfig:
         kv_loc = max(self.num_key_value_heads // tp, 1)
         p_max = max_len // page
         num_pages = 1 + num_slots * p_max
+        if self.is_latent:
+            # One array of [latent | roped key] a token a layer, no
+            # heads to divide: every rank keeps it whole.
+            if qdtype is not None:
+                raise ValueError("the latent pool is not quantized: "
+                                 f"kv_dtype={kv_dtype!r}")
+            page_bytes = (self.num_hidden_layers * page * dtype_bytes
+                          * (self.kv_lora_rank + self.qk_rope_head_dim))
+            return {
+                "page": page, "p_max": p_max, "num_pages": num_pages,
+                "kv_heads_loc": 0, "kv_dtype": "bf16",
+                "page_bytes_per_rank": page_bytes,
+                "native_page_bytes_per_rank": page_bytes,
+                "pool_bytes_per_rank": page_bytes * num_pages,
+                "bytes_per_token": page_bytes / page,
+                "capacity_ratio_vs_native": 1.0,
+                "tokens_per_page": page,
+            }
         native_bytes = (self.num_hidden_layers * kv_loc * page
                         * self.head_dim * dtype_bytes)
         if qdtype is None:
@@ -168,6 +223,28 @@ class ModelConfig:
                     num_key_value_heads=8, head_dim=8, num_experts=16,
                     num_experts_per_tok=2, moe_intermediate_size=32,
                     model_name="qwen3-moe-tiny")
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny_latent_moe(cls, **kw) -> "ModelConfig":
+        """Latent attention over routed experts at a size for the CPU
+        mesh: YaRN with an original length of 16, so that a short test
+        crosses it; 16 experts, 4 a token, 4 of them held."""
+        base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=2,
+                    num_attention_heads=4, num_key_value_heads=4,
+                    head_dim=16, q_lora_rank=32, kv_lora_rank=16,
+                    qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
+                    rope_theta=10000.0, rope_factor=8.0,
+                    rope_original_max_position=16, rope_beta_fast=4.0,
+                    rope_beta_slow=1.0, rope_mscale=1.0,
+                    rope_mscale_all_dim=1.0, rope_query_scale_beta=0.1,
+                    num_experts=16, num_experts_per_tok=4,
+                    moe_intermediate_size=32,
+                    shared_expert_intermediate_size=32,
+                    first_held_expert=0, num_held_experts=4,
+                    max_position_embeddings=128,
+                    model_name="latent-moe-tiny")
         base.update(kw)
         return cls(**base)
 
@@ -271,7 +348,51 @@ class ModelConfig:
                     raise NotImplementedError(
                         "layer_types is not an every-Nth-layer "
                         f"full-attention schedule (got {layer_types})")
+        # Rope scaling: YaRN under latent attention is computed
+        # (models/latent_moe.py); any other scaling would be served as
+        # plain rope, silently wrong past its original length: refuse.
+        rope = get("rope_parameters") or get("rope_scaling") or {}
+        if not isinstance(rope, dict):
+            rope = dict(vars(rope))
+        scaling = rope.get("rope_type") or rope.get("type") or "default"
+        latent = {}
+        if get("kv_lora_rank"):
+            if scaling not in ("default", "yarn"):
+                raise NotImplementedError(
+                    f"rope scaling {scaling!r} under latent attention: "
+                    "only 'yarn' is computed")
+            if not get("rope_interleave", True):
+                raise NotImplementedError(
+                    "latent attention ropes interleaved pairs only "
+                    "(rope_interleave false)")
+            latent = dict(
+                q_lora_rank=req("q_lora_rank"),
+                kv_lora_rank=req("kv_lora_rank"),
+                qk_nope_head_dim=req("qk_nope_head_dim"),
+                qk_rope_head_dim=req("qk_rope_head_dim"),
+                v_head_dim=req("v_head_dim"),
+                routed_scaling_factor=get("routed_scaling_factor", 1.0),
+                rope_query_scale_beta=rope.get(
+                    "llama_4_scaling_beta", 0.0) or 0.0)
+            if scaling == "yarn":
+                latent.update(
+                    rope_factor=rope["factor"],
+                    rope_original_max_position=rope[
+                        "original_max_position_embeddings"],
+                    rope_beta_fast=rope.get("beta_fast", 32.0),
+                    rope_beta_slow=rope.get("beta_slow", 1.0),
+                    rope_mscale=rope.get("mscale", 1.0),
+                    rope_mscale_all_dim=rope.get("mscale_all_dim", 0.0))
+        elif scaling != "default":
+            raise NotImplementedError(
+                f"rope scaling {scaling!r} is not computed by this "
+                "model family (plain rope only)")
+        n_experts = get("num_experts", 0) or get("n_routed_experts", 0) or 0
+        shared_ff = get("shared_expert_intermediate_size", 0) or (
+            (get("n_shared_experts", 0) or 0)
+            * (get("moe_intermediate_size", 0) or 0))
         return cls(
+            **latent,
             vocab_size=req("vocab_size"),
             hidden_size=d,
             intermediate_size=get("intermediate_size", 4 * d),
@@ -294,11 +415,12 @@ class ModelConfig:
                 or get("model_type", "qwen3") in (
                     "seed_oss", "llama", "mistral")),
             rms_norm_eps=get("rms_norm_eps", 1e-6),
-            rope_theta=get("rope_theta", 1_000_000.0),
+            rope_theta=get("rope_theta") or rope.get(
+                "rope_theta", 1_000_000.0),
             max_position_embeddings=get("max_position_embeddings", 40960),
             tie_word_embeddings=get("tie_word_embeddings", False),
             model_name=get("model_type", "qwen3"),
-            num_experts=get("num_experts", 0) or 0,
+            num_experts=n_experts,
             num_experts_per_tok=get("num_experts_per_tok", 8) or 8,
             moe_intermediate_size=get("moe_intermediate_size", 768) or 768,
             norm_topk_prob=get("norm_topk_prob", True),
@@ -316,7 +438,6 @@ class ModelConfig:
             partial_rotary_factor=(
                 get("partial_rotary_factor", 0.25) or 0.25
                 if get("model_type") == "qwen3_next" else 1.0),
-            shared_expert_intermediate_size=get(
-                "shared_expert_intermediate_size", 0) or 0,
+            shared_expert_intermediate_size=shared_ff,
             norm_zero_centered=get("model_type") == "qwen3_next",
         )

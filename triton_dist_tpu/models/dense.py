@@ -405,6 +405,15 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     return _lm_head(params, x, axis).reshape(s, k, -1), cache
 
 
+def paged_pool(cfg: ModelConfig):
+    """The pool this model keeps, as the serving engine allocates it:
+    the cache class and what a token takes in a layer, here keys and
+    values of every KV head."""
+    from triton_dist_tpu.serving.blocks import PagedKVCache
+
+    return PagedKVCache, (cfg.num_key_value_heads, cfg.head_dim)
+
+
 def paged_cache_specs(axis: str = "tp", quantized: bool = False):
     """PartitionSpec pytree for the serving
     :class:`~triton_dist_tpu.serving.blocks.PagedKVCache` (KV heads

@@ -1,0 +1,473 @@
+"""Latent attention over a chip's share of routed experts: the paged
+serving contract of :mod:`triton_dist_tpu.models.dense` for a block
+whose cache is one latent a token and whose FFN is an expert layer told
+which experts it holds.
+
+The block (``h`` the normed residual row; sizes from ``ModelConfig``'s
+latent fields):
+
+    c_q = rms(h w_dq);  q = c_q w_uq          (H, d_n + d_r) a row
+    [c_kv | k_r] = h w_dkv;  c = rms(c_kv)    (r_kv), (d_r): the CACHE
+    rope on q's last d_r and on k_r: interleaved pairs, YaRN
+        frequencies; q *= 1 + beta ln(1 + pos // original)
+    [k_n | v] = c w_ukv                       (H, d_n + d_v) a key
+    s = (q_n . k_n + q_r . k_r) sigma;  o = softmax(s) v;  + o wo
+
+Two attention paths over the one pool
+(:class:`~triton_dist_tpu.serving.blocks.LatentPagedCache`, ``[c | roped
+k_r]`` a token a layer, a page lying ``(width, page)``):
+
+- a prefill chunk's rows EXPAND: a block of the slot's pages at a time
+  is gathered, its keys and values made by ``w_ukv``, and the rows
+  attend heads of ``d_n + d_r`` and ``d_v``: the cheap form where many
+  rows share the expanded keys (:func:`_attend_expanded`);
+- decode and verification rows ABSORB ``w_ukv`` into the query and the
+  output, ``q~ = q_n w_uk^T``, ``s = (q~ . c + q_r . k_r) sigma``,
+  ``o = (softmax(s) c) w_uv``, and attend the latent itself: no key is
+  ever expanded for a row that is alone with its context
+  (:func:`_attend_absorbed`).
+
+Both walk the context in blocks of whole pages with a running softmax
+(no ``(rows, heads, context)`` score array exists) and stop at the last
+block any row can see. Plain XLA: no kernel of this repo computes
+either yet.
+
+The FFN is :func:`~triton_dist_tpu.layers.ep_moe.fwd_held`: the router
+over every expert, the held experts' part of the result, the shared
+expert whole. Every step function returns, last, ``STEP_STATS`` as one
+int32 vector summed over layers; the serving programs carry it out
+beside the picked tokens (docs/observability.md, "Held experts").
+
+Everything is replicated over ``axis`` but the head's vocabulary rows:
+the deployment this stands for divides a layer's EXPERTS over chips,
+and this module is one of those chips with no peer.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from triton_dist_tpu.layers import ep_moe
+from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.rope import (apply_rope_interleaved,
+                                         yarn_freqs, yarn_mscale)
+from triton_dist_tpu.models.config import ModelConfig
+from triton_dist_tpu.models.dense import (FwdContexts, _last_valid_row,
+                                          _lm_head)
+
+# What every step function returns last, summed over layers: the
+# token-expert pairs that fell to held experts, and the most rows one
+# held expert was given.
+STEP_STATS = ("held_pairs", "expert_rows_max")
+
+# Keys a block of the context walk holds, at most: the expanded path's
+# float32 scores are heads x rows x this.
+BLOCK_KEYS = 1280
+_NEG = -1e30
+
+
+def cache_width(cfg: ModelConfig) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def paged_pool(cfg: ModelConfig):
+    """The pool this model keeps: one array of ``[latent | roped key]``
+    a token a layer."""
+    from triton_dist_tpu.serving.blocks import LatentPagedCache
+
+    return LatentPagedCache, (cache_width(cfg),)
+
+
+def paged_cache_specs(axis: str = "tp", quantized: bool = False):
+    from triton_dist_tpu.serving.blocks import LatentPagedCache
+
+    if quantized:
+        raise ValueError("the latent pool is not quantized")
+    return LatentPagedCache(pages=P(None, None, None, None),
+                            block_table=P(None, None), lens=P(None),
+                            live=P(None))
+
+
+def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    dq = h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    dkv = h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+    f, fs = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
+    e = cfg.held_experts
+
+    def w(k, *shape):
+        return (jax.random.normal(k, shape, dtype)
+                * shape[-2] ** -0.5)
+
+    keys = jax.random.split(key, cfg.num_hidden_layers + 2)
+    layers = []
+    for li in range(cfg.num_hidden_layers):
+        k = jax.random.split(keys[li], 12)
+        moe = {"router": w(k[5], d, cfg.num_experts),
+               "w_gate": w(k[6], e, d, f), "w_up": w(k[7], e, d, f),
+               "w_down": w(k[8], e, f, d)}
+        if fs:
+            moe.update(w_shared_gate=w(k[9], d, fs),
+                       w_shared_up=w(k[10], d, fs),
+                       w_shared_down=w(k[11], fs, d))
+        layers.append({
+            "attn": {"w_dq": w(k[0], d, cfg.q_lora_rank),
+                     "q_norm": jnp.ones((cfg.q_lora_rank,), dtype),
+                     "w_uq": w(k[1], cfg.q_lora_rank, dq),
+                     "w_dkv": w(k[2], d, cache_width(cfg)),
+                     "kv_norm": jnp.ones((cfg.kv_lora_rank,), dtype),
+                     "w_ukv": w(k[3], cfg.kv_lora_rank, dkv),
+                     "wo": w(k[4], h * cfg.v_head_dim, d)},
+            "moe": moe,
+            "ln_attn": jnp.ones((d,), dtype),
+            "ln_mlp": jnp.ones((d,), dtype)})
+    table = lambda k: jax.random.normal(
+        k, (cfg.vocab_size, d), dtype) * 0.02
+    emb = table(keys[-2])
+    return {"embed": emb, "layers": layers,
+            "ln_f": jnp.ones((d,), dtype),
+            "lm_head": emb if cfg.tie_word_embeddings else table(keys[-1])}
+
+
+def param_specs(cfg: ModelConfig, axis: str = "tp") -> Dict:
+    layer = jax.tree.map(
+        lambda x: P(*(None,) * x.ndim),
+        jax.eval_shape(lambda: init_params(
+            jax.random.PRNGKey(0), cfg)["layers"][0]))
+    return {"embed": P(None, None),
+            "layers": [layer] * cfg.num_hidden_layers,
+            "ln_f": P(None), "lm_head": P(axis, None)}
+
+
+# -- the Engine's dense-cache contract: not this model's path ---------------
+
+def cache_specs(axis: str = "tp"):
+    from triton_dist_tpu.models import dense
+
+    return dense.cache_specs(axis)
+
+
+def _paged_only(*_, **__):
+    raise NotImplementedError(
+        "models.latent_moe serves through the paged latent pool only: "
+        "Engine(...).serving(prefill_buckets=...); it keeps no dense "
+        "per-request cache for Engine.serve")
+
+
+prefill = decode_step = _paged_only
+
+
+# -- projections -----------------------------------------------------------
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _rope(x, positions, cfg: ModelConfig):
+    inv = yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta,
+                     factor=cfg.rope_factor,
+                     original=cfg.rope_original_max_position,
+                     beta_fast=cfg.rope_beta_fast,
+                     beta_slow=cfg.rope_beta_slow)
+    scale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return apply_rope_interleaved(x, positions, inv, scale)
+
+
+def project(attn, h, cfg: ModelConfig, positions):
+    """A row's queries and its cache entry. h: (n, d), positions (n,).
+    Returns ``q (n, H, d_n + d_r)``, roped and scaled by position, and
+    ``latent (n, r_kv + d_r)``: ``[rms(c_kv) | roped k_r]``."""
+    n = h.shape[0]
+    dn = cfg.qk_nope_head_dim
+    eps = cfg.rms_norm_eps
+    c_q = rms_norm(jnp.dot(h, attn["w_dq"]), attn["q_norm"], eps)
+    q = jnp.dot(c_q, attn["w_uq"]).reshape(n, cfg.num_attention_heads, -1)
+    q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], positions, cfg)],
+                        axis=-1)
+    if cfg.rope_query_scale_beta:
+        scale = 1.0 + cfg.rope_query_scale_beta * jnp.log1p(jnp.floor(
+            positions.astype(jnp.float32)
+            / cfg.rope_original_max_position))
+        q = (q.astype(jnp.float32) * scale[:, None, None]).astype(q.dtype)
+    ckv = jnp.dot(h, attn["w_dkv"])
+    r = cfg.kv_lora_rank
+    latent = jnp.concatenate(
+        [rms_norm(ckv[:, :r], attn["kv_norm"], eps),
+         _rope(ckv[:, r:], positions, cfg)], axis=-1)
+    return q, latent
+
+
+def _w_ukv(attn, cfg: ModelConfig):
+    """``w_ukv`` by head: (r_kv, H, d_n + d_v)."""
+    return attn["w_ukv"].reshape(cfg.kv_lora_rank,
+                                 cfg.num_attention_heads, -1)
+
+
+# -- the context walk ------------------------------------------------------
+
+def _blocked_table(table, page: int):
+    """``(table', pages a block)`` for a walk over ``table`` (..., p_max)
+    in blocks of at most ``BLOCK_KEYS`` keys: the row padded to whole
+    blocks with the scratch page, whose positions lie past every
+    length."""
+    ppb = max(min(BLOCK_KEYS // page, table.shape[-1]), 1)
+    pad = -table.shape[-1] % ppb
+    return jnp.pad(table, [(0, 0)] * (table.ndim - 1) + [(0, pad)]), ppb
+
+
+def _walk(n_blocks, scores_and_values, shape_m, shape_acc):
+    """A running softmax over ``n_blocks`` blocks of keys (a traced
+    count: the walk stops at the last block a row can see).
+    ``scores_and_values(j)`` gives block ``j``'s masked float32 scores
+    ``(..., keys)`` and ``pv(p)``, the block's values under weights
+    ``p``. Returns the normalised output, float32."""
+    def body(j, carry):
+        m, l, acc = carry
+        s, pv = scores_and_values(j)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        return (m_new, l * alpha + jnp.sum(p, axis=-1),
+                acc * alpha[..., None] + pv(p))
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full(shape_m, _NEG, jnp.float32),
+         jnp.zeros(shape_m, jnp.float32),
+         jnp.zeros(shape_acc, jnp.float32)))
+    return acc / l[..., None]
+
+
+def _attend_expanded(attn, q, cache, li, table_row, qpos, cfg):
+    """A chunk's rows over their slot's pages, keys and values expanded
+    a block at a time. q: (C, H, d_n + d_r); qpos (C,) the last position
+    each row sees. Returns (C, H * d_v)."""
+    c, h, dqk = q.shape
+    dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    table_row, ppb = _blocked_table(table_row, cache.page)
+    keys = ppb * cache.page
+    sigma = softmax_scale(cfg)
+    w_ukv = _w_ukv(attn, cfg)
+
+    def block(j):
+        lat = cache.gather(li, jax.lax.dynamic_slice(
+            table_row, (j * ppb,), (ppb,)))            # (ppb, W, page)
+        kv = jnp.einsum("jrp,rhd->jphd", lat[:, :r], w_ukv)
+        kv = kv.reshape(keys, h, dn + dv)
+        k_r = lat[:, r:].transpose(0, 2, 1).reshape(keys, 1, dqk - dn)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (keys, h, dqk - dn))], -1)
+        s = jnp.einsum("chd,khd->hck", q, k,
+                       preferred_element_type=jnp.float32) * sigma
+        kpos = j * keys + jnp.arange(keys, dtype=jnp.int32)
+        s = jnp.where(kpos[None, None, :] <= qpos[None, :, None], s, _NEG)
+        v = kv[..., dn:]
+        return s, lambda p: jnp.einsum(
+            "hck,khd->hcd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+
+    o = _walk(jnp.max(qpos) // keys + 1, block, (h, c), (h, c, dv))
+    return o.transpose(1, 0, 2).reshape(c, h * dv).astype(q.dtype)
+
+
+def _attend_absorbed(attn, q, cache, li, qpos, cfg):
+    """Rows alone with their slot's context, in the latent. q: (S, R, H,
+    d_n + d_r), R rows a slot; qpos (S, R) the last position each sees.
+    Returns (S * R, H * d_v)."""
+    s_, r_, h, _ = q.shape
+    dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    table, ppb = _blocked_table(cache.block_table, cache.page)
+    keys = ppb * cache.page
+    sigma = softmax_scale(cfg)
+    w_ukv = _w_ukv(attn, cfg)
+    # q~ = q_n w_uk^T beside q_r: one query against [c | k_r].
+    q_lat = jnp.concatenate(
+        [jnp.einsum("srhd,chd->srhc", q[..., :dn], w_ukv[..., :dn]),
+         q[..., dn:]], axis=-1)
+
+    def block(j):
+        lat = cache.gather(li, jax.lax.dynamic_slice_in_dim(
+            table, j * ppb, ppb, axis=1))              # (S, ppb, W, page)
+        s = jnp.einsum("srhw,sjwp->srhjp", q_lat, lat,
+                       preferred_element_type=jnp.float32) * sigma
+        s = s.reshape(s.shape[:3] + (keys,))
+        kpos = j * keys + jnp.arange(keys, dtype=jnp.int32)
+        s = jnp.where(kpos[None, None, None, :]
+                      <= qpos[:, :, None, None], s, _NEG)
+        return s, lambda p: jnp.einsum(
+            "srhjp,sjcp->srhc",
+            p.astype(lat.dtype).reshape(p.shape[:3] + (ppb, -1)),
+            lat[:, :, :r], preferred_element_type=jnp.float32)
+
+    o_lat = _walk(jnp.max(qpos) // keys + 1, block, (s_, r_, h),
+                  (s_, r_, h, r))
+    o = jnp.einsum("srhc,chd->srhd", o_lat.astype(q.dtype),
+                   w_ukv[..., dn:])
+    return o.reshape(s_ * r_, h * dv)
+
+
+# -- the layers ------------------------------------------------------------
+
+def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
+    """Every layer over ``x`` (n, d) at ``positions`` (n,).
+    ``attend(li, attn_params, q, latent, cache) -> (o (n, H * d_v),
+    cache)`` writes the rows' cache entries and reads what each row's
+    query sees. Returns ``(x normed (n, d), cache, stats)``."""
+    stats = jnp.zeros((len(STEP_STATS),), jnp.int32)
+    for li, lp in enumerate(params["layers"]):
+        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
+        q, latent = project(lp["attn"], h, cfg, positions)
+        o, cache = attend(li, lp["attn"], q, latent, cache)
+        x = x + jnp.dot(o, lp["attn"]["wo"])
+        h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
+        out, layer_stats = ep_moe.fwd_held(
+            lp["moe"], h, topk=cfg.num_experts_per_tok,
+            first=cfg.first_held_expert,
+            norm_topk_prob=cfg.norm_topk_prob,
+            routed_scale=cfg.routed_scaling_factor)
+        x = x + out.astype(x.dtype)
+        stats = stats + layer_stats
+    return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache, stats
+
+
+def _check(attn_impl, mode):
+    if attn_impl != "ref":
+        raise ValueError(
+            f"attn_impl={attn_impl!r}: latent attention has its plain "
+            "XLA paths only ('ref')")
+    if mode != "xla":
+        raise ValueError(f"mode={mode!r}: models.latent_moe has no fused "
+                         "collective layer; serve it with mode='xla'")
+
+
+def _chunk_qpos(positions, start, valid):
+    """The last position each chunk row sees: its own, and for bucket
+    padding the last valid row's, so that the walk stops there."""
+    i = jnp.arange(positions.shape[0], dtype=jnp.int32)
+    last = (jnp.asarray(start, jnp.int32)
+            + jnp.maximum(jnp.asarray(valid, jnp.int32) - 1, 0))
+    return jnp.where(i < valid, positions, last)
+
+
+def _decode_qpos(cache):
+    """(S, 1): a live slot's row sees through the token appended this
+    step; a parked one position 0 (scratch: finite, discarded)."""
+    return (jnp.maximum(cache.lens + cache.live, 1) - 1)[:, None]
+
+
+def prefill_chunk_paged(params, chunk_toks, cache, table_row,
+                        cfg: ModelConfig, *, start, wfrom, valid,
+                        mode: str = "xla", axis: str = "tp",
+                        ctxs: FwdContexts = FwdContexts(),
+                        attn_impl: str = "ref"):
+    """One fixed-shape chunk of a bucketed paged prefill
+    (:func:`models.dense.prefill_chunk_paged`'s contract). Returns
+    ``(logits (vocab,) of the last valid row, cache, stats)``."""
+    _check(attn_impl, mode)
+    c = chunk_toks.shape[0]
+    positions = (jnp.asarray(start, jnp.int32)
+                 + jnp.arange(c, dtype=jnp.int32))
+    qpos = _chunk_qpos(positions, start, valid)
+
+    def attend(li, attn, q, latent, cache):
+        cache = cache.write_chunk(li, latent, table_row, positions,
+                                  valid, wfrom)
+        return _attend_expanded(attn, q, cache, li, table_row, qpos,
+                                cfg), cache
+
+    x, cache, stats = _layers(params, params["embed"][chunk_toks],
+                              positions, cache, cfg, attend)
+    logits = _lm_head(params, _last_valid_row(x, valid), axis)
+    return logits[0], cache, stats
+
+
+def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
+                      mode: str = "xla", axis: str = "tp",
+                      ctxs: FwdContexts = FwdContexts(),
+                      attn_impl: str = "ref"):
+    """One continuous-batching decode step
+    (:func:`models.dense.decode_step_paged`'s contract). Returns
+    ``(logits (S, vocab), cache.advance(), stats)``."""
+    _check(attn_impl, mode)
+
+    def attend(li, attn, q, latent, cache):
+        cache = cache.append_decode(li, latent)
+        return _attend_absorbed(attn, q[:, None], cache, li,
+                                _decode_qpos(cache), cfg), cache
+
+    x, cache, stats = _layers(params, params["embed"][token_ids],
+                              cache.lens, cache, cfg, attend)
+    return _lm_head(params, x, axis), cache.advance(), stats
+
+
+def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
+                       cfg: ModelConfig, *, start, wfrom, valid,
+                       mode: str = "xla", axis: str = "tp",
+                       ctxs: FwdContexts = FwdContexts(),
+                       attn_impl: str = "ref",
+                       decode_attn_impl: str = "ref"):
+    """A prefill chunk of one slot and a decode step of the batch in one
+    program (:func:`models.dense.chunk_decode_paged`'s contract): the
+    ``C + S`` rows share every projection and the expert layer; the
+    chunk's rows expand, the decode rows absorb. Returns ``(chunk
+    logits (vocab,), decode logits (S, vocab), cache.advance(),
+    stats)``."""
+    _check(attn_impl, mode)
+    _check(decode_attn_impl, mode)
+    c = chunk_toks.shape[0]
+    chunk_pos = (jnp.asarray(start, jnp.int32)
+                 + jnp.arange(c, dtype=jnp.int32))
+    qpos = _chunk_qpos(chunk_pos, start, valid)
+
+    def attend(li, attn, q, latent, cache):
+        # Both writes, then both reads, as the dense program does.
+        cache = cache.write_chunk(li, latent[:c], table_row, chunk_pos,
+                                  valid, wfrom)
+        cache = cache.append_decode(li, latent[c:])
+        o_chunk = _attend_expanded(attn, q[:c], cache, li, table_row,
+                                   qpos, cfg)
+        o_dec = _attend_absorbed(attn, q[c:, None], cache, li,
+                                 _decode_qpos(cache), cfg)
+        return jnp.concatenate([o_chunk, o_dec]), cache
+
+    x, cache, stats = _layers(
+        params, params["embed"][jnp.concatenate([chunk_toks, token_ids])],
+        jnp.concatenate([chunk_pos, cache.lens]), cache, cfg, attend)
+    logits = _lm_head(
+        params, jnp.concatenate([_last_valid_row(x[:c], valid), x[c:]]),
+        axis)
+    return logits[0], logits[1:], cache.advance(), stats
+
+
+def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
+                      budget=None, mode: str = "xla", axis: str = "tp",
+                      ctxs: FwdContexts = FwdContexts(),
+                      attn_impl: str = "ref"):
+    """K candidate tokens a slot through one dispatch
+    (:func:`models.dense.verify_step_paged`'s contract): candidate ``j``
+    of a live slot sees its paged history and the candidates through
+    itself, in the latent. Returns ``(logits (S, K, vocab), cache)``,
+    lengths not advanced."""
+    _check(attn_impl, mode)
+    s, k = token_ids.shape
+    lens = cache.lens
+    steps = jnp.arange(k, dtype=jnp.int32)[None]
+    positions = (lens[:, None] + steps).reshape(s * k)
+
+    def attend(li, attn, q, latent, cache):
+        cache = cache.append_block(li, latent.reshape(s, k, -1),
+                                   budget=budget)
+        qpos = jnp.maximum(
+            lens[:, None] + cache.live[:, None] * (steps + 1), 1) - 1
+        return _attend_absorbed(attn, q.reshape((s, k) + q.shape[1:]),
+                                cache, li, qpos, cfg), cache
+
+    x, cache, _ = _layers(params, params["embed"][token_ids.reshape(-1)],
+                          positions, cache, cfg, attend)
+    return _lm_head(params, x, axis).reshape(s, k, -1), cache

@@ -273,6 +273,12 @@ def _ar_ffn(cfg: ModelConfig, moe_impl, axis, ep_ctx):
                              counts=None, _layer_cursor=[0])
 
 
+def paged_pool(cfg: ModelConfig):
+    from triton_dist_tpu.models import dense as _dense
+
+    return _dense.paged_pool(cfg)
+
+
 def paged_cache_specs(axis: str = "tp", quantized: bool = False):
     from triton_dist_tpu.models import dense as _dense
 

@@ -166,6 +166,49 @@ def _merge_pages(pool, layer: int, pids, toks, write):
     return pool
 
 
+def _append_targets(block_table, lens, page: int, k: int, budget=None):
+    """Where ``k`` consecutive tokens a slot land, from each slot's own
+    length on: ``(pids, offs)``, each ``(num_slots * k,)``. A token past
+    its slot's table row, or past its slot's ``budget`` (S,), lands in
+    the scratch page, as a parked slot's (all-zero row) does."""
+    pos = lens[:, None] + jnp.arange(k, dtype=jnp.int32)[None]
+    rows_raw = pos // page
+    valid = rows_raw < block_table.shape[1]
+    if budget is not None:
+        valid = jnp.logical_and(
+            valid, jnp.arange(k, dtype=jnp.int32)[None]
+            < budget[:, None])
+    rows = jnp.clip(rows_raw, 0, block_table.shape[1] - 1)
+    pids = jnp.where(
+        valid, jnp.take_along_axis(block_table, rows, axis=1),
+        SCRATCH_PAGE)
+    return pids.reshape(-1), (pos % page).reshape(-1)
+
+
+def _chunk_window(table_row, positions, valid, wfrom, page: int):
+    """The window of whole pages a chunk of consecutive ``positions``
+    lies in: ``(pids (n_t,), write (n_t * page,) bool, loc0)``, the
+    chunk's first row at offset ``loc0`` of the window. A row is
+    written where it is below ``valid`` and at or past ``wfrom``; a
+    page none of whose rows is written (bucket padding, a prefix-shared
+    page, past the table row) is the scratch page."""
+    c = positions.shape[0]
+    n_t = (c - 1) // page + 2
+    row0 = positions[0] // page
+    loc0 = positions[0] - row0 * page
+    i = jnp.arange(c, dtype=jnp.int32)
+    write = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_t * page,), bool),
+        jnp.logical_and(i < valid, positions >= wfrom), (loc0,))
+    rows = row0 + jnp.arange(n_t, dtype=jnp.int32)
+    written = jnp.any(write.reshape(n_t, page), axis=1)
+    pids = jnp.where(
+        jnp.logical_and(written, rows < table_row.shape[0]),
+        table_row[jnp.clip(rows, 0, table_row.shape[0] - 1)],
+        SCRATCH_PAGE)
+    return pids, write, loc0
+
+
 def pool_shardings(mesh, spec_tree):
     """NamedShardings for a :class:`PagedKVCache` spec pytree, with
     trailing-``None`` dims dropped from every spec — the spelling jit
@@ -325,20 +368,8 @@ class PagedKVCache:
         (:meth:`_quant_append`)."""
         if self.quantized:
             return self._quant_append(layer, k_tok, v_tok, budget)
-        page = self.page
-        k = k_tok.shape[1]
-        pos = self.lens[:, None] + jnp.arange(k, dtype=jnp.int32)[None]
-        rows_raw = pos // page
-        valid = rows_raw < self.block_table.shape[1]
-        if budget is not None:
-            valid = jnp.logical_and(
-                valid, jnp.arange(k, dtype=jnp.int32)[None]
-                < budget[:, None])
-        rows = jnp.clip(rows_raw, 0, self.block_table.shape[1] - 1)
-        pids = jnp.where(
-            valid, jnp.take_along_axis(self.block_table, rows, axis=1),
-            SCRATCH_PAGE)
-        pids, off = pids.reshape(-1), (pos % page).reshape(-1)
+        pids, off = _append_targets(self.block_table, self.lens,
+                                    self.page, k_tok.shape[1], budget)
         kvl, hd = k_tok.shape[2:]
         return dataclasses.replace(
             self,
@@ -414,22 +445,9 @@ class PagedKVCache:
         # that begins at the start's page. Lay the rows out on that
         # window, and merge it into the pool a page at a time.
         page = self.page
-        c = positions.shape[0]
-        n_t = (c - 1) // page + 2
-        row0 = positions[0] // page
-        loc0 = positions[0] - row0 * page
-        i = jnp.arange(c, dtype=jnp.int32)
-        write = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_t * page,), bool),
-            jnp.logical_and(i < valid, positions >= wfrom), (loc0,))
-        # A page none of whose rows is written (bucket padding, a
-        # prefix-shared page, past the table row) is the scratch page.
-        rows = row0 + jnp.arange(n_t, dtype=jnp.int32)
-        written = jnp.any(write.reshape(n_t, page), axis=1)
-        pids = jnp.where(
-            jnp.logical_and(written, rows < table_row.shape[0]),
-            table_row[jnp.clip(rows, 0, table_row.shape[0] - 1)],
-            SCRATCH_PAGE)
+        pids, write, loc0 = _chunk_window(table_row, positions, valid,
+                                          wfrom, page)
+        n_t = pids.shape[0]
 
         def window(tok):
             kvl, hd = tok.shape[2:]
@@ -601,6 +619,124 @@ class PagedKVCache:
 
 jax.tree_util.register_pytree_node(
     PagedKVCache, PagedKVCache.tree_flatten, PagedKVCache.tree_unflatten)
+
+
+@dataclasses.dataclass
+class LatentPagedCache:
+    """The paged pool of a latent-attention model: ONE array, ``pages``
+    (L, num_pages, width, page), ``width`` values a token a layer (the
+    latent beside the roped key every head shares), no heads;
+    ``block_table``, ``lens`` and ``live`` as :class:`PagedKVCache`'s,
+    and the same page accounting (:class:`BlockManager` counts pages,
+    not what a page holds). Every rank keeps the whole pool: there is
+    no head to divide it by.
+
+    A page lies ``(width, page)``, a token a COLUMN: the page's 128
+    positions fill the lanes of a tile whole, where 320 values would
+    leave its last half empty (the TPU's own choice for the array the
+    other way round, which it then relaid before every read: PERF.md,
+    PR 37). Attention contracts over ``width`` as the pages lie.
+    Written in place, one layout, as :class:`PagedKVCache` is: a
+    ``lax.dynamic_update_slice`` of a column a token or of a whole page.
+    Not quantized, not tiered, not migrated: the first slice of a pool
+    stated by the model, as wide as its one model needs
+    (docs/serving.md, "The pool a model states")."""
+
+    pages: jax.Array
+    block_table: jax.Array
+    lens: jax.Array
+    live: jax.Array
+
+    quantized = False
+
+    @classmethod
+    def empty(cls, num_layers: int, num_pages: int, page: int,
+              width: int, *, num_slots: int, p_max: int,
+              dtype=jnp.float32, kv_dtype: str = "bf16"):
+        if kv_quant_spec(kv_dtype)[0] is not None:
+            raise ValueError(f"the latent pool is not quantized: "
+                             f"kv_dtype={kv_dtype!r}")
+        return cls(
+            pages=jnp.zeros((num_layers, num_pages, width, page), dtype),
+            block_table=jnp.zeros((num_slots, p_max), jnp.int32),
+            lens=jnp.zeros((num_slots,), jnp.int32),
+            live=jnp.zeros((num_slots,), jnp.int32))
+
+    empty_sharded = classmethod(PagedKVCache.empty_sharded.__func__)
+
+    @property
+    def page(self) -> int:
+        return self.pages.shape[3]
+
+    @property
+    def width(self) -> int:
+        return self.pages.shape[2]
+
+    def append_block(self, layer: int, rows,
+                     budget=None) -> "LatentPagedCache":
+        """Write K consecutive tokens a slot at each slot's own length
+        (:meth:`PagedKVCache.append_block`'s rule for parked slots, the
+        table row's end and ``budget``), a column a token. rows:
+        (num_slots, K, width)."""
+        pids, off = _append_targets(self.block_table, self.lens,
+                                    self.page, rows.shape[1], budget)
+        cols = rows.astype(self.pages.dtype).reshape(
+            -1, 1, 1, self.width, 1)
+        pool = self.pages
+        for n in range(cols.shape[0]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, cols[n], (layer, pids[n], 0, off[n]))
+        return dataclasses.replace(self, pages=pool)
+
+    def append_decode(self, layer: int, rows) -> "LatentPagedCache":
+        """One decode token a slot: rows (num_slots, width)."""
+        return self.append_block(layer, rows[:, None])
+
+    def advance(self) -> "LatentPagedCache":
+        return dataclasses.replace(
+            self, lens=self.lens + self.live.astype(jnp.int32))
+
+    def write_chunk(self, layer: int, rows, table_row, positions, valid,
+                    wfrom) -> "LatentPagedCache":
+        """Write one prefill chunk's rows (C, width) into a slot's
+        pages, a whole page at a time
+        (:meth:`PagedKVCache.write_chunk`'s rule for padding and
+        resident positions)."""
+        page, width = self.page, self.width
+        pids, write, loc0 = _chunk_window(table_row, positions, valid,
+                                          wfrom, page)
+        window = jax.lax.dynamic_update_slice(
+            jnp.zeros((width, write.shape[0]), self.pages.dtype),
+            rows.astype(self.pages.dtype).T, (0, loc0))
+        pool = self.pages
+        for j in range(pids.shape[0]):
+            at = (layer, pids[j], 0, 0)
+            cols = slice(j * page, (j + 1) * page)
+            old = jax.lax.dynamic_slice(pool, at, (1, 1, width, page))
+            new = jnp.where(write[cols], window[None, None, :, cols], old)
+            pool = jax.lax.dynamic_update_slice(pool, new, at)
+        return dataclasses.replace(self, pages=pool)
+
+    def gather(self, layer: int, page_ids):
+        """Pages ``page_ids`` (..., n) of ``layer`` as they lie:
+        (..., n, width, page)."""
+        # Out of the whole pool, the layer in the index: nothing cuts
+        # ``pages[layer]`` out (a walk's loop would hoist that copy).
+        n = self.pages.shape[1]
+        return jnp.take(self.pages.reshape((-1,) + self.pages.shape[2:]),
+                        layer * n + page_ids, axis=0)
+
+    def tree_flatten(self):
+        return (self.pages, self.block_table, self.lens, self.live), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
+jax.tree_util.register_pytree_node(
+    LatentPagedCache, LatentPagedCache.tree_flatten,
+    LatentPagedCache.tree_unflatten)
 
 
 class BlockManager:

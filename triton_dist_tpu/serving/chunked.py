@@ -27,7 +27,7 @@ import numpy as np
 from triton_dist_tpu.ops.chunked_prefill import plan_chunks
 
 __all__ = ["ChunkedPrefill", "MegaChunkedPrefill", "DEFAULT_BUCKETS",
-           "greedy_tokens"]
+           "greedy_tokens", "picked_with_stats"]
 
 # Production default (the e.g. of ROADMAP Open item 1); tests and tiny
 # models pass their own. Sizing guidance in docs/serving.md.
@@ -42,6 +42,16 @@ def greedy_tokens(logits):
     import jax.numpy as jnp
 
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def picked_with_stats(picked, stats):
+    """A step program's picked tokens with its model's ``STEP_STATS``
+    behind them, one int32 array: the counts leave the chip in the copy
+    the tick makes anyway. Rows are read by slot from the front, the
+    stats from the back."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([picked, stats.astype(jnp.int32)])
 
 
 class ChunkedPrefill:
@@ -71,7 +81,8 @@ class ChunkedPrefill:
 
     Every program's first output is its rows' greedy tokens
     (:func:`greedy_tokens`), int32 ``(1 + decode_rows,)``: row 0 the
-    chunk's last valid row, rows 1.. the decode rows. A greedy request's
+    chunk's last valid row, rows 1.. the decode rows (and behind them
+    the model's ``STEP_STATS``, where it has any). A greedy request's
     token is read from there; the logits stay outputs, on the device,
     for a request that samples.
     """
@@ -113,7 +124,10 @@ class ChunkedPrefill:
         # Quantized pools carry per-page scale leaves — the chunk
         # dispatch's cache spec must match the pool it writes.
         kv_spec = model.paged_cache_specs(
-            axis, quantized=cache_shardings.k_scale is not None)
+            axis, quantized=cache_shardings.quantized)
+        # A model with ``STEP_STATS`` returns them last from every step;
+        # they ride the picked tokens out (:func:`picked_with_stats`).
+        stats = bool(getattr(model, "STEP_STATS", ()))
 
         self.decode_rows = int(decode_rows)
         # What :meth:`step` feeds the decode rows (see
@@ -122,12 +136,15 @@ class ChunkedPrefill:
         if not self.decode_rows:
             def _chunk(params, toks, cache, table_row, start, wfrom,
                        valid):
-                logits, cache = model.prefill_chunk_paged(
+                logits, cache, *st = model.prefill_chunk_paged(
                     params, toks, cache, table_row, cfg, start=start,
                     wfrom=wfrom, valid=valid, mode=engine.mode,
                     axis=axis, ctxs=engine.ctxs, attn_impl=attn_impl,
                     **mk)
-                return greedy_tokens(logits[None]), logits, cache
+                picked = greedy_tokens(logits[None])
+                if stats:
+                    picked = picked_with_stats(picked, st[0])
+                return picked, logits, cache
 
             dec_in = dec_out = dec_sh = ()
         elif not hasattr(model, "chunk_decode_paged"):
@@ -140,7 +157,7 @@ class ChunkedPrefill:
             # at the same place in a profile.
             def _chunk(params, toks, cache, table_row, start, wfrom,
                        valid, dec_toks):
-                logits, dec, cache = model.chunk_decode_paged(
+                logits, dec, cache, *st = model.chunk_decode_paged(
                     params, toks, dec_toks, cache, table_row, cfg,
                     start=start, wfrom=wfrom, valid=valid,
                     mode=engine.mode, axis=axis, ctxs=engine.ctxs,
@@ -148,6 +165,8 @@ class ChunkedPrefill:
                     **mk)
                 picked = jnp.concatenate(
                     [greedy_tokens(logits[None]), greedy_tokens(dec)])
+                if stats:
+                    picked = picked_with_stats(picked, st[0])
                 return picked, logits, dec, cache
 
             dec_in, dec_out = (P(None),), (P(None, None),)

@@ -483,7 +483,13 @@ class ServingEngine:
             "router_prefetched_pages": 0, "worker_prefetched_pages": 0,
             "integrity_failures": 0, "slo_preemptions": 0,
             "ticks": 0,
+            # Held experts (a model with STEP_STATS): token-expert
+            # pairs the read programs routed, the ones that fell to
+            # held experts, and the fullest expert's rows a layer.
+            "expert_pairs_routed": 0, "expert_pairs_held": 0,
+            "expert_rows_max": 0, "expert_steps": 0,
         }
+        self._step_stats = ()
         self.prefill_buckets = (tuple(sorted(set(int(b) for b in
                                                  prefill_buckets)))
                                 if prefill_buckets else None)
@@ -667,14 +673,24 @@ class ServingEngine:
                 "engine instead")
         cfg, mesh, axis = eng.cfg, eng.mesh, eng.axis
         n = mesh.shape[axis]
-        # GLOBAL kv-head count here — the sharding carves it into the
-        # per-shard kv_loc the decode step sees; the pool is allocated
-        # under that sharding, never whole on one device.
-        cache, shardings = PagedKVCache.empty_sharded(
+        # The model states the pool it keeps (``paged_pool``: the class
+        # and what a token takes in a layer: K and V of every KV head,
+        # or one latent); the server allocates that. GLOBAL sizes here
+        # — the sharding carves the per-shard part the step sees; the
+        # pool is allocated under that sharding, never whole on one
+        # device.
+        pool_cls, per_token = model.paged_pool(cfg)
+        if pool_cls is not PagedKVCache and (
+                self.tiers is not None or not self.prefill_buckets):
+            raise NotImplementedError(
+                f"a {pool_cls.__name__} is filled by chunked prefill "
+                "(prefill_buckets=...) and neither tiered nor parked: "
+                "tier transfers and the monolithic prompt blit move "
+                "K and V pages")
+        cache, shardings = pool_cls.empty_sharded(
             mesh, model.paged_cache_specs, axis,
             cfg.num_hidden_layers, num_pages, self.page,
-            cfg.num_key_value_heads, cfg.head_dim, num_slots=num_slots,
-            p_max=self.p_max,
+            *per_token, num_slots=num_slots, p_max=self.p_max,
             dtype=jax.tree.leaves(eng.params)[0].dtype,
             kv_dtype=self.kv_dtype)
         kv_spec = model.paged_cache_specs(
@@ -792,8 +808,12 @@ class ServingEngine:
         # behind the head (at TP > 1 every shard holds the gathered
         # row, so the pick is replicated): a tick of greedy rows
         # fetches those integers and leaves the logits on the device.
-        from triton_dist_tpu.serving.chunked import greedy_tokens
+        from triton_dist_tpu.serving.chunked import (greedy_tokens,
+                                                     picked_with_stats)
 
+        # A model with ``STEP_STATS`` returns them last from every step;
+        # they leave the chip behind the picked tokens, in their array.
+        self._step_stats = tuple(getattr(model, "STEP_STATS", ()))
         row_sh = NamedSharding(mesh, P(None))
         logits_sh = NamedSharding(mesh, P(None, None))
         out_specs = (P(None), P(None, None), kv_spec)
@@ -820,6 +840,9 @@ class ServingEngine:
                 out = model.decode_step_paged(
                     params, toks, c, cfg, mode=eng.mode, axis=axis,
                     ctxs=eng.ctxs, attn_impl=self.attn_impl, **mk)
+                if self._step_stats:
+                    return (picked_with_stats(greedy_tokens(out[0]),
+                                              out[-1]), *out[:-1])
                 return (greedy_tokens(out[0]), *out)
 
             self._decode = jax.jit(jax.shard_map(
@@ -1075,6 +1098,19 @@ class ServingEngine:
             out["expert_load"] = self.expert_ewma.tolist()
             out["expert_totals"] = self.expert_totals.tolist()
             out["replicated_experts"] = dict(self._replicated)
+        if self._step_stats and out["expert_pairs_routed"]:
+            # Of the pairs the read programs routed, the share a held
+            # expert computed, and how full the fullest expert ran
+            # against the even share of them (1 = perfectly even).
+            out["expert_held_share"] = (out["expert_pairs_held"]
+                                        / out["expert_pairs_routed"])
+            out["expert_load_imbalance"] = (
+                out["expert_rows_max"] * self.cfg.held_experts
+                / max(out["expert_pairs_held"], 1))
+            # Rows a held expert was given, a layer of a read program.
+            out["expert_rows_mean"] = out["expert_pairs_held"] / (
+                self.cfg.held_experts * self.cfg.num_hidden_layers
+                * out["expert_steps"])
         if self.manager is not None:
             out["pool"] = self.manager.fragmentation()
         if hasattr(self, "plan"):
@@ -1800,6 +1836,8 @@ class ServingEngine:
                     picked = self._read(picked)
                     rows = _Rows(picked[1:], dec,
                                  self._read(dec) if sampled else None)
+                    self._note_step_stats(picked,
+                                          plan[1] + self.num_slots)
                 if first_done:
                     # Its token came with the batch's: row 0 of the
                     # one array, already on the host.
@@ -3045,8 +3083,37 @@ class ServingEngine:
             if ecounts is not None:
                 self._note_expert_counts(
                     self._read(ecounts).astype(np.int64))
-            return _Rows(self._read(picked), logits,
+            picked = self._read(picked)
+            self._note_step_stats(picked, self.num_slots)
+            return _Rows(picked, logits,
                          self._read(logits) if sampled else None)
+
+    def _note_step_stats(self, picked: np.ndarray, rows: int):
+        """Book the ``STEP_STATS`` a step program of ``rows`` rows
+        appended to its picked tokens (a model that has none: nothing):
+        the counters behind ``stats()["expert_pairs_held"]`` and its
+        neighbours, and one ``expert_load`` event, which a profiler
+        capture holds as ``tdt.expert_load``. Read only where the tick
+        fetched the picked tokens anyway: a chunk program that ran with
+        its decode rows parked, mid-prompt, is not counted."""
+        if not self._step_stats:
+            return
+        cfg = self.cfg
+        held, rows_max = (int(v) for v in
+                          picked[-len(self._step_stats):])
+        routed = (rows * cfg.num_experts_per_tok
+                  * cfg.num_hidden_layers)
+        c = self.stats_counters
+        c["expert_pairs_routed"] += routed
+        c["expert_pairs_held"] += held
+        c["expert_rows_max"] += rows_max
+        c["expert_steps"] += 1
+        self.obs.event(
+            "expert_load", step=c["decode_dispatches"], rows=rows,
+            held_pairs=held, routed_pairs=routed,
+            expert_rows_max=rows_max,
+            expert_imbalance=(rows_max * cfg.held_experts / held
+                              if held else None))
 
     def _wait_decode(self, outputs):
         """Block until a decode step's ``outputs`` exist, under the

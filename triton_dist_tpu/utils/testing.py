@@ -48,3 +48,65 @@ def assert_allclose(actual: Any, desired: Any, rtol: float = 1e-5,
     desired = jax.device_get(desired)
     np.testing.assert_allclose(actual, desired, rtol=rtol, atol=atol,
                                err_msg=msg)
+
+
+# -- a compiled step program: no copy of the paged pool ----------------------
+
+_IN_PLACE = {"parameter", "dynamic-update-slice", "tuple", "bitcast",
+             "get-tuple-element"}
+
+
+def pool_copies(hlo: str, pool_shape, dtype: str = "bf16"):
+    """Instructions of an optimised HLO module's entry computation that
+    copy the KV pool or one layer of it: anything that produces an array
+    of ``pool_shape`` or ``pool_shape[1:]`` other than the parameter
+    itself, a Mosaic call, or an update in place (a
+    ``dynamic-update-slice``, alone or as all a fusion does to the
+    pool), and any such array in a layout that is not row-major. A
+    ``while`` that carries the pool through unchanged (a walk over
+    pages that only reads it: in its body the pool is a tuple element
+    and nothing else) is no copy. Returns the offending lines, cut
+    short."""
+    import re
+
+    def dims(shape):
+        return dtype + "[" + ",".join(str(d) for d in shape) + "]"
+
+    pool, layer = dims(pool_shape), dims(pool_shape[1:])
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line)
+
+    def parse(line):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        return m.groups() if m else ("", "")
+
+    def relaid(result):
+        laid = re.findall(re.escape(pool) + r"\{([\d,]+)", result)
+        laid += re.findall(re.escape(layer) + r"\{([\d,]+)", result)
+        return any(lay.split(",") != sorted(lay.split(","), reverse=True)
+                   for lay in laid)
+
+    bad = []
+    for line in comps["ENTRY"]:
+        result, op = parse(line)
+        if pool not in result and layer not in result:
+            continue
+        ok = op in _IN_PLACE and layer + "{" not in result.replace(pool, "")
+        if op == "while":
+            called = re.search(r"body=%([\w.\-]+)", line).group(1)
+            ok = all(parse(inner)[1] in _IN_PLACE - {"dynamic-update-slice"}
+                     for inner in comps[called]
+                     if pool in parse(inner)[0] or layer in parse(inner)[0])
+        if op == "fusion" and layer + "{" not in result.replace(pool, ""):
+            called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+            ok = all(parse(inner)[1] in _IN_PLACE
+                     for inner in comps[called] if pool in parse(inner)[0])
+        if not ok or relaid(result):
+            bad.append(line.strip()[:200])
+    return bad
