@@ -14,6 +14,7 @@ both away from the identity).
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -325,6 +326,7 @@ def test_the_server_serves_it_with_the_pool_under_pressure(tiny):
     assert st["preemptions"] > 0 and rs["preemptions"] == 0
     for s in (st, rs):
         assert s["decode_dispatches_fused"] > 0
+        assert s["prefill_chunks"] > s["chunk_dispatches_kernel_walk"] == 0
         assert s["tokens_picked_on_device"] == s["tokens_generated"] == 60
         assert s["kv_bytes_per_token"] == 2 * 24 * 4
         assert s["expert_pairs_routed"] > s["expert_pairs_held"] > 0
@@ -341,6 +343,106 @@ def test_the_server_serves_it_with_the_pool_under_pressure(tiny):
     assert tight.decode_cache_size() == 1
     assert isinstance(tight.cache, LatentPagedCache)
     assert tight.cache.pages.shape == (2, 12, 24, 8)
+
+
+@pytest.fixture(scope="module")
+def legal(tiny):
+    """The tiny preset with the heads, the latent and the pages of a
+    size the chunk rows' kernel tiles (2 heads of 64 + 64 and 128 over a
+    latent of 128, pages of 128 positions): (config, dims, ModelConfig,
+    mesh, seeded params)."""
+    config = dict(
+        tiny[0], num_attention_heads=2, num_key_value_heads=2,
+        head_dim=128, q_lora_rank=64, kv_lora_rank=128,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+        max_position_embeddings=512)
+    mesh = tiny[3]
+    return (config, F.dims(config), SYS.model_config(config), mesh,
+            SYS.make_params(config, mesh, SEED))
+
+
+def test_the_chunk_walk_is_chosen_by_sizes(tiny, legal):
+    cfg, big = tiny[2], legal[2]
+    assert latent_moe.chunk_walk_impl(cfg, 16, 8) == "xla"
+    assert latent_moe.chunk_walk_impl(cfg, 128, 128) == "xla"   # heads
+    assert latent_moe.chunk_walk_impl(big, 128, 128) == "kernel"
+    assert latent_moe.chunk_walk_impl(big, 256, 128) == "kernel"
+    assert latent_moe.chunk_walk_impl(big, 128, 8) == "xla"     # pages
+    assert latent_moe.chunk_walk_impl(big, 72, 128) == "xla"    # rows
+    with pytest.raises(ValueError, match="the model's own paths"):
+        latent_moe.prefill_chunk_paged(
+            None, jnp.zeros((128,), jnp.int32), None, None, big, start=0,
+            wfrom=0, valid=1, attn_impl="flash")
+
+
+def test_chunks_through_the_kernel_then_decode_equal_the_reference(legal):
+    """:func:`test_chunks_then_decode_through_the_pool_equal_the_reference`
+    where the chunk rows walk in the Pallas kernel (interpreted): 200
+    tokens prefilled as chunks of 128 and 72 (in a bucket of 128,
+    padded), the second with the decode batch aboard, then 8 decode
+    steps; float32, the same 1e-4."""
+    config, dims, cfg, mesh, params = legal
+    specs = latent_moe.param_specs(cfg, "tp")
+    kv = latent_moe.paged_cache_specs("tp")
+    chunk = _on_mesh(
+        mesh, lambda p, t, c, row, start, valid:
+        latent_moe.prefill_chunk_paged(p, t, c, row, cfg, start=start,
+                                       wfrom=0, valid=valid)[:2],
+        (specs, P(None), kv, P(None), P(), P()), (P(None), kv))
+    fused = _on_mesh(
+        mesh, lambda p, t, d, c, row, start, valid:
+        latent_moe.chunk_decode_paged(p, t, d, c, row, cfg, start=start,
+                                      wfrom=0, valid=valid)[:3],
+        (specs, P(None), P(None), kv, P(None), P(), P()),
+        (P(None), P(None, None), kv))
+    decode = _on_mesh(
+        mesh, lambda p, t, c: latent_moe.decode_step_paged(p, t, c, cfg)[:2],
+        (specs, P(None), kv), (P(None, None), kv))
+    seq = np.random.default_rng(5).integers(0, dims.vocab, size=209)
+    row = jnp.asarray([3, 1], jnp.int32)
+    cache = _empty(cfg, pages=4, page=128, slots=2, p_max=2)
+    _, cache = chunk(params, jnp.asarray(seq[:128], jnp.int32), cache,
+                     row, 0, 128)
+    toks = np.zeros(128, np.int32)
+    toks[:72] = seq[128:200]
+    logits, _, cache = fused(params, jnp.asarray(toks),
+                             jnp.zeros((2,), jnp.int32), cache, row, 128,
+                             72)
+    got = [np.asarray(logits)]
+    cache = dataclasses.replace(
+        cache, block_table=jnp.stack([jnp.zeros_like(row), row]),
+        lens=jnp.asarray([0, 200], jnp.int32),
+        live=jnp.asarray([0, 1], jnp.int32))
+    for t in seq[200:208]:
+        logits, cache = decode(params, jnp.asarray([0, t], jnp.int32),
+                               cache)
+        got.append(np.asarray(logits)[1])
+    want = reference.logits_at(SEED, F, dims, jnp.float32, [seq.tolist()],
+                               [list(range(199, 208))])[0]
+    assert want.std() > 0.1
+    np.testing.assert_allclose(np.stack(got), want, rtol=0, atol=1e-4)
+
+
+def test_the_server_counts_its_chunk_dispatches_by_their_walk(legal):
+    """Every chunk program of a configuration the kernel tiles walks in
+    it: ``chunk_dispatches_kernel_walk`` is ``prefill_chunks``, and every
+    ``prefill_chunk`` span says so."""
+    _, _, cfg, mesh, params = legal
+    eng = Engine(cfg, mesh, model=latent_moe, mode="xla",
+                 dtype=jnp.float32, max_len=512, params=params)
+    srv = eng.serving(num_slots=2, page=128, prefill_buckets=(128, 256),
+                      telemetry="spans")
+    rng = np.random.default_rng(4)
+    out = srv.generate([rng.integers(0, 256, size=n).tolist()
+                        for n in (150, 300)], max_new_tokens=3)
+    assert [len(o) for o in out] == [3, 3]
+    st = srv.stats()
+    assert st["chunk_dispatches_kernel_walk"] == st["prefill_chunks"] == 4
+    assert st["attn_impl"] == st["chunk_attn"] == "ref"
+    chunks = [e.attrs for e in srv.obs.log.spans()
+              if e.kind == "prefill_chunk"]
+    assert sorted((a["bucket"], a["walk_kernel"]) for a in chunks) == [
+        (128, 1), (128, 1), (128, 1), (256, 1)]
 
 
 def test_what_the_latent_pool_does_not_do_is_refused(tiny):
@@ -371,31 +473,24 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("program", ["decode", "verify", "fused-128",
-                                     "fused-512"])
-def test_compiled_step_keeps_the_latent_pool_in_place(v5e, program):
-    """Each paged step of ``models.latent_moe``, lowered for one v5e chip
-    as the serving engine jits it (pool donated, output shardings
-    pinned): the entry computation neither relayouts the latent pool nor
-    cuts a layer out of it (writes by ``lax.dynamic_update_slice``, one
-    layout; the walks over pages carry it through and only read), and
-    the temporaries are smaller than one layer of it. The test of
-    ``models.dense``'s pool (tests/test_paged_decode.py), for the pool
-    this model states. Compile only."""
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the chunk rows' kernel through Mosaic as on the chip, not
+    through the interpreter this process's CPU backend would choose."""
+    from triton_dist_tpu.utils import distributed
+    monkeypatch.setattr(distributed, "platform", lambda: "tpu")
+
+
+def _compile_step(v5e, cfg, program, *, pages, page, slots, p_max,
+                  spec_k=4):
+    """``program`` of ``models.latent_moe`` (``decode``, ``verify``,
+    ``chunk-<rows>``, ``fused-<rows>``: a chunk with the decode batch
+    aboard) lowered and compiled for one v5e chip as the serving engine
+    jits it (pool donated, output shardings pinned). Returns ``(lowered,
+    compiled, the pool's shape)``."""
     from jax.sharding import NamedSharding
     from triton_dist_tpu.serving.blocks import pool_shardings
-    from triton_dist_tpu.utils.testing import pool_copies
 
-    cfg = ModelConfig.tiny_latent_moe(
-        vocab_size=1024, hidden_size=512, num_attention_heads=8,
-        q_lora_rank=256, kv_lora_rank=256, qk_nope_head_dim=64,
-        qk_rope_head_dim=64, v_head_dim=128, moe_intermediate_size=256,
-        shared_expert_intermediate_size=256, rope_factor=128.0,
-        rope_original_max_position=8192, rope_beta_fast=32.0)
-    # ONE LAYER of the pool larger than the chip's 128 MiB of VMEM: the
-    # walks read ``pages[layer]``, and a layer that fits XLA prefetches
-    # there whole, which no serving pool gives it room for.
-    pages, page, slots, p_max, spec_k = 2049, 128, 4, 8, 4
     mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
     axis, dt = "tp", jnp.bfloat16
 
@@ -428,6 +523,14 @@ def test_compiled_step_keeps_the_latent_pool_in_place(v5e, program):
         in_specs = (specs, P(None, None), P(None), kv_spec)
         out_specs = (P(None, None, None), kv_spec)
         args = (params, ints(slots, spec_k), ints(slots), cache)
+    elif program.startswith("chunk-"):
+        step = lambda p, t, c, row, start, wfrom, valid: (
+            latent_moe.prefill_chunk_paged(
+                p, t, c, row, cfg, start=start, wfrom=wfrom, valid=valid))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P())
+        out_specs = (P(None), kv_spec, P(None))
+        args = (params, ints(int(program[6:])), cache, ints(p_max),
+                ints(), ints(), ints())
     else:
         step = lambda p, t, c, row, start, wfrom, valid, d: (
             latent_moe.chunk_decode_paged(
@@ -438,15 +541,137 @@ def test_compiled_step_keeps_the_latent_pool_in_place(v5e, program):
         out_specs = (P(None), P(None, None), kv_spec, P(None))
         args = (params, ints(int(program[6:])), cache, ints(p_max),
                 ints(), ints(), ints(), ints(slots))
-    donate = args.index(cache)
-    compiled = jax.jit(
+    lowered = jax.jit(
         jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs, check_vma=False),
-        donate_argnums=(donate,),
+        donate_argnums=(args.index(cache),),
         out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
-                            for s in out_specs)).lower(*args).compile()
-    pool_shape = cache.pages.shape
+                            for s in out_specs)).lower(*args)
+    return lowered, lowered.compile(), cache.pages.shape
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "fused-128",
+                                     "fused-512"])
+def test_compiled_step_keeps_the_latent_pool_in_place(v5e, mosaic,
+                                                      program):
+    """Each paged step of ``models.latent_moe``, lowered for one v5e chip
+    as the serving engine jits it (pool donated, output shardings
+    pinned): the entry computation neither relayouts the latent pool nor
+    cuts a layer out of it (writes by ``lax.dynamic_update_slice``, one
+    layout; the walks over pages carry it through and only read, the
+    chunk rows' kernel fetches pages out of it whole), and the
+    temporaries are smaller than one layer of it. The test of
+    ``models.dense``'s pool (tests/test_paged_decode.py), for the pool
+    this model states. Compile only."""
+    from triton_dist_tpu.utils.testing import pool_copies
+
+    cfg = ModelConfig.tiny_latent_moe(
+        vocab_size=1024, hidden_size=512, num_attention_heads=8,
+        q_lora_rank=256, kv_lora_rank=256, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=128, moe_intermediate_size=256,
+        shared_expert_intermediate_size=256, rope_factor=128.0,
+        rope_original_max_position=8192, rope_beta_fast=32.0)
+    # ONE LAYER of the pool larger than the chip's 128 MiB of VMEM: the
+    # walks read ``pages[layer]``, and a layer that fits XLA prefetches
+    # there whole, which no serving pool gives it room for.
+    _, compiled, pool_shape = _compile_step(
+        v5e, cfg, program, pages=2049, page=128, slots=4, p_max=8)
     assert pool_shape == (2, 2049, 320, 128)
     assert pool_copies(compiled.as_text(), pool_shape) == []
     layer_bytes = 2 * int(np.prod(pool_shape[1:]))
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("program", ["chunk-512", "fused-512",
+                                     "chunk-2048", "fused-2048"])
+def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
+                                                        program):
+    """The chunk programs at the attention sizes of
+    ``mistral-small-4-1chip.longdocs`` (32 heads of 64 + 64 and 128 over
+    a latent of 256, 16 slots of 129 pages, chunks of 512 and 2048 rows
+    alone and with the decode batch aboard; the FFN and the vocabulary
+    small), compiled for one v5e chip: the chunk rows' walk lowers
+    through Mosaic, ONCE for the program's six layers (the layer is an
+    operand: PERF.md, PR 30), which are one lowered function, no float32
+    score array of heads x rows x
+    keys is left in the program, the temporaries stay under one block of
+    it (32 x 2048 x 1280 x 4 bytes), and the pool is neither copied nor
+    relaid. Compile only."""
+    from triton_dist_tpu.utils.testing import pool_copies
+
+    rows, slots, p_max = int(program[6:]), 16, 129
+    cfg = ModelConfig.tiny_latent_moe(
+        vocab_size=1024, hidden_size=1024, num_hidden_layers=6,
+        num_attention_heads=32, q_lora_rank=1024, kv_lora_rank=256,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+        moe_intermediate_size=256, shared_expert_intermediate_size=256,
+        rope_factor=128.0, rope_original_max_position=8192,
+        rope_beta_fast=32.0)
+    assert latent_moe.chunk_walk_impl(cfg, rows, 128) == "kernel"
+    lowered, compiled, pool_shape = _compile_step(
+        v5e, cfg, program, pages=slots * p_max + 1, page=128, slots=slots,
+        p_max=p_max)
+    text = lowered.as_text()
+    assert text.count('kernel_name = "latent_flash_qblock"') == 1
+    # ... inside ONE lowered function of a layer, called six times (a
+    # program is traced and lowered at every start: PERF.md, PR 39).
+    assert len(re.findall(r"func\.func private @layer\(", text)) == 1
+    assert len(re.findall(r"call @layer\(", text)) == 6
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%latent_flash_qblock(\.\d+)? = ", hlo)) == 6
+    assert f"f32[32,{rows},{latent_moe.BLOCK_KEYS}]" not in hlo
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 32 * 2048 * latent_moe.BLOCK_KEYS * 4)
+    assert pool_copies(hlo, pool_shape) == []
+
+
+def _equations(jaxpr) -> int:
+    """The equations of ``jaxpr`` and of every jaxpr under it (loop and
+    branch bodies)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+def _pallas_bodies(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["jaxpr"]
+            continue
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _pallas_bodies(sub)
+
+
+@pytest.mark.parametrize("rows", [512, 2048])
+def test_the_chunk_kernels_traced_body_stays_small(rows):
+    """The set-up budget of ``ops.latent_flash_qblock`` (PERF.md, PR 39):
+    a step program is traced and lowered at every start, no cache keeps
+    either, so the kernel's body is what its loops hold ONCE. At the
+    attention sizes of ``mistral-small-4-1chip.longdocs`` the traced
+    body is 231 equations for both buckets; with the sub-tiles, the
+    heads of a group and the pages of a step as Python loops (PR 38's
+    form, refused on ``setup_s``) it was 1,345 at 2048 rows and 697 at
+    512, 0.39 + 0.30 s to trace and lower against 0.06 + 0.07 s on this
+    sandbox's CPU. 260 is held: a body that grows past it is paid by
+    every start of the server. Trace only, nothing runs."""
+    from triton_dist_tpu.ops import latent_flash_qblock as K
+
+    s = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    assert K.block_sizes(rows, 32, 128, 128, 2) == (rows, 4, 256, 4)
+    closed = jax.make_jaxpr(
+        lambda *a: K._latent_qblock_call(*a, sigma=0.1))(
+        s((rows, 32, 128), bf), s((6, 2065, 320, 128), bf),
+        s((129,), jnp.int32), s((rows,), jnp.int32), s((256, 32, 192), bf),
+        s((1,), jnp.int32))
+    bodies = list(_pallas_bodies(closed.jaxpr))
+    assert len(bodies) == 1
+    assert _equations(bodies[0]) <= 260

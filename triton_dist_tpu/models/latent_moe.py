@@ -29,8 +29,10 @@ k_r]`` a token a layer, a page lying ``(width, page)``):
 
 Both walk the context in blocks of whole pages with a running softmax
 (no ``(rows, heads, context)`` score array exists) and stop at the last
-block any row can see. Plain XLA: no kernel of this repo computes
-either yet.
+block any row can see. The chunk rows' walk is a Pallas kernel wherever
+its sizes tile (:func:`chunk_walk_impl`,
+:mod:`triton_dist_tpu.ops.latent_flash_qblock`), with the XLA walk below
+as its fallback and its oracle; the absorbed walk is plain XLA.
 
 The FFN is :func:`~triton_dist_tpu.layers.ep_moe.fwd_held`: the router
 over every expert, the held experts' part of the result, the shared
@@ -58,6 +60,7 @@ from triton_dist_tpu.layers.rope import (apply_rope_interleaved,
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import (FwdContexts, _last_valid_row,
                                           _lm_head)
+from triton_dist_tpu.ops import latent_flash_qblock as _flash
 
 # What every step function returns last, summed over layers: the
 # token-expert pairs that fell to held experts, and the most rows one
@@ -276,6 +279,29 @@ def _attend_expanded(attn, q, cache, li, table_row, qpos, cfg):
     return o.transpose(1, 0, 2).reshape(c, h * dv).astype(q.dtype)
 
 
+def chunk_walk_impl(cfg: ModelConfig, rows: int, page: int) -> str:
+    """What walks the context for a chunk of ``rows`` rows: ``"kernel"``
+    (:func:`~triton_dist_tpu.ops.latent_flash_qblock.latent_flash_qblock`)
+    where the pool's pages, the head sizes and the row count tile for
+    Mosaic, else ``"xla"`` (:func:`_attend_expanded`). A pure function
+    of sizes: the same program on a chip and, interpreted, off it; the
+    serving engine counts its chunk dispatches by it."""
+    dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    ok = _flash.legal(rows, dqk, cfg.v_head_dim, cfg.kv_lora_rank,
+                      cache_width(cfg), page)
+    return "kernel" if ok else "xla"
+
+
+def _attend_chunk(attn, q, cache, li, table_row, qpos, cfg):
+    """A chunk's rows over their slot's pages, by
+    :func:`chunk_walk_impl`. Returns (C, H * d_v)."""
+    if chunk_walk_impl(cfg, q.shape[0], cache.page) == "xla":
+        return _attend_expanded(attn, q, cache, li, table_row, qpos, cfg)
+    return _flash.latent_flash_qblock(
+        q, cache.pages, table_row, qpos, _w_ukv(attn, cfg), layer=li,
+        sigma=softmax_scale(cfg))
+
+
 def _attend_absorbed(attn, q, cache, li, qpos, cfg):
     """Rows alone with their slot's context, in the latent. q: (S, R, H,
     d_n + d_r), R rows a slot; qpos (S, R) the last position each sees.
@@ -318,9 +344,16 @@ def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
     """Every layer over ``x`` (n, d) at ``positions`` (n,).
     ``attend(li, attn_params, q, latent, cache) -> (o (n, H * d_v),
     cache)`` writes the rows' cache entries and reads what each row's
-    query sees. Returns ``(x normed (n, d), cache, stats)``."""
-    stats = jnp.zeros((len(STEP_STATS),), jnp.int32)
-    for li, lp in enumerate(params["layers"]):
+    query sees; ``li`` is an int32 scalar, an operand. Returns ``(x
+    normed (n, d), cache, stats)``.
+
+    The layer is ONE jitted function of its index and its parameters:
+    a step program is traced and lowered at every start of the server
+    (no compile cache keeps either), and six layers written out were
+    three quarters of both (PERF.md, PR 39). XLA inlines the calls; the
+    compiled program is the one the loop written out gave."""
+    @jax.jit
+    def layer(li, lp, x, cache, stats):
         h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
         q, latent = project(lp["attn"], h, cfg, positions)
         o, cache = attend(li, lp["attn"], q, latent, cache)
@@ -331,16 +364,21 @@ def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
             first=cfg.first_held_expert,
             norm_topk_prob=cfg.norm_topk_prob,
             routed_scale=cfg.routed_scaling_factor)
-        x = x + out.astype(x.dtype)
-        stats = stats + layer_stats
+        return x + out.astype(x.dtype), cache, stats + layer_stats
+
+    stats = jnp.zeros((len(STEP_STATS),), jnp.int32)
+    for li, lp in enumerate(params["layers"]):
+        x, cache, stats = layer(jnp.asarray(li, jnp.int32), lp, x, cache,
+                                stats)
     return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache, stats
 
 
 def _check(attn_impl, mode):
     if attn_impl != "ref":
         raise ValueError(
-            f"attn_impl={attn_impl!r}: latent attention has its plain "
-            "XLA paths only ('ref')")
+            f"attn_impl={attn_impl!r}: latent attention takes 'ref' "
+            "alone, the model's own paths (the chunk rows' walk is "
+            "chosen by sizes: chunk_walk_impl)")
     if mode != "xla":
         raise ValueError(f"mode={mode!r}: models.latent_moe has no fused "
                          "collective layer; serve it with mode='xla'")
@@ -378,8 +416,8 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
     def attend(li, attn, q, latent, cache):
         cache = cache.write_chunk(li, latent, table_row, positions,
                                   valid, wfrom)
-        return _attend_expanded(attn, q, cache, li, table_row, qpos,
-                                cfg), cache
+        return _attend_chunk(attn, q, cache, li, table_row, qpos,
+                             cfg), cache
 
     x, cache, stats = _layers(params, params["embed"][chunk_toks],
                               positions, cache, cfg, attend)
@@ -430,8 +468,8 @@ def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
         cache = cache.write_chunk(li, latent[:c], table_row, chunk_pos,
                                   valid, wfrom)
         cache = cache.append_decode(li, latent[c:])
-        o_chunk = _attend_expanded(attn, q[:c], cache, li, table_row,
-                                   qpos, cfg)
+        o_chunk = _attend_chunk(attn, q[:c], cache, li, table_row, qpos,
+                                cfg)
         o_dec = _attend_absorbed(attn, q[c:, None], cache, li,
                                  _decode_qpos(cache), cfg)
         return jnp.concatenate([o_chunk, o_dec]), cache

@@ -55,10 +55,12 @@ _OP_HIST_KINDS = frozenset({
 
 # The fields an annotation carries as stats into a profiler capture:
 # the correlation keys, and the counts read there (``batch`` of a
-# decode and whether it rode a chunk program, ``fused`` 0/1;
-# ``waited_ms`` of an admission; what an ``expert_load`` event counted).
+# decode and whether it rode a chunk program, ``fused`` 0/1; whether a
+# chunk's rows walk their context in a Pallas kernel, ``walk_kernel``
+# 0/1; ``waited_ms`` of an admission; what an ``expert_load`` event
+# counted).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
-                        "bucket", "valid", "waited_ms",
+                        "bucket", "valid", "walk_kernel", "waited_ms",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",
                         "expert_rows_max", "expert_imbalance"})

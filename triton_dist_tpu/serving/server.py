@@ -473,7 +473,8 @@ class ServingEngine:
             "prefill_tokens": 0, "prefill_calls": 0, "admit_stalls": 0,
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
             "decode_tokens": 0, "prefill_chunks": 0,
-            "chunk_dispatches_parked": 0, "migrated_pages": 0,
+            "chunk_dispatches_parked": 0,
+            "chunk_dispatches_kernel_walk": 0, "migrated_pages": 0,
             "spec_drafted": 0, "spec_accepted": 0,
             "spec_sampled_fallbacks": 0,
             "greedy_agree_tokens": 0, "greedy_ref_tokens": 0,
@@ -1972,6 +1973,7 @@ class ServingEngine:
         p = self._prefiller
         slot, seq, start = h.slot, h.lane, h.prompt_pos
         bucket, valid = p.chunker.next_chunk(len(seq) - start)
+        walk_kernel = self._walk_kernel(bucket)
         toks = np.zeros((bucket,), np.int32)
         toks[:valid] = seq[start:start + valid]
         row = np.asarray(p.manager.table_row(slot), np.int32)
@@ -1988,7 +1990,7 @@ class ServingEngine:
                                request_id=h.request.request_id,
                                slot=slot, tenant=h.request.tenant,
                                start=int(start), bucket=int(bucket),
-                               valid=int(valid)), \
+                               valid=int(valid), walk_kernel=walk_kernel), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
@@ -2036,6 +2038,19 @@ class ServingEngine:
             raise
         return picked, logits, dec, (start, bucket, valid)
 
+    def _walk_kernel(self, bucket: int) -> int:
+        """1 where a chunk program of ``bucket`` rows walks its context
+        in the model's Pallas kernel, by the model's own rule on sizes
+        (``chunk_walk_impl``: ``models.latent_moe``); 0 for its XLA walk
+        and for a model that states no such choice. Host arithmetic."""
+        p = self._prefiller
+        impl = getattr(getattr(p.engine, "model", None),
+                       "chunk_walk_impl", None)
+        if impl is None:
+            return 0
+        return int(impl(p.engine.cfg, int(bucket), p.cache.page)
+                   == "kernel")
+
     def _chunk_failed(self, h: RequestHandle, e):
         """A chunk was wedged or dropped past its retries. A dying
         prefill worker fails over (``h`` requeues with the rest of its
@@ -2056,6 +2071,8 @@ class ServingEngine:
         start, bucket, valid = plan
         self._note_role_ok("prefill")
         self.stats_counters["prefill_chunks"] += 1
+        self.stats_counters["chunk_dispatches_kernel_walk"] += (
+            self._walk_kernel(bucket))
         self.stats_counters["prefill_tokens"] += valid
         h.chunks.append((start, bucket, valid))
         h.prompt_pos = start + valid
