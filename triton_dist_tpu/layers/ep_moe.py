@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops.ep_a2a import (EPContext, EP2DContext,
                                         ep_dispatch, ep_combine)
 from triton_dist_tpu.ops.ep_fused import EPFusedContext, ep_moe_fused
@@ -91,6 +92,7 @@ def param_specs(axis: str = "ep", cfg=None) -> Dict:
     return s
 
 
+@scope("router")
 def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
     """Qwen3-MoE router: softmax over experts then top-k, weights
     renormalized (reference ``models/qwen_moe.py``)."""
@@ -105,6 +107,7 @@ def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
     return topk_ids.astype(jnp.int32), topk_w
 
 
+@scope("shared_expert")
 def shared_expert_out(params, x):
     """The dense SwiGLU branch every token takes; None when the layer
     has none. Sigmoid-gated where the parameters hold a ``shared_gate``
@@ -148,23 +151,25 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
     n_held = params["w_gate"].shape[0]
     topk_ids, topk_w = route(params["router"], x, topk,
                              norm_topk_prob=norm_topk_prob)
-    local = topk_ids - first
-    held = (local >= 0) & (local < n_held)
-    flat = jnp.where(held, local, -1).reshape(-1)
-    sorted_tok, group_sizes, inv = sort_by_expert(
-        jnp.repeat(x, topk, axis=0), flat, n_held)
-    out = grouped_swiglu(sorted_tok, params["w_gate"], params["w_up"],
-                         params["w_down"], group_sizes)[inv]
-    # Rows past the last group are whatever the grouped product left
-    # there: selected away, not multiplied by zero.
-    w = (topk_w * routed_scale)[..., None]
-    out = jnp.sum(jnp.where(held[..., None],
-                            out.reshape(t, topk, d).astype(jnp.float32)
-                            * w, 0.0), axis=1)
+    with scope("experts"):
+        local = topk_ids - first
+        held = (local >= 0) & (local < n_held)
+        flat = jnp.where(held, local, -1).reshape(-1)
+        sorted_tok, group_sizes, inv = sort_by_expert(
+            jnp.repeat(x, topk, axis=0), flat, n_held)
+        out = grouped_swiglu(sorted_tok, params["w_gate"], params["w_up"],
+                             params["w_down"], group_sizes)[inv]
+        # Rows past the last group are whatever the grouped product
+        # left there: selected away, not multiplied by zero.
+        w = (topk_w * routed_scale)[..., None]
+        out = jnp.sum(jnp.where(
+            held[..., None],
+            out.reshape(t, topk, d).astype(jnp.float32) * w, 0.0), axis=1)
     shared = shared_expert_out(params, x)
     if shared is not None:
         out = out + shared
-    stats = jnp.stack([jnp.sum(group_sizes), jnp.max(group_sizes)])
+    with scope("experts"):
+        stats = jnp.stack([jnp.sum(group_sizes), jnp.max(group_sizes)])
     return out, stats.astype(jnp.int32)
 
 

@@ -23,6 +23,7 @@ from triton_dist_tpu.layers import tp_attn, tp_mlp
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops import (
     create_ag_gemm_context, create_gemm_rs_context, create_gemm_ar_context,
 )
@@ -148,6 +149,7 @@ def _forward_trunk(params, input_ids, cfg: ModelConfig, *, mode, axis,
     return x, cache
 
 
+@scope("head")
 def _lm_head(params, x, axis):
     logits_loc = jnp.dot(x, params["lm_head"].T,
                          preferred_element_type=jnp.float32)
@@ -252,24 +254,29 @@ def _paged_layers(params, x, positions, cache, cfg: ModelConfig, attend,
     n = x.shape[0]
     dec_mode = "xla" if mode == "xla" else "fused_ar"
     for li, layer_params in enumerate(params["layers"]):
-        h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
-        q, k_tok, v_tok = tp_attn.decode_project(
-            layer_params["attn"], h, cfg, positions, axis=axis)
+        with scope("attn_project"):
+            h = rms_norm(x, layer_params["ln_attn"], cfg.rms_norm_eps)
+            q, k_tok, v_tok = tp_attn.decode_project(
+                layer_params["attn"], h, cfg, positions, axis=axis)
         o, cache = attend(li, q, k_tok, v_tok, cache)
-        x = x + tp_attn.decode_output(
-            layer_params["attn"], o.reshape(n, -1), h, mode=dec_mode,
-            axis=axis, ar_ctx=ctxs.ar)
-        h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
-        if ffn_fn is None:
-            mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
-            x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
-                               axis=axis, ag_ctx=ctxs.ag, rs_ctx=ctxs.rs,
-                               ar_ctx=ctxs.ar)
-        else:
-            x = x + ffn_fn(layer_params, h)
-    return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache
+        with scope("attn_out"):
+            x = x + tp_attn.decode_output(
+                layer_params["attn"], o.reshape(n, -1), h, mode=dec_mode,
+                axis=axis, ar_ctx=ctxs.ar)
+        with scope("mlp"):
+            h = rms_norm(x, layer_params["ln_mlp"], cfg.rms_norm_eps)
+            if ffn_fn is None:
+                mlp_mode = "xla_ar" if dec_mode == "xla" else dec_mode
+                x = x + tp_mlp.fwd(layer_params["mlp"], h, mode=mlp_mode,
+                                   axis=axis, ag_ctx=ctxs.ag,
+                                   rs_ctx=ctxs.rs, ar_ctx=ctxs.ar)
+            else:
+                x = x + ffn_fn(layer_params, h)
+    with scope("head"):
+        return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache
 
 
+@scope("attn_chunk")
 def _chunk_attend(li, q, cache, table_row, positions, start, valid,
                   attn_impl):
     """A prefill chunk's queries (C, 1, H_loc, hd) over its slot's
@@ -301,6 +308,7 @@ def _chunk_attend(li, q, cache, table_row, positions, start, valid,
     return chunk_attend(q[:, 0], kd, vd, positions)
 
 
+@scope("attn_decode")
 def _decode_attend(li, q, cache, attn_impl):
     """One query a slot (S, 1, H_loc, hd) over the slot's pages at its
     own length — after the step's token was appended."""
@@ -320,6 +328,13 @@ def _decode_attend(li, q, cache, attn_impl):
     return tp_attn.sdpa(q, kd, vd, causal=False, kv_len=kv_len)
 
 
+@scope("embed")
+def _embed_rows(params, token_ids):
+    """The table's rows of ``token_ids`` (n,), replicated: (n, d)."""
+    return params["embed"][token_ids]
+
+
+@scope("head")
 def _last_valid_row(x, valid):
     """Row ``valid - 1`` of a chunk's (C, d) rows, as (1, d)."""
     return jax.lax.dynamic_slice_in_dim(
@@ -366,7 +381,7 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     accounting back via ``BlockManager.truncate_to``.
     """
     s, k = token_ids.shape
-    x = params["embed"][token_ids.reshape(s * k)]     # (S·K, d)
+    x = _embed_rows(params, token_ids.reshape(s * k))     # (S·K, d)
     lens = cache.lens
     positions = (lens[:, None]
                  + jnp.arange(k, dtype=jnp.int32)[None]).reshape(s * k)
@@ -374,30 +389,32 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     def attend(li, q, k_tok, v_tok, cache):
         hl, hd = q.shape[2], q.shape[3]
         kvl = k_tok.shape[2]
-        cache = cache.append_block(
-            li, k_tok[:, 0].reshape(s, k, kvl, hd),
-            v_tok[:, 0].reshape(s, k, kvl, hd), budget=budget)
-        if attn_impl == "flash":
-            from triton_dist_tpu.ops.paged_flash_qblock import (
-                paged_flash_qblock)
+        with scope("cache_write"):
+            cache = cache.append_block(
+                li, k_tok[:, 0].reshape(s, k, kvl, hd),
+                v_tok[:, 0].reshape(s, k, kvl, hd), budget=budget)
+        with scope("attn_decode"):
+            q = q[:, 0].reshape(s, k, hl, hd)
+            if attn_impl == "flash":
+                from triton_dist_tpu.ops.paged_flash_qblock import (
+                    paged_flash_qblock)
 
-            # Candidate j of a live slot attends positions
-            # <= lens[s]+j (its paged history + the candidate prefix
-            # through itself — block_attend's kv_len-1); parked slots
-            # clamp to position 0 (garbage the scheduler ignores).
-            qpos = jnp.maximum(
-                lens[:, None] + cache.live[:, None]
-                * (jnp.arange(k, dtype=jnp.int32)[None] + 1), 1) - 1
-            ksc, vsc = cache.layer_scales(li)
-            return paged_flash_qblock(
-                q[:, 0].reshape(s, k, hl, hd), cache.k_pages,
-                cache.v_pages, cache.block_table, qpos, layer=li,
-                k_scale=ksc, v_scale=vsc), cache
-        from triton_dist_tpu.ops.chunked_prefill import block_attend
+                # Candidate j of a live slot attends positions
+                # <= lens[s]+j (its paged history + the candidate
+                # prefix through itself — block_attend's kv_len-1);
+                # parked slots clamp to position 0 (garbage the
+                # scheduler ignores).
+                qpos = jnp.maximum(
+                    lens[:, None] + cache.live[:, None]
+                    * (jnp.arange(k, dtype=jnp.int32)[None] + 1), 1) - 1
+                ksc, vsc = cache.layer_scales(li)
+                return paged_flash_qblock(
+                    q, cache.k_pages, cache.v_pages, cache.block_table,
+                    qpos, layer=li, k_scale=ksc, v_scale=vsc), cache
+            from triton_dist_tpu.ops.chunked_prefill import block_attend
 
-        kd, vd = cache.dense_layer(li)
-        return block_attend(q[:, 0].reshape(s, k, hl, hd), kd, vd,
-                            lens, cache.live), cache
+            kd, vd = cache.dense_layer(li)
+            return block_attend(q, kd, vd, lens, cache.live), cache
 
     x, cache = _paged_layers(params, x, positions, cache, cfg, attend,
                              mode=mode, axis=axis, ctxs=ctxs,
@@ -475,13 +492,14 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
     chunks' logits are discarded.
     """
     c = chunk_toks.shape[0]
-    x = params["embed"][chunk_toks]          # (C, d) replicated
+    x = _embed_rows(params, chunk_toks)
     positions = (jnp.asarray(start, jnp.int32)
                  + jnp.arange(c, dtype=jnp.int32))
 
     def attend(li, q, k_tok, v_tok, cache):
-        cache = cache.write_chunk(li, k_tok, v_tok, table_row,
-                                  positions, valid, wfrom)
+        with scope("cache_write"):
+            cache = cache.write_chunk(li, k_tok, v_tok, table_row,
+                                      positions, valid, wfrom)
         return _chunk_attend(li, q, cache, table_row, positions, start,
                              valid, attn_impl), cache
 
@@ -523,10 +541,11 @@ def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     ``ffn_fn(layer_params, h) -> h`` overrides the FFN block (the MoE
     model's hook), exactly as in :func:`decode_step`.
     """
-    x = params["embed"][token_ids]
+    x = _embed_rows(params, token_ids)
 
     def attend(li, q, k_tok, v_tok, cache):
-        cache = cache.append_decode(li, k_tok, v_tok)
+        with scope("cache_write"):
+            cache = cache.append_decode(li, k_tok, v_tok)
         return _decode_attend(li, q, cache, attn_impl), cache
 
     x, cache = _paged_layers(params, x, cache.lens, cache, cfg, attend,
@@ -568,16 +587,17 @@ def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
     cache.advance())``.
     """
     c = chunk_toks.shape[0]
-    x = params["embed"][jnp.concatenate([chunk_toks, token_ids])]
+    x = _embed_rows(params, jnp.concatenate([chunk_toks, token_ids]))
     chunk_pos = (jnp.asarray(start, jnp.int32)
                  + jnp.arange(c, dtype=jnp.int32))
 
     def attend(li, q, k_tok, v_tok, cache):
         # Both writes, then both reads: each kernel takes the pool as
         # the layer's last writer left it, in place.
-        cache = cache.write_chunk(li, k_tok[:c], v_tok[:c], table_row,
-                                  chunk_pos, valid, wfrom)
-        cache = cache.append_decode(li, k_tok[c:], v_tok[c:])
+        with scope("cache_write"):
+            cache = cache.write_chunk(li, k_tok[:c], v_tok[:c], table_row,
+                                      chunk_pos, valid, wfrom)
+            cache = cache.append_decode(li, k_tok[c:], v_tok[c:])
         o_chunk = _chunk_attend(li, q[:c], cache, table_row, chunk_pos,
                                 start, valid, attn_impl)
         o_dec = _decode_attend(li, q[c:], cache, decode_attn_impl)
@@ -588,7 +608,8 @@ def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
     x, cache = _paged_layers(
         params, x, jnp.concatenate([chunk_pos, cache.lens]), cache, cfg,
         attend, mode=mode, axis=axis, ctxs=ctxs, ffn_fn=ffn_fn)
-    logits = _lm_head(
-        params, jnp.concatenate([_last_valid_row(x[:c], valid), x[c:]]),
-        axis)
-    return logits[0], logits[1:], cache.advance()
+    with scope("head"):
+        logits = _lm_head(params, jnp.concatenate(
+            [_last_valid_row(x[:c], valid), x[c:]]), axis)
+        chunk_logits, decode_logits = logits[0], logits[1:]
+    return chunk_logits, decode_logits, cache.advance()

@@ -58,8 +58,9 @@ from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.layers.rope import (apply_rope_interleaved,
                                          yarn_freqs, yarn_mscale)
 from triton_dist_tpu.models.config import ModelConfig
-from triton_dist_tpu.models.dense import (FwdContexts, _last_valid_row,
-                                          _lm_head)
+from triton_dist_tpu.models.dense import (FwdContexts, _embed_rows,
+                                          _last_valid_row, _lm_head)
+from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops import latent_flash_qblock as _flash
 
 # What every step function returns last, summed over layers: the
@@ -292,6 +293,7 @@ def chunk_walk_impl(cfg: ModelConfig, rows: int, page: int) -> str:
     return "kernel" if ok else "xla"
 
 
+@scope("attn_chunk")
 def _attend_chunk(attn, q, cache, li, table_row, qpos, cfg):
     """A chunk's rows over their slot's pages, by
     :func:`chunk_walk_impl`. Returns (C, H * d_v)."""
@@ -302,6 +304,7 @@ def _attend_chunk(attn, q, cache, li, table_row, qpos, cfg):
         sigma=softmax_scale(cfg))
 
 
+@scope("attn_decode")
 def _attend_absorbed(attn, q, cache, li, qpos, cfg):
     """Rows alone with their slot's context, in the latent. q: (S, R, H,
     d_n + d_r), R rows a slot; qpos (S, R) the last position each sees.
@@ -354,11 +357,14 @@ def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
     compiled program is the one the loop written out gave."""
     @jax.jit
     def layer(li, lp, x, cache, stats):
-        h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
-        q, latent = project(lp["attn"], h, cfg, positions)
+        with scope("attn_project"):
+            h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
+            q, latent = project(lp["attn"], h, cfg, positions)
         o, cache = attend(li, lp["attn"], q, latent, cache)
-        x = x + jnp.dot(o, lp["attn"]["wo"])
-        h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
+        with scope("attn_out"):
+            x = x + jnp.dot(o, lp["attn"]["wo"])
+        with scope("router"):
+            h = rms_norm(x, lp["ln_mlp"], cfg.rms_norm_eps)
         out, layer_stats = ep_moe.fwd_held(
             lp["moe"], h, topk=cfg.num_experts_per_tok,
             first=cfg.first_held_expert,
@@ -370,7 +376,9 @@ def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
     for li, lp in enumerate(params["layers"]):
         x, cache, stats = layer(jnp.asarray(li, jnp.int32), lp, x, cache,
                                 stats)
-    return rms_norm(x, params["ln_f"], cfg.rms_norm_eps), cache, stats
+    with scope("head"):
+        x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
+    return x, cache, stats
 
 
 def _check(attn_impl, mode):
@@ -414,12 +422,13 @@ def prefill_chunk_paged(params, chunk_toks, cache, table_row,
     qpos = _chunk_qpos(positions, start, valid)
 
     def attend(li, attn, q, latent, cache):
-        cache = cache.write_chunk(li, latent, table_row, positions,
-                                  valid, wfrom)
+        with scope("cache_write"):
+            cache = cache.write_chunk(li, latent, table_row, positions,
+                                      valid, wfrom)
         return _attend_chunk(attn, q, cache, li, table_row, qpos,
                              cfg), cache
 
-    x, cache, stats = _layers(params, params["embed"][chunk_toks],
+    x, cache, stats = _layers(params, _embed_rows(params, chunk_toks),
                               positions, cache, cfg, attend)
     logits = _lm_head(params, _last_valid_row(x, valid), axis)
     return logits[0], cache, stats
@@ -435,11 +444,12 @@ def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     _check(attn_impl, mode)
 
     def attend(li, attn, q, latent, cache):
-        cache = cache.append_decode(li, latent)
+        with scope("cache_write"):
+            cache = cache.append_decode(li, latent)
         return _attend_absorbed(attn, q[:, None], cache, li,
                                 _decode_qpos(cache), cfg), cache
 
-    x, cache, stats = _layers(params, params["embed"][token_ids],
+    x, cache, stats = _layers(params, _embed_rows(params, token_ids),
                               cache.lens, cache, cfg, attend)
     return _lm_head(params, x, axis), cache.advance(), stats
 
@@ -465,9 +475,10 @@ def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
 
     def attend(li, attn, q, latent, cache):
         # Both writes, then both reads, as the dense program does.
-        cache = cache.write_chunk(li, latent[:c], table_row, chunk_pos,
-                                  valid, wfrom)
-        cache = cache.append_decode(li, latent[c:])
+        with scope("cache_write"):
+            cache = cache.write_chunk(li, latent[:c], table_row,
+                                      chunk_pos, valid, wfrom)
+            cache = cache.append_decode(li, latent[c:])
         o_chunk = _attend_chunk(attn, q[:c], cache, li, table_row, qpos,
                                 cfg)
         o_dec = _attend_absorbed(attn, q[c:, None], cache, li,
@@ -475,12 +486,14 @@ def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
         return jnp.concatenate([o_chunk, o_dec]), cache
 
     x, cache, stats = _layers(
-        params, params["embed"][jnp.concatenate([chunk_toks, token_ids])],
+        params,
+        _embed_rows(params, jnp.concatenate([chunk_toks, token_ids])),
         jnp.concatenate([chunk_pos, cache.lens]), cache, cfg, attend)
-    logits = _lm_head(
-        params, jnp.concatenate([_last_valid_row(x[:c], valid), x[c:]]),
-        axis)
-    return logits[0], logits[1:], cache.advance(), stats
+    with scope("head"):
+        logits = _lm_head(params, jnp.concatenate(
+            [_last_valid_row(x[:c], valid), x[c:]]), axis)
+        chunk_logits, decode_logits = logits[0], logits[1:]
+    return chunk_logits, decode_logits, cache.advance(), stats
 
 
 def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
@@ -499,13 +512,15 @@ def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
     positions = (lens[:, None] + steps).reshape(s * k)
 
     def attend(li, attn, q, latent, cache):
-        cache = cache.append_block(li, latent.reshape(s, k, -1),
-                                   budget=budget)
+        with scope("cache_write"):
+            cache = cache.append_block(li, latent.reshape(s, k, -1),
+                                       budget=budget)
         qpos = jnp.maximum(
             lens[:, None] + cache.live[:, None] * (steps + 1), 1) - 1
         return _attend_absorbed(attn, q.reshape((s, k) + q.shape[1:]),
                                 cache, li, qpos, cfg), cache
 
-    x, cache, _ = _layers(params, params["embed"][token_ids.reshape(-1)],
+    x, cache, _ = _layers(params,
+                          _embed_rows(params, token_ids.reshape(-1)),
                           positions, cache, cfg, attend)
     return _lm_head(params, x, axis).reshape(s, k, -1), cache

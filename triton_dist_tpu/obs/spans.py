@@ -26,7 +26,8 @@ import json
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["SPAN_KINDS", "Span", "EventLog"]
+__all__ = ["SPAN_KINDS", "DEVICE_SCOPES", "SCOPE_PREFIX", "scope", "Span",
+           "EventLog"]
 
 # The serving span/event taxonomy (docs/observability.md). Interval
 # spans carry t0 < t1 on the engine clock; instant events have t1 None.
@@ -84,6 +85,40 @@ SPAN_KINDS = (
     "chaos_fault",       # event: the chaos soak injected a fault
     "chaos_restore",     # event: the soak's mid-run kill/restore drill
 )
+
+# The blocks of a step program (docs/observability.md, "In a profiler
+# capture"): what runs on the DEVICE carries ``tdt.<block>`` in its
+# operations' ``op_name``, as what runs on the host carries
+# ``tdt.<kind>`` above. One vocabulary for every model family, so that
+# one reader and one set of metric names serve them all.
+SCOPE_PREFIX = "tdt."
+DEVICE_SCOPES = (
+    "embed",             # the token rows gathered from the table
+    "attn_project",      # norm, q/k/v or the latent's projections, rope
+    "cache_write",       # the rows' entries written into the paged pool
+    "attn_chunk",        # what a prefill chunk's queries read
+    "attn_decode",       # what decode and verification rows read
+    "attn_out",          # the output projection and its residual
+    "mlp",               # norm + dense FFN
+    "router",            # the expert layer's norm, scores and top-k
+    "experts",           # sort by expert, grouped SwiGLU, the combine
+    "shared_expert",     # the expert every token goes through
+    "head",              # final norm, the rows kept, the vocabulary
+    "pick",              # the greedy token (and the step's counts)
+)
+
+
+def scope(block: str):
+    """``jax.named_scope("tdt.<block>")`` for a block of
+    ``DEVICE_SCOPES``: a context manager, or a decorator over a function
+    that is one block. Names on the traced operations and nothing else:
+    no operand, no branch, no work at run time, so nothing to switch."""
+    if block not in DEVICE_SCOPES:
+        raise ValueError(f"device scope {block!r} is not one of "
+                         f"DEVICE_SCOPES {DEVICE_SCOPES}")
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + block)
 
 
 @dataclasses.dataclass

@@ -415,6 +415,7 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
 
     out, _ = core_call(
         kernel,
+        name="paged_flash_decode",
         comm=n > 1,
         grid=(b, p_max),
         out_shape=(

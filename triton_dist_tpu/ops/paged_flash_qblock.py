@@ -317,6 +317,7 @@ def _qblock_call(q, k_pages, v_pages, block_table, positions, layer,
 
     out = core_call(
         kernel,
+        name="paged_flash_qblock",
         grid=(nb, kvh, p_max),
         out_shape=jax.ShapeDtypeStruct((nb, h, bq, hd), q.dtype),
         in_specs=in_specs,

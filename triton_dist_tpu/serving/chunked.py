@@ -24,6 +24,7 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
+from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops.chunked_prefill import plan_chunks
 
 __all__ = ["ChunkedPrefill", "MegaChunkedPrefill", "DEFAULT_BUCKETS",
@@ -34,6 +35,7 @@ __all__ = ["ChunkedPrefill", "MegaChunkedPrefill", "DEFAULT_BUCKETS",
 DEFAULT_BUCKETS = (128, 512, 2048)
 
 
+@scope("pick")
 def greedy_tokens(logits):
     """The greedy token of each logits row, picked inside the step
     program: ``np.argmax``'s rule (the first index of the maximum, a
@@ -44,6 +46,7 @@ def greedy_tokens(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@scope("pick")
 def picked_with_stats(picked, stats):
     """A step program's picked tokens with its model's ``STEP_STATS``
     behind them, one int32 array: the counts leave the chip in the copy
