@@ -194,6 +194,93 @@ def test_the_shares_add_up(tiny, monkeypatch):
     np.testing.assert_allclose(ref_total, want, rtol=1e-4, atol=1e-5)
 
 
+def _all_pairs(params, x, *, topk, first=0):
+    """``fwd_held`` as it was before it took passes (PR 37-40): every
+    token-expert pair is repeated, sorted and handed to the grouped
+    SwiGLU, the pairs of absent experts last, and the held ones are
+    selected at the very end. Returns ``(out, held, fullest)``."""
+    from triton_dist_tpu.ops.group_gemm import (grouped_swiglu,
+                                                sort_by_expert)
+
+    t, d = x.shape
+    n_held = params["w_gate"].shape[0]
+    topk_ids, topk_w = ep_moe.route(params["router"], x, topk)
+    local = topk_ids - first
+    held = (local >= 0) & (local < n_held)
+    sorted_tok, group_sizes, inv = sort_by_expert(
+        jnp.repeat(x, topk, axis=0), jnp.where(held, local, -1).reshape(-1),
+        n_held)
+    out = grouped_swiglu(sorted_tok, params["w_gate"], params["w_up"],
+                         params["w_down"], group_sizes)[inv]
+    out = jnp.sum(jnp.where(
+        held[..., None],
+        out.reshape(t, topk, d).astype(jnp.float32) * topk_w[..., None],
+        0.0), axis=1)
+    return (out + ep_moe.shared_expert_out(params, x),
+            int(jnp.sum(group_sizes)), int(jnp.max(group_sizes)))
+
+
+# 512 tokens, 2 of 64 experts a token, experts 4..7 held: 1,024 pairs and
+# an even share of 64, so the shape rule gives a pass of 128 rows and the
+# routings below need 0 to 8 of them.
+_ROUTINGS = {        # name: (held pairs, token indices -> (T, 2) experts)
+    "even": (64, lambda t: np.stack([t % 64, (t + 5) % 64], 1)),
+    "every-pair-held": (1024, lambda t: np.stack(
+        [4 + t % 4, 4 + (t + 1) % 4], 1)),
+    "no-pair-held": (0, lambda t: np.stack([t % 4, 8 + t % 56], 1)),
+    "one-expert-given-all": (512, lambda t: np.stack(
+        [6 + 0 * t, t % 4], 1)),
+    "held-exactly-a-pass": (128, lambda t: np.stack(
+        [np.where(t < 128, 4 + t % 4, t % 4), 8 + t % 56], 1)),
+    "held-a-pass-and-one": (129, lambda t: np.stack(
+        [np.where(t < 129, 4 + t % 4, t % 4), 8 + t % 56], 1)),
+}
+
+
+@pytest.mark.parametrize("routing", list(_ROUTINGS))
+def test_the_held_experts_take_their_pairs_in_passes(routing):
+    """``fwd_held`` equals the all-pairs form at every routing, with no
+    pair dropped when a pass cannot hold them all, and counts what it
+    did: ``(held pairs, the fullest expert's rows, passes)``. The pass
+    is small because few of many experts are held (the shape rule), not
+    because anything was set."""
+    t, f, e, n_held, first, topk = 512, 16, 64, 4, 4, 2
+    rows = ep_moe.held_pass_rows(t, topk, n_held, e)
+    assert rows == 128 < t * topk
+    held_pairs, experts_of = _ROUTINGS[routing]
+    ids = experts_of(np.arange(t))
+    rng = np.random.default_rng(5)
+    # The router reads a token's scores off its first 64 values: the
+    # two chosen experts stand out, the rest is noise like the other 16.
+    x = rng.normal(size=(t, e + 16)).astype(np.float32)
+    x[np.arange(t), ids[:, 0]] = 9.0
+    x[np.arange(t), ids[:, 1]] = 8.0
+    d = x.shape[1]
+    params = {k: jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)
+              for k, shape, scale in (
+                  ("w_gate", (n_held, d, f), d ** -0.5),
+                  ("w_up", (n_held, d, f), d ** -0.5),
+                  ("w_down", (n_held, f, d), f ** -0.5),
+                  ("w_shared_gate", (d, f), d ** -0.5),
+                  ("w_shared_up", (d, f), d ** -0.5),
+                  ("w_shared_down", (f, d), f ** -0.5))}
+    params["router"] = jnp.eye(d, e, dtype=jnp.float32)
+    x = jnp.asarray(x)
+    np.testing.assert_array_equal(
+        ep_moe.route(params["router"], x, topk)[0], ids)
+    want, held, fullest = _all_pairs(params, x, topk=topk, first=first)
+    assert held == held_pairs
+    out, stats = jax.jit(lambda p, v: ep_moe.fwd_held(
+        p, v, topk=topk, first=first))(params, x)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+    assert stats.tolist() == [held, fullest, -(-held // rows)]
+    if routing == "no-pair-held":
+        np.testing.assert_allclose(
+            out, ep_moe.shared_expert_out(params, x), rtol=1e-6)
+    if routing == "one-expert-given-all":
+        assert fullest == held
+
+
 def test_absorbed_and_expanded_attention_agree_on_the_same_cache(tiny):
     _, _, cfg, _, params = tiny
     attn = params["layers"][1]["attn"]
@@ -334,12 +421,22 @@ def test_the_server_serves_it_with_the_pool_under_pressure(tiny):
         assert s["expert_load_imbalance"] >= 1.0
         assert s["expert_rows_mean"] == pytest.approx(
             s["expert_pairs_held"] / (4 * 2 * s["expert_steps"]))
+        # Programs this small take every pair in one pass (the shape
+        # rule), so a layer runs one, or none where its 3 decode rows
+        # sent no pair to a held expert: a layer or two a run.
+        assert (s["expert_steps"] * 2 - 3 <= s["expert_passes"]
+                <= s["expert_steps"] * 2)
+        assert 0.9 < s["expert_passes_a_layer"] == pytest.approx(
+            s["expert_passes"] / (2 * s["expert_steps"]))
         assert s["prefill_cache_size"] <= 2
     events = [e for e in tight.obs.log.spans()
               if e.kind == "expert_load"]
     assert events and all(
         e.attrs["held_pairs"] <= e.attrs["routed_pairs"]
         == e.attrs["rows"] * 4 * 2 for e in events)
+    assert all((e.attrs["held_pairs"] > 0) <= e.attrs["passes"] <= 2
+               for e in events)
+    assert sum(e.attrs["passes"] for e in events) == st["expert_passes"]
     assert tight.decode_cache_size() == 1
     assert isinstance(tight.cache, LatentPagedCache)
     assert tight.cache.pages.shape == (2, 12, 24, 8)
@@ -625,18 +722,20 @@ def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
     assert pool_copies(hlo, pool_shape) == []
 
 
-def _equations(jaxpr) -> int:
-    """The equations of ``jaxpr`` and of every jaxpr under it (loop and
+def _all_equations(jaxpr):
+    """Every equation of ``jaxpr`` and of every jaxpr under it (loop and
     branch bodies)."""
-    n = 0
     for eqn in jaxpr.eqns:
-        n += 1
+        yield eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    n += _equations(sub)
-    return n
+                    yield from _all_equations(sub)
+
+
+def _equations(jaxpr) -> int:
+    return sum(1 for _ in _all_equations(jaxpr))
 
 
 def _pallas_bodies(jaxpr):
@@ -675,3 +774,44 @@ def test_the_chunk_kernels_traced_body_stays_small(rows):
     bodies = list(_pallas_bodies(closed.jaxpr))
     assert len(bodies) == 1
     assert _equations(bodies[0]) <= 260
+
+
+@pytest.mark.parametrize("t,n_held,passes_over_all", [
+    (2064, 32, False), (528, 32, False), (16, 32, True), (2064, 128, True)])
+def test_the_held_experts_lay_out_held_pairs_only(t, n_held,
+                                                  passes_over_all):
+    """The expert block at the sizes of ``mistral-small-4-1chip`` (d
+    4096, f 2048, 4 of 128 a token; trace only, nothing runs): ONE body
+    a layer, so three grouped products and not six (a step program is
+    traced and lowered at every start), and no array of ``T * topk`` rows
+    by ``d`` or by ``f`` where a pass is smaller than every pair. The
+    pass follows the shapes: a share of the pairs with a margin for the
+    two chunk programs, every pair for the 16 decode rows and for a
+    layer that holds every expert."""
+    d, f, topk, e = 4096, 2048, 4, 128
+    pairs = t * topk
+    rows = ep_moe.held_pass_rows(t, topk, n_held, e)
+    assert (rows == pairs) == passes_over_all
+    if not passes_over_all:
+        # The share with its margin, in an odd number of 128-row tiles.
+        assert rows % 256 == 128
+        assert 0 <= rows - 1.25 * pairs * n_held / e < 256
+    s, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+    params = {"router": s((d, e), bf), "w_gate": s((n_held, d, f), bf),
+              "w_up": s((n_held, d, f), bf), "w_down": s((n_held, f, d), bf),
+              "w_shared_gate": s((d, f), bf), "w_shared_up": s((d, f), bf),
+              "w_shared_down": s((f, d), bf)}
+    closed = jax.make_jaxpr(lambda p, x: ep_moe.fwd_held(
+        p, x, topk=topk, routed_scale=1.0))(params, s((t, d), bf))
+    eqns = list(_all_equations(closed.jaxpr))
+    products = [q for q in eqns
+                if q.primitive.name.startswith("ragged_dot")]
+    assert len(products) == 3
+    assert sorted(q.outvars[0].aval.shape for q in products) == [
+        (rows, f), (rows, f), (rows, d)]
+    if not passes_over_all:
+        wide = [v.aval.shape for q in eqns for v in q.outvars
+                if getattr(v.aval, "ndim", 0) >= 2
+                and v.aval.shape[-1] in (d, f)
+                and int(np.prod(v.aval.shape[:-1])) >= pairs]
+        assert wide == []

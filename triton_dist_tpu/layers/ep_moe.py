@@ -33,6 +33,7 @@ tokens reach their experts —
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional
 
 import jax
@@ -43,7 +44,8 @@ from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops.ep_a2a import (EPContext, EP2DContext,
                                         ep_dispatch, ep_combine)
 from triton_dist_tpu.ops.ep_fused import EPFusedContext, ep_moe_fused
-from triton_dist_tpu.ops.group_gemm import sort_by_expert, grouped_swiglu
+from triton_dist_tpu.ops.group_gemm import (grouped_swiglu, sort_by_expert,
+                                            sort_pairs, window_group_sizes)
 
 DECODE_TRANSPORTS = ("ar", "ragged", "ll", "ll2d", "auto")
 
@@ -133,6 +135,29 @@ def shared_expert_out(params, x):
     return out * gate[:, None]
 
 
+# A pass of ``fwd_held`` has room for this many times the even share of
+# the pairs (``T * topk * E_held / E_router``).
+PASS_MARGIN = 1.25
+
+
+def held_pass_rows(t: int, topk: int, n_held: int, n_router: int) -> int:
+    """The rows one pass of :func:`fwd_held` lays out, from shapes
+    alone: ``PASS_MARGIN`` times the even share of the ``t * topk``
+    pairs that falls to ``n_held`` of ``n_router`` experts, rounded up
+    to an odd number of 128-row tiles, and never more than every pair.
+    A few rows, or a layer that holds every expert, is one pass over
+    everything.
+
+    Odd, because libtpu's ragged product takes its row tile from the
+    largest power of two in its operand's rows (512 at most) and every
+    group costs it a tile at least: at ~64 rows an expert a layer of
+    the 2048+16-row program takes 4.49 ms with tiles of 128, 4.53 with
+    256, 5.24 with 64 and 6.14 with 512 (PERF.md, PR 41)."""
+    pairs = t * topk
+    room = math.ceil(PASS_MARGIN * pairs * n_held / n_router)
+    return min(pairs, (-(-room // 128) | 1) * 128)
+
+
 def fwd_held(params, x, *, topk: int, first: int = 0,
              norm_topk_prob: bool = True, routed_scale: float = 1.0):
     """One chip's share of an expert-parallel layer, with no peer here
@@ -144,32 +169,64 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
     the combine this chip does not take part in); the shared expert,
     which every chip computes alike, is added whole.
 
-    x: (T, d). Returns ``(out (T, d) float32, stats (2,) int32)``:
+    Between the router and the combine every array has the rows of held
+    pairs only. The pairs' KEYS are sorted by expert; the sorted order
+    is walked in passes of ``C = held_pass_rows(...)`` rows, a static
+    size: a pass gathers its ``C`` rows of ``x``, runs the grouped
+    SwiGLU over its cut of the groups, and adds each row, weighted in
+    float32, to its token's result. ``ceil(held / C)`` passes run: one
+    at an even routing, one more over the overflow of a skewed chunk,
+    none where nothing was held. No pair is dropped at any routing.
+
+    x: (T, d). Returns ``(out (T, d) float32, stats (3,) int32)``:
     ``stats[0]`` the token-expert pairs that fell to held experts,
-    ``stats[1]`` the most rows one held expert was given."""
+    ``stats[1]`` the most rows one held expert was given, ``stats[2]``
+    the passes run."""
     t, d = x.shape
     n_held = params["w_gate"].shape[0]
     topk_ids, topk_w = route(params["router"], x, topk,
                              norm_topk_prob=norm_topk_prob)
     with scope("experts"):
+        rows = held_pass_rows(t, topk, n_held, params["router"].shape[1])
         local = topk_ids - first
-        held = (local >= 0) & (local < n_held)
-        flat = jnp.where(held, local, -1).reshape(-1)
-        sorted_tok, group_sizes, inv = sort_by_expert(
-            jnp.repeat(x, topk, axis=0), flat, n_held)
-        out = grouped_swiglu(sorted_tok, params["w_gate"], params["w_up"],
-                             params["w_down"], group_sizes)[inv]
-        # Rows past the last group are whatever the grouped product
-        # left there: selected away, not multiplied by zero.
-        w = (topk_w * routed_scale)[..., None]
-        out = jnp.sum(jnp.where(
-            held[..., None],
-            out.reshape(t, topk, d).astype(jnp.float32) * w, 0.0), axis=1)
+        flat = jnp.where((local >= 0) & (local < n_held), local,
+                         -1).reshape(-1)
+        # Held pairs come first in ``order``; ``place`` is a pair's row
+        # in it, under ``n_pairs`` for a held pair and for no other.
+        order, group_sizes, place = sort_pairs(flat, n_held)
+        n_pairs = jnp.sum(group_sizes)
+        passes = jax.lax.div(n_pairs + (rows - 1), rows)
+        order = jnp.pad(order, (0, -order.shape[0] % rows))
+        place = place.reshape(t, topk)
+        w = topk_w * routed_scale
+
+        def one_pass(i, out):
+            lo = i * rows
+            tokens = jax.lax.div(
+                jax.lax.dynamic_slice(order, (lo,), (rows,)), topk)
+            y = grouped_swiglu(
+                x.at[tokens].get(mode="promise_in_bounds"),
+                params["w_gate"], params["w_up"], params["w_down"],
+                window_group_sizes(group_sizes, lo, rows))
+            # Rows past the pass's last group are whatever the grouped
+            # product left there: selected away, not multiplied by zero.
+            at = place - lo
+            here = (at >= 0) & (at < rows) & (place < n_pairs)
+            at = jnp.clip(at, 0, rows - 1)
+            for k in range(topk):
+                mine = y.at[at[:, k]].get(mode="promise_in_bounds")
+                out = out + jnp.where(
+                    here[:, k, None],
+                    mine.astype(jnp.float32) * w[:, k, None], 0.0)
+            return out
+
+        out = jax.lax.fori_loop(0, passes, one_pass,
+                                jnp.zeros((t, d), jnp.float32))
     shared = shared_expert_out(params, x)
     if shared is not None:
         out = out + shared
     with scope("experts"):
-        stats = jnp.stack([jnp.sum(group_sizes), jnp.max(group_sizes)])
+        stats = jnp.stack([n_pairs, jnp.max(group_sizes), passes])
     return out, stats.astype(jnp.int32)
 
 

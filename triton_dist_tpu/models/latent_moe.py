@@ -64,9 +64,9 @@ from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops import latent_flash_qblock as _flash
 
 # What every step function returns last, summed over layers: the
-# token-expert pairs that fell to held experts, and the most rows one
-# held expert was given.
-STEP_STATS = ("held_pairs", "expert_rows_max")
+# token-expert pairs that fell to held experts, the most rows one held
+# expert was given, and the passes the held experts ran.
+STEP_STATS = ("held_pairs", "expert_rows_max", "expert_passes")
 
 # Keys a block of the context walk holds, at most: the expanded path's
 # float32 scores are heads x rows x this.

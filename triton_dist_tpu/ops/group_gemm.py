@@ -7,7 +7,12 @@ Two TPU forms:
 
 - :func:`grouped_gemm` / :func:`grouped_swiglu`: tokens sorted by expert
   + ``jax.lax.ragged_dot`` (XLA's native grouped matmul, which tiles
-  onto the MXU with group offsets) — the zero-maintenance path.
+  onto the MXU with group offsets) — the zero-maintenance path. A
+  caller that holds some of the experts sorts the keys alone
+  (:func:`sort_pairs`) and hands the product a window of the sorted
+  rows with the groups cut to it (:func:`window_group_sizes`), so the
+  operand has the rows that are computed and no others
+  (``layers/ep_moe.fwd_held``).
 - :func:`grouped_gemm_tiles`: a Pallas kernel over the ``block_m``-
   aligned expert-major layout of
   :func:`~triton_dist_tpu.ops.ag_moe.prepare_grouped_tokens`. The
@@ -28,16 +33,36 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_dist_tpu.lang import core_call
 
 
+def sort_pairs(expert_ids, num_experts: int):
+    """Sort (slots,) local expert ids, the keys alone (-1 = empty slots
+    go last). Returns (order: the slots expert by expert, group_sizes
+    (num_experts,), inverse permutation: a slot's place in ``order``)."""
+    key = jnp.where(expert_ids < 0, num_experts, expert_ids)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order)
+    # Counted by comparison, not by ``bincount``: that is a scatter-add,
+    # which the TPU takes a slot at a time.
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(num_experts)[None, :],
+                          axis=0, dtype=jnp.int32)
+    return order, group_sizes, inv
+
+
 def sort_by_expert(tokens, expert_ids, num_experts: int):
     """Sort (slots, d) tokens by local expert id (-1 = empty slots go
     last). Returns (sorted_tokens, group_sizes (num_experts,), inverse
     permutation to restore slot order)."""
-    key = jnp.where(expert_ids < 0, num_experts, expert_ids)
-    order = jnp.argsort(key, stable=True)
-    inv = jnp.argsort(order)
-    sorted_tok = tokens[order]
-    group_sizes = jnp.bincount(key[order], length=num_experts + 1)[:-1]
-    return sorted_tok, group_sizes.astype(jnp.int32), inv
+    order, group_sizes, inv = sort_pairs(expert_ids, num_experts)
+    return tokens[order], group_sizes, inv
+
+
+def window_group_sizes(group_sizes, lo, rows: int):
+    """The group sizes of rows ``[lo, lo + rows)`` of an expert-sorted
+    layout whose groups are ``group_sizes``: each group cut to the part
+    of it that lies in the window. They sum to the sorted rows the
+    window holds, ``rows`` at most."""
+    ends = jnp.cumsum(group_sizes)
+    cut = jnp.clip(jnp.stack([ends - group_sizes, ends]) - lo, 0, rows)
+    return (cut[1] - cut[0]).astype(jnp.int32)
 
 
 def grouped_gemm(x, w, group_sizes):

@@ -486,9 +486,10 @@ class ServingEngine:
             "ticks": 0,
             # Held experts (a model with STEP_STATS): token-expert
             # pairs the read programs routed, the ones that fell to
-            # held experts, and the fullest expert's rows a layer.
+            # held experts, the fullest expert's rows a layer, and
+            # the passes the held experts ran.
             "expert_pairs_routed": 0, "expert_pairs_held": 0,
-            "expert_rows_max": 0, "expert_steps": 0,
+            "expert_rows_max": 0, "expert_passes": 0, "expert_steps": 0,
         }
         self._step_stats = ()
         self.prefill_buckets = (tuple(sorted(set(int(b) for b in
@@ -1112,6 +1113,10 @@ class ServingEngine:
             out["expert_rows_mean"] = out["expert_pairs_held"] / (
                 self.cfg.held_experts * self.cfg.num_hidden_layers
                 * out["expert_steps"])
+            # Passes the held experts ran, a layer of a read program:
+            # 1.0 = one pass had room for the held pairs every time.
+            out["expert_passes_a_layer"] = out["expert_passes"] / (
+                self.cfg.num_hidden_layers * out["expert_steps"])
         if self.manager is not None:
             out["pool"] = self.manager.fragmentation()
         if hasattr(self, "plan"):
@@ -3116,19 +3121,20 @@ class ServingEngine:
         if not self._step_stats:
             return
         cfg = self.cfg
-        held, rows_max = (int(v) for v in
-                          picked[-len(self._step_stats):])
+        held, rows_max, passes = (int(v) for v in
+                                  picked[-len(self._step_stats):])
         routed = (rows * cfg.num_experts_per_tok
                   * cfg.num_hidden_layers)
         c = self.stats_counters
         c["expert_pairs_routed"] += routed
         c["expert_pairs_held"] += held
         c["expert_rows_max"] += rows_max
+        c["expert_passes"] += passes
         c["expert_steps"] += 1
         self.obs.event(
             "expert_load", step=c["decode_dispatches"], rows=rows,
             held_pairs=held, routed_pairs=routed,
-            expert_rows_max=rows_max,
+            expert_rows_max=rows_max, passes=passes,
             expert_imbalance=(rows_max * cfg.held_experts / held
                               if held else None))
 
