@@ -16,14 +16,18 @@ import pytest
 
 import triton_dist_tpu as tdt
 from triton_dist_tpu import obs
-from triton_dist_tpu.models import Engine, ModelConfig, latent_moe
+from triton_dist_tpu.models import (Engine, ModelConfig, latent_moe,
+                                    mamba_moe)
 
 # What each family's chunk program runs; ``embed``, the attention
-# blocks, ``head`` and ``pick`` are common to both.
+# blocks, ``head`` and ``pick`` are common to all.
 COMMON = {"embed", "attn_project", "cache_write", "attn_chunk",
           "attn_decode", "attn_out", "head", "pick"}
+EXPERTS = {"router", "experts", "shared_expert"}
 RUNS = {"dense": COMMON | {"mlp"},
-        "latent_moe": COMMON | {"router", "experts", "shared_expert"}}
+        "latent_moe": COMMON | EXPERTS,
+        "mamba_moe": COMMON | EXPERTS | {"ssm_project", "ssm", "ssm_out",
+                                         "expert_latent"}}
 
 
 def _chunk_program_text(family: str) -> str:
@@ -33,16 +37,21 @@ def _chunk_program_text(family: str) -> str:
     if family == "dense":
         eng = Engine(ModelConfig.tiny(), mesh, mode="xla", max_len=32,
                      seed=0)
-    else:
+    elif family == "latent_moe":
         eng = Engine(ModelConfig.tiny_latent_moe(), mesh, model=latent_moe,
+                     mode="xla", dtype=jnp.float32, max_len=32, seed=0)
+    else:
+        eng = Engine(ModelConfig.tiny_mamba_moe(), mesh, model=mamba_moe,
                      mode="xla", dtype=jnp.float32, max_len=32, seed=0)
     srv = eng.serving(num_slots=2, page=8, prefill_buckets=(8,))
     assert srv.chunker.decode_rows == 2
     p_max = srv.cache.block_table.shape[1]
+    # A pool whose sequences keep state is told the chunk's slot.
+    slot = (np.int32(0),) * bool(srv.cache.seq)
     return srv.chunker._chunk.lower(
         eng.params, jnp.zeros((8,), jnp.int32), srv.cache,
         jnp.zeros((p_max,), jnp.int32), np.int32(0), np.int32(0),
-        np.int32(8), jnp.zeros((2,), jnp.int32)).compile().as_text()
+        np.int32(8), *slot, jnp.zeros((2,), jnp.int32)).compile().as_text()
 
 
 @pytest.mark.parametrize("family", sorted(RUNS))
@@ -56,7 +65,7 @@ def test_the_chunk_program_names_every_block_it_runs(family):
     assert RUNS[family] <= set(obs.DEVICE_SCOPES)
 
 
-def test_both_families_together_run_the_whole_vocabulary():
+def test_the_families_together_run_the_whole_vocabulary():
     assert set().union(*RUNS.values()) == set(obs.DEVICE_SCOPES)
 
 

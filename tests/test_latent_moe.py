@@ -722,6 +722,89 @@ def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
     assert pool_copies(hlo, pool_shape) == []
 
 
+@pytest.mark.parametrize("program", ["decode", "fused-512"])
+def test_compiled_state_space_steps_keep_pool_and_state_in_place(
+        v5e, mosaic, program):
+    """``models.mamba_moe``'s paged steps (its pool is the dense
+    family's with the attention layers alone, beside what a SEQUENCE
+    keeps: PR 43), lowered for one v5e chip as the serving engine jits
+    them, at the published Mamba-2 sizes over a narrow residual: neither the K/V pool nor the sequences'
+    recurrent state is copied or relaid (both are donated and written
+    by ``lax.dynamic_update_slice``), and the chunk rows' and decode
+    rows' attention are the two paged kernels at 4 heads a group. Here
+    and not in a file of its own: one worker holds libtpu. Compile
+    only."""
+    from jax.sharding import NamedSharding
+    from triton_dist_tpu.models import mamba_moe
+    from triton_dist_tpu.serving.blocks import PagedKVCache, pool_shardings
+    from triton_dist_tpu.utils.testing import pool_copies
+
+    cfg = ModelConfig.tiny_mamba_moe(
+        vocab_size=1024, hidden_size=512, num_hidden_layers=7,
+        layer_pattern="MMEM*MM", num_attention_heads=8,
+        num_key_value_heads=2, head_dim=128, mamba_num_heads=128,
+        mamba_head_dim=64,
+        mamba_n_groups=8, ssm_state_size=128, mamba_chunk_size=128,
+        moe_latent_size=256, moe_intermediate_size=256,
+        shared_expert_intermediate_size=512)
+    # The sequences' state as the cell has it, 168 MB: past the chip's
+    # 128 MiB of VMEM, where a smaller one is fetched whole.
+    slots, page, p_max, pages = 16, 128, 8, 2049
+    mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
+    dt = jnp.bfloat16
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=(
+                    s if isinstance(s, NamedSharding)
+                    else NamedSharding(mesh, s))),
+            tree, specs, is_leaf=lambda s: isinstance(s, P))
+
+    specs = mamba_moe.param_specs(cfg, "tp")
+    params = on_mesh(jax.eval_shape(lambda: mamba_moe.init_params(
+        jax.random.PRNGKey(0), cfg, dt)), specs)
+    kv_spec = mamba_moe.paged_cache_specs("tp")
+    kv_sh = pool_shardings(mesh, kv_spec)
+    _, per_token, keeps = mamba_moe.paged_pool(cfg)
+    cache = on_mesh(jax.eval_shape(lambda: PagedKVCache.empty(
+        keeps["layers"], pages, page, *per_token, num_slots=slots,
+        p_max=p_max, dtype=dt, seq_state=keeps["seq_state"])), kv_sh)
+    ints = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    if program == "decode":
+        step = lambda p, t, c: mamba_moe.decode_step_paged(
+            p, t, c, cfg, attn_impl="flash")
+        in_specs = (specs, P(None), kv_spec)
+        out_specs = (P(None, None), kv_spec, P(None))
+        args = (params, ints(slots), cache)
+    else:
+        step = lambda p, t, c, row, start, wfrom, valid, slot, d: (
+            mamba_moe.chunk_decode_paged(
+                p, t, d, c, row, cfg, start=start, wfrom=wfrom,
+                valid=valid, slot=slot, attn_impl="flash",
+                decode_attn_impl="flash"))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P(), P(),
+                    P(None))
+        out_specs = (P(None), P(None, None), kv_spec, P(None))
+        args = (params, ints(512), cache, ints(p_max), ints(), ints(),
+                ints(), ints(), ints(slots))
+    hlo = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=(args.index(cache),),
+        out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
+                            for s in out_specs)).lower(*args).compile(
+                            ).as_text()
+    assert cache.k_pages.shape == (1, 2049, 2, 128, 128)    # one layer's
+    state = cache.seq[mamba_moe.STATE]
+    assert (state.shape, state.dtype) == ((5, 16, 128, 64, 128), dt)
+    assert pool_copies(hlo, cache.k_pages.shape) == []
+    assert pool_copies(hlo, state.shape) == []
+    assert "paged_flash_decode" in hlo
+    assert ("paged_flash_qblock" in hlo) == (program != "decode")
+
+
 def _all_equations(jaxpr):
     """Every equation of ``jaxpr`` and of every jaxpr under it (loop and
     branch bodies)."""

@@ -44,8 +44,9 @@ from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops.ep_a2a import (EPContext, EP2DContext,
                                         ep_dispatch, ep_combine)
 from triton_dist_tpu.ops.ep_fused import EPFusedContext, ep_moe_fused
-from triton_dist_tpu.ops.group_gemm import (grouped_swiglu, sort_by_expert,
-                                            sort_pairs, window_group_sizes)
+from triton_dist_tpu.ops.group_gemm import (grouped_relu2, grouped_swiglu,
+                                            sort_by_expert, sort_pairs,
+                                            window_group_sizes)
 
 DECODE_TRANSPORTS = ("ar", "ragged", "ll", "ll2d", "auto")
 
@@ -95,15 +96,32 @@ def param_specs(axis: str = "ep", cfg=None) -> Dict:
 
 
 @scope("router")
-def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
-    """Qwen3-MoE router: softmax over experts then top-k, weights
-    renormalized (reference ``models/qwen_moe.py``)."""
+def route(router_w, x, topk: int, *, norm_topk_prob: bool = True,
+          scoring: str = "softmax", bias=None):
+    """The experts a token goes to and their weights: ``(ids (T, topk)
+    int32, weights (T, topk) float32)``.
+
+    ``scoring="softmax"`` (Qwen3-MoE; reference ``models/qwen_moe.py``):
+    softmax over experts then top-k, weights renormalized.
+    ``"sigmoid"``: every expert scored on its own. ``bias`` (E,), a leaf
+    of the layer, is added to the scores for the SELECTION alone; the
+    weights are the chosen experts' scores without it."""
     # Full float32 products: on the TPU a float32 dot is otherwise one
     # bfloat16 pass, and near-ties among the k-th choices flip on it.
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topk_w, topk_ids = jax.lax.top_k(probs, topk)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring={scoring!r}: 'softmax' | 'sigmoid'")
+    if bias is None:
+        topk_w, topk_ids = jax.lax.top_k(scores, topk)
+    else:
+        topk_ids = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                 topk)[1]
+        topk_w = jnp.take_along_axis(scores, topk_ids, axis=-1)
     if norm_topk_prob:
         topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
     return topk_ids.astype(jnp.int32), topk_w
@@ -111,16 +129,23 @@ def route(router_w, x, topk: int, *, norm_topk_prob: bool = True):
 
 @scope("shared_expert")
 def shared_expert_out(params, x):
-    """The dense SwiGLU branch every token takes; None when the layer
-    has none. Sigmoid-gated where the parameters hold a ``shared_gate``
-    vector (qwen3_next), plain where they do not (the latent-attention
-    family). Under TP ffn-sharded weights the result is a PARTIAL sum
-    (the caller's reduce completes it — the sigmoid gate uses the
-    replicated ``shared_gate`` vector so every rank scales by the same
-    factor); under replicated weights (EP) it is the full
-    contribution."""
+    """The dense branch every token takes; None when the layer has
+    none. A SwiGLU where the parameters hold ``w_shared_gate``; up,
+    squared ReLU, down where they hold ``w_shared_up`` and
+    ``w_shared_down`` alone. Sigmoid-gated where they hold a
+    ``shared_gate`` vector (qwen3_next), plain where they do not (the
+    latent-attention family). Under TP ffn-sharded weights the result
+    is a PARTIAL sum (the caller's reduce completes it — the sigmoid
+    gate uses the replicated ``shared_gate`` vector so every rank
+    scales by the same factor); under replicated weights (EP) it is
+    the full contribution."""
     if "w_shared_gate" not in params:
-        return None
+        if "w_shared_up" not in params:
+            return None
+        u = jnp.dot(x, params["w_shared_up"])
+        act = jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(x.dtype)
+        return jnp.dot(act, params["w_shared_down"],
+                       preferred_element_type=jnp.float32)
     g = jnp.dot(x, params["w_shared_gate"])
     u = jnp.dot(x, params["w_shared_up"])
     act = (jax.nn.silu(g.astype(jnp.float32))
@@ -158,8 +183,40 @@ def held_pass_rows(t: int, topk: int, n_held: int, n_router: int) -> int:
     return min(pairs, (-(-room // 128) | 1) * 128)
 
 
+def expert_store_width(f: int) -> int:
+    """The width a held expert's matrices are STORED at: ``f`` rounded up
+    to a whole number of 512 columns once it is 1,024 or more, the
+    padding zeros (a zero column of the up-projection gives a zero
+    activation under either form of expert, and meets a zero row of the
+    down-projection: each sum gains zeros and nothing else).
+
+    libtpu's ragged product takes its N and K tiles from the operand's
+    size: at 2,688 = 21 x 128 columns, 128 groups, a product of 14,208
+    rows takes 5.24 ms (N) and 4.20 (K); at 3,072 = 6 x 512, 2.67 and
+    2.53, for a seventh more weights (PERF.md, PR 43). Narrow experts
+    (the CPU presets) are stored as they are. :func:`fwd_held` takes no
+    other width: a tree at 2,688 columns is refused there, not served
+    on the slower tiles."""
+    return f if f < 1024 else -(-f // 512) * 512
+
+
+def pad_expert_width(w_up, w_down, *more_up):
+    """``w_up (E, d, f)``, ``w_down (E, f, d)`` (and a gated form's
+    ``w_gate`` in ``more_up``) zero-padded to :func:`expert_store_width`,
+    for whoever makes a parameter tree (a model's ``init_params``, the
+    benchmark's seeded weights). It is the MAKER that pads, leaf by leaf
+    as it makes them: padding a whole tree where it enters ``Engine``
+    would hold both copies of every expert at once (9.3 + 8.1 GB of
+    the benchmark's period on a 16 GB chip)."""
+    pad = expert_store_width(w_up.shape[-1]) - w_up.shape[-1]
+    ups = tuple(jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+                for w in (w_up,) + more_up)
+    return (ups[0], jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))) + ups[1:]
+
+
 def fwd_held(params, x, *, topk: int, first: int = 0,
-             norm_topk_prob: bool = True, routed_scale: float = 1.0):
+             norm_topk_prob: bool = True, routed_scale: float = 1.0,
+             scoring: str = "softmax", act: str = "swiglu"):
     """One chip's share of an expert-parallel layer, with no peer here
     and no exchange: the router is as wide as the deployment's
     (``params["router"]``: every expert), the weights are those of the
@@ -178,14 +235,45 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
     at an even routing, one more over the overflow of a skewed chunk,
     none where nothing was held. No pair is dropped at any routing.
 
+    The router is :func:`route`'s (``scoring``; its selection bias is
+    the layer's ``router_bias`` leaf, where it has one). ``act`` is the
+    routed experts' form: ``"swiglu"`` (``w_gate``, ``w_up``,
+    ``w_down``) or ``"relu2"`` (``w_up``, ``w_down``: up, squared ReLU,
+    down). Where the parameters hold ``w_latent_in`` and
+    ``w_latent_out`` the routed experts work in a LATENT: the rows are
+    projected into it once, every pass gathers and combines rows of the
+    latent's width, and the weighted sum is projected out once; the
+    router and the shared expert read ``x`` itself.
+
     x: (T, d). Returns ``(out (T, d) float32, stats (3,) int32)``:
     ``stats[0]`` the token-expert pairs that fell to held experts,
     ``stats[1]`` the most rows one held expert was given, ``stats[2]``
     the passes run."""
     t, d = x.shape
-    n_held = params["w_gate"].shape[0]
+    n_held, _, f = params["w_up"].shape
+    if f != expert_store_width(f):
+        raise ValueError(
+            f"held experts {f} columns wide: they are stored at whole "
+            f"512s once 1,024 wide ({expert_store_width(f)}; "
+            "ep_moe.pad_expert_width)")
     topk_ids, topk_w = route(params["router"], x, topk,
-                             norm_topk_prob=norm_topk_prob)
+                             norm_topk_prob=norm_topk_prob,
+                             scoring=scoring,
+                             bias=params.get("router_bias"))
+    if act == "swiglu":
+        def experts(rows_in, sizes):
+            return grouped_swiglu(rows_in, params["w_gate"],
+                                  params["w_up"], params["w_down"], sizes)
+    elif act == "relu2":
+        def experts(rows_in, sizes):
+            return grouped_relu2(rows_in, params["w_up"],
+                                 params["w_down"], sizes)
+    else:
+        raise ValueError(f"act={act!r}: 'swiglu' | 'relu2'")
+    u = x
+    if "w_latent_in" in params:
+        with scope("expert_latent"):
+            u = jnp.dot(x, params["w_latent_in"])
     with scope("experts"):
         rows = held_pass_rows(t, topk, n_held, params["router"].shape[1])
         local = topk_ids - first
@@ -204,10 +292,8 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
             lo = i * rows
             tokens = jax.lax.div(
                 jax.lax.dynamic_slice(order, (lo,), (rows,)), topk)
-            y = grouped_swiglu(
-                x.at[tokens].get(mode="promise_in_bounds"),
-                params["w_gate"], params["w_up"], params["w_down"],
-                window_group_sizes(group_sizes, lo, rows))
+            y = experts(u.at[tokens].get(mode="promise_in_bounds"),
+                        window_group_sizes(group_sizes, lo, rows))
             # Rows past the pass's last group are whatever the grouped
             # product left there: selected away, not multiplied by zero.
             at = place - lo
@@ -221,7 +307,11 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
             return out
 
         out = jax.lax.fori_loop(0, passes, one_pass,
-                                jnp.zeros((t, d), jnp.float32))
+                                jnp.zeros(u.shape, jnp.float32))
+    if "w_latent_out" in params:
+        with scope("expert_latent"):
+            out = jnp.dot(out.astype(x.dtype), params["w_latent_out"],
+                          preferred_element_type=jnp.float32)
     shared = shared_expert_out(params, x)
     if shared is not None:
         out = out + shared

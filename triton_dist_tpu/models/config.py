@@ -93,10 +93,49 @@ class ModelConfig:
     # (layers/ep_moe.py ``fwd_held``).
     first_held_expert: int = 0
     num_held_experts: int = 0
+    # The expert layer's other forms (layers/ep_moe.py): ``"sigmoid"``
+    # scores every expert on its own and a correction bias, a leaf of
+    # the layer, is added for the SELECTION alone (``route``);
+    # ``"relu2"`` experts are up, squared ReLU, down, with no gate;
+    # ``moe_latent_size`` > 0 is the width the routed experts work in,
+    # a latent the layer projects into and out of.
+    moe_scoring: str = "softmax"
+    moe_act: str = "swiglu"
+    moe_latent_size: int = 0
+    # A layer is a mixer OR a feed-forward part (models/mamba_moe.py):
+    # ``layer_pattern`` gives each layer one letter, ``M`` a Mamba-2
+    # mixer, ``E`` an expert layer, ``*`` attention; "" = every layer
+    # the family's one block. A Mamba-2 layer has ``mamba_num_heads``
+    # heads of ``mamba_head_dim``, B and C in ``mamba_n_groups`` groups
+    # of ``ssm_state_size``, a causal depthwise convolution of
+    # ``mamba_conv_kernel`` taps before them, and scans in chunks of
+    # ``mamba_chunk_size`` rows; it keeps a state and the convolution's
+    # tail a SEQUENCE, and no pages.
+    layer_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    ssm_state_size: int = 128
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def num_moe_layers(self) -> int:
+        """Layers with routed experts."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
+        return self.num_hidden_layers if self.is_moe else 0
+
+    @property
+    def num_paged_layers(self) -> int:
+        """Layers that keep pages of keys."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("*")
+        return self.num_hidden_layers
 
     @property
     def is_hybrid(self) -> bool:
@@ -149,7 +188,7 @@ class ModelConfig:
             if qdtype is not None:
                 raise ValueError("the latent pool is not quantized: "
                                  f"kv_dtype={kv_dtype!r}")
-            page_bytes = (self.num_hidden_layers * page * dtype_bytes
+            page_bytes = (self.num_paged_layers * page * dtype_bytes
                           * (self.kv_lora_rank + self.qk_rope_head_dim))
             return {
                 "page": page, "p_max": p_max, "num_pages": num_pages,
@@ -161,13 +200,13 @@ class ModelConfig:
                 "capacity_ratio_vs_native": 1.0,
                 "tokens_per_page": page,
             }
-        native_bytes = (self.num_hidden_layers * kv_loc * page
+        native_bytes = (self.num_paged_layers * kv_loc * page
                         * self.head_dim * dtype_bytes)
         if qdtype is None:
             page_bytes = native_bytes
         else:
             # 1 byte/element storage + the per-page per-head scale.
-            page_bytes = (self.num_hidden_layers * kv_loc
+            page_bytes = (self.num_paged_layers * kv_loc
                           * (page * self.head_dim + 4))
         plan = {
             "page": page, "p_max": p_max, "num_pages": num_pages,
@@ -249,6 +288,92 @@ class ModelConfig:
         return cls(**base)
 
     @classmethod
+    def tiny_mamba_moe(cls, **kw) -> "ModelConfig":
+        """Mamba-2 layers, latent experts behind a sigmoid router and
+        one attention layer at a size for the CPU mesh: 16 experts, 4 a
+        token, 4 of them held; scan chunks of 8 rows."""
+        base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=6,
+                    layer_pattern="MEM*EM", num_attention_heads=4,
+                    num_key_value_heads=2, head_dim=16, rms_norm_eps=1e-5,
+                    mamba_num_heads=8, mamba_head_dim=16, mamba_n_groups=2,
+                    ssm_state_size=16, mamba_conv_kernel=4,
+                    mamba_chunk_size=8, num_experts=16,
+                    num_experts_per_tok=4, moe_scoring="sigmoid",
+                    moe_act="relu2", moe_latent_size=32,
+                    moe_intermediate_size=48,
+                    shared_expert_intermediate_size=96,
+                    routed_scaling_factor=5.0, first_held_expert=0,
+                    num_held_experts=4, max_position_embeddings=128,
+                    model_name="mamba-moe-tiny")
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def _from_nemotron_h(cls, get, req) -> "ModelConfig":
+        """``model_type: nemotron_h``: ``hybrid_override_pattern`` gives
+        every layer ONE part (models/mamba_moe.py)."""
+        pattern = req("hybrid_override_pattern")
+        n_layers = req("num_hidden_layers")
+        if len(pattern) != n_layers or set(pattern) - set("ME*"):
+            raise NotImplementedError(
+                f"hybrid_override_pattern {pattern!r}: one of 'M', 'E', "
+                f"'*' for each of the {n_layers} layers is served (a "
+                "dense '-' layer is not)")
+        if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+            raise NotImplementedError(
+                f"n_group={get('n_group')}, topk_group="
+                f"{get('topk_group')}: group-limited routing is not "
+                "computed; the router chooses among all experts")
+        if get("mlp_hidden_act", "relu2") != "relu2" or get(
+                "mamba_hidden_act", "silu") != "silu":
+            raise NotImplementedError(
+                "nemotron_h with squared-ReLU experts and a SiLU "
+                "convolution is computed, not mlp_hidden_act="
+                f"{get('mlp_hidden_act')!r}, mamba_hidden_act="
+                f"{get('mamba_hidden_act')!r}")
+        if any(get(k) for k in ("attention_bias", "mlp_bias", "use_bias",
+                                "mamba_proj_bias")) or not get(
+                                    "use_conv_bias", True):
+            raise NotImplementedError(
+                "nemotron_h is computed with no bias but the "
+                "convolution's")
+        d, heads = req("hidden_size"), req("mamba_num_heads")
+        if heads * req("mamba_head_dim") != (get("expand") or 2) * d:
+            raise ValueError("mamba_num_heads * mamba_head_dim is not "
+                             "expand * hidden_size")
+        return cls(
+            vocab_size=req("vocab_size"), hidden_size=d,
+            intermediate_size=get("intermediate_size", 4 * d),
+            num_hidden_layers=n_layers, layer_pattern=pattern,
+            num_attention_heads=req("num_attention_heads"),
+            num_key_value_heads=req("num_key_value_heads"),
+            head_dim=req("head_dim"),
+            rms_norm_eps=get("norm_eps") or get("layer_norm_epsilon")
+            or 1e-5,
+            # Carried and unused: this family's attention does not
+            # rotate (the Mamba layers carry position).
+            rope_theta=get("rope_theta") or 10000.0,
+            partial_rotary_factor=get("partial_rotary_factor") or 1.0,
+            qk_norm=False,
+            max_position_embeddings=get("max_position_embeddings", 40960),
+            tie_word_embeddings=get("tie_word_embeddings", False),
+            model_name="nemotron_h",
+            mamba_num_heads=heads, mamba_head_dim=req("mamba_head_dim"),
+            mamba_n_groups=req("n_groups"),
+            ssm_state_size=req("ssm_state_size"),
+            mamba_conv_kernel=req("conv_kernel"),
+            mamba_chunk_size=get("chunk_size") or 128,
+            num_experts=req("n_routed_experts"),
+            num_experts_per_tok=req("num_experts_per_tok"),
+            moe_intermediate_size=req("moe_intermediate_size"),
+            moe_latent_size=get("moe_latent_size") or 0,
+            shared_expert_intermediate_size=get(
+                "moe_shared_expert_intermediate_size") or 0,
+            norm_topk_prob=get("norm_topk_prob", True),
+            routed_scaling_factor=get("routed_scaling_factor") or 1.0,
+            moe_scoring="sigmoid", moe_act="relu2")
+
+    @classmethod
     def qwen3_next_80b_a3b(cls) -> "ModelConfig":
         """Qwen3-Next-80B-A3B geometry: 48 layers, 3 GDN : 1 full-attn,
         MoE FFN (512 experts, 10 active + shared omitted)."""
@@ -309,6 +434,8 @@ class ModelConfig:
                     "a supported config.json?")
             return v
 
+        if get("model_type") == "nemotron_h":
+            return cls._from_nemotron_h(get, req)
         d = req("hidden_size")
         heads = req("num_attention_heads")
 

@@ -95,13 +95,19 @@ SCOPE_PREFIX = "tdt."
 DEVICE_SCOPES = (
     "embed",             # the token rows gathered from the table
     "attn_project",      # norm, q/k/v or the latent's projections, rope
-    "cache_write",       # the rows' entries written into the paged pool
+    "cache_write",       # the rows' entries written into the paged pool,
+                         # and a sequence's state where it keeps one
     "attn_chunk",        # what a prefill chunk's queries read
     "attn_decode",       # what decode and verification rows read
     "attn_out",          # the output projection and its residual
+    "ssm_project",       # a state-space layer's norm and in-projection
+    "ssm",               # between its two projections: convolution,
+                         # step sizes, the scan or the step, gated norm
+    "ssm_out",           # its output projection and residual
     "mlp",               # norm + dense FFN
     "router",            # the expert layer's norm, scores and top-k
     "experts",           # sort by expert, grouped SwiGLU, the combine
+    "expert_latent",     # into and out of the latent the experts work in
     "shared_expert",     # the expert every token goes through
     "head",              # final norm, the rows kept, the vocabulary
     "pick",              # the greedy token (and the step's counts)

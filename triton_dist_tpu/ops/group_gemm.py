@@ -5,7 +5,8 @@ persistent grouped GEMM with token-block swizzle) + ``moe_utils.py``.
 
 Two TPU forms:
 
-- :func:`grouped_gemm` / :func:`grouped_swiglu`: tokens sorted by expert
+- :func:`grouped_gemm` / :func:`grouped_swiglu` /
+  :func:`grouped_relu2`: tokens sorted by expert
   + ``jax.lax.ragged_dot`` (XLA's native grouped matmul, which tiles
   onto the MXU with group offsets) — the zero-maintenance path. A
   caller that holds some of the experts sorts the keys alone
@@ -154,6 +155,17 @@ def grouped_swiglu(x, w_gate, w_up, w_down, group_sizes):
     u = jax.lax.ragged_dot(x, w_up, group_sizes,
                            preferred_element_type=jnp.float32)
     h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(h, w_down, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
+def grouped_relu2(x, w_up, w_down, group_sizes):
+    """Per-expert ungated MLP over expert-sorted tokens: up, squared
+    ReLU, down. w_up: (E, d, f); w_down: (E, f, d)."""
+    u = jax.lax.ragged_dot(x, w_up, group_sizes,
+                           preferred_element_type=jnp.float32)
+    h = jnp.square(jax.nn.relu(u)).astype(x.dtype)
     return jax.lax.ragged_dot(h, w_down, group_sizes,
                               preferred_element_type=jnp.float32
                               ).astype(x.dtype)

@@ -29,8 +29,9 @@ dead slots land there instead of corrupting a reused page.
 from __future__ import annotations
 
 import dataclasses
+import types
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -241,6 +242,24 @@ class BlockTableOverflowError(RuntimeError):
     the server."""
 
 
+class SeqArray(NamedTuple):
+    """One array a SEQUENCE keeps beside its pages, as a model's
+    ``paged_pool`` states it: ``shape`` a slot (the layer first, as it
+    leads the pages), always in the pool's type, which is the
+    parameters'; the array the server allocates has the slots' axis
+    inserted at ``slot_axis`` (the model says where, as it says how a
+    page lies: the layout is what its step programs slice). Not
+    position-addressed: nothing of it is found through the block table
+    or cut back by a length."""
+    shape: Tuple[int, ...]
+    slot_axis: int = 0
+
+    def zeros(self, num_slots: int, pool_dtype):
+        full = (self.shape[:self.slot_axis] + (num_slots,)
+                + self.shape[self.slot_axis:])
+        return jnp.zeros(full, pool_dtype)
+
+
 @dataclasses.dataclass
 class PagedKVCache:
     """Device half of the paged cache (see module docstring).
@@ -267,6 +286,15 @@ class PagedKVCache:
     scale) and every read path (``dense_row``/``dense_layer``, the
     fused kernel prefetch) dequantizes. The unquantized path keeps the
     scales ``None`` and runs the original code bit-identically.
+
+    ``seq``: what a sequence keeps that is no page of keys (a recurrent
+    state, a convolution's tail), name -> array over the decode slots
+    (:class:`SeqArray`); empty for a model that keeps none, and then no
+    leaf of the tree. The model's step programs reset a slot's at its
+    first chunk, carry it chunk to chunk and advance it for live decode
+    rows only; the server allocates it and never reads it. Whatever
+    moves PAGES (tiers, migration, prefix sharing) knows nothing of it:
+    the server refuses those for a pool that states one.
     """
 
     k_pages: jax.Array
@@ -276,12 +304,14 @@ class PagedKVCache:
     live: jax.Array
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    seq: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
 
     @classmethod
     def empty(cls, num_layers: int, num_pages: int, page: int,
               kv_heads_loc: int, head_dim: int, *, num_slots: int,
-              p_max: int, dtype=jnp.float32,
-              kv_dtype: str = "bf16") -> "PagedKVCache":
+              p_max: int, dtype=jnp.float32, kv_dtype: str = "bf16",
+              seq_state: Optional[Dict[str, SeqArray]] = None
+              ) -> "PagedKVCache":
         shape = (num_layers, num_pages, kv_heads_loc, page, head_dim)
         qdtype, _ = kv_quant_spec(kv_dtype)
         pool_dtype = dtype if qdtype is None else qdtype
@@ -294,7 +324,9 @@ class PagedKVCache:
             lens=jnp.zeros((num_slots,), jnp.int32),
             live=jnp.zeros((num_slots,), jnp.int32),
             k_scale=scale, v_scale=(None if scale is None
-                                    else jnp.ones_like(scale)))
+                                    else jnp.ones_like(scale)),
+            seq={name: a.zeros(num_slots, dtype)
+                 for name, a in (seq_state or {}).items()})
 
     @classmethod
     def empty_sharded(cls, mesh, spec_fn, axis: str, *shape_args,
@@ -610,7 +642,7 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return (self.k_pages, self.v_pages, self.block_table, self.lens,
-                self.live, self.k_scale, self.v_scale), None
+                self.live, self.k_scale, self.v_scale, self.seq), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -648,6 +680,9 @@ class LatentPagedCache:
     live: jax.Array
 
     quantized = False
+    # What a sequence keeps beside its pages (:class:`PagedKVCache` has
+    # the field): nothing, for every model of this pool.
+    seq = types.MappingProxyType({})
 
     @classmethod
     def empty(cls, num_layers: int, num_pages: int, page: int,

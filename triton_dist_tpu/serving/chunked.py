@@ -20,7 +20,7 @@ afterwards).
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -131,6 +131,13 @@ class ChunkedPrefill:
         # A model with ``STEP_STATS`` returns them last from every step;
         # they ride the picked tokens out (:func:`picked_with_stats`).
         stats = bool(getattr(model, "STEP_STATS", ()))
+        # A pool whose sequences keep state beside their pages
+        # (``PagedKVCache.seq``): the chunk program is told the slot
+        # whose state its rows carry, one more int32 scalar.
+        self._slotted = slotted = bool(cache_shardings.seq)
+
+        def slot_of(more):
+            return {"slot": more[0]} if slotted else {}
 
         self.decode_rows = int(decode_rows)
         # What :meth:`step` feeds the decode rows (see
@@ -138,12 +145,12 @@ class ChunkedPrefill:
         self._parked = None
         if not self.decode_rows:
             def _chunk(params, toks, cache, table_row, start, wfrom,
-                       valid):
+                       valid, *more):
                 logits, cache, *st = model.prefill_chunk_paged(
                     params, toks, cache, table_row, cfg, start=start,
                     wfrom=wfrom, valid=valid, mode=engine.mode,
                     axis=axis, ctxs=engine.ctxs, attn_impl=attn_impl,
-                    **mk)
+                    **slot_of(more), **mk)
                 picked = greedy_tokens(logits[None])
                 if stats:
                     picked = picked_with_stats(picked, st[0])
@@ -159,13 +166,13 @@ class ChunkedPrefill:
             # Under the chunk program's name: one program a bucket,
             # at the same place in a profile.
             def _chunk(params, toks, cache, table_row, start, wfrom,
-                       valid, dec_toks):
+                       valid, *more):
                 logits, dec, cache, *st = model.chunk_decode_paged(
-                    params, toks, dec_toks, cache, table_row, cfg,
+                    params, toks, more[-1], cache, table_row, cfg,
                     start=start, wfrom=wfrom, valid=valid,
                     mode=engine.mode, axis=axis, ctxs=engine.ctxs,
                     attn_impl=attn_impl, decode_attn_impl=decode_attn,
-                    **mk)
+                    **slot_of(more), **mk)
                 picked = jnp.concatenate(
                     [greedy_tokens(logits[None]), greedy_tokens(dec)])
                 if stats:
@@ -179,7 +186,7 @@ class ChunkedPrefill:
             jax.shard_map(
                 _chunk, mesh=mesh,
                 in_specs=(engine._specs, P(None), kv_spec, P(None),
-                          P(), P(), P()) + dec_in,
+                          P(), P(), P()) + (P(),) * slotted + dec_in,
                 out_specs=(P(None), P(None)) + dec_out + (kv_spec,),
                 check_vma=False),
             donate_argnums=(2,),
@@ -196,23 +203,29 @@ class ChunkedPrefill:
         return self.plan(remaining)[0]
 
     def step(self, params, toks: np.ndarray, cache, table_row,
-             start: int, wfrom: int, valid: int):
-        """Dispatch one chunk; returns ``(picked (1 + decode_rows,),
-        logits (vocab,), cache)``, ``picked[0]`` the greedy token of
-        ``logits``. ``toks`` is (bucket,) int32 padded; scalars ride as
-        int32 data so the trace signature depends only on the bucket
-        length. A program that carries decode rows runs with all of
+             start: int, wfrom: int, valid: int, *,
+             slot: Optional[int] = None):
+        """Dispatch one chunk of decode slot ``slot`` (asked of a model
+        whose sequences keep state beside their pages, and read by no
+        other); returns
+        ``(picked (1 + decode_rows,), logits (vocab,), cache)``,
+        ``picked[0]`` the greedy token of ``logits``. ``toks`` is
+        (bucket,) int32 padded; scalars ride as int32 data so the trace
+        signature depends only on the bucket length. A program that carries decode rows runs with all of
         them parked (and returns the pool with no slot live)."""
+        more = self._slot_arg(slot)
         if not self.decode_rows:
             return self._dispatch(params, toks, cache, table_row, start,
-                                  wfrom, valid)
+                                  wfrom, valid, *more)
         dec_toks, cache = self._parked_rows(cache)
         picked, logits, _, cache = self._dispatch(
-            params, toks, cache, table_row, start, wfrom, valid, dec_toks)
+            params, toks, cache, table_row, start, wfrom, valid, *more,
+            dec_toks)
         return picked, logits, cache
 
     def step_decode(self, params, toks: np.ndarray, cache, table_row,
-                    start: int, wfrom: int, valid: int, dec_toks):
+                    start: int, wfrom: int, valid: int, dec_toks, *,
+                    slot: Optional[int] = None):
         """Dispatch one chunk with a decode batch aboard (a chunker
         built with ``decode_rows``): ``dec_toks`` (decode_rows,) are the
         batch's input tokens and ``cache`` carries its block table,
@@ -222,7 +235,19 @@ class ChunkedPrefill:
         lengths advanced; ``picked[1 + slot]`` is the greedy token of
         decode row ``slot``."""
         return self._dispatch(params, toks, cache, table_row, start,
-                              wfrom, valid, dec_toks)
+                              wfrom, valid, *self._slot_arg(slot), dec_toks)
+
+    def _slot_arg(self, slot: Optional[int]) -> tuple:
+        """The chunk's slot as the program takes it: one int32 scalar
+        for a pool whose sequences keep state, nothing otherwise."""
+        if not self._slotted:
+            return ()
+        if slot is None:
+            raise ValueError(
+                "this pool's sequences keep state beside their pages: "
+                "a chunk names the decode slot whose state it carries "
+                "(slot=)")
+        return (np.int32(slot),)
 
     def _parked_rows(self, cache):
         """``(dec_toks, cache)`` with every decode row parked: scratch
@@ -246,7 +271,7 @@ class ChunkedPrefill:
             live=jnp.asarray(rows))
 
     def _dispatch(self, params, toks, cache, table_row, start, wfrom,
-                  valid, *dec_toks):
+                  valid, *more):
         import jax.numpy as jnp
 
         tel = self.telemetry
@@ -254,7 +279,7 @@ class ChunkedPrefill:
         out = self._chunk(
             params, jnp.asarray(toks, jnp.int32), cache,
             jnp.asarray(table_row, jnp.int32), np.int32(start),
-            np.int32(wfrom), np.int32(valid), *dec_toks)
+            np.int32(wfrom), np.int32(valid), *more)
         if t0 is not None:
             # Host dispatch time (the chunk result is async; the
             # request-level wait is the server's prefill_chunk span) +
@@ -319,7 +344,7 @@ class MegaChunkedPrefill:
         return self.plan(remaining)[0]
 
     def step(self, params, toks: np.ndarray, cache, table_row,
-             start: int, wfrom: int, valid: int):
+             start: int, wfrom: int, valid: int, *, slot: int = 0):
         """Dispatch one chunk through the megakernel chunk task pair;
         returns ``(None, logits (vocab,), cache)`` — the last VALID
         row's logits, bit-identical to the one-token prefill lane's at

@@ -90,10 +90,12 @@ class PrefillWorker:
             raise ValueError("the prefill worker is a layer-path role; "
                              "the megakernel's prefill lane already "
                              "rides its decode batch")
-        if engine.model.paged_pool(engine.cfg)[0] is not PagedKVCache:
+        pool = engine.model.paged_pool(engine.cfg)
+        if pool[0] is not PagedKVCache or len(pool) > 2:
             raise NotImplementedError(
-                "the prefill worker stages and migrates K and V pages; "
-                f"model {engine.model.__name__!r} states another pool")
+                "the prefill worker stages and migrates K and V pages of "
+                f"every layer; model {engine.model.__name__!r} states "
+                "another pool")
         self.engine = engine
         self.page, self.p_max = page, p_max
         self.kv_dtype = kv_dtype

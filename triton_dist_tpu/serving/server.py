@@ -490,8 +490,14 @@ class ServingEngine:
             # the passes the held experts ran.
             "expert_pairs_routed": 0, "expert_pairs_held": 0,
             "expert_rows_max": 0, "expert_passes": 0, "expert_steps": 0,
+            # First chunks that zeroed a slot's sequence state (a model
+            # whose pool states one).
+            "seq_state_resets": 0,
         }
         self._step_stats = ()
+        # Bytes of what the sequences keep beside their pages (a pool
+        # that states such arrays; else 0).
+        self._seq_state_bytes = 0
         self.prefill_buckets = (tuple(sorted(set(int(b) for b in
                                                  prefill_buckets)))
                                 if prefill_buckets else None)
@@ -677,11 +683,15 @@ class ServingEngine:
         n = mesh.shape[axis]
         # The model states the pool it keeps (``paged_pool``: the class
         # and what a token takes in a layer: K and V of every KV head,
-        # or one latent); the server allocates that. GLOBAL sizes here
-        # — the sharding carves the per-shard part the step sees; the
-        # pool is allocated under that sharding, never whole on one
-        # device.
-        pool_cls, per_token = model.paged_pool(cfg)
+        # or one latent; and, third, where it differs from that: how
+        # many of its layers keep pages at all, ``layers``, and what a
+        # SEQUENCE keeps beside them, ``seq_state``, name -> SeqArray);
+        # the server allocates that. GLOBAL sizes here — the sharding
+        # carves the per-shard part the step sees; the pool is
+        # allocated under that sharding, never whole on one device.
+        pool_cls, per_token, *keeps = model.paged_pool(cfg)
+        keeps = dict(keeps[0]) if keeps else {}
+        pool_layers = keeps.pop("layers", cfg.num_hidden_layers)
         if pool_cls is not PagedKVCache and (
                 self.tiers is not None or not self.prefill_buckets):
             raise NotImplementedError(
@@ -689,12 +699,40 @@ class ServingEngine:
                 "(prefill_buckets=...) and neither tiered nor parked: "
                 "tier transfers and the monolithic prompt blit move "
                 "K and V pages")
+        if keeps.get("seq_state"):
+            # A state a sequence keeps is not position-addressed.
+            refused = [
+                (bool(self.spec_k), "spec_k: lengths cannot roll a "
+                 "state back past a refused candidate"),
+                (self.manager.prefix_reuse, "prefix_reuse: a shared "
+                 "page holds no state to start the rest of a prompt "
+                 "from"),
+                (self.tiers is not None, "kv_tiers (and with them "
+                 "park and resume): a tier moves pages, and the state "
+                 "would stay behind"),
+                (not self.prefill_buckets, "the monolithic prompt "
+                 "blit: only a chunk program resets and carries the "
+                 "state (prefill_buckets=...)")]
+            for on, why in refused:
+                if on:
+                    raise NotImplementedError(
+                        "a model whose sequences keep state beside "
+                        f"their pages is served without {why}")
         cache, shardings = pool_cls.empty_sharded(
             mesh, model.paged_cache_specs, axis,
-            cfg.num_hidden_layers, num_pages, self.page,
+            pool_layers, num_pages, self.page,
             *per_token, num_slots=num_slots, p_max=self.p_max,
             dtype=jax.tree.leaves(eng.params)[0].dtype,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype, **keeps)
+        # What the pool stated, for ``stats()``. Every decode slot owns
+        # its sequence state, so admission's second budget (beside
+        # pages) IS the slots and ``Scheduler.admit`` counts one thing;
+        # fewer states than slots would need a count of its own there.
+        self._pool_layers = pool_layers
+        self._seq_layers = max((a.shape[0] for a in (
+            keeps.get("seq_state") or {}).values()), default=0)
+        self._seq_state_bytes = sum(int(v.nbytes)
+                                    for v in cache.seq.values())
         kv_spec = model.paged_cache_specs(
             axis, quantized=cache.quantized)
         self.cache = cache
@@ -1111,12 +1149,21 @@ class ServingEngine:
                 / max(out["expert_pairs_held"], 1))
             # Rows a held expert was given, a layer of a read program.
             out["expert_rows_mean"] = out["expert_pairs_held"] / (
-                self.cfg.held_experts * self.cfg.num_hidden_layers
+                self.cfg.held_experts * self.cfg.num_moe_layers
                 * out["expert_steps"])
             # Passes the held experts ran, a layer of a read program:
             # 1.0 = one pass had room for the held pairs every time.
             out["expert_passes_a_layer"] = out["expert_passes"] / (
-                self.cfg.num_hidden_layers * out["expert_steps"])
+                self.cfg.num_moe_layers * out["expert_steps"])
+        if self._seq_state_bytes:
+            # What the sequences keep beside their pages, as the pool
+            # stated it: its bytes over all slots, the slots that own
+            # one (every decode slot), and how many layers keep such
+            # state and how many keep pages.
+            out["seq_state_bytes"] = self._seq_state_bytes
+            out["seq_state_slots"] = self.num_slots
+            out["seq_state_layers"] = self._seq_layers
+            out["paged_layers"] = self._pool_layers
         if self.manager is not None:
             out["pool"] = self.manager.fragmentation()
         if hasattr(self, "plan"):
@@ -2005,7 +2052,8 @@ class ServingEngine:
                                 p.engine.params, toks,
                                 _dc.replace(p.cache, block_table=tbl,
                                             lens=lens, live=live),
-                                row, start, h.resident, valid, dec_toks))
+                                row, start, h.resident, valid, dec_toks,
+                                slot=slot))
                     # The copies are asked for now, behind the program.
                     picked.copy_to_host_async()
                     if rows:
@@ -2013,7 +2061,7 @@ class ServingEngine:
                     return picked, logits, dec
                 picked, logits, p.cache = p.chunker.step(
                     p.engine.params, toks, p.cache, row, start,
-                    h.resident, valid)
+                    h.resident, valid, slot=slot)
                 if picked is not None and start + valid >= len(seq):
                     picked.copy_to_host_async()   # the prompt's token
                 if self.timeout_s is not None:
@@ -2079,6 +2127,9 @@ class ServingEngine:
         self.stats_counters["chunk_dispatches_kernel_walk"] += (
             self._walk_kernel(bucket))
         self.stats_counters["prefill_tokens"] += valid
+        if start == 0 and self._seq_state_bytes:
+            # The program zeroed the slot's state before its first row.
+            self.stats_counters["seq_state_resets"] += 1
         h.chunks.append((start, bucket, valid))
         h.prompt_pos = start + valid
         if h.prompt_pos < len(h.lane):
@@ -3123,8 +3174,7 @@ class ServingEngine:
         cfg = self.cfg
         held, rows_max, passes = (int(v) for v in
                                   picked[-len(self._step_stats):])
-        routed = (rows * cfg.num_experts_per_tok
-                  * cfg.num_hidden_layers)
+        routed = rows * cfg.num_experts_per_tok * cfg.num_moe_layers
         c = self.stats_counters
         c["expert_pairs_routed"] += routed
         c["expert_pairs_held"] += held
