@@ -185,7 +185,9 @@ def check_served(seed, family, dims, dtype, sample, limit, *, control=False,
     widest, n_tok, n_same, spread = 0.0, 0, 0, []
     for r, rows in zip(sample, ref):
         g = gaps(rows, r.tokens)
-        widest = max(widest, float(g.max()))
+        # A gap that is not a number must reach the comparison below:
+        # ``max`` keeps its first argument beside a NaN.
+        widest = float(np.max([widest, g.max()]))
         n_tok += len(g)
         n_same += int((g == 0).sum())
         spread.append(float(rows.std()))
@@ -204,8 +206,8 @@ def check_served(seed, family, dims, dtype, sample, limit, *, control=False,
         low = logits_at(seed, family, dims, dtype, seqs + seqs[:1] * fill,
                         wanted + wanted[:1] * fill, int8=True,
                         **sizes)[:len(seqs)]
-        cw = max(float(gaps(rows, lo.argmax(axis=1)).max())
-                 for rows, lo in zip(ref, low))
+        cw = float(np.max([gaps(rows, lo.argmax(axis=1)).max()
+                           for rows, lo in zip(ref, low)]))
         numbers["control_widest_gap"] = cw
         log(f"control: int8 in the reference's place, widest gap {cw:.6g} "
             f"(limit {limit:.6g}): "

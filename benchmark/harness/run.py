@@ -49,6 +49,14 @@ if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
 
 REHEARSE_DATA = os.path.join(REPO_ROOT, "tests", "benchmark", "data")
+
+# libtpu pins a host buffer for transfers inside the first look at the
+# device: 4 GiB unless told otherwise, which on a host without transparent
+# hugepages took 5-19 s of every run's set-up and moved with what the
+# process before had left to free (PERF.md, section 6, PR 45). The cells
+# move token ids between host and chip, which this size holds many times
+# over. A value given from outside stands.
+PREMAPPED_BUFFER_BYTES = 256 << 20
 _COMPILE = {"seconds": 0.0, "compiles": 0, "listening": False}
 
 
@@ -87,6 +95,12 @@ def _compile_cache_dir(jax) -> str:
     path = os.path.join(REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def runtime_env(environ=os.environ) -> None:
+    """What the TPU runtime reads when JAX loads it; call before that."""
+    environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE",
+                       str(PREMAPPED_BUFFER_BYTES))
 
 
 def parse(argv):
@@ -358,6 +372,7 @@ def main(argv=None) -> int:
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "")
                 + " --xla_force_host_platform_device_count=8")
+    runtime_env()
     try:
         import jax
 
@@ -381,7 +396,8 @@ def main(argv=None) -> int:
     devs = jax.devices()
     _t.timed = devs[0].platform == "tpu"
     log(f"start: imports {_t(t_imported - _T_PROCESS)} s, first look at "
-        f"the device {_t(time.perf_counter() - t_imported)} s")
+        f"the device {_t(time.perf_counter() - t_imported)} s (premapped "
+        f"host buffer {os.environ['TPU_PREMAPPED_BUFFER_SIZE']} bytes)")
     if not args.rehearse:
         if devs[0].platform != "tpu":
             print(f"run.py: no TPU: JAX found platform="

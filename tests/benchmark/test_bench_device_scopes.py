@@ -26,11 +26,12 @@ NEW = {"chunk_attn_ms.docs": ("attn_chunk", "kernels"),
        "chunk_mlp_ms.docs": ("mlp", "model step"),
        "head_ms.docs": ("head", "model step")}
 # Written, measured (PERF.md, PR 40) and tested on the recorded rows;
-# their entries wait for a ``benchmark`` PR (PERF.md section 7).
-LONG_FILES = {"chunk_attn_ms.longdocs": "attn_chunk",
-              "decode_rows_attn_ms.longdocs": "attn_decode",
-              "chunk_experts_ms.longdocs": "experts",
-              "chunk_shared_expert_ms.longdocs": "shared_expert"}
+# their entries came with PR 45.
+LONG_FILES = {"chunk_attn_ms.longdocs": ("attn_chunk", "kernels"),
+              "decode_rows_attn_ms.longdocs": ("attn_decode", "kernels"),
+              "chunk_experts_ms.longdocs": ("experts", "expert layer"),
+              "chunk_shared_expert_ms.longdocs": ("shared_expert",
+                                                  "expert layer")}
 US = 1_000
 
 
@@ -148,20 +149,22 @@ def test_a_program_mostly_unscoped_fails_the_read(rows):
         scope_ms.block_ms(rows, "^jit__chunk", "slowest", "head")
 
 
-@pytest.mark.parametrize("name", sorted(NEW))
-def test_the_loader_finds_the_metric_with_its_cell(name):
-    cell = loader.load_cell(CELL)
+@pytest.mark.parametrize("workload, name", [
+    *((CELL, n) for n in sorted(NEW)),
+    *((LONG, n) for n in sorted(LONG_FILES))])
+def test_the_loader_finds_the_metric_with_its_cell(workload, name):
+    cell = loader.load_cell(workload)
     entry, spec = next((m, s) for m, s in cell.per_layer
                        if m["name"] == name)
-    scope, layer = NEW[name]
+    scope, layer = {**NEW, **LONG_FILES}[name]
     assert spec == {"reducer": "scope_ms", "params": {
         "pattern": "^jit__chunk", "variant": "slowest", "scope": scope}}
     assert (entry["source"], entry["moves"], entry["unit"],
             entry["better"], entry["layer"], entry["workloads"]) == (
-        "device_trace", "tokens_per_s", "ms", "lower", layer, [CELL])
+        "device_trace", "tokens_per_s", "ms", "lower", layer, [workload])
     # The same program as the metric that times it from outside.
     outside = next(s for m, s in cell.per_layer
-                   if m["name"] == "prefill_chunk_ms.docs")
+                   if m["name"] == "prefill_chunk_ms." + name.split(".")[1])
     assert {k: spec["params"][k] for k in ("pattern", "variant")} == (
         outside["params"])
 
@@ -230,11 +233,11 @@ def test_the_longdocs_metric_files_read_the_recorded_rows(
     with open(loader.find_data("layer_metrics", name,
                                [loader.DATA_ROOT])) as f:
         spec = json.load(f)
-    assert spec["params"]["scope"] == LONG_FILES[name]
+    assert spec["params"]["scope"] == LONG_FILES[name][0]
     monkeypatch.setattr(D, "rows_of", lambda ctx: recorded)
     logged = []
     got, _, _ = D.blocks_ms(recorded, "^jit__chunk", "slowest")
-    assert read_metric(spec, _ctx(logged)) == got[LONG_FILES[name]]
+    assert read_metric(spec, _ctx(logged)) == got[LONG_FILES[name][0]]
     assert "3 runs" in logged[0] and "experts 39.61" in logged[0]
 
 

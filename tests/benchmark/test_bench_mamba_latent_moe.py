@@ -34,8 +34,7 @@ METRICS = tuple(n + ".nemotron" for n in (
     "chunk_shared_expert_ms", "chunk_attn_ms", "decode_rows_attn_ms",
     "head_ms", "idle_in_tick_ms", "idle_schedule_ms", "idle_enqueue_ms",
     "idle_fetch_ms", "idle_sample_ms", "idle_submit_ms", "queue_wait_ms",
-    "decode_batch", "decode_fused_share", "expert_load_imbalance",
-    "decode_step_ms", "decode_hbm_share"))
+    "decode_batch", "decode_fused_share", "expert_load_imbalance"))
 PATTERN = "MEMEMEM*EME"
 
 

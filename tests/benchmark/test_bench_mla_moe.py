@@ -26,7 +26,9 @@ CELL = "mistral-small-4-1chip.longdocs"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 METRICS = ("prefill_chunk_ms.longdocs", "chunk_roofline_share.longdocs",
            "idle_in_tick_ms.longdocs", "decode_batch.longdocs",
-           "decode_fused_share.longdocs", "expert_load_imbalance.longdocs")
+           "decode_fused_share.longdocs", "expert_load_imbalance.longdocs",
+           "chunk_attn_ms.longdocs", "decode_rows_attn_ms.longdocs",
+           "chunk_experts_ms.longdocs", "chunk_shared_expert_ms.longdocs")
 
 
 @pytest.fixture(scope="module")
@@ -44,8 +46,8 @@ def test_the_cell_resolves_to_its_files(cell):
     assert [m["name"] for m, _ in cell.per_layer] == [
         "compile_s", *METRICS]
     assert {spec["reducer"] for _, spec in cell.per_layer} == {
-        "compile_seconds", "program_ms", "roofline_max", "idle_by_span",
-        "span_stat"}
+        "compile_seconds", "program_ms", "roofline_max", "scope_ms",
+        "idle_by_span", "span_stat"}
     mix = cell.traffic
     assert (mix["loop"], mix["clients"], mix["check_requests"]) == (
         "closed", 32, 4)
