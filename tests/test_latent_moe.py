@@ -535,6 +535,7 @@ def test_the_server_counts_its_chunk_dispatches_by_their_walk(legal):
     assert [len(o) for o in out] == [3, 3]
     st = srv.stats()
     assert st["chunk_dispatches_kernel_walk"] == st["prefill_chunks"] == 4
+    assert st["chunk_dispatches_kernel_scan"] == 0      # no such layer
     assert st["attn_impl"] == st["chunk_attn"] == "ref"
     chunks = [e.attrs for e in srv.obs.log.spans()
               if e.kind == "prefill_chunk"]
@@ -789,13 +790,13 @@ def test_compiled_state_space_steps_keep_pool_and_state_in_place(
         out_specs = (P(None), P(None, None), kv_spec, P(None))
         args = (params, ints(512), cache, ints(p_max), ints(), ints(),
                 ints(), ints(), ints(slots))
-    hlo = jax.jit(
+    lowered = jax.jit(
         jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs, check_vma=False),
         donate_argnums=(args.index(cache),),
         out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
-                            for s in out_specs)).lower(*args).compile(
-                            ).as_text()
+                            for s in out_specs)).lower(*args)
+    hlo = lowered.compile().as_text()
     assert cache.k_pages.shape == (1, 2049, 2, 128, 128)    # one layer's
     state = cache.seq[mamba_moe.STATE]
     assert (state.shape, state.dtype) == ((5, 16, 128, 64, 128), dt)
@@ -803,6 +804,19 @@ def test_compiled_state_space_steps_keep_pool_and_state_in_place(
     assert pool_copies(hlo, state.shape) == []
     assert "paged_flash_decode" in hlo
     assert ("paged_flash_qblock" in hlo) == (program != "decode")
+    # The chunk rows' scan is ``ops.mamba2_chunk_scan`` (PR 46), lowered
+    # through Mosaic ONCE for the five Mamba-2 layers, every call under
+    # the block's scope ``tdt.ssm``; no float32 array of chunks x heads
+    # x Q x Q is left in the program. The decode rows step in XLA.
+    scans = re.findall(r"%mamba2_chunk_scan(?:\.\d+)? = .*", hlo)
+    if program == "decode":
+        assert scans == [] and "mamba2_chunk_scan" not in lowered.as_text()
+        return
+    assert mamba_moe.chunk_scan_impl(cfg, 512) == "kernel"
+    assert lowered.as_text().count(
+        'kernel_name = "mamba2_chunk_scan"') == 1
+    assert len(scans) == 5 and all("tdt.ssm/" in s for s in scans)
+    assert "f32[4,128,128,128]" not in hlo
 
 
 def _all_equations(jaxpr):
@@ -857,6 +871,25 @@ def test_the_chunk_kernels_traced_body_stays_small(rows):
     bodies = list(_pallas_bodies(closed.jaxpr))
     assert len(bodies) == 1
     assert _equations(bodies[0]) <= 260
+
+
+@pytest.mark.parametrize("rows", [512, 2048])
+def test_the_scan_kernels_traced_body_stays_small(rows):
+    """The same budget for ``ops.mamba2_chunk_scan`` (PR 46) at the
+    Mamba-2 sizes of ``nemotron-3-super-1chip.longdocs``: the slabs of
+    a group are ONE traced body (a ``pl.loop``, unrolled by Mosaic and
+    not by Python), 169 equations in both buckets; 200 is held. Trace
+    only, nothing runs."""
+    from triton_dist_tpu.ops import mamba2_chunk_scan as K
+
+    s, f32 = jax.ShapeDtypeStruct, jnp.float32
+    closed = jax.make_jaxpr(lambda *a: K.ssd_chunk_scan(*a, chunk=128))(
+        s((rows, 128, 64), f32), s((rows, 128), f32), s((128,), f32),
+        s((rows, 8, 128), f32), s((rows, 8, 128), f32), s((128,), f32),
+        s((128, 64, 128), f32))
+    bodies = list(_pallas_bodies(closed.jaxpr))
+    assert len(bodies) == 1
+    assert _equations(bodies[0]) <= 200
 
 
 @pytest.mark.parametrize("t,n_held,passes_over_all", [

@@ -26,7 +26,7 @@ import triton_dist_tpu as tdt
 from benchmark.harness import loader, reference, weights as W
 from triton_dist_tpu.layers import ep_moe
 from triton_dist_tpu.models import Engine, ModelConfig, mamba_moe
-from triton_dist_tpu.ops import mamba2
+from triton_dist_tpu.ops import mamba2, mamba2_chunk_scan
 from triton_dist_tpu.serving.blocks import PagedKVCache
 
 DATA = os.path.join(os.path.dirname(__file__), "benchmark", "data")
@@ -199,6 +199,117 @@ def test_a_row_with_no_step_size_neither_decays_nor_writes():
     _, s_pad = mamba2.ssd_chunked(x, dt, a, b, c, d, chunk=8)
     _, s_cut = _scan_by_token(x[:17], dt[:17], a, b[:17], c[:17], d)
     np.testing.assert_allclose(s_pad, s_cut, rtol=0, atol=1e-5)
+
+
+# -- the scan as one Pallas kernel (interpreted here) ------------------------
+
+# Sizes the kernel tiles that the interpreter holds (no buffer past 64
+# KB: docs/testing.md): scan chunks of 128 rows, 2 groups of 2 heads of
+# 64 (one 128-lane slab a group), a state of 128.
+KH, KP, KG, KN = 4, 64, 2, 128
+
+
+def _scan_inputs(rows, decay, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed + rows), 6)
+    x = jax.random.normal(k[0], (rows, KH, KP))
+    b = jax.random.normal(k[1], (rows, KG, KN))
+    c = jax.random.normal(k[2], (rows, KG, KN))
+    dt = jax.nn.softplus(jax.random.normal(k[3], (rows, KH)))
+    a = jnp.full((KH,), math.log(decay)) / jnp.mean(dt)
+    d = 1.0 + 0.1 * jax.random.normal(k[4], (KH,))
+    s0 = jax.random.normal(k[5], (KH, KP, KN))
+    return x, dt, a, b, c, d, s0
+
+
+@pytest.mark.parametrize("decay", [0.5, 0.9, 0.99, 0.9999])
+def test_the_scan_kernel_is_the_token_sequential_scan(decay):
+    """Three scan chunks of 128 rows from a state that is not zero, at
+    decays from 0.5 to 0.9999 a step: the kernel (what ``ssd_prefill``
+    picks at these sizes) against ``ssd_step`` a token at a time, and
+    against ``ssd_chunked``, whose sums it repeats."""
+    x, dt, a, b, c, d, s0 = _scan_inputs(384, decay)
+    assert mamba2.chunk_scan_impl(384, KH, KP, KG, KN, 128) == "kernel"
+    want_y, want_s = _scan_by_token(x, dt, a, b, c, d, s0)
+    got_y, got_s = mamba2.ssd_prefill(x, dt, a, b, c, d, s0, chunk=128)
+    scale = float(jnp.max(jnp.abs(want_y)))
+    np.testing.assert_allclose(got_y, want_y, rtol=0, atol=1e-5 * scale)
+    np.testing.assert_allclose(got_s, want_s, rtol=0, atol=1e-5 * scale)
+    xla_y, xla_s = mamba2.ssd_chunked(x, dt, a, b, c, d, s0, chunk=128)
+    np.testing.assert_allclose(got_y, xla_y, rtol=0, atol=2e-6 * scale)
+    np.testing.assert_allclose(got_s, xla_s, rtol=0, atol=2e-6 * scale)
+
+
+def test_the_scan_kernel_carries_a_state_from_call_to_call():
+    """One call over three chunks is a call over the first and a call
+    over the other two that starts from what the first left; no state
+    given is a state of zeros."""
+    x, dt, a, b, c, d, s0 = _scan_inputs(384, 0.99, seed=1)
+    scan = mamba2_chunk_scan.ssd_chunk_scan
+    want_y, want_s = scan(x, dt, a, b, c, d, s0, chunk=128)
+    y1, s1 = scan(x[:128], dt[:128], a, b[:128], c[:128], d, s0, chunk=128)
+    y2, s2 = scan(x[128:], dt[128:], a, b[128:], c[128:], d, s1, chunk=128)
+    scale = float(jnp.max(jnp.abs(want_y)))
+    np.testing.assert_allclose(jnp.concatenate([y1, y2]), want_y, rtol=0,
+                               atol=1e-6 * scale)
+    np.testing.assert_allclose(s2, want_s, rtol=0, atol=1e-6 * scale)
+    none_y, none_s = scan(x[:128], dt[:128], a, b[:128], c[:128], d,
+                          chunk=128)
+    zero_y, zero_s = scan(x[:128], dt[:128], a, b[:128], c[:128], d,
+                          jnp.zeros_like(s0), chunk=128)
+    np.testing.assert_array_equal(none_y, zero_y)
+    np.testing.assert_array_equal(none_s, zero_s)
+
+
+def test_a_row_with_no_step_size_stays_out_of_the_scan_kernel():
+    """Rows past ``valid`` and padding have ``dt = 0``: in the kernel
+    too they neither decay the state nor write to it, inside the last
+    chunk and as a whole chunk of nothing."""
+    x, dt, a, b, c, d, s0 = _scan_inputs(384, 0.9, seed=2)
+    dt = jnp.where(jnp.arange(384)[:, None] < 200, dt, 0.0)
+    y_pad, s_pad = mamba2_chunk_scan.ssd_chunk_scan(x, dt, a, b, c, d, s0,
+                                                    chunk=128)
+    y_cut, s_cut = _scan_by_token(x[:200], dt[:200], a, b[:200], c[:200],
+                                  d, s0)
+    scale = float(jnp.max(jnp.abs(y_cut)))
+    np.testing.assert_allclose(s_pad, s_cut, rtol=0, atol=1e-5 * scale)
+    np.testing.assert_allclose(y_pad[:200], y_cut, rtol=0,
+                               atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("sizes, impl", [
+    ((2048, 128, 64, 8, 128, 128), "kernel"),    # the cell's two buckets
+    ((512, 128, 64, 8, 128, 128), "kernel"),
+    ((384, KH, KP, KG, KN, 128), "kernel"),      # these tests'
+    ((16, 8, 16, 2, 16, 8), "xla"),              # the tiny file's
+    ((2048, 128, 64, 8, 128, 8), "xla"),         # a chunk of 8 rows
+    ((2000, 128, 64, 8, 128, 128), "xla"),       # a ragged T
+    ((2048, 128, 48, 8, 128, 128), "xla"),       # heads that fill no slab
+    ((2048, 128, 64, 8, 64, 128), "xla"),        # a state of half a tile
+    ((2048, 12, 64, 8, 128, 128), "xla"),        # heads in no whole groups
+    ((2048, 128, 64, 1, 128, 128), "xla")])      # a group VMEM does not hold
+def test_the_scans_form_is_a_pure_function_of_sizes(sizes, impl):
+    assert mamba2.chunk_scan_impl(*sizes) == impl
+    if impl == "xla":
+        rows, h, p, g, n, chunk = sizes
+        with pytest.raises(ValueError, match="cannot tile"):
+            mamba2_chunk_scan.ssd_chunk_scan(
+                jnp.zeros((rows, h, p)), jnp.zeros((rows, h)),
+                jnp.zeros((h,)), jnp.zeros((rows, g, n)),
+                jnp.zeros((rows, g, n)), jnp.zeros((h,)), chunk=chunk)
+
+
+def test_the_model_states_the_scans_form_for_its_sizes(tiny):
+    """``mamba_moe.chunk_scan_impl`` is the op's rule at the model's
+    sizes: the kernel at the published Mamba-2 sizes in both of the
+    cell's buckets, the XLA form for the tiny file."""
+    _, _, cfg, _, _ = tiny
+    assert {mamba_moe.chunk_scan_impl(cfg, r) for r in (8, 16, 128)} == {
+        "xla"}
+    cell = ModelConfig.tiny_mamba_moe(
+        mamba_num_heads=128, mamba_head_dim=64, mamba_n_groups=8,
+        ssm_state_size=128, mamba_chunk_size=128)
+    assert [mamba_moe.chunk_scan_impl(cell, r) for r in (2048, 512, 200)
+            ] == ["kernel", "kernel", "xla"]
 
 
 # -- the router and the experts ---------------------------------------------
@@ -514,6 +625,10 @@ def test_the_state_is_stored_in_the_parameters_type_and_stepped_in_float32(
                         1)[0]
 
     assert np.abs(last_logits() - want).max() < 1e-5
+    # The model asks ``ssd_prefill``, which at these sizes is
+    # ``ssd_chunked``, looked up where it is patched below.
+    assert {mamba_moe.chunk_scan_impl(cfg, rows) for rows in (16, 8)} == {
+        "xla"}
     sound = mamba2.ssd_chunked
 
     def rounded_inside(x, dt, a, b, c, d, state=None, *, chunk):
@@ -585,6 +700,44 @@ def test_the_server_serves_it_and_counts_what_the_sequences_keep(tiny):
         tight.chunker.step(params, np.zeros(8, np.int32), tight.cache,
                            np.zeros(4, np.int32), 0, 0, 8)
     assert tight.decode_cache_size() == 1 and tight.prefill_cache_size() <= 2
+
+
+def test_the_server_counts_its_chunk_dispatches_by_their_scan(tiny):
+    """A configuration the scan kernel tiles (interpreted here) runs it
+    in every chunk program: ``chunk_dispatches_kernel_scan`` is
+    ``prefill_chunks`` and every ``prefill_chunk`` span says so, while
+    the tiny file's sizes fall to the XLA form and count none."""
+    cfg = ModelConfig.tiny_mamba_moe(
+        num_hidden_layers=2, layer_pattern="M*", mamba_num_heads=KH,
+        mamba_head_dim=KP, mamba_n_groups=KG, ssm_state_size=KN,
+        mamba_chunk_size=128, max_position_embeddings=512)
+    mesh = tdt.make_mesh(tp=1, devices=jax.devices()[:1])
+    params = mamba_moe.init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 256, size=n).tolist() for n in (150, 300)]
+
+    def serve():
+        eng = Engine(cfg, mesh, model=mamba_moe, mode="xla",
+                     dtype=jnp.float32, max_len=512, params=params)
+        srv = eng.serving(num_slots=2, page=128, prefill_buckets=(128, 256),
+                          telemetry="spans")
+        return srv, srv.generate(prompts, max_new_tokens=3)
+
+    srv, out = serve()
+    st = srv.stats()
+    assert st["chunk_dispatches_kernel_scan"] == st["prefill_chunks"] == 4
+    assert st["chunk_dispatches_kernel_walk"] == 0
+    chunks = [e.attrs for e in srv.obs.log.spans()
+              if e.kind == "prefill_chunk"]
+    assert sorted((a["bucket"], a["scan_kernel"], a["walk_kernel"])
+                  for a in chunks) == [(128, 1, 0), (128, 1, 0),
+                                       (128, 1, 0), (256, 1, 0)]
+    _, _, tiny_cfg, tiny_mesh, tiny_params = tiny
+    small, _ = _serve(tiny_cfg, tiny_mesh, tiny_params, [prompts[0][:21]])
+    assert small.stats()["prefill_chunks"] > small.stats()[
+        "chunk_dispatches_kernel_scan"] == 0
+    assert {e.attrs["scan_kernel"] for e in small.obs.log.spans()
+            if e.kind == "prefill_chunk"} == {0}
 
 
 @pytest.mark.parametrize("knob, what", [

@@ -246,6 +246,16 @@ def _gated_norm(mp, y, z, cfg: ModelConfig, dtype):
     return (v.reshape(n, -1) * mp["norm"].astype(jnp.float32)).astype(dtype)
 
 
+def chunk_scan_impl(cfg: ModelConfig, rows: int) -> str:
+    """What scans a chunk program's ``rows`` chunk rows in every Mamba-2
+    layer, ``"kernel"`` or ``"xla"``: :func:`ops.mamba2.chunk_scan_impl`
+    at this model's sizes. The serving engine counts its chunk
+    dispatches by it."""
+    return _ssd.chunk_scan_impl(
+        rows, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_n_groups,
+        cfg.ssm_state_size, cfg.mamba_chunk_size)
+
+
 def _mamba_chunk(mp, z, xbc, dt, state, tail, cfg: ModelConfig, valid):
     """A chunk's rows of ONE sequence. ``state`` (H, P, N) float32 and
     ``tail`` (taps - 1, W) as the rows before left them. Returns ``(y
@@ -258,7 +268,7 @@ def _mamba_chunk(mp, z, xbc, dt, state, tail, cfg: ModelConfig, valid):
     x, dt, a, b, cc = _ssm_inputs(mp, conved, dt, cfg)
     # A row past the chunk's last token neither decays nor writes.
     dt = jnp.where((jnp.arange(c) < valid)[:, None], dt, 0.0)
-    y, state = _ssd.ssd_chunked(x, dt, a, b, cc,
+    y, state = _ssd.ssd_prefill(x, dt, a, b, cc,
                                 mp["d_skip"].astype(jnp.float32), state,
                                 chunk=cfg.mamba_chunk_size)
     # The last taps - 1 VALID inputs: rows valid - taps + 1 .. valid - 1.

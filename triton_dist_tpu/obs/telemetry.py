@@ -57,10 +57,12 @@ _OP_HIST_KINDS = frozenset({
 # the correlation keys, and the counts read there (``batch`` of a
 # decode and whether it rode a chunk program, ``fused`` 0/1; whether a
 # chunk's rows walk their context in a Pallas kernel, ``walk_kernel``
-# 0/1; ``waited_ms`` of an admission; what an ``expert_load`` event
-# counted).
+# 0/1, and whether its state-space layers scan them in one,
+# ``scan_kernel`` 0/1; ``waited_ms`` of an admission; what an
+# ``expert_load`` event counted).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
-                        "bucket", "valid", "walk_kernel", "waited_ms",
+                        "bucket", "valid", "walk_kernel", "scan_kernel",
+                        "waited_ms",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",
                         "expert_rows_max", "expert_imbalance"})

@@ -12,7 +12,11 @@ Recurrence (a head ``j`` of group ``j // (H / G)``, state ``S`` of
 
 Two forms of the one recurrence, plain XLA both: :func:`ssd_step`, a
 token a sequence (decode), and :func:`ssd_chunked`, in chunks of ``Q``
-rows (the tests hold it to the step under a ``lax.scan``). With ``b_t``
+rows (the tests hold it to the step under a ``lax.scan``).
+:func:`ssd_prefill` is what a model calls for a prefill chunk's rows:
+:func:`ssd_chunked`, or where the sizes tile
+(:func:`chunk_scan_impl`) the same sums in one Pallas kernel
+(:mod:`triton_dist_tpu.ops.mamba2_chunk_scan`). With ``b_t``
 the running sum of ``A dt`` inside a chunk:
 
     y_t = sum_{s<=t} e^(b_t - b_s) (C_t . B_s) dt_s x_s     inside
@@ -31,6 +35,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from triton_dist_tpu.ops import mamba2_chunk_scan as _scan
 
 CHUNK = 128
 
@@ -104,3 +110,24 @@ def ssd_chunked(x, dt, A, B, C, D, initial_state=None, *,
     y = y + _dot("ctgn,cgrpn->ctgrp", Cc, S_in) * from_start
     y = y.reshape(c * q, h, p)[:t] + D[:, None] * x[:t]
     return y, S.reshape(h, p, n)
+
+
+def chunk_scan_impl(rows: int, heads: int, head_dim: int, groups: int,
+                    state: int, chunk: int = CHUNK) -> str:
+    """What scans a prefill chunk of ``rows`` rows: ``"kernel"``
+    (:func:`~triton_dist_tpu.ops.mamba2_chunk_scan.ssd_chunk_scan`)
+    where the rows are whole scan chunks and chunk, head and state tile
+    for Mosaic, else ``"xla"`` (:func:`ssd_chunked`). A pure function of
+    sizes: the same program on a chip and, interpreted, off it; the
+    serving engine counts its chunk dispatches by it."""
+    ok = _scan.legal(rows, heads, head_dim, groups, state, chunk)
+    return "kernel" if ok else "xla"
+
+
+def ssd_prefill(x, dt, A, B, C, D, initial_state=None, *,
+                chunk: int = CHUNK):
+    """:func:`ssd_chunked`'s contract, in the form
+    :func:`chunk_scan_impl` picks for these sizes."""
+    impl = chunk_scan_impl(*x.shape, *B.shape[1:], chunk)
+    scan = _scan.ssd_chunk_scan if impl == "kernel" else ssd_chunked
+    return scan(x, dt, A, B, C, D, initial_state, chunk=chunk)

@@ -474,7 +474,8 @@ class ServingEngine:
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
             "decode_tokens": 0, "prefill_chunks": 0,
             "chunk_dispatches_parked": 0,
-            "chunk_dispatches_kernel_walk": 0, "migrated_pages": 0,
+            "chunk_dispatches_kernel_walk": 0,
+            "chunk_dispatches_kernel_scan": 0, "migrated_pages": 0,
             "spec_drafted": 0, "spec_accepted": 0,
             "spec_sampled_fallbacks": 0,
             "greedy_agree_tokens": 0, "greedy_ref_tokens": 0,
@@ -2026,6 +2027,7 @@ class ServingEngine:
         slot, seq, start = h.slot, h.lane, h.prompt_pos
         bucket, valid = p.chunker.next_chunk(len(seq) - start)
         walk_kernel = self._walk_kernel(bucket)
+        scan_kernel = self._scan_kernel(bucket)
         toks = np.zeros((bucket,), np.int32)
         toks[:valid] = seq[start:start + valid]
         row = np.asarray(p.manager.table_row(slot), np.int32)
@@ -2042,7 +2044,8 @@ class ServingEngine:
                                request_id=h.request.request_id,
                                slot=slot, tenant=h.request.tenant,
                                start=int(start), bucket=int(bucket),
-                               valid=int(valid), walk_kernel=walk_kernel), \
+                               valid=int(valid), walk_kernel=walk_kernel,
+                               scan_kernel=scan_kernel), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
@@ -2091,18 +2094,29 @@ class ServingEngine:
             raise
         return picked, logits, dec, (start, bucket, valid)
 
+    def _model_rule(self, rule: str):
+        """``(cfg, *sizes) -> "kernel" | "xla"``: the rule on sizes the
+        served model's module states under the name ``rule``, or None
+        for a model that states no such choice."""
+        return getattr(getattr(self._prefiller.engine, "model", None),
+                       rule, None)
+
     def _walk_kernel(self, bucket: int) -> int:
         """1 where a chunk program of ``bucket`` rows walks its context
-        in the model's Pallas kernel, by the model's own rule on sizes
-        (``chunk_walk_impl``: ``models.latent_moe``); 0 for its XLA walk
-        and for a model that states no such choice. Host arithmetic."""
-        p = self._prefiller
-        impl = getattr(getattr(p.engine, "model", None),
-                       "chunk_walk_impl", None)
-        if impl is None:
-            return 0
-        return int(impl(p.engine.cfg, int(bucket), p.cache.page)
-                   == "kernel")
+        in the model's Pallas kernel (``chunk_walk_impl``:
+        ``models.latent_moe``), else 0. Host arithmetic."""
+        p, impl = self._prefiller, self._model_rule("chunk_walk_impl")
+        return int(impl is not None and impl(
+            p.engine.cfg, int(bucket), p.cache.page) == "kernel")
+
+    def _scan_kernel(self, bucket: int) -> int:
+        """1 where a chunk program of ``bucket`` rows scans them, in
+        every state-space layer, in the model's Pallas kernel
+        (``chunk_scan_impl``: ``models.mamba_moe``), else 0. Host
+        arithmetic."""
+        impl = self._model_rule("chunk_scan_impl")
+        return int(impl is not None and impl(
+            self._prefiller.engine.cfg, int(bucket)) == "kernel")
 
     def _chunk_failed(self, h: RequestHandle, e):
         """A chunk was wedged or dropped past its retries. A dying
@@ -2126,6 +2140,8 @@ class ServingEngine:
         self.stats_counters["prefill_chunks"] += 1
         self.stats_counters["chunk_dispatches_kernel_walk"] += (
             self._walk_kernel(bucket))
+        self.stats_counters["chunk_dispatches_kernel_scan"] += (
+            self._scan_kernel(bucket))
         self.stats_counters["prefill_tokens"] += valid
         if start == 0 and self._seq_state_bytes:
             # The program zeroed the slot's state before its first row.
