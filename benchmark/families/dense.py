@@ -12,6 +12,22 @@ the program's side is ``dense_system.py``.
                                       (``attention`` is its first half)
     decode_step_bytes, prefill_chunk_flops   the algorithm's least needs
 
+and, only where its layers do not run each once in the order of their
+index (several passes over the same weights, something between layers,
+leaves that belong to no layer),
+
+    trunk(x, apply, final_norm, dims)   everything between the embedding
+                                        and the head's norm
+
+whose contract is ``harness/reference.py``'s docstring: ``apply(x, li,
+kind)`` runs ``layer`` of that ``kind`` on the seeded leaves of index
+``li`` (the same index, the same leaves; an index past ``dims.layers``
+for what belongs to no layer) and returns what ``layer`` returns;
+``final_norm(x)`` is the head's norm; the int8 control rides ``apply``.
+A family without one, as this one, has layers ``0 .. dims.layers - 1``
+applied once each. The harness itself reads ``vocab``, ``d``, ``eps``
+and ``tie`` of ``dims``, and ``layers`` where there is no ``trunk``.
+
 Pre-norm blocks of grouped-query attention (rotate-half rope, optional
 per-head q/k norm, optional q/k/v biases) and a SwiGLU MLP. Leaf layout
 (plain ``x @ w``): ``wq`` (d, H*hd), ``wk``/``wv`` (d, KV*hd), ``wo``

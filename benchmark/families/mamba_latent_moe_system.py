@@ -11,21 +11,14 @@ sibling's, ``mamba_latent_moe.py``.
 
 from __future__ import annotations
 
-import importlib
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from benchmark.harness import loader, weights as W
-
-# By name at run time, as ``mla_moe_system.py`` does and for its reason:
-# ``tests/benchmark/test_bench_families.py`` pins the list of files that
-# hold an import statement of the program.
-_models = importlib.import_module("triton_dist_tpu.models")
-ModelConfig, mamba_moe = _models.ModelConfig, _models.mamba_moe
-_pad_expert_width = importlib.import_module(
-    "triton_dist_tpu.layers.ep_moe").pad_expert_width
+from triton_dist_tpu.layers.ep_moe import (
+    pad_expert_width as _pad_expert_width)
+from triton_dist_tpu.models import ModelConfig, mamba_moe
 
 F = loader.sibling(__file__, "mamba_latent_moe")
 

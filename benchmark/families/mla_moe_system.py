@@ -11,20 +11,11 @@ sibling's, ``mla_moe.py``.
 
 from __future__ import annotations
 
-import importlib
-
 import jax
 from jax.sharding import NamedSharding
 
 from benchmark.harness import loader, weights as W
-
-# This half imports the program, as a family's system half does, but by
-# name at run time: ``tests/benchmark/test_bench_families.py`` pins the
-# list of files that hold an import statement of it, and a PR that adds a
-# family may not edit a file the benchmark has (PERF.md section 7 asks a
-# ``benchmark`` PR to make that list a rule).
-_models = importlib.import_module("triton_dist_tpu.models")
-ModelConfig, latent_moe = _models.ModelConfig, _models.latent_moe
+from triton_dist_tpu.models import ModelConfig, latent_moe
 
 F = loader.sibling(__file__, "mla_moe")
 

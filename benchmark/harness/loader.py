@@ -14,6 +14,13 @@ the same way:
     families/<family>_system.py   what builds the program's side
 
 so a later PR adds files and entries and edits no file that is there.
+The names a family's first half may give are listed in
+``families/dense.py``'s docstring, ``trunk`` among them: its layers'
+own order of application, where that is not each layer once
+(``harness/reference.py`` has its contract). Of the two halves only
+``*_system.py`` may import the program
+(``tests/benchmark/test_bench_families.py`` holds every ``families``
+directory to that).
 """
 
 from __future__ import annotations

@@ -10,6 +10,44 @@ one layer at a time (a float32 layer of Seed-OSS is 2.2 GB, the whole
 model would not fit), in the served type and then upcast: the model IS
 the bf16 leaves.
 
+Between the embedding and the head the layers run in the order the
+family states. A family that states none has every layer ``0 ..
+dims.layers - 1`` applied once, in that order. One whose layers run in
+another order, more than once over the same weights, or with something
+between them defines ``trunk(x, apply, final_norm, dims)``:
+
+1. ``x`` is the embedded batch, (B, S, d) float32. What ``trunk``
+   returns goes to the wanted rows' gather and to the head, which
+   applies the final norm and the head's table as for every family: a
+   model whose last pass ends in that norm returns what stood BEFORE it.
+2. ``apply(x, li, kind)`` is this module's ``_layer_step`` with the
+   seed's key, the family, ``dims``, the served type and the control's
+   ``int8`` bound. The leaves of index ``li`` are made by
+   ``weights.make_layer`` inside that one call, never stacked and never
+   kept: an index applied twice gives the SAME leaves, and a model of
+   any depth costs the reference one layer's weights at a time. ``li``
+   need not be below ``dims.layers``: an index past the stack, under a
+   ``kind`` of its own in the family's ``layer_leaves`` and ``layer``,
+   holds what belongs to no layer (a gate's row).
+3. ``apply`` returns what the family's ``layer`` returns for that
+   ``kind``, a sequence at a time under ``lax.map``: (B,) + that shape,
+   which need not be (S, d) (a gate returns (S, 1) scores).
+4. ``final_norm(x)`` is ``rms`` with ``weights.make_final_norm``'s gain
+   and ``dims.eps`` over the last axis: the gain the head's norm has, so
+   that a family can norm between passes with the model's one final norm.
+5. The int8 control reaches every application through ``apply``:
+   ``--control`` needs no word from the family.
+6. Without ``trunk`` the programs are the ones there always were:
+   ``_embed``, ``_layer_step`` and ``_head_block`` keep their text, so
+   the compile cache and the readings behind every limit stand.
+
+``trunk`` runs once a call of ``logits_at``, in Python and untraced;
+each ``apply`` is one dispatch of a program compiled once a ``kind``.
+Keep the calls of ``apply`` out of any ``jit`` of the family's own: a
+traced ``apply`` would unroll every application into one program. Of
+``dims`` this module reads ``vocab``, ``d``, ``eps`` and ``tie``, and
+``layers`` only where the family has no ``trunk``.
+
 What is compared, after the window has closed: for a seeded sample of
 the requests the window finished (the longest among them), the reference
 runs once over ``prompt + served tokens`` and reads, at every served
@@ -90,6 +128,13 @@ def _head_block(x, root, block, *, dims, dtype, int8):
     return (_dot_int8 if int8 else _dot)(y, t.astype(jnp.float32).T)
 
 
+@functools.partial(jax.jit, static_argnames=("dims", "dtype"))
+def _final_norm(x, root, *, dims, dtype):
+    """The head's norm alone, for a family's ``trunk``."""
+    return rms(x, W.make_final_norm(root, dims, dtype).astype(jnp.float32),
+               dims.eps)
+
+
 def _round_up(n, to):
     return -(-int(n) // to) * to
 
@@ -97,8 +142,9 @@ def _round_up(n, to):
 def logits_at(seed, family, dims, dtype, sequences, wanted, *, int8=False,
               pad_to=None, rows_to=None):
     """Reference logits of ``family``'s model at ``dims`` (of which this
-    module reads ``vocab``, ``d``, ``layers``, ``eps``, ``tie``).
-    ``sequences[i]`` is a token list; ``wanted[i]``
+    module reads ``vocab``, ``d``, ``eps``, ``tie``, and ``layers`` only
+    where the family states no ``trunk``: the module's docstring has the
+    contract). ``sequences[i]`` is a token list; ``wanted[i]``
     the positions whose next-token logits are returned, as one float32
     array (len(wanted[i]), vocab) per sequence. Every sequence is padded
     to ``pad_to`` positions and ``rows_to`` wanted rows (defaults: the
@@ -114,10 +160,18 @@ def logits_at(seed, family, dims, dtype, sequences, wanted, *, int8=False,
         ids[i, :len(seq)] = seq   # causal: the padding is in the future
         pos[i, :len(want)] = want
     x = _embed(jnp.asarray(ids), root, dims=dims, dtype=dtype)
-    for li in range(dims.layers):
-        x = _layer_step(x, root, li, family=family,
-                        kind=family.layer_kind(dims, li), dims=dims,
-                        dtype=dtype, int8=int8)
+
+    def apply(x, li, kind):
+        return _layer_step(x, root, li, family=family, kind=kind, dims=dims,
+                           dtype=dtype, int8=int8)
+
+    trunk = getattr(family, "trunk", None)
+    if trunk is None:
+        for li in range(dims.layers):
+            x = apply(x, li, family.layer_kind(dims, li))
+    else:
+        x = trunk(x, apply, functools.partial(_final_norm, root=root,
+                                              dims=dims, dtype=dtype), dims)
     rows = jnp.take_along_axis(x, jnp.asarray(pos)[:, :, None], axis=1)
     rows = rows.reshape(n * r_pad, dims.d)
     logits = np.concatenate(

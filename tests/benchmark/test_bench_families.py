@@ -104,29 +104,54 @@ def _python_files(top):
                 yield os.path.join(base, f)
 
 
-def _imports(path):
-    with open(path) as f:
-        tree = ast.parse(f.read())
-    for node in ast.walk(tree):
+def _imports(source):
+    """The modules a file's text imports: by statement, or by name at
+    run time (a call that is handed a module's name as a string)."""
+    for node in ast.walk(ast.parse(source)):
         if isinstance(node, ast.Import):
             yield from (a.name for a in node.names)
         elif isinstance(node, ast.ImportFrom) and node.module:
             yield node.module
+        elif isinstance(node, ast.Call):
+            yield from (a.value for a in node.args
+                        if isinstance(a, ast.Constant)
+                        and isinstance(a.value, str)
+                        and re.fullmatch(r"[A-Za-z_][\w.]*", a.value))
+
+
+def _may_import_the_program(rel):
+    """The rule: of the harness ``system.py`` alone, and under any
+    ``families`` directory the files named ``*_system.py``."""
+    return (rel == "benchmark/harness/system.py"
+            or (os.path.basename(os.path.dirname(rel)) == "families"
+                and rel.endswith("_system.py")))
 
 
 def test_only_system_and_a_familys_system_half_import_the_program():
     importers = []
     for root in (loader.DATA_ROOT, DATA):
         for path in _python_files(root):
-            if any(m.split(".")[0] == "triton_dist_tpu"
-                   for m in _imports(path)):
+            with open(path) as f:
+                found = list(_imports(f.read()))
+            if any(m.split(".")[0] == "triton_dist_tpu" for m in found):
                 importers.append(os.path.relpath(path, loader.REPO_ROOT))
     inside = [p for p in importers if p.startswith("benchmark/harness/")
               or "/families/" in p]
-    assert sorted(inside) == [
-        "benchmark/families/dense_system.py",
-        "benchmark/harness/system.py",
-        "tests/benchmark/data/families/tiny_moe_system.py"]
+    assert [p for p in inside if not _may_import_the_program(p)] == []
+    # The scan sees an import where there is one: the harness's one
+    # importer and every system half there is, the expert families' by a
+    # plain statement since the list of files became this rule (PR 47).
+    assert {"benchmark/harness/system.py",
+            "benchmark/families/dense_system.py",
+            "benchmark/families/mla_moe_system.py",
+            "benchmark/families/mamba_latent_moe_system.py",
+            "tests/benchmark/data/families/tiny_moe_system.py"} <= set(inside)
+    # ... whose name decides, and an import by name at run time is one.
+    assert not _may_import_the_program("benchmark/families/dense.py")
+    assert not _may_import_the_program("benchmark/harness/reference.py")
+    assert not _may_import_the_program("tests/benchmark/data/x_system.py")
+    assert list(_imports('m = importlib.import_module("triton_dist_tpu.ops")'
+                         )) == ["triton_dist_tpu.ops"]
 
 
 def test_no_file_of_the_harness_holds_a_dense_leaf_or_key():
