@@ -17,7 +17,7 @@ import pytest
 import triton_dist_tpu as tdt
 from triton_dist_tpu import obs
 from triton_dist_tpu.models import (Engine, ModelConfig, latent_moe,
-                                    mamba_moe)
+                                    looped, mamba_moe)
 
 # What each family's chunk program runs; ``embed``, the attention
 # blocks, ``head`` and ``pick`` are common to all.
@@ -26,6 +26,7 @@ COMMON = {"embed", "attn_project", "cache_write", "attn_chunk",
 EXPERTS = {"router", "experts", "shared_expert"}
 RUNS = {"dense": COMMON | {"mlp"},
         "latent_moe": COMMON | EXPERTS,
+        "looped": COMMON | {"mlp", "pass_norm", "exit_gate"},
         "mamba_moe": COMMON | EXPERTS | {"ssm_project", "ssm", "ssm_out",
                                          "expert_latent"}}
 
@@ -39,6 +40,9 @@ def _chunk_program_text(family: str) -> str:
                      seed=0)
     elif family == "latent_moe":
         eng = Engine(ModelConfig.tiny_latent_moe(), mesh, model=latent_moe,
+                     mode="xla", dtype=jnp.float32, max_len=32, seed=0)
+    elif family == "looped":
+        eng = Engine(ModelConfig.tiny_looped(), mesh, model=looped,
                      mode="xla", dtype=jnp.float32, max_len=32, seed=0)
     else:
         eng = Engine(ModelConfig.tiny_mamba_moe(), mesh, model=mamba_moe,

@@ -931,3 +931,112 @@ def test_the_held_experts_lay_out_held_pairs_only(t, n_held,
                 and v.aval.shape[-1] in (d, f)
                 and int(np.prod(v.aval.shape[:-1])) >= pairs]
         assert wide == []
+
+
+def _pool_results(hlo: str, pool_shape, dtype: str = "bf16"):
+    """``(op, line)`` of every instruction of ANY computation of an
+    optimised HLO module whose result holds an array of ``pool_shape``:
+    the entry's, a ``while``'s body's, a fusion's."""
+    pool = dtype + "[" + ",".join(map(str, pool_shape)) + "]"
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if m and pool in m.group(1):
+            out.append((m.group(2), line.strip()[:200]))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "fused-128", "fused-512"])
+def test_compiled_looped_steps_keep_the_pool_in_place(v5e, mosaic, program):
+    """``models.looped``'s paged steps (layers applied several times
+    over stacked weights, a cache a pass: PR 48), lowered for one v5e
+    chip as the serving engine jits them, at the attention sizes and the
+    pool geometry of ``ouro-2.6b-1chip.fewshot`` (16 heads over 16 KV
+    heads of 128: ONE head a group; 6 slots of 6 pages, 37 pages of 128)
+    with 8 layers applied four times and a narrow FFN (a pool of 32
+    layers, 2.5 GB a side: at 6 pool layers, 0.47 GB, XLA's memory-space
+    assignment stages the whole pool through another space and back in
+    the 512-row program, which the cell's 192 layers leave it no room
+    for). The pool is written
+    INSIDE the scans over passes and layers, so ``pool_copies``, which
+    reads the entry computation, does not apply: here every instruction
+    of every computation that yields the pool is a parameter, a tuple
+    or its element, a loop, or an update in place (alone or as all a
+    fusion does to it), in the one row-major layout. Both paged kernels
+    lower through Mosaic once for the 32 layer applications (the pool's
+    layer is an operand). Here and not in a file of its own: one worker
+    holds libtpu. Compile only."""
+    from jax.sharding import NamedSharding
+    from triton_dist_tpu.models import looped
+    from triton_dist_tpu.serving.blocks import PagedKVCache, pool_shardings
+
+    cfg = ModelConfig.tiny_looped(
+        vocab_size=1024, hidden_size=2048, intermediate_size=512,
+        num_hidden_layers=8, num_passes=4, num_attention_heads=16,
+        num_key_value_heads=16, head_dim=128, rope_theta=1e6)
+    slots, page, p_max, pages = 6, 128, 6, 37
+    mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
+    dt = jnp.bfloat16
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=(
+                    s if isinstance(s, NamedSharding)
+                    else NamedSharding(mesh, s))),
+            tree, specs, is_leaf=lambda s: isinstance(s, P))
+
+    specs = looped.param_specs(cfg, "tp")
+    params = on_mesh(jax.eval_shape(lambda: looped.init_params(
+        jax.random.PRNGKey(0), cfg, dt)), specs)
+    kv_spec = looped.paged_cache_specs("tp")
+    kv_sh = pool_shardings(mesh, kv_spec)
+    _, per_token, keeps = looped.paged_pool(cfg)
+    cache = on_mesh(jax.eval_shape(lambda: PagedKVCache.empty(
+        keeps["layers"], pages, page, *per_token, num_slots=slots,
+        p_max=p_max, dtype=dt)), kv_sh)
+    assert cache.k_pages.shape == (32, 37, 16, 128, 128)
+    ints = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    if program == "decode":
+        step = lambda p, t, c: looped.decode_step_paged(
+            p, t, c, cfg, attn_impl="flash")
+        in_specs = (specs, P(None), kv_spec)
+        out_specs = (P(None, None), kv_spec, P(None))
+        args = (params, ints(slots), cache)
+    else:
+        step = lambda p, t, c, row, start, wfrom, valid, d: (
+            looped.chunk_decode_paged(
+                p, t, d, c, row, cfg, start=start, wfrom=wfrom,
+                valid=valid, attn_impl="flash", decode_attn_impl="flash"))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P(),
+                    P(None))
+        out_specs = (P(None), P(None, None), kv_spec, P(None))
+        args = (params, ints(int(program[6:])), cache, ints(p_max), ints(),
+                ints(), ints(), ints(slots))
+    lowered = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=(args.index(cache),),
+        out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
+                            for s in out_specs)).lower(*args)
+    text = lowered.as_text()
+    assert text.count('kernel_name = "paged_flash_decode"') == 1
+    assert text.count('kernel_name = "paged_flash_qblock"') == (
+        program != "decode")
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    yields = _pool_results(hlo, cache.k_pages.shape)
+    in_place = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                "while", "dynamic-update-slice", "fusion"}
+    assert yields and [ln for op, ln in yields if op not in in_place] == []
+    assert {"while", "dynamic-update-slice"} <= {op for op, _ in yields}
+    pool = "bf16[32,37,16,128,128]"
+    assert set(re.findall(re.escape(pool) + r"\{([\d,]+)", hlo)) == {
+        "4,3,2,1,0"}
+    # Donated: both sides' bytes are aliased outputs, and the program's
+    # temporaries are far smaller than one side of the pool.
+    mem = compiled.memory_analysis()
+    side = 2 * int(np.prod(cache.k_pages.shape))
+    assert mem.alias_size_in_bytes >= 2 * side
+    assert mem.temp_size_in_bytes < side // 8

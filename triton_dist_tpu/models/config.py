@@ -118,6 +118,16 @@ class ModelConfig:
     ssm_state_size: int = 128
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
+    # Layers applied several times (models/looped.py): the stack runs
+    # ``num_passes`` times over the SAME weights, the final norm after
+    # every pass, and each pass of a layer keeps pages of its own; an
+    # exit gate scores every pass, and a row's logits are those of the
+    # first pass at which the cumulated exit probability reaches
+    # ``exit_threshold`` (1.0: the last). ``post_norm``: a second
+    # RMSNorm AFTER each sublayer, before its residual is added.
+    num_passes: int = 1
+    exit_threshold: float = 1.0
+    post_norm: bool = False
 
     @property
     def is_moe(self) -> bool:
@@ -132,10 +142,11 @@ class ModelConfig:
 
     @property
     def num_paged_layers(self) -> int:
-        """Layers that keep pages of keys."""
+        """Layers of the pool: every application of a layer that keeps
+        pages of keys keeps its own."""
         if self.layer_pattern:
             return self.layer_pattern.count("*")
-        return self.num_hidden_layers
+        return self.num_hidden_layers * self.num_passes
 
     @property
     def is_hybrid(self) -> bool:
@@ -305,6 +316,19 @@ class ModelConfig:
                     routed_scaling_factor=5.0, first_held_expert=0,
                     num_held_experts=4, max_position_embeddings=128,
                     model_name="mamba-moe-tiny")
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny_looped(cls, **kw) -> "ModelConfig":
+        """Two four-norm blocks applied three times at a size for the
+        CPU mesh (models/looped.py)."""
+        base = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                    num_hidden_layers=2, num_attention_heads=2,
+                    num_key_value_heads=2, head_dim=32, qk_norm=False,
+                    rope_theta=10000.0, max_position_embeddings=128,
+                    num_passes=3, post_norm=True,
+                    model_name="looped-tiny")
         base.update(kw)
         return cls(**base)
 
@@ -514,12 +538,24 @@ class ModelConfig:
             raise NotImplementedError(
                 f"rope scaling {scaling!r} is not computed by this "
                 "model family (plain rope only)")
+        # ``model_type: ouro`` (models/looped.py): the stack applied
+        # ``total_ut_steps`` times, four norms a block, an exit gate.
+        looped = {}
+        if get("model_type") == "ouro":
+            if get("use_sliding_window") or get("sliding_window"):
+                raise NotImplementedError(
+                    "ouro with a sliding window: every pass of every "
+                    "layer is served with full attention")
+            looped = dict(
+                num_passes=req("total_ut_steps"),
+                exit_threshold=float(get("early_exit_threshold", 1.0)),
+                post_norm=True)
         n_experts = get("num_experts", 0) or get("n_routed_experts", 0) or 0
         shared_ff = get("shared_expert_intermediate_size", 0) or (
             (get("n_shared_experts", 0) or 0)
             * (get("moe_intermediate_size", 0) or 0))
         return cls(
-            **latent,
+            **latent, **looped,
             vocab_size=req("vocab_size"),
             hidden_size=d,
             intermediate_size=get("intermediate_size", 4 * d),
@@ -540,7 +576,7 @@ class ModelConfig:
             qk_norm=not (
                 str(get("model_type", "")).startswith("qwen2")
                 or get("model_type", "qwen3") in (
-                    "seed_oss", "llama", "mistral")),
+                    "seed_oss", "llama", "mistral", "ouro")),
             rms_norm_eps=get("rms_norm_eps", 1e-6),
             rope_theta=get("rope_theta") or rope.get(
                 "rope_theta", 1_000_000.0),

@@ -111,6 +111,9 @@ DEVICE_SCOPES = (
     "shared_expert",     # the expert every token goes through
     "head",              # final norm, the rows kept, the vocabulary
     "pick",              # the greedy token (and the step's counts)
+    "pass_norm",         # the final norm between two passes of a stack
+                         # applied several times (models/looped.py)
+    "exit_gate",         # its exit gate's scores and the pick among passes
 )
 
 

@@ -59,10 +59,13 @@ _OP_HIST_KINDS = frozenset({
 # chunk's rows walk their context in a Pallas kernel, ``walk_kernel``
 # 0/1, and whether its state-space layers scan them in one,
 # ``scan_kernel`` 0/1; ``waited_ms`` of an admission; what an
-# ``expert_load`` event counted).
+# ``expert_load`` event counted; of a model whose layers run several
+# times, ``passes`` and the pass the rows' logits were read from,
+# ``exit_pass``: a decode's mean over its rows, known once its tokens
+# are on the host, so set while the span is open).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
                         "bucket", "valid", "walk_kernel", "scan_kernel",
-                        "waited_ms",
+                        "waited_ms", "passes", "exit_pass",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",
                         "expert_rows_max", "expert_imbalance"})
@@ -87,9 +90,11 @@ class _SpanCtx:
     """One timed region: a profiler annotation around it, clock at
     enter/exit, histogram fold, and (in spans mode) an EventLog append
     — error type recorded when the region raised. A ``tick`` span makes
-    its index the ambient ``Telemetry.tick`` while it is open."""
+    its index the ambient ``Telemetry.tick`` while it is open. A field
+    set while the span is open (``span.fields[...] = ...``) reaches the
+    annotation as it closes."""
 
-    __slots__ = ("tel", "kind", "fields", "ann", "t0")
+    __slots__ = ("tel", "kind", "fields", "ann", "t0", "entered")
 
     def __init__(self, tel: "Telemetry", kind: str, fields: dict):
         self.tel = tel
@@ -101,6 +106,8 @@ class _SpanCtx:
         if self.kind == "tick":
             tel.tick = self.fields["tick"]
         self.ann = tel._annotation(self.kind, self.fields)
+        self.entered = (None if self.ann is _NULL
+                        else frozenset(self.fields))
         self.ann.__enter__()
         self.t0 = tel.clock()
         return self
@@ -109,6 +116,12 @@ class _SpanCtx:
         tel = self.tel
         fields = self.fields
         t1 = tel.clock()
+        if self.ann is not _NULL:
+            late = {k: v for k, v in fields.items()
+                    if k in _ANNOTATED and k not in self.entered
+                    and v is not None}
+            if late:
+                self.ann.set_metadata(**late)
         self.ann.__exit__(etype, exc, tb)
         if etype is not None:
             fields["error"] = etype.__name__

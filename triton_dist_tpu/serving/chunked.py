@@ -128,9 +128,12 @@ class ChunkedPrefill:
         # dispatch's cache spec must match the pool it writes.
         kv_spec = model.paged_cache_specs(
             axis, quantized=cache_shardings.quantized)
-        # A model with ``STEP_STATS`` returns them last from every step;
-        # they ride the picked tokens out (:func:`picked_with_stats`).
-        stats = bool(getattr(model, "STEP_STATS", ()))
+        # A model with ``STEP_STATS`` (counts of the step) or
+        # ``ROW_STATS`` (one number a head row) returns them last from
+        # every step; they ride the picked tokens out
+        # (:func:`picked_with_stats`).
+        stats = bool(getattr(model, "STEP_STATS", ())
+                     or getattr(model, "ROW_STATS", ()))
         # A pool whose sequences keep state beside their pages
         # (``PagedKVCache.seq``): the chunk program is told the slot
         # whose state its rows carry, one more int32 scalar.

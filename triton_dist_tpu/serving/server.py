@@ -70,13 +70,17 @@ class _Row:
     own greedy pick from it. The floats stay on the device unless the
     tick fetched them (``host``: a row of its batch samples); read as an
     array (``np.asarray``: a sampled request, a tap standing in for
-    :meth:`ServingEngine._pick`) the row is copied then."""
+    :meth:`ServingEngine._pick`) the row is copied then. ``exit_pass``:
+    the pass the row's logits were read from (a model with
+    ``ROW_STATS``), else None."""
 
-    __slots__ = ("token", "_logits", "_index", "_host")
+    __slots__ = ("token", "_logits", "_index", "_host", "exit_pass")
 
-    def __init__(self, token, logits, index=None, host=None):
+    def __init__(self, token, logits, index=None, host=None,
+                 exit_pass=None):
         self.token, self._logits = int(token), logits
         self._index, self._host = index, host
+        self.exit_pass = exit_pass
 
     def __len__(self) -> int:
         return self._logits.shape[-1]
@@ -92,14 +96,17 @@ class _Row:
 class _Rows:
     """A step's rows by slot, as :class:`_Row`: ``picked`` the
     program's tokens on the host, ``logits`` its rows on the device,
-    ``host`` those rows on the host where the tick fetched them."""
+    ``host`` those rows on the host where the tick fetched them,
+    ``exits`` the pass each row took (a model with ``ROW_STATS``)."""
 
-    def __init__(self, picked, logits, host=None):
+    def __init__(self, picked, logits, host=None, exits=None):
         self.picked, self.logits, self.host = picked, logits, host
+        self.exits = exits
 
     def __getitem__(self, slot) -> _Row:
         return _Row(self.picked[slot], self.logits, slot,
-                    None if self.host is None else self.host[slot])
+                    None if self.host is None else self.host[slot],
+                    None if self.exits is None else int(self.exits[slot]))
 
 
 def save_checkpoint(snap: dict, path: str) -> str:
@@ -496,6 +503,7 @@ class ServingEngine:
             "seq_state_resets": 0,
         }
         self._step_stats = ()
+        self._row_stats = ()
         # Bytes of what the sequences keep beside their pages (a pool
         # that states such arrays; else 0).
         self._seq_state_bytes = 0
@@ -855,6 +863,12 @@ class ServingEngine:
         # A model with ``STEP_STATS`` returns them last from every step;
         # they leave the chip behind the picked tokens, in their array.
         self._step_stats = tuple(getattr(model, "STEP_STATS", ()))
+        # ``ROW_STATS``: one int32 a head row instead, the pass the row
+        # took (``models.looped``), behind the tokens the same way; the
+        # served tokens are counted by it.
+        self._row_stats = tuple(getattr(model, "ROW_STATS", ()))
+        if self._row_stats:
+            self._picked_by_pass = np.zeros((cfg.num_passes,), np.int64)
         row_sh = NamedSharding(mesh, P(None))
         logits_sh = NamedSharding(mesh, P(None, None))
         out_specs = (P(None), P(None, None), kv_spec)
@@ -881,7 +895,7 @@ class ServingEngine:
                 out = model.decode_step_paged(
                     params, toks, c, cfg, mode=eng.mode, axis=axis,
                     ctxs=eng.ctxs, attn_impl=self.attn_impl, **mk)
-                if self._step_stats:
+                if self._step_stats or self._row_stats:
                     return (picked_with_stats(greedy_tokens(out[0]),
                                               out[-1]), *out[:-1])
                 return (greedy_tokens(out[0]), *out)
@@ -1165,6 +1179,16 @@ class ServingEngine:
             out["seq_state_slots"] = self.num_slots
             out["seq_state_layers"] = self._seq_layers
             out["paged_layers"] = self._pool_layers
+        if self._row_stats:
+            # Layers applied several times: how often, the layers the
+            # pool keeps and the layer applications of one step program
+            # (both passes x layers), and the served tokens by the pass
+            # their logits were read from (index 0 = pass 1).
+            out["passes"] = self.cfg.num_passes
+            out["paged_layers"] = self._pool_layers
+            out["layer_applications_a_step"] = (
+                self.cfg.num_passes * self.cfg.num_hidden_layers)
+            out["picked_by_pass"] = self._picked_by_pass.tolist()
         if self.manager is not None:
             out["pool"] = self.manager.fragmentation()
         if hasattr(self, "plan"):
@@ -1871,7 +1895,7 @@ class ServingEngine:
         try:
             with self.obs.span(
                     "decode", step=self.stats_counters["decode_dispatches"],
-                    batch=len(active), fused=1):
+                    batch=len(active), fused=1) as span:
                 with self.obs.span("decode_enqueue"):
                     batch = tuple(jnp.asarray(a) for a in (
                         self._toks, tbl, self._lens, self._live))
@@ -1888,10 +1912,13 @@ class ServingEngine:
                     picked = self._wait_decode(picked)
                 with self.obs.span("decode_fetch"):
                     picked = self._read(picked)
+                    exits = self._exit_passes(picked)
                     rows = _Rows(picked[1:], dec,
-                                 self._read(dec) if sampled else None)
+                                 self._read(dec) if sampled else None,
+                                 None if exits is None else exits[1:])
                     self._note_step_stats(picked,
                                           plan[1] + self.num_slots)
+                    self._note_exit_passes(span, rows, active)
                 if first_done:
                     # Its token came with the batch's: row 0 of the
                     # one array, already on the host.
@@ -2028,6 +2055,8 @@ class ServingEngine:
         bucket, valid = p.chunker.next_chunk(len(seq) - start)
         walk_kernel = self._walk_kernel(bucket)
         scan_kernel = self._scan_kernel(bucket)
+        passes = ({"passes": self.cfg.num_passes} if self._row_stats
+                  else {})
         toks = np.zeros((bucket,), np.int32)
         toks[:valid] = seq[start:start + valid]
         row = np.asarray(p.manager.table_row(slot), np.int32)
@@ -2045,7 +2074,7 @@ class ServingEngine:
                                slot=slot, tenant=h.request.tenant,
                                start=int(start), bucket=int(bucket),
                                valid=int(valid), walk_kernel=walk_kernel,
-                               scan_kernel=scan_kernel), \
+                               scan_kernel=scan_kernel, **passes), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
@@ -2178,11 +2207,17 @@ class ServingEngine:
         if not h.tokens:
             picked, logits = last
             with self.obs.span("prefill_fetch", slot=slot,
-                               request_id=h.request.request_id):
+                               request_id=h.request.request_id) as span:
                 if picked is None or h.request.temperature > 0.0:
                     row = self._read(logits)
                 else:
-                    row = _Row(self._read(picked)[0], logits)
+                    picked = self._read(picked)
+                    exits = self._exit_passes(picked)
+                    row = _Row(picked[0], logits, exit_pass=(
+                        None if exits is None else int(exits[0])))
+                    if exits is not None and self.obs.enabled:
+                        span.fields.update(passes=self.cfg.num_passes,
+                                           exit_pass=row.exit_pass)
             self._sample_emit(h, row)
 
     def _read(self, out) -> np.ndarray:
@@ -2204,6 +2239,8 @@ class ServingEngine:
                            device=device):
             tok = self._pick(row, h.request, len(h.tokens))
             self.stats_counters["tokens_picked_on_device"] += device
+            if getattr(row, "exit_pass", None) is not None:
+                self._picked_by_pass[row.exit_pass - 1] += 1
         with self.obs.span("emit", slot=h.slot, request_id=rid):
             self._emit(h, tok)
 
@@ -2655,9 +2692,11 @@ class ServingEngine:
                 with self.obs.span(
                         "decode",
                         step=self.stats_counters["decode_dispatches"],
-                        batch=len(active), fused=0), \
+                        batch=len(active), fused=0) as span, \
                         faults.on_op_call("serving_decode"):
-                    return self._dispatch(tbl, _samples(active))
+                    rows = self._dispatch(tbl, _samples(active))
+                    self._note_exit_passes(span, rows, active)
+                    return rows
 
             rows = self._run_op_with_retry(
                 "serving_decode", _attempt,
@@ -3175,7 +3214,25 @@ class ServingEngine:
             picked = self._read(picked)
             self._note_step_stats(picked, self.num_slots)
             return _Rows(picked, logits,
-                         self._read(logits) if sampled else None)
+                         self._read(logits) if sampled else None,
+                         self._exit_passes(picked))
+
+    def _exit_passes(self, picked: np.ndarray):
+        """The pass each head row of a step program took, 1-based: what
+        a model with ``ROW_STATS`` appends to its picked tokens, row for
+        row; None for any other model."""
+        return picked[len(picked) // 2:] if self._row_stats else None
+
+    def _note_exit_passes(self, span, rows, active):
+        """The open ``decode`` span's stats ``passes`` and ``exit_pass``
+        (the mean over the slots that decoded) for a model with
+        ``ROW_STATS``; nothing for any other."""
+        if (self.obs.enabled and isinstance(rows, _Rows)
+                and rows.exits is not None):
+            span.fields.update(
+                passes=self.cfg.num_passes,
+                exit_pass=float(np.mean([rows.exits[h.slot]
+                                         for h in active])))
 
     def _note_step_stats(self, picked: np.ndarray, rows: int):
         """Book the ``STEP_STATS`` a step program of ``rows`` rows
