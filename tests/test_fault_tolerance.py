@@ -320,7 +320,8 @@ def test_dropped_second_chunk_of_a_riding_tick_fails_its_request_alone(
     ok = srv.submit([1, 2, 3], max_new_tokens=6)
     srv.step()
     assert ok.status == "running"
-    doomed = srv.submit(list(range(4, 15)), max_new_tokens=3)  # 4+4+3
+    # A two-program tail stays two programs (4+4+3 would be one of 16).
+    doomed = srv.submit(list(range(4, 11)), max_new_tokens=3)  # 4+3
     nxt = srv.submit(list(range(20, 26)), max_new_tokens=3)    # 4+2
     lens, told = int(srv._lens[ok.slot]), len(ok.tokens)
     with faults.inject(faults.get_plan("fail_kth_call",

@@ -469,19 +469,20 @@ def test_decode_rides_chunks_token_exact(engine, attn, lens, rode):
 
 
 # Buckets (4, 16): a riding tick holds 16 bucket rows. A decoder (3
-# tokens) is live when A (27 = 16 + 4 + 4 + 3), B (11 = 4 + 4 + 3) and
-# C (21 = 16 + 4 + 1) are admitted together, in that order.
+# tokens) is live when A (24 = 16 + 4 + 4), B (7 = 4 + 3) and C (27 =
+# 16 + 11, the tail ONE padded program of 16 where the greedy cover
+# took 4 + 4 + 3: ``plan_chunks``) are admitted together, in that order.
 TICK_BUCKETS = (4, 16)
-TICK_PROMPTS = {"D": 3, "A": 27, "B": 11, "C": 21}
-ONE_CHUNK_A_SLOT = [["A16", "B4", "C16"], ["A4", "B4", "C4"],
-                    ["A4", "B4", "C4"], ["A4"]]
+TICK_PROMPTS = {"D": 3, "A": 24, "B": 7, "C": 27}
+ONE_CHUNK_A_SLOT = [["A16", "B4", "C16"], ["A4", "B4", "C16"], ["A4"]]
 # case: (engine arguments, a decoder live before A, B and C arrive, the
 # decode batch rides, the chunks of each tick from then on)
 TICK_CASES = {
     # Oldest first, a prompt's tail chunks in one tick, the next
-    # prompt's behind them while the tick's rows stay within 16.
-    "riding": ({}, True, True, [["A16"], ["A4", "A4", "A4", "B4"],
-                                ["B4", "B4"], ["C16"], ["C4", "C4"]]),
+    # prompt's behind them while the tick's rows stay within 16; a
+    # padded tail is the tick's whole room.
+    "riding": ({}, True, True, [["A16"], ["A4", "A4", "B4", "B4"],
+                                ["C16"], ["C16"]]),
     # The same engine held to the old rule: the schedule whose tokens
     # the riding one must equal.
     "one_chunk_a_slot": ({}, True, False, ONE_CHUNK_A_SLOT),
@@ -489,10 +490,10 @@ TICK_CASES = {
     # rides, so nothing is saved by waiting.
     "speculation": ({"spec_k": 1}, True, False, ONE_CHUNK_A_SLOT),
     # No decoder live in the first tick: its chunk programs pipeline
-    # back to back, one a slot. From the next tick on D decodes.
+    # back to back, one a slot. From the next tick on D decodes, and
+    # C's padded tail does not fit behind A's and B's.
     "no_live_decoder": ({}, False, True, [
-        ["D4", "A16", "B4", "C16"], ["A4", "A4", "A4", "B4"],
-        ["B4", "C4", "C4"]]),
+        ["D4", "A16", "B4", "C16"], ["A4", "A4", "B4"], ["C16"]]),
 }
 
 
@@ -546,6 +547,10 @@ def test_riding_tick_prefills_one_programs_worth(engine, case):
     # Every chunk program but the one a tick's batch rode ran parked.
     assert st["chunk_dispatches_parked"] == (
         st["prefill_chunks"] - len(rode) if rides else 0)
+    # C's tail alone took a larger bucket than the greedy step; the
+    # padding rows are D's 1, B's 1 and C's 5.
+    assert (st["chunk_dispatches_padded_up"],
+            st["prefill_rows_padded"]) == (1, 7)
     assert srv.chunker.cache_size() <= len(TICK_BUCKETS)
     assert st["pool"]["used_pages"] == 0
 

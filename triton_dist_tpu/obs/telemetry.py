@@ -58,13 +58,17 @@ _OP_HIST_KINDS = frozenset({
 # decode and whether it rode a chunk program, ``fused`` 0/1; whether a
 # chunk's rows walk their context in a Pallas kernel, ``walk_kernel``
 # 0/1, and whether its state-space layers scan them in one,
-# ``scan_kernel`` 0/1; ``waited_ms`` of an admission; what an
+# ``scan_kernel`` 0/1; whether it is a prompt's tail in one padded
+# program of a larger bucket than the greedy step takes, ``padded_up``
+# 0/1, and on ``prefill_fetch`` the programs the prompt took,
+# ``chunks``; ``waited_ms`` of an admission; what an
 # ``expert_load`` event counted; of a model whose layers run several
 # times, ``passes`` and the pass the rows' logits were read from,
 # ``exit_pass``: a decode's mean over its rows, known once its tokens
 # are on the host, so set while the span is open).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
                         "bucket", "valid", "walk_kernel", "scan_kernel",
+                        "padded_up", "chunks",
                         "waited_ms", "passes", "exit_pass",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",

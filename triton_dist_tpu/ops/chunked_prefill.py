@@ -62,13 +62,54 @@ def gather_pages_dense(pool, table, scale=None):
     return g.reshape(*table.shape[:-1], table.shape[-1] * page, kvh, hd)
 
 
+def _greedy_step(rem: int, bs) -> tuple:
+    """One step of the greedy cover over sorted buckets ``bs``: the
+    largest bucket that fits in ``rem`` whole, else the smallest one
+    that covers it, padded."""
+    fit = [b for b in bs if b <= rem]
+    if fit:
+        return fit[-1], fit[-1]
+    return next(b for b in bs if b >= rem), rem
+
+
+def _greedy_cover(rem: int, bs) -> list:
+    """The greedy steps that cover ``rem``."""
+    out = []
+    while rem > 0:
+        out.append(_greedy_step(rem, bs))
+        rem -= out[-1][1]
+    return out
+
+
+def padded_up(bucket: int, valid: int, buckets) -> bool:
+    """Whether a chunk ``(bucket, valid)`` of :func:`plan_chunks` took a
+    larger bucket than the greedy step would have: its rows stop short
+    of the bucket and a smaller bucket fits in them whole."""
+    return valid < bucket and min(buckets) <= valid
+
+
 def plan_chunks(n_tokens: int, buckets) -> list:
-    """Deterministic bucket cover of ``n_tokens``: greedily the largest
-    bucket that fits, then the smallest bucket covering the remainder
-    (padded). Returns ``[(bucket, valid), ...]`` with
-    ``sum(valid) == n_tokens``. Pure host planning — the resume path
-    re-prefills through the SAME sequence for the same length, which is
-    what makes preemption recovery deterministic."""
+    """Deterministic bucket cover of ``n_tokens``, a pure function of
+    ``(n_tokens, buckets)``. Returns ``[(bucket, valid), ...]`` with
+    ``sum(valid) == n_tokens``; only the last chunk is padded.
+
+    At each step, with ``rem`` tokens left, the GREEDY step is the
+    largest bucket that fits, else the smallest bucket covering the
+    remainder (padded). A chunk program pays a fixed price whatever its
+    rows (the weights' read, a launch a layer), so where the greedy
+    cover ``G`` of ``rem`` takes THREE OR MORE programs and the
+    smallest bucket ``B >= rem`` has at most a third more rows than
+    they (``3 B <= 4 rows(G)``), the one padded chunk ``(B, rem)``
+    takes their place; a tail of two programs stays two. With
+    ``(128, 512)``: a tail of 257-511 tokens is one 512-row program,
+    one of 1-256 is one or two 128-row programs as before.
+
+    The rule reads sizes alone, step by step, so
+    ``plan_chunks(n, b)[i:]`` is ``plan_chunks`` of what is left after
+    ``i`` chunks: a stream that asks for its next chunk by the tokens
+    left walks this cover, and the resume path re-prefills through the
+    SAME sequence for the same length, which is what makes preemption
+    recovery deterministic."""
     if n_tokens < 0:
         raise ValueError(f"n_tokens must be >= 0, got {n_tokens}")
     bs = sorted(set(int(b) for b in buckets))
@@ -77,15 +118,14 @@ def plan_chunks(n_tokens: int, buckets) -> list:
     out = []
     rem = int(n_tokens)
     while rem > 0:
-        fit = [b for b in bs if b <= rem]
-        if fit:
-            b = max(fit)
-            out.append((b, b))
-            rem -= b
-        else:                    # tail: smallest bucket covers it, padded
-            b = min(x for x in bs if x >= rem)
-            out.append((b, rem))
-            rem = 0
+        step = _greedy_step(rem, bs)
+        if step[0] < rem <= bs[-1]:       # a tail of several programs
+            b = next(x for x in bs if x >= rem)
+            g = _greedy_cover(rem, bs)
+            if len(g) >= 3 and 3 * b <= 4 * sum(x for x, _ in g):
+                step = (b, rem)
+        out.append(step)
+        rem -= step[1]
     return out
 
 

@@ -198,7 +198,11 @@ class ChunkedPrefill:
 
     def plan(self, n_tokens: int) -> List[Tuple[int, int]]:
         """Deterministic ``[(bucket, valid), ...]`` cover of
-        ``n_tokens`` (see :func:`ops.chunked_prefill.plan_chunks`)."""
+        ``n_tokens``, a function of it and ``buckets`` alone
+        (:func:`ops.chunked_prefill.plan_chunks`): greedy, but a tail
+        of three or more programs is one padded chunk of the next
+        bucket up. Still one program a bucket: the padded chunk's
+        ``valid`` rides as data."""
         return plan_chunks(n_tokens, self.buckets)
 
     def next_chunk(self, remaining: int) -> Tuple[int, int]:

@@ -481,6 +481,7 @@ class ServingEngine:
             "preemptions": 0, "comm_timeouts": 0, "decode_time_s": 0.0,
             "decode_tokens": 0, "prefill_chunks": 0,
             "chunk_dispatches_parked": 0,
+            "chunk_dispatches_padded_up": 0, "prefill_rows_padded": 0,
             "chunk_dispatches_kernel_walk": 0,
             "chunk_dispatches_kernel_scan": 0, "migrated_pages": 0,
             "spec_drafted": 0, "spec_accepted": 0,
@@ -2073,7 +2074,9 @@ class ServingEngine:
                                request_id=h.request.request_id,
                                slot=slot, tenant=h.request.tenant,
                                start=int(start), bucket=int(bucket),
-                               valid=int(valid), walk_kernel=walk_kernel,
+                               valid=int(valid),
+                               padded_up=self._padded_up(bucket, valid),
+                               walk_kernel=walk_kernel,
                                scan_kernel=scan_kernel, **passes), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
@@ -2130,6 +2133,15 @@ class ServingEngine:
         return getattr(getattr(self._prefiller.engine, "model", None),
                        rule, None)
 
+    def _padded_up(self, bucket: int, valid: int) -> int:
+        """1 where the chunk ``(bucket, valid)`` is a prompt's tail in
+        one padded program of a larger bucket than the greedy step
+        takes (:func:`ops.chunked_prefill.plan_chunks`), else 0."""
+        from triton_dist_tpu.ops.chunked_prefill import padded_up
+
+        return int(padded_up(bucket, valid,
+                             self._prefiller.chunker.buckets))
+
     def _walk_kernel(self, bucket: int) -> int:
         """1 where a chunk program of ``bucket`` rows walks its context
         in the model's Pallas kernel (``chunk_walk_impl``:
@@ -2171,6 +2183,9 @@ class ServingEngine:
             self._walk_kernel(bucket))
         self.stats_counters["chunk_dispatches_kernel_scan"] += (
             self._scan_kernel(bucket))
+        self.stats_counters["chunk_dispatches_padded_up"] += (
+            self._padded_up(bucket, valid))
+        self.stats_counters["prefill_rows_padded"] += bucket - valid
         self.stats_counters["prefill_tokens"] += valid
         if start == 0 and self._seq_state_bytes:
             # The program zeroed the slot's state before its first row.
@@ -2207,7 +2222,8 @@ class ServingEngine:
         if not h.tokens:
             picked, logits = last
             with self.obs.span("prefill_fetch", slot=slot,
-                               request_id=h.request.request_id) as span:
+                               request_id=h.request.request_id,
+                               chunks=len(h.chunks)) as span:
                 if picked is None or h.request.temperature > 0.0:
                     row = self._read(logits)
                 else:
