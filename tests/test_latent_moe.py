@@ -237,22 +237,31 @@ _ROUTINGS = {        # name: (held pairs, token indices -> (T, 2) experts)
 }
 
 
+@pytest.mark.parametrize("impl, f, noise", [("xla", 16, 16),
+                                            ("kernel", 128, 64)])
 @pytest.mark.parametrize("routing", list(_ROUTINGS))
-def test_the_held_experts_take_their_pairs_in_passes(routing):
+def test_the_held_experts_take_their_pairs_in_passes(routing, impl, f,
+                                                     noise):
     """``fwd_held`` equals the all-pairs form at every routing, with no
     pair dropped when a pass cannot hold them all, and counts what it
     did: ``(held pairs, the fullest expert's rows, passes)``. The pass
     is small because few of many experts are held (the shape rule), not
-    because anything was set."""
-    t, f, e, n_held, first, topk = 512, 16, 64, 4, 4, 2
+    because anything was set. In both forms of a pass
+    (``ep_moe.experts_impl``, by sizes): the ragged products over the
+    sorted rows at ``d`` 80 and ``f`` 16, the Pallas kernel over the
+    expert-major layout (interpreted) at whole lanes, 128 and 128: a
+    pass of one row tile laid out in 1 + 4."""
+    t, e, n_held, first, topk = 512, 64, 4, 4, 2
     rows = ep_moe.held_pass_rows(t, topk, n_held, e)
     assert rows == 128 < t * topk
+    assert ep_moe.experts_impl(rows, n_held, e + noise, f,
+                               jnp.float32) == impl
     held_pairs, experts_of = _ROUTINGS[routing]
     ids = experts_of(np.arange(t))
     rng = np.random.default_rng(5)
     # The router reads a token's scores off its first 64 values: the
-    # two chosen experts stand out, the rest is noise like the other 16.
-    x = rng.normal(size=(t, e + 16)).astype(np.float32)
+    # two chosen experts stand out, the rest is noise like the others.
+    x = rng.normal(size=(t, e + noise)).astype(np.float32)
     x[np.arange(t), ids[:, 0]] = 9.0
     x[np.arange(t), ids[:, 1]] = 8.0
     d = x.shape[1]
@@ -536,11 +545,52 @@ def test_the_server_counts_its_chunk_dispatches_by_their_walk(legal):
     st = srv.stats()
     assert st["chunk_dispatches_kernel_walk"] == st["prefill_chunks"] == 4
     assert st["chunk_dispatches_kernel_scan"] == 0      # no such layer
+    assert st["chunk_dispatches_kernel_experts"] == 0   # 64 and 32 wide
     assert st["attn_impl"] == st["chunk_attn"] == "ref"
     chunks = [e.attrs for e in srv.obs.log.spans()
               if e.kind == "prefill_chunk"]
-    assert sorted((a["bucket"], a["walk_kernel"]) for a in chunks) == [
-        (128, 1), (128, 1), (128, 1), (256, 1)]
+    assert sorted((a["bucket"], a["walk_kernel"], a["experts_kernel"])
+                  for a in chunks) == [
+        (128, 1, 0), (128, 1, 0), (128, 1, 0), (256, 1, 0)]
+
+
+def test_the_server_counts_its_chunk_dispatches_by_their_experts(tiny):
+    """The tiny preset with a model width and experts of whole lanes
+    (128 and 128): every chunk program's pass is whole row tiles, so its
+    held experts run in the Pallas kernel (interpreted here),
+    ``chunk_dispatches_kernel_experts`` is ``prefill_chunks`` and every
+    ``prefill_chunk`` span says so; the tokens are those of the same
+    weights under the XLA form."""
+    config = dict(tiny[0], hidden_size=128, moe_intermediate_size=128,
+                  max_position_embeddings=512)
+    cfg, mesh = SYS.model_config(config), tiny[3]
+    params = SYS.make_params(config, mesh, SEED)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 256, size=n).tolist() for n in (150, 300)]
+
+    def serve():
+        eng = Engine(cfg, mesh, model=latent_moe, mode="xla",
+                     dtype=jnp.float32, max_len=512, params=params)
+        srv = eng.serving(num_slots=2, page=8, prefill_buckets=(128, 256),
+                          telemetry="spans")
+        return srv, srv.generate(prompts, max_new_tokens=3)
+
+    assert [latent_moe.experts_impl(cfg, rows, jnp.float32)
+            for rows in (130, 258, 2)] == ["kernel", "kernel", "xla"]
+    srv, out = serve()
+    st = srv.stats()
+    assert st["chunk_dispatches_kernel_experts"] == st["prefill_chunks"] == 4
+    assert st["chunk_dispatches_kernel_walk"] == 0      # pages of 8
+    assert st["expert_pairs_held"] > 0
+    assert {(e.attrs["bucket"], e.attrs["experts_kernel"])
+            for e in srv.obs.log.spans() if e.kind == "prefill_chunk"} == {
+        (128, 1), (256, 1)}
+    asked = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ep_moe, "experts_impl",
+                   lambda *a: asked.append(a) or "xla")
+        assert serve()[1] == out
+    assert asked                      # a new engine traces its programs
 
 
 def test_what_the_latent_pool_does_not_do_is_refused(tiny):
@@ -711,12 +761,19 @@ def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
         p_max=p_max)
     text = lowered.as_text()
     assert text.count('kernel_name = "latent_flash_qblock"') == 1
+    # ... and so do the held experts (PR 50: whole row tiles, a width
+    # and an f of whole lanes), once for the six layers too.
+    assert latent_moe.experts_impl(cfg, rows + slots * (
+        program[:5] == "fused"), jnp.bfloat16) == "kernel"
+    assert text.count('kernel_name = "grouped_mlp_tiles"') == 1
     # ... inside ONE lowered function of a layer, called six times (a
     # program is traced and lowered at every start: PERF.md, PR 39).
     assert len(re.findall(r"func\.func private @layer\(", text)) == 1
     assert len(re.findall(r"call @layer\(", text)) == 6
     hlo = compiled.as_text()
     assert len(re.findall(r"%latent_flash_qblock(\.\d+)? = ", hlo)) == 6
+    assert len(re.findall(r"%grouped_mlp_tiles(\.\d+)? = ", hlo)) == 6
+    assert "ragged-dot" not in hlo
     assert f"f32[32,{rows},{latent_moe.BLOCK_KEYS}]" not in hlo
     assert (compiled.memory_analysis().temp_size_in_bytes
             < 32 * 2048 * latent_moe.BLOCK_KEYS * 4)
@@ -819,16 +876,19 @@ def test_compiled_state_space_steps_keep_pool_and_state_in_place(
     assert "f32[4,128,128,128]" not in hlo
 
 
-def _all_equations(jaxpr):
+def _all_equations(jaxpr, into_kernels=True):
     """Every equation of ``jaxpr`` and of every jaxpr under it (loop and
-    branch bodies)."""
+    branch bodies; a Pallas kernel's body unless ``into_kernels`` is
+    False)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _all_equations(sub)
+                    yield from _all_equations(sub, into_kernels)
 
 
 def _equations(jaxpr) -> int:
@@ -892,22 +952,30 @@ def test_the_scan_kernels_traced_body_stays_small(rows):
     assert _equations(bodies[0]) <= 200
 
 
-@pytest.mark.parametrize("t,n_held,passes_over_all", [
-    (2064, 32, False), (528, 32, False), (16, 32, True), (2064, 128, True)])
+@pytest.mark.parametrize("t,n_held,passes_over_all,impl", [
+    (2064, 32, False, "kernel"), (528, 32, False, "kernel"),
+    (16, 32, True, "xla"), (2064, 128, True, "xla")])
 def test_the_held_experts_lay_out_held_pairs_only(t, n_held,
-                                                  passes_over_all):
+                                                  passes_over_all, impl):
     """The expert block at the sizes of ``mistral-small-4-1chip`` (d
     4096, f 2048, 4 of 128 a token; trace only, nothing runs): ONE body
-    a layer, so three grouped products and not six (a step program is
-    traced and lowered at every start), and no array of ``T * topk`` rows
-    by ``d`` or by ``f`` where a pass is smaller than every pair. The
-    pass follows the shapes: a share of the pairs with a margin for the
-    two chunk programs, every pair for the 16 decode rows and for a
-    layer that holds every expert."""
+    a layer (a step program is traced and lowered at every start), and
+    no array of ``T * topk`` rows by ``d`` or by ``f`` where a pass is
+    smaller than every pair. The pass follows the shapes: a share of
+    the pairs with a margin for the two chunk programs, every pair for
+    the 16 decode rows and for a layer that holds every expert. What a
+    pass runs follows them too (``ep_moe.experts_impl``): under
+    ``"xla"`` three grouped products of the pass's rows and not six;
+    under ``"kernel"`` ONE ``pallas_call`` and no grouped product, and
+    outside it nothing wider than the pass's layout, ``rows / 128 +
+    held`` tiles of 128 rows: fewer rows than the pairs in the 2048-row
+    program, and in the 512-row one the 39 tiles its 32 experts may
+    need."""
     d, f, topk, e = 4096, 2048, 4, 128
     pairs = t * topk
     rows = ep_moe.held_pass_rows(t, topk, n_held, e)
     assert (rows == pairs) == passes_over_all
+    assert ep_moe.experts_impl(rows, n_held, d, f, jnp.bfloat16) == impl
     if not passes_over_all:
         # The share with its margin, in an odd number of 128-row tiles.
         assert rows % 256 == 128
@@ -919,17 +987,26 @@ def test_the_held_experts_lay_out_held_pairs_only(t, n_held,
               "w_shared_down": s((f, d), bf)}
     closed = jax.make_jaxpr(lambda p, x: ep_moe.fwd_held(
         p, x, topk=topk, routed_scale=1.0))(params, s((t, d), bf))
-    eqns = list(_all_equations(closed.jaxpr))
+    eqns = list(_all_equations(closed.jaxpr, into_kernels=False))
     products = [q for q in eqns
                 if q.primitive.name.startswith("ragged_dot")]
-    assert len(products) == 3
-    assert sorted(q.outvars[0].aval.shape for q in products) == [
-        (rows, f), (rows, f), (rows, d)]
+    kernels = [q for q in eqns if q.primitive.name == "pallas_call"]
+    if impl == "kernel":
+        laid_out = rows + 128 * n_held
+        assert (len(products), len(kernels)) == (0, 1)
+        assert [v.aval.shape for v in kernels[0].outvars] == [(laid_out, d)]
+        assert (laid_out < pairs) == (t == 2064)
+    else:
+        laid_out = rows
+        assert (len(products), len(kernels)) == (3, 0)
+        assert sorted(q.outvars[0].aval.shape for q in products) == [
+            (rows, f), (rows, f), (rows, d)]
     if not passes_over_all:
         wide = [v.aval.shape for q in eqns for v in q.outvars
                 if getattr(v.aval, "ndim", 0) >= 2
                 and v.aval.shape[-1] in (d, f)
-                and int(np.prod(v.aval.shape[:-1])) >= pairs]
+                and int(np.prod(v.aval.shape[:-1])) > max(
+                    laid_out, pairs - 1)]
         assert wide == []
 
 

@@ -706,11 +706,18 @@ def test_the_server_counts_its_chunk_dispatches_by_their_scan(tiny):
     """A configuration the scan kernel tiles (interpreted here) runs it
     in every chunk program: ``chunk_dispatches_kernel_scan`` is
     ``prefill_chunks`` and every ``prefill_chunk`` span says so, while
-    the tiny file's sizes fall to the XLA form and count none."""
+    the tiny file's sizes fall to the XLA form and count none. The same
+    for the held experts' kernel (``chunk_dispatches_kernel_experts``,
+    ``experts_kernel``): a latent and experts of whole lanes, and
+    programs of 128 + 2 and 256 + 2 rows whose pass is three row
+    tiles."""
     cfg = ModelConfig.tiny_mamba_moe(
-        num_hidden_layers=2, layer_pattern="M*", mamba_num_heads=KH,
+        num_hidden_layers=3, layer_pattern="M*E", mamba_num_heads=KH,
         mamba_head_dim=KP, mamba_n_groups=KG, ssm_state_size=KN,
-        mamba_chunk_size=128, max_position_embeddings=512)
+        mamba_chunk_size=128, max_position_embeddings=512,
+        moe_latent_size=128, moe_intermediate_size=128)
+    assert [mamba_moe.experts_impl(cfg, rows, jnp.float32)
+            for rows in (130, 258, 2)] == ["kernel", "kernel", "xla"]
     mesh = tdt.make_mesh(tp=1, devices=jax.devices()[:1])
     params = mamba_moe.init_params(jax.random.PRNGKey(3), cfg)
     rng = np.random.default_rng(4)
@@ -726,18 +733,22 @@ def test_the_server_counts_its_chunk_dispatches_by_their_scan(tiny):
     srv, out = serve()
     st = srv.stats()
     assert st["chunk_dispatches_kernel_scan"] == st["prefill_chunks"] == 4
+    assert st["chunk_dispatches_kernel_experts"] == 4
     assert st["chunk_dispatches_kernel_walk"] == 0
+    assert st["expert_pairs_held"] > 0
     chunks = [e.attrs for e in srv.obs.log.spans()
               if e.kind == "prefill_chunk"]
-    assert sorted((a["bucket"], a["scan_kernel"], a["walk_kernel"])
-                  for a in chunks) == [(128, 1, 0), (128, 1, 0),
-                                       (128, 1, 0), (256, 1, 0)]
+    assert sorted((a["bucket"], a["scan_kernel"], a["experts_kernel"],
+                   a["walk_kernel"]) for a in chunks) == [
+        (128, 1, 1, 0), (128, 1, 1, 0), (128, 1, 1, 0), (256, 1, 1, 0)]
     _, _, tiny_cfg, tiny_mesh, tiny_params = tiny
     small, _ = _serve(tiny_cfg, tiny_mesh, tiny_params, [prompts[0][:21]])
     assert small.stats()["prefill_chunks"] > small.stats()[
-        "chunk_dispatches_kernel_scan"] == 0
-    assert {e.attrs["scan_kernel"] for e in small.obs.log.spans()
-            if e.kind == "prefill_chunk"} == {0}
+        "chunk_dispatches_kernel_scan"] == small.stats()[
+        "chunk_dispatches_kernel_experts"] == 0
+    assert {(e.attrs["scan_kernel"], e.attrs["experts_kernel"])
+            for e in small.obs.log.spans()
+            if e.kind == "prefill_chunk"} == {(0, 0)}
 
 
 @pytest.mark.parametrize("knob, what", [

@@ -44,8 +44,10 @@ from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops.ep_a2a import (EPContext, EP2DContext,
                                         ep_dispatch, ep_combine)
 from triton_dist_tpu.ops.ep_fused import EPFusedContext, ep_moe_fused
-from triton_dist_tpu.ops.group_gemm import (grouped_relu2, grouped_swiglu,
-                                            sort_by_expert, sort_pairs,
+from triton_dist_tpu.ops.group_gemm import (grouped_mlp_tiles,
+                                            grouped_relu2, grouped_swiglu,
+                                            mlp_tiles, sort_by_expert,
+                                            sort_pairs, tile_layout,
                                             window_group_sizes)
 
 DECODE_TRANSPORTS = ("ar", "ragged", "ll", "ll2d", "auto")
@@ -214,6 +216,23 @@ def pad_expert_width(w_up, w_down, *more_up):
     return (ups[0], jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))) + ups[1:]
 
 
+def experts_impl(rows: int, n_held: int, d: int, f: int, dtype) -> str:
+    """What runs the held experts' MLP over a pass of ``rows`` sorted
+    rows (:func:`held_pass_rows`) in :func:`fwd_held`: ``"kernel"``
+    (:func:`~triton_dist_tpu.ops.group_gemm.grouped_mlp_tiles` over the
+    expert-major layout of :func:`~triton_dist_tpu.ops.group_gemm
+    .tile_layout`) where the pass is whole row tiles and the experts'
+    ``d`` and ``f`` tile for Mosaic, else ``"xla"``
+    (:func:`~triton_dist_tpu.ops.group_gemm.grouped_swiglu` /
+    ``grouped_relu2``, which stay the definition). ``d`` is the width
+    the routed experts work at, the latent's where there is one. A pure
+    function of sizes: the same program on a chip and, interpreted, off
+    it; the serving engine counts its chunk dispatches by it."""
+    ok = n_held > 0 and mlp_tiles(rows, d, f,
+                                  jnp.dtype(dtype).itemsize) is not None
+    return "kernel" if ok else "xla"
+
+
 def fwd_held(params, x, *, topk: int, first: int = 0,
              norm_topk_prob: bool = True, routed_scale: float = 1.0,
              scoring: str = "softmax", act: str = "swiglu"):
@@ -234,6 +253,18 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
     float32, to its token's result. ``ceil(held / C)`` passes run: one
     at an even routing, one more over the overflow of a skewed chunk,
     none where nothing was held. No pair is dropped at any routing.
+
+    What a pass does between its gather and its combine is chosen by
+    :func:`experts_impl` from sizes alone. ``"xla"``: the rows in sorted
+    order through ``grouped_swiglu`` / ``grouped_relu2``, three (two)
+    ragged products. ``"kernel"``: the rows gathered into an
+    expert-major layout whose 128-row tiles belong to one expert each
+    (``tile_layout``: ``C / 128 + E_held`` tiles, built from the pass's
+    group sizes without a scatter), through ONE Pallas kernel
+    (``grouped_mlp_tiles``) that reads an expert's matrices once a tile
+    and keeps the activation in VMEM; the combine reads a pair's result
+    at its layout row. The same sums in the same precision either way
+    (bf16 operands, float32 sums, the activation rounded once).
 
     The router is :func:`route`'s (``scoring``; its selection bias is
     the layer's ``router_bias`` leaf, where it has one). ``act`` is the
@@ -260,25 +291,20 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
                              norm_topk_prob=norm_topk_prob,
                              scoring=scoring,
                              bias=params.get("router_bias"))
-    if act == "swiglu":
-        def experts(rows_in, sizes):
-            return grouped_swiglu(rows_in, params["w_gate"],
-                                  params["w_up"], params["w_down"], sizes)
-    elif act == "relu2":
-        def experts(rows_in, sizes):
-            return grouped_relu2(rows_in, params["w_up"],
-                                 params["w_down"], sizes)
-    else:
+    if act not in ("swiglu", "relu2"):
         raise ValueError(f"act={act!r}: 'swiglu' | 'relu2'")
+    w_gate = params["w_gate"] if act == "swiglu" else None
     u = x
     if "w_latent_in" in params:
         with scope("expert_latent"):
             u = jnp.dot(x, params["w_latent_in"])
     with scope("experts"):
         rows = held_pass_rows(t, topk, n_held, params["router"].shape[1])
+        kernel = experts_impl(rows, n_held, u.shape[1], f,
+                              u.dtype) == "kernel"
         local = topk_ids - first
-        flat = jnp.where((local >= 0) & (local < n_held), local,
-                         -1).reshape(-1)
+        held = (local >= 0) & (local < n_held)
+        flat = jnp.where(held, local, -1).reshape(-1)
         # Held pairs come first in ``order``; ``place`` is a pair's row
         # in it, under ``n_pairs`` for a held pair and for no other.
         order, group_sizes, place = sort_pairs(flat, n_held)
@@ -290,15 +316,34 @@ def fwd_held(params, x, *, topk: int, first: int = 0,
 
         def one_pass(i, out):
             lo = i * rows
-            tokens = jax.lax.div(
-                jax.lax.dynamic_slice(order, (lo,), (rows,)), topk)
-            y = experts(u.at[tokens].get(mode="promise_in_bounds"),
-                        window_group_sizes(group_sizes, lo, rows))
-            # Rows past the pass's last group are whatever the grouped
-            # product left there: selected away, not multiplied by zero.
+            window = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            sizes = window_group_sizes(group_sizes, lo, rows)
             at = place - lo
             here = (at >= 0) & (at < rows) & (place < n_pairs)
-            at = jnp.clip(at, 0, rows - 1)
+            if kernel:
+                # A tile's rows are one expert's: a pair's row follows
+                # from its expert's first tile and first window row.
+                tile_expert, n_used, src, shift = tile_layout(sizes, rows)
+                window = window.at[jnp.maximum(src, 0)].get(
+                    mode="promise_in_bounds")
+                at = at + jnp.sum(jnp.where(
+                    local[..., None] == jnp.arange(n_held), shift, 0), -1)
+            tokens = jax.lax.div(window, topk)
+            rows_in = u.at[tokens].get(mode="promise_in_bounds")
+            if kernel:
+                y = grouped_mlp_tiles(rows_in, params["w_up"],
+                                      params["w_down"], tile_expert,
+                                      n_used, w_gate=w_gate, act=act)
+            elif act == "swiglu":
+                y = grouped_swiglu(rows_in, w_gate, params["w_up"],
+                                   params["w_down"], sizes)
+            else:
+                y = grouped_relu2(rows_in, params["w_up"],
+                                  params["w_down"], sizes)
+            # Rows past the pass's last group (and a tile's rows past
+            # its expert's last) are whatever the product left there:
+            # selected away, not multiplied by zero.
+            at = jnp.clip(at, 0, y.shape[0] - 1)
             for k in range(topk):
                 mine = y.at[at[:, k]].get(mode="promise_in_bounds")
                 out = out + jnp.where(

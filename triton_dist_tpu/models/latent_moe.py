@@ -293,6 +293,18 @@ def chunk_walk_impl(cfg: ModelConfig, rows: int, page: int) -> str:
     return "kernel" if ok else "xla"
 
 
+def experts_impl(cfg: ModelConfig, rows: int, dtype) -> str:
+    """What runs the held experts' MLP in a step program of ``rows``
+    rows, ``"kernel"`` or ``"xla"``: :func:`ep_moe.experts_impl` at the
+    pass :func:`ep_moe.held_pass_rows` gives them and this model's
+    sizes. The serving engine counts its chunk dispatches by it."""
+    n_held = cfg.held_experts
+    return ep_moe.experts_impl(
+        ep_moe.held_pass_rows(rows, cfg.num_experts_per_tok, n_held,
+                              cfg.num_experts),
+        n_held, cfg.hidden_size, cfg.moe_intermediate_size, dtype)
+
+
 @scope("attn_chunk")
 def _attend_chunk(attn, q, cache, li, table_row, qpos, cfg):
     """A chunk's rows over their slot's pages, by

@@ -256,6 +256,23 @@ def chunk_scan_impl(cfg: ModelConfig, rows: int) -> str:
         cfg.ssm_state_size, cfg.mamba_chunk_size)
 
 
+def experts_impl(cfg: ModelConfig, rows: int, dtype) -> str:
+    """What runs the held experts' MLP in every ``E`` layer of a step
+    program of ``rows`` rows, ``"kernel"`` or ``"xla"``:
+    :func:`ep_moe.experts_impl` at the pass
+    :func:`ep_moe.held_pass_rows` gives them, in the latent and at the
+    width the experts are stored at. The serving engine counts its
+    chunk dispatches by it."""
+    if "E" not in cfg.layer_pattern:
+        return "xla"
+    n_held = cfg.held_experts
+    return ep_moe.experts_impl(
+        ep_moe.held_pass_rows(rows, cfg.num_experts_per_tok, n_held,
+                              cfg.num_experts),
+        n_held, cfg.moe_latent_size,
+        ep_moe.expert_store_width(cfg.moe_intermediate_size), dtype)
+
+
 def _mamba_chunk(mp, z, xbc, dt, state, tail, cfg: ModelConfig, valid):
     """A chunk's rows of ONE sequence. ``state`` (H, P, N) float32 and
     ``tail`` (taps - 1, W) as the rows before left them. Returns ``(y

@@ -57,8 +57,9 @@ _OP_HIST_KINDS = frozenset({
 # the correlation keys, and the counts read there (``batch`` of a
 # decode and whether it rode a chunk program, ``fused`` 0/1; whether a
 # chunk's rows walk their context in a Pallas kernel, ``walk_kernel``
-# 0/1, and whether its state-space layers scan them in one,
-# ``scan_kernel`` 0/1; whether it is a prompt's tail in one padded
+# 0/1, whether its state-space layers scan them in one,
+# ``scan_kernel`` 0/1, and whether its held experts' MLP is one,
+# ``experts_kernel`` 0/1; whether it is a prompt's tail in one padded
 # program of a larger bucket than the greedy step takes, ``padded_up``
 # 0/1, and on ``prefill_fetch`` the programs the prompt took,
 # ``chunks``; ``waited_ms`` of an admission; what an
@@ -68,7 +69,7 @@ _OP_HIST_KINDS = frozenset({
 # are on the host, so set while the span is open).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
                         "bucket", "valid", "walk_kernel", "scan_kernel",
-                        "padded_up", "chunks",
+                        "experts_kernel", "padded_up", "chunks",
                         "waited_ms", "passes", "exit_pass",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",

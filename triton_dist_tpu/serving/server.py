@@ -483,7 +483,8 @@ class ServingEngine:
             "chunk_dispatches_parked": 0,
             "chunk_dispatches_padded_up": 0, "prefill_rows_padded": 0,
             "chunk_dispatches_kernel_walk": 0,
-            "chunk_dispatches_kernel_scan": 0, "migrated_pages": 0,
+            "chunk_dispatches_kernel_scan": 0,
+            "chunk_dispatches_kernel_experts": 0, "migrated_pages": 0,
             "spec_drafted": 0, "spec_accepted": 0,
             "spec_sampled_fallbacks": 0,
             "greedy_agree_tokens": 0, "greedy_ref_tokens": 0,
@@ -520,6 +521,7 @@ class ServingEngine:
         # Whether this engine's own chunker carries the decode batch
         # (set where the layer path builds it; never by the caller).
         self._rides = False
+        self._experts_kernel_of = {}      # bucket -> 0/1, by the rule
 
         if self.mega:
             # kv_dtype / spec_k are ENGINE knobs on the megakernel lane
@@ -2056,6 +2058,7 @@ class ServingEngine:
         bucket, valid = p.chunker.next_chunk(len(seq) - start)
         walk_kernel = self._walk_kernel(bucket)
         scan_kernel = self._scan_kernel(bucket)
+        experts_kernel = self._experts_kernel(bucket)
         passes = ({"passes": self.cfg.num_passes} if self._row_stats
                   else {})
         toks = np.zeros((bucket,), np.int32)
@@ -2077,7 +2080,8 @@ class ServingEngine:
                                valid=int(valid),
                                padded_up=self._padded_up(bucket, valid),
                                walk_kernel=walk_kernel,
-                               scan_kernel=scan_kernel, **passes), \
+                               scan_kernel=scan_kernel,
+                               experts_kernel=experts_kernel, **passes), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
@@ -2159,6 +2163,21 @@ class ServingEngine:
         return int(impl is not None and impl(
             self._prefiller.engine.cfg, int(bucket)) == "kernel")
 
+    def _experts_kernel(self, bucket: int) -> int:
+        """1 where a chunk program of ``bucket`` rows (and the decode
+        rows it carries) runs its held experts' MLP in the Pallas
+        kernel (``experts_impl``: ``models.latent_moe``,
+        ``models.mamba_moe``), else 0. Host arithmetic."""
+        import jax
+
+        known = self._experts_kernel_of
+        if bucket not in known:       # twice a chunk, in the host's turn
+            p, impl = self._prefiller, self._model_rule("experts_impl")
+            known[bucket] = int(impl is not None and impl(
+                p.engine.cfg, int(bucket) + p.chunker.decode_rows,
+                jax.tree.leaves(p.engine.params)[0].dtype) == "kernel")
+        return known[bucket]
+
     def _chunk_failed(self, h: RequestHandle, e):
         """A chunk was wedged or dropped past its retries. A dying
         prefill worker fails over (``h`` requeues with the rest of its
@@ -2183,6 +2202,8 @@ class ServingEngine:
             self._walk_kernel(bucket))
         self.stats_counters["chunk_dispatches_kernel_scan"] += (
             self._scan_kernel(bucket))
+        self.stats_counters["chunk_dispatches_kernel_experts"] += (
+            self._experts_kernel(bucket))
         self.stats_counters["chunk_dispatches_padded_up"] += (
             self._padded_up(bucket, valid))
         self.stats_counters["prefill_rows_padded"] += bucket - valid
