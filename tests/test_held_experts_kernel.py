@@ -212,8 +212,16 @@ def test_the_experts_form_is_a_pure_function_of_sizes(sizes, impl):
     assert ep_moe.experts_impl(*sizes) == impl
 
 
+def _experts_form(model, cfg, rows, dtype):
+    """What ``model.step_kernels`` states of the held experts' MLP in a
+    program of ``rows`` rows in all."""
+    ran = model.step_kernels(cfg, rows, decode_rows=0, page=128,
+                             dtype=dtype)
+    return "kernel" if "experts" in ran else "xla"
+
+
 def test_the_models_state_the_experts_form_for_their_sizes():
-    """``latent_moe.experts_impl`` and ``mamba_moe.experts_impl`` are
+    """``latent_moe.step_kernels`` and ``mamba_moe.step_kernels`` state
     the layer's rule at the model's sizes and the pass its rows give:
     the two ``longdocs`` configurations' chunk programs run the kernel,
     the CPU presets' narrow experts and a pattern with no ``E`` layer
@@ -222,22 +230,26 @@ def test_the_models_state_the_experts_form_for_their_sizes():
     mistral = ModelConfig.tiny_latent_moe(
         hidden_size=4096, moe_intermediate_size=2048, num_experts=128,
         num_experts_per_tok=4, num_held_experts=32)
-    assert [latent_moe.experts_impl(mistral, r, bf)
+    assert [_experts_form(latent_moe, mistral, r, bf)
             for r in (2064, 528, 2048, 16)] == ["kernel"] * 3 + ["xla"]
+    # The decode rows aboard count: 2048 + 16 is 2064 above.
+    assert "experts" in latent_moe.step_kernels(
+        mistral, 2048, decode_rows=16, page=128, dtype=bf)
     nemotron = ModelConfig.tiny_mamba_moe(
         moe_latent_size=1024, moe_intermediate_size=2688, num_experts=512,
         num_experts_per_tok=22, num_held_experts=128)
     assert ep_moe.expert_store_width(2688) == 3072
     # 16 decode rows' 352 pairs go in passes of ONE row tile: whole tiles.
     assert ep_moe.held_pass_rows(16, 22, 128, 512) == 128
-    assert [mamba_moe.experts_impl(nemotron, r, bf)
+    assert [_experts_form(mamba_moe, nemotron, r, bf)
             for r in (2064, 528, 16, 2)] == ["kernel"] * 3 + ["xla"]
     for rows in (8, 16, 128, 2064):
-        assert latent_moe.experts_impl(
-            ModelConfig.tiny_latent_moe(), rows, jnp.float32) == "xla"
-        assert mamba_moe.experts_impl(
-            ModelConfig.tiny_mamba_moe(), rows, jnp.float32) == "xla"
-    assert mamba_moe.experts_impl(
+        assert _experts_form(latent_moe, ModelConfig.tiny_latent_moe(),
+                             rows, jnp.float32) == "xla"
+        assert _experts_form(mamba_moe, ModelConfig.tiny_mamba_moe(),
+                             rows, jnp.float32) == "xla"
+    assert _experts_form(
+        mamba_moe,
         ModelConfig.tiny_mamba_moe(
             layer_pattern="M*", num_hidden_layers=2, moe_latent_size=1024,
             moe_intermediate_size=3072, num_experts=512,

@@ -467,14 +467,21 @@ def legal(tiny):
             SYS.make_params(config, mesh, SEED))
 
 
+def _stated(model, cfg, rows, page=128, dtype=jnp.float32):
+    """What ``model.step_kernels`` states for a program of ``rows`` rows
+    in all."""
+    return model.step_kernels(cfg, rows, decode_rows=0, page=page,
+                              dtype=dtype)
+
+
 def test_the_chunk_walk_is_chosen_by_sizes(tiny, legal):
     cfg, big = tiny[2], legal[2]
-    assert latent_moe.chunk_walk_impl(cfg, 16, 8) == "xla"
-    assert latent_moe.chunk_walk_impl(cfg, 128, 128) == "xla"   # heads
-    assert latent_moe.chunk_walk_impl(big, 128, 128) == "kernel"
-    assert latent_moe.chunk_walk_impl(big, 256, 128) == "kernel"
-    assert latent_moe.chunk_walk_impl(big, 128, 8) == "xla"     # pages
-    assert latent_moe.chunk_walk_impl(big, 72, 128) == "xla"    # rows
+    assert "walk" not in _stated(latent_moe, cfg, 16, 8)
+    assert "walk" not in _stated(latent_moe, cfg, 128, 128)     # heads
+    assert "walk" in _stated(latent_moe, big, 128, 128)
+    assert "walk" in _stated(latent_moe, big, 256, 128)
+    assert "walk" not in _stated(latent_moe, big, 128, 8)       # pages
+    assert "walk" not in _stated(latent_moe, big, 72, 128)      # rows
     with pytest.raises(ValueError, match="the model's own paths"):
         latent_moe.prefill_chunk_paged(
             None, jnp.zeros((128,), jnp.int32), None, None, big, start=0,
@@ -575,8 +582,8 @@ def test_the_server_counts_its_chunk_dispatches_by_their_experts(tiny):
                           telemetry="spans")
         return srv, srv.generate(prompts, max_new_tokens=3)
 
-    assert [latent_moe.experts_impl(cfg, rows, jnp.float32)
-            for rows in (130, 258, 2)] == ["kernel", "kernel", "xla"]
+    assert ["experts" in _stated(latent_moe, cfg, rows, 8)
+            for rows in (130, 258, 2)] == [True, True, False]
     srv, out = serve()
     st = srv.stats()
     assert st["chunk_dispatches_kernel_experts"] == st["prefill_chunks"] == 4
@@ -755,7 +762,7 @@ def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
         moe_intermediate_size=256, shared_expert_intermediate_size=256,
         rope_factor=128.0, rope_original_max_position=8192,
         rope_beta_fast=32.0)
-    assert latent_moe.chunk_walk_impl(cfg, rows, 128) == "kernel"
+    assert "walk" in _stated(latent_moe, cfg, rows)
     lowered, compiled, pool_shape = _compile_step(
         v5e, cfg, program, pages=slots * p_max + 1, page=128, slots=slots,
         p_max=p_max)
@@ -763,8 +770,9 @@ def test_compiled_chunk_walks_its_context_in_one_kernel(v5e, mosaic,
     assert text.count('kernel_name = "latent_flash_qblock"') == 1
     # ... and so do the held experts (PR 50: whole row tiles, a width
     # and an f of whole lanes), once for the six layers too.
-    assert latent_moe.experts_impl(cfg, rows + slots * (
-        program[:5] == "fused"), jnp.bfloat16) == "kernel"
+    assert "experts" in latent_moe.step_kernels(
+        cfg, rows, decode_rows=slots * (program[:5] == "fused"), page=128,
+        dtype=jnp.bfloat16)
     assert text.count('kernel_name = "grouped_mlp_tiles"') == 1
     # ... inside ONE lowered function of a layer, called six times (a
     # program is traced and lowered at every start: PERF.md, PR 39).
@@ -869,7 +877,7 @@ def test_compiled_state_space_steps_keep_pool_and_state_in_place(
     if program == "decode":
         assert scans == [] and "mamba2_chunk_scan" not in lowered.as_text()
         return
-    assert mamba_moe.chunk_scan_impl(cfg, 512) == "kernel"
+    assert "scan" in _stated(mamba_moe, cfg, 512)
     assert lowered.as_text().count(
         'kernel_name = "mamba2_chunk_scan"') == 1
     assert len(scans) == 5 and all("tdt.ssm/" in s for s in scans)

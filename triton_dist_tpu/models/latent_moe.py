@@ -30,7 +30,7 @@ k_r]`` a token a layer, a page lying ``(width, page)``):
 Both walk the context in blocks of whole pages with a running softmax
 (no ``(rows, heads, context)`` score array exists) and stop at the last
 block any row can see. The chunk rows' walk is a Pallas kernel wherever
-its sizes tile (:func:`chunk_walk_impl`,
+its sizes tile (:func:`step_kernels`,
 :mod:`triton_dist_tpu.ops.latent_flash_qblock`), with the XLA walk below
 as its fallback and its oracle; the absorbed walk is plain XLA.
 
@@ -57,9 +57,9 @@ from triton_dist_tpu.layers import ep_moe
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.layers.rope import (apply_rope_interleaved,
                                          yarn_freqs, yarn_mscale)
+from triton_dist_tpu.models import paged_step
 from triton_dist_tpu.models.config import ModelConfig
-from triton_dist_tpu.models.dense import (FwdContexts, _embed_rows,
-                                          _last_valid_row, _lm_head)
+from triton_dist_tpu.models.dense import FwdContexts
 from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops import latent_flash_qblock as _flash
 
@@ -280,36 +280,39 @@ def _attend_expanded(attn, q, cache, li, table_row, qpos, cfg):
     return o.transpose(1, 0, 2).reshape(c, h * dv).astype(q.dtype)
 
 
-def chunk_walk_impl(cfg: ModelConfig, rows: int, page: int) -> str:
-    """What walks the context for a chunk of ``rows`` rows: ``"kernel"``
-    (:func:`~triton_dist_tpu.ops.latent_flash_qblock.latent_flash_qblock`)
-    where the pool's pages, the head sizes and the row count tile for
-    Mosaic, else ``"xla"`` (:func:`_attend_expanded`). A pure function
-    of sizes: the same program on a chip and, interpreted, off it; the
+def _walk_tiles(cfg: ModelConfig, rows: int, page: int) -> bool:
+    """Whether a chunk of ``rows`` rows walks its context in the Pallas
+    kernel (``latent_flash_qblock``: the pool's pages, the head sizes
+    and the row count tile for Mosaic) or in XLA
+    (:func:`_attend_expanded`). A pure function of sizes: the same
+    program on a chip and, interpreted, off it."""
+    return _flash.legal(rows, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                        cfg.v_head_dim, cfg.kv_lora_rank, cache_width(cfg),
+                        page)
+
+
+def step_kernels(cfg: ModelConfig, rows: int, *, decode_rows: int,
+                 page: int, dtype) -> tuple:
+    """The blocks, of ``paged_step.STEP_KERNELS``, that a chunk program
+    of ``rows`` chunk rows with ``decode_rows`` aboard runs in a Pallas
+    kernel: the chunk rows' walk (:func:`_walk_tiles`) and the held
+    experts' MLP, by :func:`ep_moe.experts_impl` at the pass
+    :func:`ep_moe.held_pass_rows` gives every row of the program. The
     serving engine counts its chunk dispatches by it."""
-    dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    ok = _flash.legal(rows, dqk, cfg.v_head_dim, cfg.kv_lora_rank,
-                      cache_width(cfg), page)
-    return "kernel" if ok else "xla"
-
-
-def experts_impl(cfg: ModelConfig, rows: int, dtype) -> str:
-    """What runs the held experts' MLP in a step program of ``rows``
-    rows, ``"kernel"`` or ``"xla"``: :func:`ep_moe.experts_impl` at the
-    pass :func:`ep_moe.held_pass_rows` gives them and this model's
-    sizes. The serving engine counts its chunk dispatches by it."""
     n_held = cfg.held_experts
-    return ep_moe.experts_impl(
-        ep_moe.held_pass_rows(rows, cfg.num_experts_per_tok, n_held,
-                              cfg.num_experts),
+    experts = ep_moe.experts_impl(
+        ep_moe.held_pass_rows(rows + decode_rows, cfg.num_experts_per_tok,
+                              n_held, cfg.num_experts),
         n_held, cfg.hidden_size, cfg.moe_intermediate_size, dtype)
+    return (("walk",) * _walk_tiles(cfg, rows, page)
+            + ("experts",) * (experts == "kernel"))
 
 
 @scope("attn_chunk")
 def _attend_chunk(attn, q, cache, li, table_row, qpos, cfg):
     """A chunk's rows over their slot's pages, by
-    :func:`chunk_walk_impl`. Returns (C, H * d_v)."""
-    if chunk_walk_impl(cfg, q.shape[0], cache.page) == "xla":
+    :func:`_walk_tiles`. Returns (C, H * d_v)."""
+    if not _walk_tiles(cfg, q.shape[0], cache.page):
         return _attend_expanded(attn, q, cache, li, table_row, qpos, cfg)
     return _flash.latent_flash_qblock(
         q, cache.pages, table_row, qpos, _w_ukv(attn, cfg), layer=li,
@@ -355,18 +358,29 @@ def _attend_absorbed(attn, q, cache, li, qpos, cfg):
 
 # -- the layers ------------------------------------------------------------
 
-def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
-    """Every layer over ``x`` (n, d) at ``positions`` (n,).
-    ``attend(li, attn_params, q, latent, cache) -> (o (n, H * d_v),
-    cache)`` writes the rows' cache entries and reads what each row's
-    query sees; ``li`` is an int32 scalar, an operand. Returns ``(x
-    normed (n, d), cache, stats)``.
+def _layers(params, rows, cache, cfg: ModelConfig, *, mode, axis, attn_impl,
+            decode_attn_impl, ctxs: FwdContexts = FwdContexts()):
+    """The trunk every step of this family is built from
+    (:func:`paged_step.build`): ``rows`` embedded, (n, d), then every
+    layer over the latent pool's halves (:func:`_attend`), then the
+    final norm. Returns ``(x (n, d), cache, stats)``: ``STEP_STATS``.
 
-    The layer is ONE jitted function of its index and its parameters:
-    a step program is traced and lowered at every start of the server
-    (no compile cache keeps either), and six layers written out were
-    three quarters of both (PERF.md, PR 39). XLA inlines the calls; the
-    compiled program is the one the loop written out gave."""
+    The layer is ONE jitted function of its index (an int32 scalar, an
+    operand) and its parameters: a step program is traced and lowered at
+    every start of the server (no compile cache keeps either), and six
+    layers written out were three quarters of both (PERF.md, PR 39). XLA
+    inlines the calls; the compiled program is the one the loop written
+    out gave."""
+    for impl in (attn_impl, decode_attn_impl):
+        if impl != "ref":
+            raise ValueError(
+                f"attn_impl={impl!r}: latent attention takes 'ref' alone, "
+                "the model's own paths (the chunk rows' walk is chosen by "
+                "sizes: step_kernels)")
+    attend = _attend(rows, cfg)
+    x = paged_step.embed_rows(params, rows.tokens())
+    positions = rows.positions(cache)
+
     @jax.jit
     def layer(li, lp, x, cache, stats):
         with scope("attn_project"):
@@ -393,17 +407,6 @@ def _layers(params, x, positions, cache, cfg: ModelConfig, attend):
     return x, cache, stats
 
 
-def _check(attn_impl, mode):
-    if attn_impl != "ref":
-        raise ValueError(
-            f"attn_impl={attn_impl!r}: latent attention takes 'ref' "
-            "alone, the model's own paths (the chunk rows' walk is "
-            "chosen by sizes: chunk_walk_impl)")
-    if mode != "xla":
-        raise ValueError(f"mode={mode!r}: models.latent_moe has no fused "
-                         "collective layer; serve it with mode='xla'")
-
-
 def _chunk_qpos(positions, start, valid):
     """The last position each chunk row sees: its own, and for bucket
     padding the last valid row's, so that the walk stops there."""
@@ -419,120 +422,49 @@ def _decode_qpos(cache):
     return (jnp.maximum(cache.lens + cache.live, 1) - 1)[:, None]
 
 
-def prefill_chunk_paged(params, chunk_toks, cache, table_row,
-                        cfg: ModelConfig, *, start, wfrom, valid,
-                        mode: str = "xla", axis: str = "tp",
-                        ctxs: FwdContexts = FwdContexts(),
-                        attn_impl: str = "ref"):
-    """One fixed-shape chunk of a bucketed paged prefill
-    (:func:`models.dense.prefill_chunk_paged`'s contract). Returns
-    ``(logits (vocab,) of the last valid row, cache, stats)``."""
-    _check(attn_impl, mode)
-    c = chunk_toks.shape[0]
-    positions = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-    qpos = _chunk_qpos(positions, start, valid)
+def _attend(rows: paged_step.Rows, cfg: ModelConfig):
+    """The latent pool's halves: ``attend(li, attn_params, q, latent,
+    cache) -> (o (n, H * d_v), cache)`` writes one latent a row, then
+    the chunk's rows expand (:func:`_attend_chunk`) and the decode rows
+    absorb (:func:`_attend_absorbed`), both writes before both reads as
+    the K/V pool's (:func:`paged_step.kv_attend`); candidate ``j`` of a
+    verification row's slot sees its paged history and the candidates
+    through itself, in the latent."""
+    if rows.k:
+        s, k, steps = rows.s, rows.k, rows.steps
+
+        def attend(li, attn, q, latent, cache):
+            with scope("cache_write"):
+                cache = cache.append_block(li, latent.reshape(s, k, -1),
+                                           budget=rows.budget)
+            qpos = jnp.maximum(cache.lens[:, None] + cache.live[:, None]
+                               * (steps + 1), 1) - 1
+            return _attend_absorbed(attn, q.reshape((s, k) + q.shape[1:]),
+                                    cache, li, qpos, cfg), cache
+
+        return attend
+    pos = qpos = None
+    if rows.c:
+        pos = rows.chunk_pos
+        qpos = _chunk_qpos(pos, rows.start, rows.valid)
 
     def attend(li, attn, q, latent, cache):
         with scope("cache_write"):
-            cache = cache.write_chunk(li, latent, table_row, positions,
-                                      valid, wfrom)
-        return _attend_chunk(attn, q, cache, li, table_row, qpos,
-                             cfg), cache
+            _, cache = rows.split(
+                lambda cache, lat: (None, cache.write_chunk(
+                    li, lat, rows.table_row, pos, rows.valid, rows.wfrom)),
+                lambda cache, lat: (None, cache.append_decode(li, lat)),
+                cache, latent)
+        return rows.split(
+            lambda cache, q: (_attend_chunk(
+                attn, q, cache, li, rows.table_row, qpos, cfg), cache),
+            lambda cache, q: (_attend_absorbed(
+                attn, q, cache, li, _decode_qpos(cache), cfg), cache),
+            cache, q, per_slot=True)
 
-    x, cache, stats = _layers(params, _embed_rows(params, chunk_toks),
-                              positions, cache, cfg, attend)
-    logits = _lm_head(params, _last_valid_row(x, valid), axis)
-    return logits[0], cache, stats
-
-
-def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref"):
-    """One continuous-batching decode step
-    (:func:`models.dense.decode_step_paged`'s contract). Returns
-    ``(logits (S, vocab), cache.advance(), stats)``."""
-    _check(attn_impl, mode)
-
-    def attend(li, attn, q, latent, cache):
-        with scope("cache_write"):
-            cache = cache.append_decode(li, latent)
-        return _attend_absorbed(attn, q[:, None], cache, li,
-                                _decode_qpos(cache), cfg), cache
-
-    x, cache, stats = _layers(params, _embed_rows(params, token_ids),
-                              cache.lens, cache, cfg, attend)
-    return _lm_head(params, x, axis), cache.advance(), stats
+    return attend
 
 
-def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
-                       cfg: ModelConfig, *, start, wfrom, valid,
-                       mode: str = "xla", axis: str = "tp",
-                       ctxs: FwdContexts = FwdContexts(),
-                       attn_impl: str = "ref",
-                       decode_attn_impl: str = "ref"):
-    """A prefill chunk of one slot and a decode step of the batch in one
-    program (:func:`models.dense.chunk_decode_paged`'s contract): the
-    ``C + S`` rows share every projection and the expert layer; the
-    chunk's rows expand, the decode rows absorb. Returns ``(chunk
-    logits (vocab,), decode logits (S, vocab), cache.advance(),
-    stats)``."""
-    _check(attn_impl, mode)
-    _check(decode_attn_impl, mode)
-    c = chunk_toks.shape[0]
-    chunk_pos = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-    qpos = _chunk_qpos(chunk_pos, start, valid)
-
-    def attend(li, attn, q, latent, cache):
-        # Both writes, then both reads, as the dense program does.
-        with scope("cache_write"):
-            cache = cache.write_chunk(li, latent[:c], table_row,
-                                      chunk_pos, valid, wfrom)
-            cache = cache.append_decode(li, latent[c:])
-        o_chunk = _attend_chunk(attn, q[:c], cache, li, table_row, qpos,
-                                cfg)
-        o_dec = _attend_absorbed(attn, q[c:, None], cache, li,
-                                 _decode_qpos(cache), cfg)
-        return jnp.concatenate([o_chunk, o_dec]), cache
-
-    x, cache, stats = _layers(
-        params,
-        _embed_rows(params, jnp.concatenate([chunk_toks, token_ids])),
-        jnp.concatenate([chunk_pos, cache.lens]), cache, cfg, attend)
-    with scope("head"):
-        logits = _lm_head(params, jnp.concatenate(
-            [_last_valid_row(x[:c], valid), x[c:]]), axis)
-        chunk_logits, decode_logits = logits[0], logits[1:]
-    return chunk_logits, decode_logits, cache.advance(), stats
-
-
-def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      budget=None, mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref"):
-    """K candidate tokens a slot through one dispatch
-    (:func:`models.dense.verify_step_paged`'s contract): candidate ``j``
-    of a live slot sees its paged history and the candidates through
-    itself, in the latent. Returns ``(logits (S, K, vocab), cache)``,
-    lengths not advanced."""
-    _check(attn_impl, mode)
-    s, k = token_ids.shape
-    lens = cache.lens
-    steps = jnp.arange(k, dtype=jnp.int32)[None]
-    positions = (lens[:, None] + steps).reshape(s * k)
-
-    def attend(li, attn, q, latent, cache):
-        with scope("cache_write"):
-            cache = cache.append_block(li, latent.reshape(s, k, -1),
-                                       budget=budget)
-        qpos = jnp.maximum(
-            lens[:, None] + cache.live[:, None] * (steps + 1), 1) - 1
-        return _attend_absorbed(attn, q.reshape((s, k) + q.shape[1:]),
-                                cache, li, qpos, cfg), cache
-
-    x, cache, _ = _layers(params,
-                          _embed_rows(params, token_ids.reshape(-1)),
-                          positions, cache, cfg, attend)
-    return _lm_head(params, x, axis).reshape(s, k, -1), cache
+(prefill_chunk_paged, decode_step_paged, chunk_decode_paged,
+ verify_step_paged) = paged_step.build(
+    _layers, xla_only="models.latent_moe has no fused collective layer")

@@ -49,10 +49,9 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.layers import tp_attn, tp_mlp
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.models import dense as _dense
+from triton_dist_tpu.models import paged_step
 from triton_dist_tpu.models.config import ModelConfig
-from triton_dist_tpu.models.dense import (FwdContexts, _chunk_attend,
-                                          _decode_attend, _embed_rows,
-                                          _last_valid_row, _lm_head)
+from triton_dist_tpu.models.dense import FwdContexts
 from triton_dist_tpu.obs import scope
 
 # What every step returns last, one int32 a head row: the pass whose
@@ -136,14 +135,19 @@ prefill = decode_step = _paged_only
 
 # -- the passes --------------------------------------------------------------
 
-def _passes(params, x, positions, cache, cfg: ModelConfig, attend, *,
-            axis):
-    """Replicated rows ``x`` (n, d) at per-row ``positions`` through the
-    ``num_passes`` passes. ``attend(pool_layer, q, k_tok, v_tok, cache)
-    -> (o, cache)`` is what tells the steps apart, as in
-    :func:`models.dense._paged_layers`; ``pool_layer`` is an int32
-    scalar. Returns ``(h (n, d), exit_pass (n,) int32, cache)``: each
-    row's ``h_t`` at the pass it left by, already normed."""
+def _passes(params, rows, cache, cfg: ModelConfig, *, mode, axis, attn_impl,
+            decode_attn_impl, ctxs: FwdContexts = FwdContexts()):
+    """The trunk every step of this family is built from
+    (:func:`paged_step.build`): ``rows`` embedded, replicated (n, d),
+    through the ``num_passes`` passes over the K/V pool's halves
+    (:func:`paged_step.kv_attend`, the pool's layer an int32 scalar):
+    every weight is read once a PASS for the rows of both halves.
+    Returns ``(h (n, d), cache, exit_pass (n,) int32)``: each row's
+    ``h_t`` at the pass it left by, already normed, and that pass
+    (``ROW_STATS``)."""
+    attend = paged_step.kv_attend(rows, attn_impl, decode_attn_impl)
+    x = paged_step.embed_rows(params, rows.tokens())
+    positions = rows.positions(cache)
     n, eps = x.shape[0], cfg.rms_norm_eps
     n_layers, n_passes = cfg.num_hidden_layers, cfg.num_passes
     f32 = jnp.float32
@@ -195,96 +199,12 @@ def _passes(params, x, positions, cache, cfg: ModelConfig, attend, *,
         (x, cache, jnp.zeros_like(x), jnp.zeros((n,), jnp.int32),
          jnp.zeros((n,), f32), jnp.ones((n,), f32)),
         jnp.arange(n_passes, dtype=jnp.int32))
-    return out, exit_pass, cache
+    return out, cache, exit_pass
 
 
-def _check(mode):
-    if mode != "xla":
-        raise ValueError(f"mode={mode!r}: models.looped runs its layers "
-                         "under a scan, with XLA's collectives; serve it "
-                         "with mode='xla'")
-
-
-def prefill_chunk_paged(params, chunk_toks, cache, table_row,
-                        cfg: ModelConfig, *, start, wfrom, valid,
-                        mode: str = "xla", axis: str = "tp",
-                        ctxs: FwdContexts = FwdContexts(),
-                        attn_impl: str = "ref"):
-    """One fixed-shape chunk of a prompt
-    (:func:`models.dense.prefill_chunk_paged`'s contract). Returns
-    ``(logits (vocab,) of the last valid row, cache, exit_pass (1,))``."""
-    _check(mode)
-    c = chunk_toks.shape[0]
-    positions = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-
-    def attend(li, q, k_tok, v_tok, cache):
-        with scope("cache_write"):
-            cache = cache.write_chunk(li, k_tok, v_tok, table_row,
-                                      positions, valid, wfrom)
-        return _chunk_attend(li, q, cache, table_row, positions, start,
-                             valid, attn_impl), cache
-
-    x, exits, cache = _passes(params, _embed_rows(params, chunk_toks),
-                              positions, cache, cfg, attend, axis=axis)
-    logits = _lm_head(params, _last_valid_row(x, valid), axis)
-    return logits[0], cache, _last_valid_row(exits, valid)
-
-
-def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref"):
-    """One continuous-batching decode step
-    (:func:`models.dense.decode_step_paged`'s contract). Returns
-    ``(logits (S, vocab), cache.advance(), exit_pass (S,))``."""
-    _check(mode)
-
-    def attend(li, q, k_tok, v_tok, cache):
-        with scope("cache_write"):
-            cache = cache.append_decode(li, k_tok, v_tok)
-        return _decode_attend(li, q, cache, attn_impl), cache
-
-    x, exits, cache = _passes(params, _embed_rows(params, token_ids),
-                              cache.lens, cache, cfg, attend, axis=axis)
-    return _lm_head(params, x, axis), cache.advance(), exits
-
-
-def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
-                       cfg: ModelConfig, *, start, wfrom, valid,
-                       mode: str = "xla", axis: str = "tp",
-                       ctxs: FwdContexts = FwdContexts(),
-                       attn_impl: str = "ref",
-                       decode_attn_impl: str = "ref"):
-    """A prefill chunk of one slot and a decode step of the batch in one
-    program (:func:`models.dense.chunk_decode_paged`'s contract): every
-    weight is read once a PASS for both. Returns ``(chunk logits
-    (vocab,), decode logits (S, vocab), cache.advance(), exit_pass
-    (1 + S,))``, the chunk's row first."""
-    _check(mode)
-    c = chunk_toks.shape[0]
-    chunk_pos = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-
-    def attend(li, q, k_tok, v_tok, cache):
-        # Both writes, then both reads, as the dense step's.
-        with scope("cache_write"):
-            cache = cache.write_chunk(li, k_tok[:c], v_tok[:c], table_row,
-                                      chunk_pos, valid, wfrom)
-            cache = cache.append_decode(li, k_tok[c:], v_tok[c:])
-        o_chunk = _chunk_attend(li, q[:c], cache, table_row, chunk_pos,
-                                start, valid, attn_impl)
-        o_dec = _decode_attend(li, q[c:], cache, decode_attn_impl)
-        return jnp.concatenate(
-            [o_chunk.reshape(c, -1),
-             o_dec.reshape(q.shape[0] - c, -1)]), cache
-
-    x, exits, cache = _passes(
-        params, _embed_rows(params, jnp.concatenate([chunk_toks, token_ids])),
-        jnp.concatenate([chunk_pos, cache.lens]), cache, cfg, attend,
-        axis=axis)
-    with scope("head"):
-        logits = _lm_head(params, jnp.concatenate(
-            [_last_valid_row(x[:c], valid), x[c:]]), axis)
-    exits = jnp.concatenate([_last_valid_row(exits[:c], valid), exits[c:]])
-    return logits[0], logits[1:], cache.advance(), exits
+# No ``verify_step_paged``: see the module's docstring.
+prefill_chunk_paged, decode_step_paged, chunk_decode_paged, _ = (
+    paged_step.build(
+        _passes, row_stats=True,
+        xla_only="models.looped runs its layers under a scan, with XLA's "
+                 "collectives"))

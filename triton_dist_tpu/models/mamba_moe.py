@@ -23,8 +23,8 @@ letter in ``cfg.layer_pattern`` (``h`` the normed row):
 ``*``, attention: ``q, k, v = h w_q, h w_k, h w_v``, scores times
 ``head_dim^-1/2``, causal softmax, ``w_o``. NO rotation and no q/k
 norm: the Mamba layers carry position. The dense family's pool and its
-kernels (:func:`~triton_dist_tpu.models.dense._chunk_attend`,
-``_decode_attend``), every head on every rank.
+kernels (:func:`~triton_dist_tpu.models.paged_step.kv_attend`), every
+head on every rank.
 
 ``E``, latent experts: :func:`~triton_dist_tpu.layers.ep_moe.fwd_held`
 behind a sigmoid router with a selection-only bias, the held experts
@@ -63,9 +63,9 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.layers import ep_moe
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.models import dense as _dense
+from triton_dist_tpu.models import paged_step
 from triton_dist_tpu.models.config import ModelConfig
-from triton_dist_tpu.models.dense import (FwdContexts, _embed_rows,
-                                          _last_valid_row, _lm_head)
+from triton_dist_tpu.models.dense import FwdContexts
 from triton_dist_tpu.models.latent_moe import STEP_STATS
 from triton_dist_tpu.obs import scope
 from triton_dist_tpu.ops import mamba2 as _ssd
@@ -246,31 +246,27 @@ def _gated_norm(mp, y, z, cfg: ModelConfig, dtype):
     return (v.reshape(n, -1) * mp["norm"].astype(jnp.float32)).astype(dtype)
 
 
-def chunk_scan_impl(cfg: ModelConfig, rows: int) -> str:
-    """What scans a chunk program's ``rows`` chunk rows in every Mamba-2
-    layer, ``"kernel"`` or ``"xla"``: :func:`ops.mamba2.chunk_scan_impl`
-    at this model's sizes. The serving engine counts its chunk
-    dispatches by it."""
-    return _ssd.chunk_scan_impl(
+def step_kernels(cfg: ModelConfig, rows: int, *, decode_rows: int,
+                 page: int, dtype) -> tuple:
+    """The blocks, of ``paged_step.STEP_KERNELS``, that a chunk program
+    of ``rows`` chunk rows with ``decode_rows`` aboard runs in a Pallas
+    kernel: every Mamba-2 layer's scan of the chunk rows, by
+    :func:`ops.mamba2.chunk_scan_impl` (what ``ssd_prefill`` decides
+    by), and every ``E`` layer's held experts' MLP, by
+    :func:`ep_moe.experts_impl` at the pass every row of the program
+    gives, in the latent and at the width the experts are stored at. The
+    serving engine counts its chunk dispatches by it."""
+    scan = _ssd.chunk_scan_impl(
         rows, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_n_groups,
         cfg.ssm_state_size, cfg.mamba_chunk_size)
-
-
-def experts_impl(cfg: ModelConfig, rows: int, dtype) -> str:
-    """What runs the held experts' MLP in every ``E`` layer of a step
-    program of ``rows`` rows, ``"kernel"`` or ``"xla"``:
-    :func:`ep_moe.experts_impl` at the pass
-    :func:`ep_moe.held_pass_rows` gives them, in the latent and at the
-    width the experts are stored at. The serving engine counts its
-    chunk dispatches by it."""
-    if "E" not in cfg.layer_pattern:
-        return "xla"
     n_held = cfg.held_experts
-    return ep_moe.experts_impl(
-        ep_moe.held_pass_rows(rows, cfg.num_experts_per_tok, n_held,
-                              cfg.num_experts),
+    experts = "E" in cfg.layer_pattern and ep_moe.experts_impl(
+        ep_moe.held_pass_rows(rows + decode_rows, cfg.num_experts_per_tok,
+                              n_held, cfg.num_experts),
         n_held, cfg.moe_latent_size,
         ep_moe.expert_store_width(cfg.moe_intermediate_size), dtype)
+    return (("scan",) * (scan == "kernel")
+            + ("experts",) * (experts == "kernel"))
 
 
 def _mamba_chunk(mp, z, xbc, dt, state, tail, cfg: ModelConfig, valid):
@@ -358,15 +354,30 @@ def _mix_decode(mp, z, xbc, dt, cache, mi, cfg):
 
 # -- the layers ------------------------------------------------------------
 
-def _layers(params, x, cache, cfg: ModelConfig, mix, attend):
-    """Every layer over ``x`` (n, d). ``mix(mi, mamba_params, z, xBC,
-    dt, cache) -> (y (n, H P), cache)`` runs a Mamba layer's rows
-    through the sequences' state; ``attend(ai, q, k, v, cache) -> (o (n,
-    H hd), cache)`` writes and reads attention layer ``ai``'s pages.
-    ``mi`` and ``ai`` count the layers of their kind and are int32
-    operands: a kind of layer is ONE jitted function of its index and
-    parameters (``latent_moe._layers`` has why). Returns ``(x normed (n,
-    d), cache, stats)``."""
+def _layers(params, rows, cache, cfg: ModelConfig, *, mode, axis, attn_impl,
+            decode_attn_impl, ctxs: FwdContexts = FwdContexts()):
+    """The trunk every step of this family is built from
+    (:func:`paged_step.build`): ``rows`` embedded, (n, d), then every
+    layer, then the final norm. An attention layer takes the K/V pool's
+    halves (:func:`paged_step.kv_attend`); a Mamba layer's rows go
+    through the sequences' state (``mix``), the chunk's through their
+    slot's (``rows.slot``), the decode rows each through their own (the
+    chunk's slot is parked in the batch: the two never meet). A kind of
+    layer is ONE jitted function of its parameters and its index among
+    its kind, an int32 operand (``latent_moe._layers`` has why). Returns
+    ``(x (n, d), cache, stats)``: ``STEP_STATS``."""
+    attend = paged_step.kv_attend(rows, attn_impl, decode_attn_impl)
+
+    def mix(mi, mp, z, xbc, dt, cache):
+        return rows.split(
+            lambda cache, z, xbc, dt: _mix_chunk(
+                mp, z, xbc, dt, cache, mi, cfg, slot=rows.slot,
+                start=rows.start, valid=rows.valid),
+            lambda cache, z, xbc, dt: _mix_decode(
+                mp, z, xbc, dt, cache, mi, cfg),
+            cache, z, xbc, dt)
+
+    x = paged_step.embed_rows(params, rows.tokens())
     n = x.shape[0]
     eps = cfg.rms_norm_eps
 
@@ -414,108 +425,8 @@ def _layers(params, x, cache, cfg: ModelConfig, mix, attend):
     return x, cache, stats
 
 
-def _check(mode):
-    if mode != "xla":
-        raise ValueError(f"mode={mode!r}: models.mamba_moe has no fused "
-                         "collective layer; serve it with mode='xla'")
-
-
-def prefill_chunk_paged(params, chunk_toks, cache, table_row,
-                        cfg: ModelConfig, *, start, wfrom, valid, slot,
-                        mode: str = "xla", axis: str = "tp",
-                        ctxs: FwdContexts = FwdContexts(),
-                        attn_impl: str = "ref"):
-    """One fixed-shape chunk of decode slot ``slot``'s prompt
-    (:func:`models.dense.prefill_chunk_paged`'s contract, and the slot
-    whose state the rows carry). Returns ``(logits (vocab,) of the last
-    valid row, cache, stats)``."""
-    _check(mode)
-    c = chunk_toks.shape[0]
-    positions = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-
-    def mix(mi, mp, z, xbc, dt, cache):
-        return _mix_chunk(mp, z, xbc, dt, cache, mi, cfg, slot=slot,
-                          start=start, valid=valid)
-
-    def attend(ai, q, k, v, cache):
-        with scope("cache_write"):
-            cache = cache.write_chunk(ai, k, v, table_row, positions,
-                                      valid, wfrom)
-        return _dense._chunk_attend(ai, q, cache, table_row, positions,
-                                    start, valid, attn_impl), cache
-
-    x, cache, stats = _layers(params, _embed_rows(params, chunk_toks),
-                              cache, cfg, mix, attend)
-    logits = _lm_head(params, _last_valid_row(x, valid), axis)
-    return logits[0], cache, stats
-
-
-def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref"):
-    """One continuous-batching decode step
-    (:func:`models.dense.decode_step_paged`'s contract). Returns
-    ``(logits (S, vocab), cache.advance(), stats)``."""
-    _check(mode)
-
-    def mix(mi, mp, z, xbc, dt, cache):
-        return _mix_decode(mp, z, xbc, dt, cache, mi, cfg)
-
-    def attend(ai, q, k, v, cache):
-        with scope("cache_write"):
-            cache = cache.append_decode(ai, k, v)
-        return _dense._decode_attend(ai, q, cache, attn_impl), cache
-
-    x, cache, stats = _layers(params, _embed_rows(params, token_ids),
-                              cache, cfg, mix, attend)
-    return _lm_head(params, x, axis), cache.advance(), stats
-
-
-def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
-                       cfg: ModelConfig, *, start, wfrom, valid, slot,
-                       mode: str = "xla", axis: str = "tp",
-                       ctxs: FwdContexts = FwdContexts(),
-                       attn_impl: str = "ref",
-                       decode_attn_impl: str = "ref"):
-    """A prefill chunk of slot ``slot`` and a decode step of the batch
-    in one program (:func:`models.dense.chunk_decode_paged`'s contract):
-    the ``C + S`` rows share every projection and the expert layers; the
-    chunk's rows go through their slot's state in the chunked form, the
-    decode rows each through their own, a step. The chunk's slot is
-    parked in the batch, so the two never meet. Returns ``(chunk logits
-    (vocab,), decode logits (S, vocab), cache.advance(), stats)``."""
-    _check(mode)
-    c = chunk_toks.shape[0]
-    chunk_pos = (jnp.asarray(start, jnp.int32)
-                 + jnp.arange(c, dtype=jnp.int32))
-
-    def mix(mi, mp, z, xbc, dt, cache):
-        y_chunk, cache = _mix_chunk(mp, z[:c], xbc[:c], dt[:c], cache, mi,
-                                    cfg, slot=slot, start=start,
-                                    valid=valid)
-        y_dec, cache = _mix_decode(mp, z[c:], xbc[c:], dt[c:], cache, mi,
-                                   cfg)
-        return jnp.concatenate([y_chunk, y_dec]), cache
-
-    def attend(ai, q, k, v, cache):
-        with scope("cache_write"):
-            cache = cache.write_chunk(ai, k[:c], v[:c], table_row,
-                                      chunk_pos, valid, wfrom)
-            cache = cache.append_decode(ai, k[c:], v[c:])
-        o_chunk = _dense._chunk_attend(ai, q[:c], cache, table_row,
-                                       chunk_pos, start, valid, attn_impl)
-        o_dec = _dense._decode_attend(ai, q[c:], cache, decode_attn_impl)
-        return jnp.concatenate(
-            [o_chunk.reshape(c, -1),
-             o_dec.reshape(q.shape[0] - c, -1)]), cache
-
-    x, cache, stats = _layers(
-        params,
-        _embed_rows(params, jnp.concatenate([chunk_toks, token_ids])),
-        cache, cfg, mix, attend)
-    with scope("head"):
-        logits = _lm_head(params, jnp.concatenate(
-            [_last_valid_row(x[:c], valid), x[c:]]), axis)
-    return logits[0], logits[1:], cache.advance(), stats
+# No ``verify_step_paged``: see the module's docstring.
+prefill_chunk_paged, decode_step_paged, chunk_decode_paged, _ = (
+    paged_step.build(
+        _layers, slotted=True,
+        xla_only="models.mamba_moe has no fused collective layer"))

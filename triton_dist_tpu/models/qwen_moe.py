@@ -14,6 +14,7 @@ the reference's TP_MoE vs EP_MoE layers):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -22,6 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.layers import tp_attn, ep_moe, tp_moe
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.models import dense as _dense
+from triton_dist_tpu.models import paged_step
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import FwdContexts
 from triton_dist_tpu.ops.ep_a2a import EPContext, create_ep_context
@@ -177,29 +180,23 @@ def forward_tokens(params, input_ids, cfg: ModelConfig, *,
     an :class:`EPContext` (flat) or ``EP2DContext`` (hierarchical
     ICI-then-DCN dispatch, ``ops/ep_a2a.ep_dispatch_2d``).
 
-    The transformer trunk is ``dense._forward_trunk`` with the MoE
+    The transformer trunk is ``dense.forward_trunk`` with the MoE
     block plugged in via its ``ffn_fn`` hook — one trunk, two models.
     """
-    import functools
-
-    from triton_dist_tpu.models.dense import _forward_trunk, _lm_head
-
     b, s = input_ids.shape
     ffn = functools.partial(_moe_block, cfg=cfg, moe_impl=moe_impl,
                             mode=mode, axis=axis, ctxs=ctxs,
                             ep_ctx=ep_ctx, moe_block_m=moe_block_m)
-    x, _ = _forward_trunk(params, input_ids, cfg, mode=mode, axis=axis,
+    x, _ = _dense.forward_trunk(params, input_ids, cfg, mode=mode, axis=axis,
                           ctxs=ctxs, cache=None, ffn_fn=ffn)
-    return _lm_head(params, x, axis).reshape(b, s, cfg.vocab_size)
+    return paged_step.lm_head(params, x, axis).reshape(
+        b, s, cfg.vocab_size)
 
 
 # --- Engine serve contract (delegates to models.dense with the MoE
 # --- ffn_fn hook) -----------------------------------------------------------
 
-def cache_specs(axis: str = "tp"):
-    from triton_dist_tpu.models import dense as _dense
-
-    return _dense.cache_specs(axis)
+cache_specs = _dense.cache_specs
 
 
 def prefill(params, input_ids, cfg: ModelConfig, *, mode: str = "xla",
@@ -215,10 +212,6 @@ def prefill(params, input_ids, cfg: ModelConfig, *, mode: str = "xla",
     one model_kwargs dict serves both dispatches; prefill always rides
     the full dispatch/combine path."""
     del transport, replicas
-    import functools
-
-    from triton_dist_tpu.models import dense as _dense
-
     ffn = functools.partial(_moe_block, cfg=cfg, moe_impl=moe_impl,
                             mode=mode, axis=axis, ctxs=ctxs,
                             ep_ctx=ep_ctx, moe_block_m=moe_block_m)
@@ -236,10 +229,6 @@ def decode_step(params, token_ids, cache, cfg: ModelConfig, *,
     ``with_expert_counts=True`` appends the step's per-expert routed
     assignment counts (E,) int32, summed over layers, to the return
     tuple (the serving layer's load telemetry)."""
-    import functools
-
-    from triton_dist_tpu.models import dense as _dense
-
     counts = [] if with_expert_counts else None
     ffn = functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
                             axis=axis, ep_ctx=ep_ctx,
@@ -261,124 +250,40 @@ def _sum_counts(counts, cfg: ModelConfig):
     return jnp.zeros((cfg.num_experts,), jnp.int32)
 
 
-def _ar_ffn(cfg: ModelConfig, moe_impl, axis, ep_ctx):
-    """The ``ffn_fn`` hook of the steps whose replicated rows take the
-    MoE FFN in the AR decode regime whatever the decode dispatch's
-    transport is: prefill chunks, verification, a chunk with the decode
-    batch aboard."""
-    import functools
-
-    return functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
-                             axis=axis, ep_ctx=ep_ctx, transport="ar",
-                             counts=None, _layer_cursor=[0])
+paged_pool = _dense.paged_pool
+paged_cache_specs = _dense.paged_cache_specs
 
 
-def paged_pool(cfg: ModelConfig):
-    from triton_dist_tpu.models import dense as _dense
-
-    return _dense.paged_pool(cfg)
-
-
-def paged_cache_specs(axis: str = "tp", quantized: bool = False):
-    from triton_dist_tpu.models import dense as _dense
-
-    return _dense.paged_cache_specs(axis, quantized=quantized)
-
-
-def verify_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      budget=None, mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref",
-                      moe_impl: str = "tp", ep_ctx=None, transport=None,
-                      replicas=None, with_expert_counts: bool = False):
-    """Speculative K-token verification with the MoE FFN in the AR
-    decode regime — like the prefill chunk, the verification block's
-    S·K replicated rows fit the masked-local + psum expert path for
-    any K, so the verify dispatch needs no transport of its own.
-    ``transport``/``replicas``/counts are decode-dispatch knobs the
-    verification contract ignores."""
-    del transport, replicas, with_expert_counts
-    from triton_dist_tpu.models import dense as _dense
-
-    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
-    return _dense.verify_step_paged(params, token_ids, cache, cfg,
-                                    budget=budget, mode=mode, axis=axis,
-                                    ctxs=ctxs, attn_impl=attn_impl,
-                                    ffn_fn=ffn)
+def _paged_layers(params, rows, cache, cfg: ModelConfig, *, mode, axis,
+                  attn_impl, decode_attn_impl,
+                  ctxs: FwdContexts = FwdContexts(), moe_impl: str = "tp",
+                  ep_ctx=None, transport=None, replicas=None,
+                  with_expert_counts: bool = False):
+    """The dense trunk with the MoE FFN plugged in (the ServingEngine's
+    model contract; :func:`paged_step.build` makes the steps of it).
+    Decode rows ALONE take the decode dispatch's knobs: ``transport``
+    routes the EP dispatch (see :func:`moe_ffn_decode`), ``replicas`` is
+    the full hot-expert replica state (data, refreshed between steps),
+    and ``with_expert_counts=True`` hands out last the step's (E,) int32
+    expert counts. Every other step's replicated rows (a prefill chunk,
+    verification, a chunk with the decode batch aboard, which the
+    serving engine builds only where the decode dispatch has no
+    transport of its own) take the MoE FFN in the AR decode regime, the
+    masked-local + psum expert path that fits any row count exactly,
+    and ignore the three knobs, so that one ``model_kwargs`` dict
+    serves every dispatch."""
+    alone = not (rows.c or rows.k)
+    counts = [] if alone and with_expert_counts else None
+    ffn = functools.partial(
+        _moe_ffn_decode, cfg=cfg, moe_impl=moe_impl, axis=axis,
+        ep_ctx=ep_ctx, transport=transport if alone else "ar",
+        replicas=replicas if alone else None, counts=counts,
+        _layer_cursor=[0])
+    out = _dense.paged_layers(
+        params, rows, cache, cfg, mode=mode, axis=axis, attn_impl=attn_impl,
+        decode_attn_impl=decode_attn_impl, ctxs=ctxs, ffn_fn=ffn)
+    return out if counts is None else out + (_sum_counts(counts, cfg),)
 
 
-def prefill_chunk_paged(params, chunk_toks, cache, table_row,
-                        cfg: ModelConfig, *, start, wfrom, valid,
-                        mode: str = "xla", axis: str = "tp",
-                        ctxs: FwdContexts = FwdContexts(),
-                        attn_impl: str = "ref",
-                        moe_impl: str = "tp", ep_ctx=None, transport=None,
-                        replicas=None, with_expert_counts: bool = False):
-    """One bucketed chunk of a paged prefill with the MoE FFN in the
-    AR decode regime (the chunk residual is replicated, so the
-    masked-local + psum expert path is the transport that fits any
-    chunk length exactly). ``transport``/``replicas``/counts are
-    decode-dispatch knobs — prefill chunks ignore them; decode keeps
-    its own resolved transport."""
-    del transport, replicas, with_expert_counts
-    from triton_dist_tpu.models import dense as _dense
-
-    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
-    return _dense.prefill_chunk_paged(params, chunk_toks, cache,
-                                      table_row, cfg, start=start,
-                                      wfrom=wfrom, valid=valid,
-                                      mode=mode, axis=axis, ctxs=ctxs,
-                                      attn_impl=attn_impl, ffn_fn=ffn)
-
-
-def decode_step_paged(params, token_ids, cache, cfg: ModelConfig, *,
-                      mode: str = "xla", axis: str = "tp",
-                      ctxs: FwdContexts = FwdContexts(),
-                      attn_impl: str = "ref", moe_impl: str = "tp",
-                      ep_ctx=None, transport=None, replicas=None,
-                      with_expert_counts: bool = False):
-    """Continuous-batching decode over a PagedKVCache — the dense
-    serving step with the MoE small-batch FFN plugged in (the
-    ServingEngine's model contract). ``transport`` routes the EP
-    dispatch (see :func:`moe_ffn_decode`); ``replicas`` is the full
-    hot-expert replica state (data, refreshed between steps);
-    ``with_expert_counts=True`` appends the step's (E,) int32 expert
-    counts to the return tuple."""
-    import functools
-
-    from triton_dist_tpu.models import dense as _dense
-
-    counts = [] if with_expert_counts else None
-    ffn = functools.partial(_moe_ffn_decode, cfg=cfg, moe_impl=moe_impl,
-                            axis=axis, ep_ctx=ep_ctx,
-                            transport=transport, replicas=replicas,
-                            counts=counts, _layer_cursor=[0])
-    out = _dense.decode_step_paged(params, token_ids, cache, cfg,
-                                   mode=mode, axis=axis, ctxs=ctxs,
-                                   attn_impl=attn_impl, ffn_fn=ffn)
-    if not with_expert_counts:
-        return out
-    return out + (_sum_counts(counts, cfg),)
-
-
-def chunk_decode_paged(params, chunk_toks, token_ids, cache, table_row,
-                       cfg: ModelConfig, *, start, wfrom, valid,
-                       mode: str = "xla", axis: str = "tp",
-                       ctxs: FwdContexts = FwdContexts(),
-                       attn_impl: str = "ref",
-                       decode_attn_impl: str = "ref",
-                       moe_impl: str = "tp", ep_ctx=None):
-    """A prefill chunk and the batch's decode step in one program (see
-    ``dense.chunk_decode_paged``), all ``C + S`` replicated rows through
-    the MoE FFN in the AR decode regime, as the chunk's rows are in
-    :func:`prefill_chunk_paged`. The serving engine builds it only
-    where the decode dispatch has no transport of its own (the TP
-    expert regime): EP decode keeps its program."""
-    from triton_dist_tpu.models import dense as _dense
-
-    ffn = _ar_ffn(cfg, moe_impl, axis, ep_ctx)
-    return _dense.chunk_decode_paged(
-        params, chunk_toks, token_ids, cache, table_row, cfg,
-        start=start, wfrom=wfrom, valid=valid, mode=mode, axis=axis,
-        ctxs=ctxs, attn_impl=attn_impl,
-        decode_attn_impl=decode_attn_impl, ffn_fn=ffn)
+(prefill_chunk_paged, decode_step_paged, chunk_decode_paged,
+ verify_step_paged) = paged_step.build(_paged_layers)

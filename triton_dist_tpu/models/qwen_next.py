@@ -33,10 +33,9 @@ from triton_dist_tpu.layers import ep_moe, gdn_attn, tp_attn, tp_mlp, tp_moe
 from triton_dist_tpu.models.qwen_moe import moe_ffn, moe_ffn_decode
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.models.config import ModelConfig
-from triton_dist_tpu.models.dense import (
-    FwdContexts, _embed_tokens, _lm_head,
-)
+from triton_dist_tpu.models.dense import FwdContexts, embed_tokens
 from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.models.paged_step import lm_head
 
 
 @dataclasses.dataclass
@@ -178,7 +177,7 @@ def _trunk(params, input_ids, cfg, *, mode, axis, ctxs, cache,
            moe_impl="tp", ep_ctx=None, moe_block_m=None):
     b, s = input_ids.shape
     kinds, _, _ = _layer_kinds(cfg)
-    x = _embed_tokens(params, input_ids, mode=mode, axis=axis)
+    x = embed_tokens(params, input_ids, mode=mode, axis=axis)
     for li, lp in enumerate(params["layers"]):
         kind, ordinal = kinds[li]
         h = rms_norm(x, lp["ln_attn"], cfg.rms_norm_eps)
@@ -235,7 +234,7 @@ def forward_tokens(params, input_ids, cfg: ModelConfig, *,
     x, _ = _trunk(params, input_ids, cfg, mode=mode, axis=axis,
                   ctxs=ctxs, cache=None, moe_impl=moe_impl,
                   ep_ctx=ep_ctx, moe_block_m=moe_block_m)
-    return _lm_head(params, x, axis).reshape(b, s, cfg.vocab_size)
+    return lm_head(params, x, axis).reshape(b, s, cfg.vocab_size)
 
 
 def prefill(params, input_ids, cfg: ModelConfig, *, mode: str = "xla",
@@ -252,7 +251,7 @@ def prefill(params, input_ids, cfg: ModelConfig, *, mode: str = "xla",
     cache.kv = dataclasses.replace(cache.kv,
                                    length=jnp.asarray(s, jnp.int32))
     last = x.reshape(b, s, cfg.hidden_size)[:, -1]
-    return _lm_head(params, last, axis), cache
+    return lm_head(params, last, axis), cache
 
 
 def decode_step(params, token_ids, cache: HybridCache,
@@ -312,7 +311,7 @@ def decode_step(params, token_ids, cache: HybridCache,
                                ar_ctx=ctxs.ar)
 
     x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-    logits = _lm_head(params, x, axis)
+    logits = lm_head(params, x, axis)
     cache = HybridCache(
         kv=KVCache(k=new_k, v=new_v, length=cache.kv.length + 1),
         states=new_states, conv=new_conv)

@@ -521,7 +521,7 @@ class ServingEngine:
         # Whether this engine's own chunker carries the decode batch
         # (set where the layer path builds it; never by the caller).
         self._rides = False
-        self._experts_kernel_of = {}      # bucket -> 0/1, by the rule
+        self._kernels_of = {}     # bucket -> the model's step_kernels
 
         if self.mega:
             # kv_dtype / spec_k are ENGINE knobs on the megakernel lane
@@ -2049,6 +2049,7 @@ class ServingEngine:
         chunk's and rows 1.. the decode rows'."""
         import dataclasses as _dc
 
+        from triton_dist_tpu.models.paged_step import STEP_KERNELS
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import (
             CommTimeoutError, block_until_ready)
@@ -2056,9 +2057,9 @@ class ServingEngine:
         p = self._prefiller
         slot, seq, start = h.slot, h.lane, h.prompt_pos
         bucket, valid = p.chunker.next_chunk(len(seq) - start)
-        walk_kernel = self._walk_kernel(bucket)
-        scan_kernel = self._scan_kernel(bucket)
-        experts_kernel = self._experts_kernel(bucket)
+        ran = self._step_kernels(bucket)
+        kernels = {f"{block}_kernel": int(block in ran)
+                   for block in STEP_KERNELS}
         passes = ({"passes": self.cfg.num_passes} if self._row_stats
                   else {})
         toks = np.zeros((bucket,), np.int32)
@@ -2079,9 +2080,7 @@ class ServingEngine:
                                start=int(start), bucket=int(bucket),
                                valid=int(valid),
                                padded_up=self._padded_up(bucket, valid),
-                               walk_kernel=walk_kernel,
-                               scan_kernel=scan_kernel,
-                               experts_kernel=experts_kernel, **passes), \
+                               **kernels, **passes), \
                     faults.on_op_call("chunked_prefill"):
                 if batch is not None:
                     dec_toks, tbl, lens, live = batch
@@ -2130,13 +2129,6 @@ class ServingEngine:
             raise
         return picked, logits, dec, (start, bucket, valid)
 
-    def _model_rule(self, rule: str):
-        """``(cfg, *sizes) -> "kernel" | "xla"``: the rule on sizes the
-        served model's module states under the name ``rule``, or None
-        for a model that states no such choice."""
-        return getattr(getattr(self._prefiller.engine, "model", None),
-                       rule, None)
-
     def _padded_up(self, bucket: int, valid: int) -> int:
         """1 where the chunk ``(bucket, valid)`` is a prompt's tail in
         one padded program of a larger bucket than the greedy step
@@ -2146,36 +2138,24 @@ class ServingEngine:
         return int(padded_up(bucket, valid,
                              self._prefiller.chunker.buckets))
 
-    def _walk_kernel(self, bucket: int) -> int:
-        """1 where a chunk program of ``bucket`` rows walks its context
-        in the model's Pallas kernel (``chunk_walk_impl``:
-        ``models.latent_moe``), else 0. Host arithmetic."""
-        p, impl = self._prefiller, self._model_rule("chunk_walk_impl")
-        return int(impl is not None and impl(
-            p.engine.cfg, int(bucket), p.cache.page) == "kernel")
-
-    def _scan_kernel(self, bucket: int) -> int:
-        """1 where a chunk program of ``bucket`` rows scans them, in
-        every state-space layer, in the model's Pallas kernel
-        (``chunk_scan_impl``: ``models.mamba_moe``), else 0. Host
-        arithmetic."""
-        impl = self._model_rule("chunk_scan_impl")
-        return int(impl is not None and impl(
-            self._prefiller.engine.cfg, int(bucket)) == "kernel")
-
-    def _experts_kernel(self, bucket: int) -> int:
-        """1 where a chunk program of ``bucket`` rows (and the decode
-        rows it carries) runs its held experts' MLP in the Pallas
-        kernel (``experts_impl``: ``models.latent_moe``,
-        ``models.mamba_moe``), else 0. Host arithmetic."""
+    def _step_kernels(self, bucket: int) -> tuple:
+        """The blocks (of ``models.paged_step.STEP_KERNELS``) that a
+        chunk program of ``bucket`` rows, with the decode rows it
+        carries, runs in a Pallas kernel: what the served model's module
+        states (``step_kernels``, by the rules on sizes its program
+        decides by), nothing for a model that states no such choice.
+        Host arithmetic, once a bucket."""
         import jax
 
-        known = self._experts_kernel_of
-        if bucket not in known:       # twice a chunk, in the host's turn
-            p, impl = self._prefiller, self._model_rule("experts_impl")
-            known[bucket] = int(impl is not None and impl(
-                p.engine.cfg, int(bucket) + p.chunker.decode_rows,
-                jax.tree.leaves(p.engine.params)[0].dtype) == "kernel")
+        known = self._kernels_of
+        if bucket not in known:
+            p = self._prefiller
+            states = getattr(getattr(p.engine, "model", None),
+                             "step_kernels", None)
+            known[bucket] = () if states is None else states(
+                p.engine.cfg, int(bucket),
+                decode_rows=p.chunker.decode_rows, page=p.cache.page,
+                dtype=jax.tree.leaves(p.engine.params)[0].dtype)
         return known[bucket]
 
     def _chunk_failed(self, h: RequestHandle, e):
@@ -2198,12 +2178,8 @@ class ServingEngine:
         start, bucket, valid = plan
         self._note_role_ok("prefill")
         self.stats_counters["prefill_chunks"] += 1
-        self.stats_counters["chunk_dispatches_kernel_walk"] += (
-            self._walk_kernel(bucket))
-        self.stats_counters["chunk_dispatches_kernel_scan"] += (
-            self._scan_kernel(bucket))
-        self.stats_counters["chunk_dispatches_kernel_experts"] += (
-            self._experts_kernel(bucket))
+        for block in self._step_kernels(bucket):
+            self.stats_counters[f"chunk_dispatches_kernel_{block}"] += 1
         self.stats_counters["chunk_dispatches_padded_up"] += (
             self._padded_up(bucket, valid))
         self.stats_counters["prefill_rows_padded"] += bucket - valid
