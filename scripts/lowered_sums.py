@@ -114,7 +114,12 @@ def main(argv):
         pool_cls, per_token, *keeps = model.paged_pool(cfg)
         keeps = dict(keeps[0]) if keeps else {}
         layers = keeps.pop("layers", cfg.num_hidden_layers)
-        kv_spec = model.paged_cache_specs("tp")
+        ring = {}
+        if "window" in keeps:      # sized as the server sizes them
+            keeps["window"] = keeps["window"].sized(
+                page, max(srv["prefill_buckets"]), slots)
+            ring = {"ring": keeps["window"].ring}
+        kv_spec = model.paged_cache_specs("tp", **ring)
         kv_sh = pool_shardings(mesh, kv_spec)
         cache = on_mesh(jax.eval_shape(lambda: pool_cls.empty(
             layers, 1 + slots * p_max, page, *per_token, num_slots=slots,
@@ -129,8 +134,9 @@ def main(argv):
             program = f"chunk-{bucket}+{slots}"
             if only in ("", program):
                 report(name, program, chunker._chunk.lower(
-                    params, ints(bucket), cache, ints(p_max), ints(),
-                    ints(), ints(), *slot, ints(slots)))
+                    params, ints(bucket), cache,
+                    ints(cache.block_table.shape[1]), ints(), ints(),
+                    ints(), *slot, ints(slots)))
 
         # The server's decode program (``ServingEngine._decode``).
         stats = bool(getattr(model, "STEP_STATS", ())

@@ -17,7 +17,7 @@ import pytest
 import triton_dist_tpu as tdt
 from triton_dist_tpu import obs
 from triton_dist_tpu.models import (Engine, ModelConfig, latent_moe,
-                                    looped, mamba_moe)
+                                    looped, mamba_moe, window_moe)
 
 # What each family's chunk program runs; ``embed``, the attention
 # blocks, ``head`` and ``pick`` are common to all.
@@ -28,7 +28,11 @@ RUNS = {"dense": COMMON | {"mlp"},
         "latent_moe": COMMON | EXPERTS,
         "looped": COMMON | {"mlp", "pass_norm", "exit_gate"},
         "mamba_moe": COMMON | EXPERTS | {"ssm_project", "ssm", "ssm_out",
-                                         "expert_latent"}}
+                                         "expert_latent"},
+        # Both kinds of attention layer, the leading dense layer and
+        # the expert layers.
+        "window_moe": COMMON | EXPERTS | {"mlp", "attn_chunk_window",
+                                          "attn_decode_window"}}
 
 
 def _chunk_program_text(family: str) -> str:
@@ -43,6 +47,9 @@ def _chunk_program_text(family: str) -> str:
                      mode="xla", dtype=jnp.float32, max_len=32, seed=0)
     elif family == "looped":
         eng = Engine(ModelConfig.tiny_looped(), mesh, model=looped,
+                     mode="xla", dtype=jnp.float32, max_len=32, seed=0)
+    elif family == "window_moe":
+        eng = Engine(ModelConfig.tiny_window_moe(), mesh, model=window_moe,
                      mode="xla", dtype=jnp.float32, max_len=32, seed=0)
     else:
         eng = Engine(ModelConfig.tiny_mamba_moe(), mesh, model=mamba_moe,

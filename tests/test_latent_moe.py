@@ -1125,3 +1125,119 @@ def test_compiled_looped_steps_keep_the_pool_in_place(v5e, mosaic, program):
     side = 2 * int(np.prod(cache.k_pages.shape))
     assert mem.alias_size_in_bytes >= 2 * side
     assert mem.temp_size_in_bytes < side // 8
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk-512", "fused-512"])
+def test_compiled_window_steps_keep_both_pools_in_place(v5e, mosaic,
+                                                        program):
+    """``models.window_moe``'s paged steps (window layers whose pages
+    are a ring in a pool of their own beside the global layers': PR 52),
+    lowered for one v5e chip as the serving engine jits them, at the
+    attention sizes and the page geometry of
+    ``k-exaone-236b-1chip.longdocs`` (64 heads over 8 KV heads of 128,
+    pages of 128, window 128, a ring of 18 for buckets up to 2,048) with
+    narrow FFNs, and with the cell's 8 slots of 129 pages (shapes only:
+    a pool of tens of megabytes XLA's memory-space assignment stages
+    through another space and back, which the cell's 0.54 and 0.23 GB a
+    side leave it no room for). Both pools are written and read
+    with the layer an operand, in place: every instruction of every
+    computation that yields either pool is a parameter, a tuple or its
+    element, or an update in place, in the one row-major layout, and
+    both are aliased outputs. Each paged kernel lowers through Mosaic
+    TWICE a program, once with the window and once without, for the
+    eight layers. Here and not in a file of its own: one worker holds
+    libtpu. Compile only."""
+    from jax.sharding import NamedSharding
+    from triton_dist_tpu.models import window_moe
+    from triton_dist_tpu.serving.blocks import PagedKVCache, pool_shardings
+
+    cfg = ModelConfig.tiny_window_moe(
+        vocab_size=1024, hidden_size=1024, intermediate_size=512,
+        num_attention_heads=64, num_key_value_heads=8, head_dim=128,
+        sliding_window=128, rope_theta=1e6, moe_intermediate_size=256,
+        shared_expert_intermediate_size=256, num_experts=16,
+        num_held_experts=4)
+    slots, page, p_max = 8, 128, 129
+    mesh = tdt.make_mesh(tp=1, devices=v5e.devices[:1])
+    dt = jnp.bfloat16
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=(
+                    s if isinstance(s, NamedSharding)
+                    else NamedSharding(mesh, s))),
+            tree, specs, is_leaf=lambda s: isinstance(s, P))
+
+    specs = window_moe.param_specs(cfg, "tp")
+    params = on_mesh(jax.eval_shape(lambda: window_moe.init_params(
+        jax.random.PRNGKey(0), cfg, dt)), specs)
+    _, per_token, keeps = window_moe.paged_pool(cfg)
+    window = keeps["window"].sized(page, 2048, slots)
+    assert (window.ring, window.num_pages) == (18, 145)
+    kv_spec = window_moe.paged_cache_specs("tp", ring=window.ring)
+    kv_sh = pool_shardings(mesh, kv_spec)
+    cache = on_mesh(jax.eval_shape(lambda: PagedKVCache.empty(
+        keeps["layers"], 1 + slots * p_max, page, *per_token,
+        num_slots=slots, p_max=p_max, dtype=dt, window=window)), kv_sh)
+    pools = {cache.k_pages.shape: "global", cache.win["k"].shape: "window"}
+    assert pools == {(2, 1033, 8, 128, 128): "global",
+                     (6, 145, 8, 128, 128): "window"}
+    assert cache.block_table.shape == (slots, p_max + 18)
+    ints = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    flash = dict(attn_impl="flash")
+    if program == "decode":
+        step = lambda p, t, c: window_moe.decode_step_paged(
+            p, t, c, cfg, **flash)
+        in_specs = (specs, P(None), kv_spec)
+        out_specs = (P(None, None), kv_spec, P(None))
+        args = (params, ints(slots), cache)
+    elif program.startswith("chunk-"):
+        step = lambda p, t, c, row, start, wfrom, valid: (
+            window_moe.prefill_chunk_paged(
+                p, t, c, row, cfg, start=start, wfrom=wfrom, valid=valid,
+                **flash))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P())
+        out_specs = (P(None), kv_spec, P(None))
+        args = (params, ints(int(program[6:])), cache, ints(p_max + 18),
+                ints(), ints(), ints())
+    else:
+        step = lambda p, t, c, row, start, wfrom, valid, d: (
+            window_moe.chunk_decode_paged(
+                p, t, d, c, row, cfg, start=start, wfrom=wfrom,
+                valid=valid, decode_attn_impl="flash", **flash))
+        in_specs = (specs, P(None), kv_spec, P(None), P(), P(), P(),
+                    P(None))
+        out_specs = (P(None), P(None, None), kv_spec, P(None))
+        args = (params, ints(int(program[6:])), cache, ints(p_max + 18),
+                ints(), ints(), ints(), ints(slots))
+    lowered = jax.jit(
+        jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+        donate_argnums=(args.index(cache),),
+        out_shardings=tuple(kv_sh if s is kv_spec else NamedSharding(mesh, s)
+                            for s in out_specs)).lower(*args)
+    text = lowered.as_text()
+    assert text.count('kernel_name = "paged_flash_decode"') == 2 * (
+        not program.startswith("chunk-"))
+    assert text.count('kernel_name = "paged_flash_qblock"') == 2 * (
+        program != "decode")
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    in_place = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                "dynamic-update-slice", "fusion", "custom-call"}
+    for shape in pools:
+        yields = _pool_results(hlo, shape)
+        assert yields and [ln for op, ln in yields
+                           if op not in in_place] == []
+        # The Mosaic kernels READ a pool; none gives one back.
+        assert not [ln for op, ln in yields if op == "custom-call"
+                    and ln.split(" = ")[1].startswith("bf16[")]
+        pool = "bf16[" + ",".join(map(str, shape)) + "]"
+        assert set(re.findall(re.escape(pool) + r"\{([\d,]+)", hlo)) == {
+            "4,3,2,1,0"}
+    mem = compiled.memory_analysis()
+    sides = 2 * 2 * sum(int(np.prod(shape)) for shape in pools)
+    assert mem.alias_size_in_bytes >= sides
+    assert mem.temp_size_in_bytes < sides // 8

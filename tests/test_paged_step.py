@@ -15,7 +15,8 @@ import pytest
 
 import triton_dist_tpu as tdt
 from triton_dist_tpu.models import (Engine, ModelConfig, dense, latent_moe,
-                                    looped, mamba_moe, paged_step, qwen_moe)
+                                    looped, mamba_moe, paged_step, qwen_moe,
+                                    window_moe)
 from triton_dist_tpu.models.dense import FwdContexts
 
 FAMILIES = {
@@ -23,9 +24,11 @@ FAMILIES = {
     "latent_moe": (latent_moe, ModelConfig.tiny_latent_moe()),
     "mamba_moe": (mamba_moe, ModelConfig.tiny_mamba_moe()),
     "looped": (looped, ModelConfig.tiny_looped()),
+    "window_moe": (window_moe, ModelConfig.tiny_window_moe()),
 }
 TRUNK = {"dense": dense.paged_layers, "latent_moe": latent_moe._layers,
-         "mamba_moe": mamba_moe._layers, "looped": looped._passes}
+         "mamba_moe": mamba_moe._layers, "looped": looped._passes,
+         "window_moe": window_moe._layers}
 C, S, PAGE, P_MAX = 8, 3, 4, 6
 
 
@@ -51,6 +54,8 @@ def _shapes(model, cfg):
     pool_cls, per_token, *keeps = model.paged_pool(cfg)
     keeps = dict(keeps[0]) if keeps else {}
     layers = keeps.pop("layers", cfg.num_hidden_layers)
+    if "window" in keeps:          # sized as the server sizes them
+        keeps["window"] = keeps["window"].sized(PAGE, C, S)
     cache = jax.eval_shape(lambda: pool_cls.empty(
         layers, 1 + S * P_MAX, PAGE, *per_token, num_slots=S, p_max=P_MAX,
         dtype=jnp.float32, **keeps))
@@ -159,6 +164,7 @@ _OWN = {
     "latent_moe": ({}, (), True),
     "mamba_moe": ({}, ("slot",), False),
     "looped": ({}, (), False),
+    "window_moe": ({}, (), False),
 }
 _STEPS = {
     "prefill_chunk_paged": (_CHUNK, True, {}),

@@ -134,11 +134,14 @@ def _o_bias(params, y):
     return y + params["bo"] if "bo" in params else y
 
 
-def _norm_rope(q, k, params, cfg, positions):
-    """q: (B, S, H_loc, hd); k: (B, S, KV_loc, hd)."""
+def _norm_rope(q, k, params, cfg, positions, rope: bool = True):
+    """q: (B, S, H_loc, hd); k: (B, S, KV_loc, hd). ``rope=False``: the
+    norm alone (a layer that carries no position of its own)."""
     if "q_norm" in params:       # Qwen3 per-head norm; absent for
         q = rms_norm(q, params["q_norm"], cfg.rms_norm_eps)  # Seed-OSS
         k = rms_norm(k, params["k_norm"], cfg.rms_norm_eps)
+    if not rope:
+        return q, k
     # Partial RoPE (Qwen3-Next rotates only the first fraction of each
     # head; the rest passes through position-free).
     rot = int(cfg.head_dim * getattr(cfg, "partial_rotary_factor", 1.0))
@@ -254,8 +257,10 @@ def fwd_prefill(params, x, cfg, *, batch: int, mode: str = "xla",
     return (y, (k, v)) if kv_out else y
 
 
-def decode_project(params, x, cfg, positions, *, axis: str = "tp"):
-    """Project one token per row: QKV + q/k norm + rope.
+def decode_project(params, x, cfg, positions, *, axis: str = "tp",
+                   rope: bool = True):
+    """Project one token per row: QKV + q/k norm + rope (``rope=False``:
+    no rotation, for a layer of a model that rotates only some).
 
     x: (B, d) replicated; ``positions``: (B,) int32 — PER-ROW cache
     positions. Two callers, one contract: the continuous-batching
@@ -283,7 +288,7 @@ def decode_project(params, x, cfg, positions, *, axis: str = "tp"):
     k = k.reshape(b, 1, kv_loc, hd)
     v = v.reshape(b, 1, kv_loc, hd)
     pos2 = jnp.asarray(positions, jnp.int32).reshape(b, 1)
-    q, k = _norm_rope(q, k, params, cfg, pos2)
+    q, k = _norm_rope(q, k, params, cfg, pos2, rope)
     return q, k, v
 
 

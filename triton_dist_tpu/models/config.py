@@ -128,6 +128,16 @@ class ModelConfig:
     num_passes: int = 1
     exit_threshold: float = 1.0
     post_norm: bool = False
+    # Window and global attention mixed (models/window_moe.py):
+    # ``attn_pattern`` gives each layer one letter, ``L`` a layer whose
+    # row ``i`` reads keys ``i - sliding_window < j <= i`` (and keeps, a
+    # sequence, a bounded ring of pages), ``G`` one that reads every
+    # key; "" = every layer reads every key. The first
+    # ``first_dense_layers`` layers have a dense FFN of
+    # ``intermediate_size``, the others routed experts.
+    attn_pattern: str = ""
+    sliding_window: int = 0
+    first_dense_layers: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -138,7 +148,9 @@ class ModelConfig:
         """Layers with routed experts."""
         if self.layer_pattern:
             return self.layer_pattern.count("E")
-        return self.num_hidden_layers if self.is_moe else 0
+        if not self.is_moe:
+            return 0
+        return self.num_hidden_layers - self.first_dense_layers
 
     @property
     def num_paged_layers(self) -> int:
@@ -146,7 +158,15 @@ class ModelConfig:
         pages of keys keeps its own."""
         if self.layer_pattern:
             return self.layer_pattern.count("*")
+        if self.attn_pattern:      # the window layers keep a pool apart
+            return self.attn_pattern.count("G")
         return self.num_hidden_layers * self.num_passes
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers that keep a window of positions, and a ring of pages
+        a sequence in a pool of their own."""
+        return self.attn_pattern.count("L")
 
     @property
     def is_hybrid(self) -> bool:
@@ -320,6 +340,26 @@ class ModelConfig:
         return cls(**base)
 
     @classmethod
+    def tiny_window_moe(cls, **kw) -> "ModelConfig":
+        """Window and global layers mixed over routed experts at a size
+        for the CPU mesh (models/window_moe.py): two periods ``LLLG``,
+        a window of 8, one leading dense layer, 8 experts behind a
+        sigmoid router, 2 a token, 2 of them held, a shared one."""
+        base = dict(vocab_size=256, hidden_size=64, intermediate_size=96,
+                    num_hidden_layers=8, attn_pattern="LLLGLLLG",
+                    sliding_window=8, first_dense_layers=1,
+                    num_attention_heads=4, num_key_value_heads=2,
+                    head_dim=16, rms_norm_eps=1e-5, rope_theta=10000.0,
+                    num_experts=8, num_experts_per_tok=2,
+                    moe_scoring="sigmoid", moe_intermediate_size=32,
+                    shared_expert_intermediate_size=32,
+                    routed_scaling_factor=2.5, first_held_expert=0,
+                    num_held_experts=2, max_position_embeddings=256,
+                    model_name="window-moe-tiny")
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def tiny_looped(cls, **kw) -> "ModelConfig":
         """Two four-norm blocks applied three times at a size for the
         CPU mesh (models/looped.py)."""
@@ -398,6 +438,91 @@ class ModelConfig:
             moe_scoring="sigmoid", moe_act="relu2")
 
     @classmethod
+    def _from_exaone_moe(cls, get, req, leave_out=()) -> "ModelConfig":
+        """``model_type: exaone_moe``: window and global attention by
+        ``layer_types``, leading dense layers, sigmoid-routed experts
+        and shared ones (models/window_moe.py)."""
+        n_layers = req("num_hidden_layers")
+        letters = {"sliding_attention": "L", "full_attention": "G"}
+        types = req("layer_types")
+        if len(types) != n_layers or set(types) - set(letters):
+            raise NotImplementedError(
+                f"layer_types {types!r}: 'sliding_attention' or "
+                f"'full_attention' for each of the {n_layers} layers is "
+                "served")
+        pattern = "".join(letters[t] for t in types)
+        window = get("sliding_window") or 0
+        if "L" in pattern and window < 1:
+            raise ValueError("sliding_attention layers need a "
+                             "sliding_window of 1 or more")
+        windows = get("sliding_windows")
+        if windows is not None and list(windows) != [
+                window if c == "L" else 0 for c in pattern]:
+            raise NotImplementedError(
+                f"sliding_windows {windows!r}: one window, "
+                f"sliding_window={window}, on every sliding_attention "
+                "layer and none on the others is served")
+        dense = get("first_k_dense_replace") or 0
+        mlp_types = get("mlp_layer_types")
+        if mlp_types is not None and list(mlp_types) != (
+                ["dense"] * dense + ["sparse"] * (n_layers - dense)):
+            raise NotImplementedError(
+                f"mlp_layer_types {mlp_types!r}: first_k_dense_replace="
+                f"{dense} leading dense layers, then sparse ones, is "
+                "served")
+        rope = get("rope_parameters") or get("rope_scaling") or {}
+        kind = rope.get("rope_type") or rope.get("type") or "default"
+        if kind != "default":
+            raise NotImplementedError(
+                f"rope_type {kind!r}: exaone_moe is computed with plain "
+                "rope on its window layers")
+        if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+            raise NotImplementedError(
+                f"n_group={get('n_group')}, topk_group="
+                f"{get('topk_group')}: group-limited routing is not "
+                "computed; the router chooses among all experts")
+        if (get("num_nextn_predict_layers") or 0) and (
+                "mtp" not in leave_out):
+            raise NotImplementedError(
+                "num_nextn_predict_layers="
+                f"{get('num_nextn_predict_layers')}: the multi-token-"
+                "prediction module is not computed; say "
+                "from_hf_config(..., leave_out=('mtp',)) to serve the "
+                "model without it")
+        if get("scoring_func", "sigmoid") != "sigmoid" or get(
+                "hidden_act", "silu") != "silu":
+            raise NotImplementedError(
+                "exaone_moe with a sigmoid router and SiLU-gated "
+                f"experts is computed, not scoring_func="
+                f"{get('scoring_func')!r}, hidden_act="
+                f"{get('hidden_act')!r}")
+        heads = req("num_attention_heads")
+        d = req("hidden_size")
+        ff = req("moe_intermediate_size")
+        return cls(
+            vocab_size=req("vocab_size"), hidden_size=d,
+            intermediate_size=req("intermediate_size"),
+            num_hidden_layers=n_layers, attn_pattern=pattern,
+            sliding_window=window, first_dense_layers=dense,
+            num_attention_heads=heads,
+            num_key_value_heads=get("num_key_value_heads", heads),
+            head_dim=get("head_dim") or d // heads, qk_norm=True,
+            rms_norm_eps=get("rms_norm_eps", 1e-5),
+            rope_theta=get("rope_theta") or rope.get("rope_theta",
+                                                     1_000_000.0),
+            max_position_embeddings=get("max_position_embeddings", 40960),
+            tie_word_embeddings=get("tie_word_embeddings", False),
+            model_name="exaone_moe",
+            num_experts=req("num_experts"),
+            num_experts_per_tok=req("num_experts_per_tok"),
+            moe_intermediate_size=ff,
+            shared_expert_intermediate_size=ff * (
+                get("num_shared_experts") or 0),
+            norm_topk_prob=get("norm_topk_prob", True),
+            routed_scaling_factor=get("routed_scaling_factor") or 1.0,
+            moe_scoring="sigmoid")
+
+    @classmethod
     def qwen3_next_80b_a3b(cls) -> "ModelConfig":
         """Qwen3-Next-80B-A3B geometry: 48 layers, 3 GDN : 1 full-attn,
         MoE FFN (512 experts, 10 active + shared omitted)."""
@@ -436,11 +561,14 @@ class ModelConfig:
                    head_dim=head_dim, model_name="qwen3-tiny")
 
     @classmethod
-    def from_hf_config(cls, hf_cfg) -> "ModelConfig":
+    def from_hf_config(cls, hf_cfg, leave_out=()) -> "ModelConfig":
         """Build from a transformers AutoConfig OR a raw ``config.json``
         dict (reference loads HF checkpoints, ``models/dense.py:150``).
         The single HF→ModelConfig mapper: covers dense, MoE
         (Qwen3-MoE), and hybrid GDN (Qwen3-Next) field sets.
+        ``leave_out``: parts of the published model the caller states
+        are served WITHOUT (``"mtp"``: a multi-token-prediction
+        module), where the family would otherwise refuse the config.
         """
         if isinstance(hf_cfg, dict):
             get = lambda k, d=None: hf_cfg.get(k, d)
@@ -460,6 +588,8 @@ class ModelConfig:
 
         if get("model_type") == "nemotron_h":
             return cls._from_nemotron_h(get, req)
+        if get("model_type") == "exaone_moe":
+            return cls._from_exaone_moe(get, req, tuple(leave_out))
         d = req("hidden_size")
         heads = req("num_attention_heads")
 
@@ -483,9 +613,21 @@ class ModelConfig:
                     f", mlp_only_layers={get('mlp_only_layers')})")
         interval = get("full_attention_interval", 4) or 4
         layer_types = get("layer_types")
-        # Only hybrid (GDN) models consult the schedule; non-hybrid
-        # layer_types lists (e.g. sliding-window patterns) are not this
-        # config's concern and must not block loading.
+        # A window on some layer: served with full attention everywhere
+        # it would be silently wrong past the window. Only the family
+        # that computes it reads such a config (exaone_moe, above).
+        windowed = [t for t in (layer_types or ())
+                    if "sliding" in str(t) or "chunked" in str(t)]
+        if windowed or (get("sliding_window") and get(
+                "use_sliding_window", layer_types is None
+                and get("model_type") in ("mistral", "mixtral"))):
+            raise NotImplementedError(
+                f"model_type {get('model_type')!r} with a window "
+                f"(sliding_window={get('sliding_window')}, "
+                f"{len(windowed)} windowed layer_types): this family "
+                "computes full attention on every layer; window layers "
+                "are served by models.window_moe (exaone_moe)")
+        # Only hybrid (GDN) models consult the schedule.
         if layer_types and (get("linear_num_value_heads", 0) or 0):
             fulls = [i for i, t in enumerate(layer_types)
                      if t == "full_attention"]

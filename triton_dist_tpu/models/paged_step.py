@@ -187,6 +187,20 @@ class Rows:
 
 # -- the K/V pool's halves --------------------------------------------------
 
+def _valid_qpos(positions, start, valid):
+    """A chunk's query positions with the bucket-padding rows clamped
+    to the last VALID position: their outputs are discarded garbage
+    either way, but unclamped they would stretch a kernel's page walk
+    to the padded tail (8x the DMA traffic for exactly the
+    short-prompt-in-a-big-bucket case the kernel exists to make
+    cheap)."""
+    i = jnp.arange(positions.shape[0], dtype=jnp.int32)
+    last_valid = (jnp.asarray(start, jnp.int32)
+                  + jnp.maximum(jnp.asarray(valid, jnp.int32)
+                                - 1, 0))
+    return jnp.where(i < valid, positions, last_valid)
+
+
 @scope("attn_chunk")
 def chunk_attend(li, q, cache, table_row, positions, start, valid,
                  attn_impl):
@@ -201,17 +215,7 @@ def chunk_attend(li, q, cache, table_row, positions, start, valid,
         from triton_dist_tpu.ops.paged_flash_qblock import (
             paged_flash_qblock)
 
-        # Bucket-padding rows clamp to the last VALID position:
-        # their outputs are discarded garbage either way, but
-        # unclamped they would stretch the kernel's page-walk
-        # bound (max position) to the padded tail — 8x the DMA
-        # traffic for exactly the short-prompt-in-a-big-bucket
-        # case the kernel exists to make cheap.
-        i = jnp.arange(positions.shape[0], dtype=jnp.int32)
-        last_valid = (jnp.asarray(start, jnp.int32)
-                      + jnp.maximum(jnp.asarray(valid, jnp.int32)
-                                    - 1, 0))
-        qpos = jnp.where(i < valid, positions, last_valid)
+        qpos = _valid_qpos(positions, start, valid)
         ksc, vsc = cache.layer_scales(li)
         return paged_flash_qblock(
             q[:, 0][None], cache.k_pages, cache.v_pages,
@@ -221,6 +225,35 @@ def chunk_attend(li, q, cache, table_row, positions, start, valid,
 
     kd, vd = cache.dense_row(li, table_row)
     return chunk_attend(q[:, 0], kd, vd, positions)
+
+
+@scope("attn_chunk_window")
+def chunk_attend_window(li, q, cache, ring_row, positions, start, valid,
+                        attn_impl, window: int):
+    """:func:`chunk_attend` for a WINDOW layer (``li`` its index among
+    them): row ``i`` reads keys ``j`` with ``i - window < j <= i`` out
+    of the slot's ring ``ring_row`` in the window layers' pool.
+    ``"flash"`` walks, a row block, the pages that hold a key in its
+    reach (``paged_flash_qblock(window=...)``); ``"ref"`` gathers the
+    ring whole, in position order from the first row's oldest key."""
+    qpos = _valid_qpos(positions, start, valid)
+    if attn_impl == "flash":
+        from triton_dist_tpu.ops.paged_flash_qblock import (
+            paged_flash_qblock)
+
+        return paged_flash_qblock(
+            q[:, 0][None], cache.win["k"], cache.win["v"], ring_row[None],
+            qpos[None], layer=li, window=window)[0]
+    from triton_dist_tpu.ops.chunked_prefill import (gather_ring_dense,
+                                                     window_attend)
+
+    first = jnp.maximum(positions[0] - (window - 1), 0) // cache.page
+    ring = ring_row.shape[0]
+    kd, key_pos = gather_ring_dense(cache.win["k"][li], ring_row, first,
+                                    ring)
+    vd, _ = gather_ring_dense(cache.win["v"][li], ring_row, first, ring)
+    return window_attend(q[:, 0][None], kd[None], vd[None], qpos[None],
+                         key_pos[None], window)[0]
 
 
 @scope("attn_decode")
@@ -242,10 +275,42 @@ def decode_attend(li, q, cache, attn_impl):
 
         ksc, vsc = cache.layer_scales(li)
         return paged_flash_decode(
-            q[:, 0], cache.k_pages, cache.v_pages, cache.block_table,
-            kv_len, layer=li, axis=None, k_scale=ksc, v_scale=vsc)
+            q[:, 0], cache.k_pages, cache.v_pages,
+            cache.table_of(cache.block_table), kv_len, layer=li,
+            axis=None, k_scale=ksc, v_scale=vsc)
     kd, vd = cache.dense_layer(li)
     return tp_attn.sdpa(q, kd, vd, causal=False, kv_len=kv_len)
+
+
+@scope("attn_decode_window")
+def decode_attend_window(li, q, cache, attn_impl, window: int):
+    """:func:`decode_attend` for a WINDOW layer (``li`` its index among
+    them): a slot's query reads its last ``window`` keys, the token
+    appended this step among them, out of the slot's ring.
+    ``"kernel"`` / ``"flash"`` walk the pages those keys span
+    (``paged_flash_decode(window=...)``); ``"ref"`` gathers them."""
+    kv_len = jnp.maximum(cache.lens + cache.live, 1).astype(jnp.int32)
+    rings = cache.table_of(cache.block_table, window=True)
+    if attn_impl in ("kernel", "flash"):
+        from triton_dist_tpu.ops.paged_flash_decode import (
+            paged_flash_decode)
+
+        return paged_flash_decode(
+            q[:, 0], cache.win["k"], cache.win["v"], rings, kv_len,
+            layer=li, axis=None, window=window)
+    from triton_dist_tpu.ops.chunked_prefill import (gather_ring_dense,
+                                                     window_attend)
+
+    from triton_dist_tpu.ops.paged_flash_decode import window_walk_pages
+
+    page = cache.page
+    n_pages = min(window_walk_pages(1, window, page), rings.shape[1])
+    first = jnp.maximum(kv_len - window, 0) // page
+    kd, key_pos = gather_ring_dense(cache.win["k"][li], rings, first,
+                                    n_pages)
+    vd, _ = gather_ring_dense(cache.win["v"][li], rings, first, n_pages)
+    return window_attend(q, kd, vd, (kv_len - 1)[:, None], key_pos,
+                         window)
 
 
 def _verify_attend(li, q, k_tok, v_tok, cache, rows, attn_impl):
@@ -285,7 +350,8 @@ def _verify_attend(li, q, k_tok, v_tok, cache, rows, attn_impl):
         return block_attend(q, kd, vd, lens, cache.live), cache
 
 
-def kv_attend(rows: Rows, attn_impl: str, decode_attn_impl: str):
+def kv_attend(rows: Rows, attn_impl: str, decode_attn_impl: str,
+              window: int = 0):
     """What a layer over a ``PagedKVCache`` does between its
     projections: ``attend(li, q, k_tok, v_tok, cache) -> (o, cache)``
     for rows ``(n, 1, heads, hd)``, ``li`` the POOL's layer (an int, or
@@ -293,25 +359,53 @@ def kv_attend(rows: Rows, attn_impl: str, decode_attn_impl: str):
     (``write_chunk``) and read by :func:`chunk_attend` under
     ``attn_impl``; decode rows append through ``cache.block_table`` and
     read by :func:`decode_attend` under ``decode_attn_impl``;
-    verification rows take ``attn_impl``. ``o`` reshapes to (n, -1)."""
+    verification rows take ``attn_impl``. ``o`` reshapes to (n, -1).
+
+    ``window`` > 0: the halves of a WINDOW layer, which reads a row's
+    last ``window`` keys alone. ``li`` counts among the window layers;
+    both kinds of rows write and read the window layers' pool through
+    the slot's ring (``PagedKVCache.win``), by
+    :func:`chunk_attend_window` and :func:`decode_attend_window`. A
+    model of both kinds of layer makes one ``attend`` a kind."""
     if rows.k:
+        if window:
+            raise NotImplementedError(
+                "verification rows over window layers: a refused "
+                "candidate's entry would have written over a key the "
+                "ring still needs")
         return functools.partial(_verify_attend, rows=rows,
                                  attn_impl=attn_impl)
     pos = rows.chunk_pos if rows.c else None
+    kind = {"window": True} if window else {}
+    if window:
+        def read_chunk(li, q, cache):
+            return chunk_attend_window(
+                li, q, cache, cache.table_of(rows.table_row, window=True),
+                pos, rows.start, rows.valid, attn_impl, window)
+
+        def read_decode(li, q, cache):
+            return decode_attend_window(li, q, cache, decode_attn_impl,
+                                        window)
+    else:
+        def read_chunk(li, q, cache):
+            return chunk_attend(li, q, cache, cache.table_of(rows.table_row),
+                                pos, rows.start, rows.valid, attn_impl)
+
+        def read_decode(li, q, cache):
+            return decode_attend(li, q, cache, decode_attn_impl)
 
     def attend(li, q, k_tok, v_tok, cache):
         with scope("cache_write"):
             _, cache = rows.split(
                 lambda cache, k, v: (None, cache.write_chunk(
-                    li, k, v, rows.table_row, pos, rows.valid, rows.wfrom)),
-                lambda cache, k, v: (None, cache.append_decode(li, k, v)),
+                    li, k, v, rows.table_row, pos, rows.valid, rows.wfrom,
+                    **kind)),
+                lambda cache, k, v: (None, cache.append_decode(li, k, v,
+                                                               **kind)),
                 cache, k_tok, v_tok)
         return rows.split(
-            lambda cache, q: (chunk_attend(
-                li, q, cache, rows.table_row, pos, rows.start, rows.valid,
-                attn_impl), cache),
-            lambda cache, q: (decode_attend(li, q, cache,
-                                            decode_attn_impl), cache),
+            lambda cache, q: (read_chunk(li, q, cache), cache),
+            lambda cache, q: (read_decode(li, q, cache), cache),
             cache, q)
 
     return attend

@@ -99,6 +99,10 @@ DEVICE_SCOPES = (
                          # and a sequence's state where it keeps one
     "attn_chunk",        # what a prefill chunk's queries read
     "attn_decode",       # what decode and verification rows read
+    "attn_chunk_window", # what a chunk's queries read in a layer that
+                         # keeps a window of positions (the other two
+                         # then hold the layers that keep all)
+    "attn_decode_window",  # what decode rows read in such a layer
     "attn_out",          # the output projection and its residual
     "ssm_project",       # a state-space layer's norm and in-projection
     "ssm",               # between its two projections: convolution,

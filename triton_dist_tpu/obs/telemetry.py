@@ -66,11 +66,14 @@ _OP_HIST_KINDS = frozenset({
 # ``expert_load`` event counted; of a model whose layers run several
 # times, ``passes`` and the pass the rows' logits were read from,
 # ``exit_pass``: a decode's mean over its rows, known once its tokens
-# are on the host, so set while the span is open).
+# are on the host, so set while the span is open; of a model with
+# window layers, ``window_pages``: the pages a chunk's slot holds in
+# such a layer, its ring).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
                         "bucket", "valid", "walk_kernel", "scan_kernel",
                         "experts_kernel", "padded_up", "chunks",
                         "waited_ms", "passes", "exit_pass",
+                        "window_pages",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",
                         "expert_rows_max", "expert_imbalance"})

@@ -62,6 +62,52 @@ def gather_pages_dense(pool, table, scale=None):
     return g.reshape(*table.shape[:-1], table.shape[-1] * page, kvh, hd)
 
 
+def gather_ring_dense(pool, ring_table, first_page, n_pages: int):
+    """Pages ``first_page .. first_page + n_pages - 1`` of each slot out
+    of a window layer's RING, position-major, with the positions they
+    hold if they are the slot's newest: the gather-path view of a pool
+    whose table entry ``n % ring`` holds page ``n``.
+
+    pool: (num_pages, KV, page, hd), ONE layer's; ring_table: (...,
+    ring) int32; first_page: (...) int32. Returns ``(dense (..., n_pages
+    * page, KV, hd), key_pos (..., n_pages * page) int32)``. An entry
+    may hold an older page (or another request's): the caller's mask
+    bounds the keys on both sides."""
+    page = pool.shape[2]
+    pages = (jnp.asarray(first_page, jnp.int32)[..., None]
+             + jnp.arange(n_pages, dtype=jnp.int32))
+    ids = jnp.take_along_axis(ring_table, pages % ring_table.shape[-1],
+                              axis=-1)
+    key_pos = (pages[..., None] * page
+               + jnp.arange(page, dtype=jnp.int32)).reshape(
+                   *pages.shape[:-1], n_pages * page)
+    return gather_pages_dense(pool, ids), key_pos
+
+
+def window_attend(q, k_dense, v_dense, positions, key_pos, window: int):
+    """Attention of queries that read the last ``window`` keys through
+    their own, over a gathered view whose keys carry their positions
+    (:func:`gather_ring_dense`).
+
+    q: (B, Cq, H, hd); k_dense/v_dense: (B, T, KV, hd); positions: (B,
+    Cq) int32; key_pos: (B, T) int32. Query ``(b, i)`` reads keys with
+    ``positions[b, i] - window < key_pos <= positions[b, i]``. GQA by
+    head repeat, fp32 softmax (:func:`chunk_attend`'s numerics).
+    Returns (B, Cq, H, hd)."""
+    h, hd = q.shape[2:]
+    rep = h // k_dense.shape[2]
+    k = jnp.repeat(k_dense, rep, axis=2)
+    v = jnp.repeat(v_dense, rep, axis=2)
+    scores = jnp.einsum("bchd,bthd->bhct", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(hd))
+    kp, qp = key_pos[:, None, :], positions[:, :, None]
+    mask = jnp.logical_and(kp <= qp, kp > qp - window)
+    scores = jnp.where(mask[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhct,bthd->bchd", probs, v)
+
+
 def _greedy_step(rem: int, bs) -> tuple:
     """One step of the greedy cover over sorted buckets ``bs``: the
     largest bucket that fits in ``rem`` whole, else the smallest one

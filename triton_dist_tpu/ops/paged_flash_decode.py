@@ -142,10 +142,19 @@ def _pool_layer(pool, layer):
     return None
 
 
+def window_walk_pages(rows: int, window: int, page: int) -> int:
+    """Pages that ``rows`` consecutive query positions walk under
+    ``window``, at most: the keys in reach of any of them are ``rows +
+    window - 1`` consecutive positions, which begin anywhere in a page.
+    One decode row: ``rows`` 1."""
+    return (rows + window - 2) // page + 2
+
+
 def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
                    kvh: int, rep: int, hd: int, shard_len: int,
                    paged: bool, sim: bool, quantized: bool = False,
-                   layered: bool = False):
+                   layered: bool = False, window: int = 0,
+                   ring: int = 0):
     """``axes``: list of (axis_name, n_ax) exchange stages, innermost
     first (1 entry = flat; 2 = hierarchical outer x inner, where the
     flat shard order is outer-major). ``paged=False`` reads a dense
@@ -157,7 +166,12 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     (B, P_max, KV) fp32 scale tables ride in VMEM — the dequant fuses
     into each page's compute step (:func:`page_attend`).
     ``layered=True``: the first operand is :func:`_pool_layer`'s layer
-    index, read from SMEM and put before the page id."""
+    index, read from SMEM and put before the page id.
+    ``window`` > 0 (paged, local, unquantized): a slot's query, at
+    position ``len - 1``, reads keys ``j >= len - window``. The table
+    is then a RING of ``ring`` entries (page ``n`` of the slot in entry
+    ``n % ring``) and ``p_max`` the pages a slot WALKS (the grid's last
+    dimension), from the page that holds its oldest key in reach."""
     ks_ref = vs_ref = None
     prefix = ()
     if layered:
@@ -197,11 +211,20 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     # Page p of batch b lives at pool slot table[b, p]. Pages past this
     # batch's (local) length are skipped entirely.
     local_end = jnp.clip(len_ref[b] - off, 0, shard_len)
-    active = p * page < local_end
+    if window:
+        def first_page(b_):
+            return jnp.maximum(len_ref[b_] - window, 0) // page
+
+        page_no = first_page(b) + p
+    else:
+        page_no = p
+    active = page_no * page < local_end
     lin = b * p_max + p
     par = jax.lax.rem(lin, 2)
 
     def load(b2, p2, buf):
+        if window:
+            p2 = jax.lax.rem(first_page(b2) + p2, ring)
         if paged:
             pid = table_ref[b2, p2]
             ksrc = kp_ref.at[(*prefix, pid)]
@@ -233,7 +256,8 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     b2 = jnp.minimum(nxt // p_max, n_b - 1)
     p2 = jax.lax.rem(nxt, p_max)
     end2 = jnp.clip(len_ref[b2] - off, 0, shard_len)
-    active2 = jnp.logical_and(nxt < n_b * p_max, p2 * page < end2)
+    page_no2 = first_page(b2) + p2 if window else p2
+    active2 = jnp.logical_and(nxt < n_b * p_max, page_no2 * page < end2)
 
     @pl.when(active2)
     def _():
@@ -248,8 +272,11 @@ def _decode_kernel(*refs, axes, ctx: MeshContext, page: int, p_max: int,
     @pl.when(active)
     def _():
         q2 = q_ref[0, b].astype(jnp.float32)
-        pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        pos = page_no * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page), 1)
         mask = pos < local_end
+        if window:
+            mask = jnp.logical_and(mask, pos >= local_end - window)
         ksc = vsc = None
         if quantized:
             # Per-page per-head dequant scales, gathered host-side
@@ -371,7 +398,7 @@ def _check_kv_len(kv_len, p_max: int, page: int, n: int, sim: bool):
 
 def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
                  page, p_max, paged, sim_ranks=0, k_scale=None,
-                 v_scale=None, layer=None):
+                 v_scale=None, layer=None, window: int = 0):
     """Shared host plumbing for the paged and dense decode kernels."""
     b, h, hd = q.shape
     kvh = k_arr.shape[-3]
@@ -379,12 +406,23 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
     quantized = k_scale is not None
     axes, n, sim = _normalize_axes(axis, ctx, sim_ranks)
     shard_len = p_max * page
-    _check_kv_len(kv_len, p_max, page, n, sim)
+    more = {}
+    if window:
+        if not paged or n > 1 or quantized:
+            raise ValueError("window decode reads a local, unquantized "
+                             "paged pool (axis=None)")
+        # The table is a ring, which holds any position; the grid's
+        # last dimension is the pages that ``window`` keys span.
+        more = {"window": window, "ring": p_max}
+        p_max = min(window_walk_pages(1, window, page), p_max)
+        shard_len = 2 ** 30
+    else:
+        _check_kv_len(kv_len, p_max, page, n, sim)
 
     kernel = functools.partial(
         _decode_kernel, axes=axes, ctx=ctx, page=page, p_max=p_max,
         kvh=kvh, rep=rep, hd=hd, shard_len=shard_len, paged=paged,
-        sim=sim, quantized=quantized, layered=layer is not None)
+        sim=sim, quantized=quantized, layered=layer is not None, **more)
 
     n_sem = max(sum(n_ax - 1 for _, n_ax in axes), 1)
     n_slots = max(max(n_ax for _, n_ax in axes), 1)
@@ -451,7 +489,7 @@ def _decode_call(q, k_arr, v_arr, block_table, kv_len, *, ctx, axis,
 
 def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
                        layer=None, ctx: MeshContext = None, axis="sp",
-                       k_scale=None, v_scale=None):
+                       k_scale=None, v_scale=None, window: int = 0):
     """Paged-KV GQA decode step (in shard_map) on a 4-D pool, or 5-D + layer.
 
     Distributed: call inside shard_map.
@@ -476,9 +514,19 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
     shards in outer-major flat order; the in-kernel partial exchange
     runs inner-axis first, so only one already-combined partial per
     outer peer crosses the slow link.
+    ``window`` > 0 (static; ``axis=None``, an unquantized pool): the
+    query reads its slot's last ``window`` keys alone, ``j >= kv_len -
+    window``, and ``block_table`` (B, ring) is each slot's RING, page
+    ``n`` of the slot in entry ``n % ring``; a slot's walk is the pages
+    those keys span, whatever its length.
     Returns (B, H, hd).
     """
     _require_pool_scales(k_pages, k_scale, reject_spurious=True)
+    if window:
+        return _paged_decode_call(q, k_pages, v_pages, block_table, kv_len,
+                                  _pool_layer(k_pages, layer), k_scale,
+                                  v_scale, ctx=ctx, axis=axis,
+                                  window=int(window))
     _check_kv_len(kv_len, block_table.shape[1], k_pages.shape[-2],
                   *_normalize_axes(axis, ctx, 0)[1:])
     return _paged_decode_call(q, k_pages, v_pages, block_table, kv_len,
@@ -486,15 +534,17 @@ def paged_flash_decode(q, k_pages, v_pages, block_table, kv_len, *,
                               v_scale, ctx=ctx, axis=axis)
 
 
-@functools.partial(jax.jit, static_argnames=("ctx", "axis"))
+@functools.partial(jax.jit, static_argnames=("ctx", "axis", "window"))
 def _paged_decode_call(q, k_pages, v_pages, block_table, kv_len, layer,
-                       k_scale, v_scale, *, ctx, axis):
+                       k_scale, v_scale, *, ctx, axis, window: int = 0):
     """:func:`paged_flash_decode` behind one jit: the layers of a step
     program share its trace and its lowering (:func:`_pool_layer`)."""
+    more = {"window": window} if window else {}
     return _decode_call(q, k_pages, v_pages, block_table, kv_len,
                         ctx=ctx, axis=axis, page=k_pages.shape[-2],
                         p_max=block_table.shape[1], paged=True,
-                        k_scale=k_scale, v_scale=v_scale, layer=layer)
+                        k_scale=k_scale, v_scale=v_scale, layer=layer,
+                        **more)
 
 
 def paged_flash_decode_ref(q, k_pages, v_pages, block_table, kv_len,

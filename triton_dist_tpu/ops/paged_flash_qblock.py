@@ -41,8 +41,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.lang import core_call
-from triton_dist_tpu.ops.paged_flash_decode import (
-    _require_pool_scales, _pool_layer)
+from triton_dist_tpu.ops.paged_flash_decode import (  # noqa: F401
+    _require_pool_scales, _pool_layer, window_walk_pages)
 
 
 def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
@@ -81,7 +81,8 @@ def qblock_page_attend(q2, kpage, vpage, m, l, acc, mask, rep: int,
 
 def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
                    hd: int, cq: int, quantized: bool,
-                   layered: bool = False):
+                   layered: bool = False, window: int = 0,
+                   ring: int = 0):
     """Grid (B, KV, P_max): slot-major, then one KV head (and its
     ``rep`` query heads) at a time, then that head's page walk with the
     decode kernel's double-buffered prefetch (per-parity semaphores);
@@ -94,11 +95,22 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     pools use (every rank holds the full sequence for its heads).
     ``layered=True``: the first operand is :func:`~triton_dist_tpu.ops.
     paged_flash_decode._pool_layer`'s layer index, read from SMEM and
-    put before the page id."""
-    ks_ref = vs_ref = None
+    put before the page id.
+
+    ``window`` > 0: a query at position ``i`` reads keys ``j`` with ``i
+    - window < j <= i``. The table is then a RING of ``ring`` entries
+    (page ``n`` of the slot in entry ``n % ring``), ``p_max`` is the
+    pages a block WALKS (the grid's last dimension: what ``cq`` rows
+    and the window span, whatever the context), and one more SMEM
+    operand, ``lo_ref``, gives each block the first page that holds a
+    key in its first row's reach: step ``p`` of the walk is page
+    ``lo_ref[b] + p``."""
+    ks_ref = vs_ref = lo_ref = None
     prefix = ()
     if layered:
         prefix, refs = (refs[0][0],), refs[1:]
+    if window:
+        lo_ref, refs = refs[0], refs[1:]
     if quantized:
         (table_ref, end_ref, pos_ref, q_ref, kp_ref, vp_ref, ks_ref,
          vs_ref, o_ref) = refs[:9]
@@ -118,12 +130,21 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     # slot's maximum attended position carry no unmasked key for ANY
     # query — skip them entirely (this is what makes the kernel scale
     # with resident pages, not capacity).
-    end = jnp.clip(end_ref[b], 1, p_max * page)
-    active = p * page < end
+    if window:
+        # No row bound: a ring holds any position. The walk's first
+        # page always holds a key of the block's first row.
+        end = jnp.maximum(end_ref[b], 1)
+        page_no = lo_ref[b] + p
+    else:
+        end = jnp.clip(end_ref[b], 1, p_max * page)
+        page_no = p
+    active = page_no * page < end
     lin = (b * kvh + g) * p_max + p
     par = jax.lax.rem(lin, 2)
 
     def load(b2, g2, p2, buf):
+        if window:
+            p2 = jax.lax.rem(lo_ref[b2] + p2, ring)
         pid = table_ref[b2, p2]
         src = (*prefix, pid, pl.ds(g2, 1))
         pltpu.make_async_copy(kp_ref.at[src], kpage.at[buf],
@@ -149,8 +170,14 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     b2 = jnp.minimum(nxt // (kvh * p_max), n_b - 1)
     g2 = jax.lax.rem(nxt // p_max, kvh)
     p2 = jax.lax.rem(nxt, p_max)
-    end2 = jnp.clip(end_ref[b2], 1, p_max * page)
-    active2 = jnp.logical_and(nxt < n_b * kvh * p_max, p2 * page < end2)
+    if window:
+        end2 = jnp.maximum(end_ref[b2], 1)
+        page_no2 = lo_ref[b2] + p2
+    else:
+        end2 = jnp.clip(end_ref[b2], 1, p_max * page)
+        page_no2 = p2
+    active2 = jnp.logical_and(nxt < n_b * kvh * p_max,
+                              page_no2 * page < end2)
 
     @pl.when(active2)
     def _():
@@ -165,9 +192,11 @@ def _qblock_kernel(*refs, page: int, p_max: int, kvh: int, rep: int,
     @pl.when(active)
     def _():
         q2 = q_ref[0].astype(jnp.float32)                # (rep, Cq, hd)
-        key_pos = p * page + jax.lax.broadcasted_iota(
+        key_pos = page_no * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, page), 1)
         mask = key_pos <= pos_ref[0]         # (Cq, 1) -> (Cq, page)
+        if window:
+            mask = jnp.logical_and(mask, key_pos > pos_ref[0] - window)
         ksc = vsc = None
         if quantized:
             # This head's per-page dequant scale, picked out of the
@@ -213,7 +242,8 @@ def qblock_rows(cq: int, rep: int, hd: int, page: int, itemsize: int,
 
 
 def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
-                       layer=None, k_scale=None, v_scale=None):
+                       layer=None, k_scale=None, v_scale=None,
+                       window: int = 0):
     """Paged-KV GQA attention of a Q-BLOCK per slot; 4-D pool, or 5-D + layer.
 
     The local form (no partial exchange).
@@ -233,6 +263,16 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
     the chunk case passes ``start + arange(C)`` and the verification
     case ``lens[s] + j`` (parked slots 0).
 
+    ``window`` > 0 (static): a query reads only the last ``window``
+    keys through its own, ``positions[b, i] - window < j``, and
+    ``block_table`` (B, ring) is each slot's RING in the pool, page
+    ``n`` of the slot in entry ``n % ring``. A row block then walks
+    from the first page that holds a key in reach of its lowest
+    position, :func:`window_walk_pages` pages at most whatever the
+    context; the positions of a block lie within its row count of each
+    other (a chunk's consecutive rows, its padding clamped to the last
+    valid one). Not for quantized pools.
+
     Positions ride as DATA — the trace signature depends only on the
     block shape (B, Cq), never on lengths, so the serving dispatches
     built on this kernel keep their one-entry jit caches. Concrete
@@ -244,6 +284,12 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
     p_max = block_table.shape[1]
     _require_pool_scales(k_pages, k_scale, reject_spurious=True)
     positions = jnp.maximum(jnp.asarray(positions, jnp.int32), 0)
+    if window:
+        if k_scale is not None:
+            raise ValueError("window attention reads an unquantized pool")
+        return _qblock_call(q, k_pages, v_pages, block_table, positions,
+                            _pool_layer(k_pages, layer), None, None,
+                            window=int(window))
     if not isinstance(positions, jax.core.Tracer):
         import numpy as _np
 
@@ -260,9 +306,9 @@ def paged_flash_qblock(q, k_pages, v_pages, block_table, positions, *,
                         _pool_layer(k_pages, layer), k_scale, v_scale)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("window",))
 def _qblock_call(q, k_pages, v_pages, block_table, positions, layer,
-                 k_scale, v_scale):
+                 k_scale, v_scale, window: int = 0):
     """:func:`paged_flash_qblock` behind one jit: the layers of a step
     program share its trace and its lowering (``_pool_layer``)."""
     b, cq, h, hd = q.shape
@@ -285,9 +331,21 @@ def _qblock_call(q, k_pages, v_pages, block_table, positions, layer,
     end = jnp.max(positions, axis=1) + 1
     q_hm = q.transpose(0, 2, 1, 3)              # (nb, H, bq, hd)
 
+    ring = 0
+    if window:
+        # The table is a ring; the grid's last dimension is the walk.
+        ring, p_max = p_max, window_walk_pages(bq, window, page)
+        if p_max > ring:
+            raise ValueError(
+                f"a block of {bq} rows under window {window} walks "
+                f"{p_max} pages of {page}, the ring has {ring}")
+        lo = jnp.maximum(jnp.min(positions, axis=1) - (window - 1),
+                         0) // page
+
     kernel = functools.partial(
         _qblock_kernel, page=page, p_max=p_max, kvh=kvh, rep=rep,
-        hd=hd, cq=bq, quantized=quantized, layered=layer is not None)
+        hd=hd, cq=bq, quantized=quantized, layered=layer is not None,
+        **({"window": window, "ring": ring} if window else {}))
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),          # block_table
@@ -311,6 +369,9 @@ def _qblock_call(q, k_pages, v_pages, block_table, positions, layer,
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale[block_table].astype(jnp.float32),
                      v_scale[block_table].astype(jnp.float32)]
+    if window:
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.insert(0, lo.astype(jnp.int32))
     if layer is not None:
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.insert(0, layer)

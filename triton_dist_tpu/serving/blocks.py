@@ -167,13 +167,18 @@ def _merge_pages(pool, layer: int, pids, toks, write):
     return pool
 
 
-def _append_targets(block_table, lens, page: int, k: int, budget=None):
+def _append_targets(block_table, lens, page: int, k: int, budget=None,
+                    ring: bool = False):
     """Where ``k`` consecutive tokens a slot land, from each slot's own
     length on: ``(pids, offs)``, each ``(num_slots * k,)``. A token past
     its slot's table row, or past its slot's ``budget`` (S,), lands in
-    the scratch page, as a parked slot's (all-zero row) does."""
+    the scratch page, as a parked slot's (all-zero row) does. ``ring``:
+    the table is a window layer's ring, page ``i`` of a slot in entry
+    ``i % entries``, and no position lies past it."""
     pos = lens[:, None] + jnp.arange(k, dtype=jnp.int32)[None]
     rows_raw = pos // page
+    if ring:
+        rows_raw = rows_raw % block_table.shape[1]
     valid = rows_raw < block_table.shape[1]
     if budget is not None:
         valid = jnp.logical_and(
@@ -186,13 +191,15 @@ def _append_targets(block_table, lens, page: int, k: int, budget=None):
     return pids.reshape(-1), (pos % page).reshape(-1)
 
 
-def _chunk_window(table_row, positions, valid, wfrom, page: int):
+def _chunk_window(table_row, positions, valid, wfrom, page: int,
+                  ring: bool = False):
     """The window of whole pages a chunk of consecutive ``positions``
     lies in: ``(pids (n_t,), write (n_t * page,) bool, loc0)``, the
     chunk's first row at offset ``loc0`` of the window. A row is
     written where it is below ``valid`` and at or past ``wfrom``; a
     page none of whose rows is written (bucket padding, a prefix-shared
-    page, past the table row) is the scratch page."""
+    page, past the table row) is the scratch page. ``ring``: the row is
+    a window layer's ring (see :func:`_append_targets`)."""
     c = positions.shape[0]
     n_t = (c - 1) // page + 2
     row0 = positions[0] // page
@@ -202,6 +209,8 @@ def _chunk_window(table_row, positions, valid, wfrom, page: int):
         jnp.zeros((n_t * page,), bool),
         jnp.logical_and(i < valid, positions >= wfrom), (loc0,))
     rows = row0 + jnp.arange(n_t, dtype=jnp.int32)
+    if ring:
+        rows = rows % table_row.shape[0]
     written = jnp.any(write.reshape(n_t, page), axis=1)
     pids = jnp.where(
         jnp.logical_and(written, rows < table_row.shape[0]),
@@ -260,6 +269,36 @@ class SeqArray(NamedTuple):
         return jnp.zeros(full, pool_dtype)
 
 
+class WindowLayers(NamedTuple):
+    """The window layers of a model, as its ``paged_pool`` states them
+    (``window=WindowLayers(layers, window)``: how many of its paged
+    layers read only the last ``window`` positions) and as the server
+    completes them from its own sizes (:meth:`sized`): ``ring``, the
+    pages a slot holds in each such layer, and ``num_pages``, the
+    window pool's pages, the scratch page among them."""
+    layers: int
+    window: int
+    ring: int = 0
+    num_pages: int = 0
+
+    def sized(self, page: int, largest_chunk: int,
+              num_slots: int) -> "WindowLayers":
+        """With the ring a slot needs: the pages that ``window`` keys
+        and the rows of the largest chunk program span, and one more
+        (a chunk's rows are all written before any of them reads, so
+        the first row's oldest key and the last row's own must lie in
+        different entries whatever the chunk's offset in its page); and
+        pages for every slot's ring beside the scratch page."""
+        ring = -(-(self.window + max(largest_chunk, 1)) // page) + 1
+        return self._replace(ring=ring, num_pages=1 + num_slots * ring)
+
+    def require_sized(self) -> "WindowLayers":
+        if self.ring < 1 or self.num_pages < 2:
+            raise ValueError(f"window={self}: not sized "
+                             "(WindowLayers.sized)")
+        return self
+
+
 @dataclasses.dataclass
 class PagedKVCache:
     """Device half of the paged cache (see module docstring).
@@ -295,6 +334,21 @@ class PagedKVCache:
     rows only; the server allocates it and never reads it. Whatever
     moves PAGES (tiers, migration, prefix sharing) knows nothing of it:
     the server refuses those for a pool that states one.
+
+    ``win``: the pools of the model's WINDOW layers, ``"k"`` and ``"v"``
+    (L_w, window pages, KV_loc, page, hd), for a model some of whose
+    layers read only the last ``w`` positions (:class:`WindowLayers`);
+    empty for a model that states none, and then no leaf of the tree. A
+    slot holds ``ring`` pages there, a static count, used as a RING:
+    position ``p`` lies in entry ``(p // page) % ring``, and a page
+    behind the window is written over as the sequence grows. The slot's
+    ring is the last ``ring`` entries of its ``block_table`` row, behind
+    the ``p_max`` pages of the other layers (one upload a tick carries
+    both tables, :meth:`table_of` cuts a kind's part out); a window
+    layer writes and reads under ``window=True``, its index counted
+    among the window layers. What reads a ring bounds its keys from
+    below as well as above: an entry's page may hold an older page's
+    positions, or another request's.
     """
 
     k_pages: jax.Array
@@ -305,28 +359,41 @@ class PagedKVCache:
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
     seq: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
+    win: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
+    ring: int = 0
 
     @classmethod
     def empty(cls, num_layers: int, num_pages: int, page: int,
               kv_heads_loc: int, head_dim: int, *, num_slots: int,
               p_max: int, dtype=jnp.float32, kv_dtype: str = "bf16",
-              seq_state: Optional[Dict[str, SeqArray]] = None
+              seq_state: Optional[Dict[str, SeqArray]] = None,
+              window: Optional["WindowLayers"] = None
               ) -> "PagedKVCache":
         shape = (num_layers, num_pages, kv_heads_loc, page, head_dim)
         qdtype, _ = kv_quant_spec(kv_dtype)
         pool_dtype = dtype if qdtype is None else qdtype
+        win, ring = {}, 0
+        if window is not None:
+            if qdtype is not None:
+                raise ValueError("window layers keep an unquantized "
+                                 f"pool: kv_dtype={kv_dtype!r}")
+            ring = window.require_sized().ring
+            wshape = (window.layers, window.num_pages) + shape[2:]
+            win = {"k": jnp.zeros(wshape, dtype),
+                   "v": jnp.zeros(wshape, dtype)}
         scale = (None if qdtype is None else jnp.ones(
             (num_layers, num_pages, kv_heads_loc), jnp.float32))
         return cls(
             k_pages=jnp.zeros(shape, pool_dtype),
             v_pages=jnp.zeros(shape, pool_dtype),
-            block_table=jnp.zeros((num_slots, p_max), jnp.int32),
+            block_table=jnp.zeros((num_slots, p_max + ring), jnp.int32),
             lens=jnp.zeros((num_slots,), jnp.int32),
             live=jnp.zeros((num_slots,), jnp.int32),
             k_scale=scale, v_scale=(None if scale is None
                                     else jnp.ones_like(scale)),
             seq={name: a.zeros(num_slots, dtype)
-                 for name, a in (seq_state or {}).items()})
+                 for name, a in (seq_state or {}).items()},
+            win=win, ring=ring)
 
     @classmethod
     def empty_sharded(cls, mesh, spec_fn, axis: str, *shape_args,
@@ -369,9 +436,30 @@ class PagedKVCache:
     @property
     def capacity(self) -> int:
         """Tokens one block-table row can address (p_max · page)."""
-        return self.block_table.shape[1] * self.page
+        return (self.block_table.shape[1] - self.ring) * self.page
 
-    def append_decode(self, layer: int, k_tok, v_tok) -> "PagedKVCache":
+    def table_of(self, table, window: bool = False):
+        """The part of ``table`` (a slot's row, or the batch's rows)
+        that one kind of layer reads: the ``p_max`` pages first, the
+        window layers' ring behind them. A pool with no window layers
+        has one kind, and ``table`` is returned as it is."""
+        if not self.ring:
+            return table
+        cut = table.shape[-1] - self.ring
+        return table[..., cut:] if window else table[..., :cut]
+
+    def _pools(self, window: bool):
+        if window:
+            return self.win["k"], self.win["v"]
+        return self.k_pages, self.v_pages
+
+    def _with_pools(self, window: bool, k, v) -> "PagedKVCache":
+        if window:
+            return dataclasses.replace(self, win={"k": k, "v": v})
+        return dataclasses.replace(self, k_pages=k, v_pages=v)
+
+    def append_decode(self, layer: int, k_tok, v_tok,
+                      window: bool = False) -> "PagedKVCache":
         """Append one decode token's K/V per slot at each slot's own
         length — the paged half of the shared cache-update contract
         (:meth:`~triton_dist_tpu.models.kv_cache.KVCache.append_decode`
@@ -381,10 +469,10 @@ class PagedKVCache:
         The one-token case of :meth:`append_block`: an unquantized pool
         is updated in place, a row a slot (:func:`_put_rows`).
         """
-        return self.append_block(layer, k_tok, v_tok)
+        return self.append_block(layer, k_tok, v_tok, window=window)
 
-    def append_block(self, layer: int, k_tok, v_tok,
-                     budget=None) -> "PagedKVCache":
+    def append_block(self, layer: int, k_tok, v_tok, budget=None,
+                     window: bool = False) -> "PagedKVCache":
         """Write K consecutive tokens per slot at each slot's own
         length — the speculative-verification form of
         :meth:`append_decode` (positions ``lens[s]..lens[s]+K-1``; the
@@ -397,18 +485,20 @@ class PagedKVCache:
         contents (or, quantized, inflate its scale). An unquantized
         pool is updated in place in its own layout, a row a token
         (:func:`_put_rows`); a quantized one requantizes whole pages
-        (:meth:`_quant_append`)."""
+        (:meth:`_quant_append`). ``window``: ``layer`` is a window
+        layer's, and the tokens go into its pool through the slots'
+        rings."""
         if self.quantized:
             return self._quant_append(layer, k_tok, v_tok, budget)
-        pids, off = _append_targets(self.block_table, self.lens,
-                                    self.page, k_tok.shape[1], budget)
+        pids, off = _append_targets(
+            self.table_of(self.block_table, window), self.lens,
+            self.page, k_tok.shape[1], budget, ring=window)
         kvl, hd = k_tok.shape[2:]
-        return dataclasses.replace(
-            self,
-            k_pages=_put_rows(self.k_pages, layer, pids, off,
-                              k_tok.reshape(-1, kvl, hd)),
-            v_pages=_put_rows(self.v_pages, layer, pids, off,
-                              v_tok.reshape(-1, kvl, hd)))
+        kp, vp = self._pools(window)
+        return self._with_pools(
+            window,
+            _put_rows(kp, layer, pids, off, k_tok.reshape(-1, kvl, hd)),
+            _put_rows(vp, layer, pids, off, v_tok.reshape(-1, kvl, hd)))
 
     def _quant_append(self, layer: int, k_tok, v_tok,
                       budget=None) -> "PagedKVCache":
@@ -451,7 +541,8 @@ class PagedKVCache:
             self, lens=self.lens + self.live.astype(jnp.int32))
 
     def write_chunk(self, layer: int, k_tok, v_tok, table_row,
-                    positions, valid, wfrom) -> "PagedKVCache":
+                    positions, valid, wfrom,
+                    window: bool = False) -> "PagedKVCache":
         """Write one prefill CHUNK's K/V into a slot's pages — the
         chunked-prefill half of the cache-update contract
         (:meth:`append_decode` is the one-token decode half).
@@ -466,7 +557,9 @@ class PagedKVCache:
         unquantized pool is updated in place in its own layout, a whole
         page at a time (:func:`_merge_pages`: 2 to 5 pages a chunk, not
         one scatter row a token); a quantized one requantizes the
-        touched pages (:meth:`_quant_write_chunk`).
+        touched pages (:meth:`_quant_write_chunk`). ``window``:
+        ``layer`` is a window layer's, and the rows go into its pool
+        through the slot's ring, which ``table_row`` ends in.
         """
         if self.quantized:
             return self._quant_write_chunk(layer, k_tok, v_tok,
@@ -477,22 +570,22 @@ class PagedKVCache:
         # that begins at the start's page. Lay the rows out on that
         # window, and merge it into the pool a page at a time.
         page = self.page
-        pids, write, loc0 = _chunk_window(table_row, positions, valid,
-                                          wfrom, page)
+        pids, write, loc0 = _chunk_window(
+            self.table_of(table_row, window), positions, valid, wfrom,
+            page, ring=window)
         n_t = pids.shape[0]
 
-        def window(tok):
+        def pages_of(tok):
             kvl, hd = tok.shape[2:]
             return jax.lax.dynamic_update_slice(
                 jnp.zeros((kvl, n_t * page, hd), tok.dtype),
                 tok[:, 0].transpose(1, 0, 2), (0, loc0, 0))
 
-        return dataclasses.replace(
-            self,
-            k_pages=_merge_pages(self.k_pages, layer, pids,
-                                 window(k_tok), write),
-            v_pages=_merge_pages(self.v_pages, layer, pids,
-                                 window(v_tok), write))
+        kp, vp = self._pools(window)
+        return self._with_pools(
+            window,
+            _merge_pages(kp, layer, pids, pages_of(k_tok), write),
+            _merge_pages(vp, layer, pids, pages_of(v_tok), write))
 
     def _quant_write_chunk(self, layer, k_tok, v_tok, table_row,
                            positions, valid, wfrom) -> "PagedKVCache":
@@ -596,7 +689,7 @@ class PagedKVCache:
 
         def gather(pool, scale):
             return gather_pages_dense(
-                pool[layer], self.block_table,
+                pool[layer], self.table_of(self.block_table),
                 None if scale is None else scale[layer])
 
         return (gather(self.k_pages, self.k_scale),
@@ -642,11 +735,12 @@ class PagedKVCache:
 
     def tree_flatten(self):
         return (self.k_pages, self.v_pages, self.block_table, self.lens,
-                self.live, self.k_scale, self.v_scale, self.seq), None
+                self.live, self.k_scale, self.v_scale, self.seq,
+                self.win), self.ring
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, ring=aux)
 
 
 jax.tree_util.register_pytree_node(
@@ -795,6 +889,16 @@ class BlockManager:
     fires per victim BEFORE its page is freed: the hook offloads the
     page's bytes to the tier below, turning eviction from
     drop-and-recompute into demote-and-prefetch.
+
+    ``window`` (a sized :class:`WindowLayers`): the model's window
+    layers keep a pool of their own, and this manager its free list. A
+    slot takes its whole RING there at admission (``window.ring`` pages,
+    whatever its prompt's length: the ring is what the step programs
+    index modulo) and hands it back at :meth:`free_slot`; while the
+    request runs nothing is allocated there, a page behind the window is
+    written over in place (``window_pages_recycled`` counts those, at
+    :meth:`free_slot`). A ring's pages are never shared: a manager with
+    both ``window`` and ``prefix_reuse`` is refused.
     """
 
     def __init__(self, num_pages: int, page: int, p_max: int, *,
@@ -802,10 +906,23 @@ class BlockManager:
                  page_bytes: Optional[int] = None,
                  native_page_bytes: Optional[int] = None,
                  score_decay: float = 0.9,
-                 on_demote=None):
+                 on_demote=None,
+                 window: Optional[WindowLayers] = None):
         if num_pages < 2:
             raise ValueError(f"num_pages={num_pages} < 2 (page 0 is the "
                              "reserved scratch page)")
+        if window is not None:
+            if prefix_reuse:
+                raise ValueError(
+                    "prefix_reuse over window layers: a ring's pages "
+                    "are written over while their request runs, so none "
+                    "of them can be another request's prefix")
+            window.require_sized()
+        self.window = window
+        self.ring = window.ring if window is not None else 0
+        self._wfree: deque = deque(
+            range(1, window.num_pages if window is not None else 1))
+        self._slot_ring: Dict[int, List[int]] = {}
         self.num_pages = num_pages
         self.page = page
         self.p_max = p_max
@@ -853,6 +970,35 @@ class BlockManager:
         self.stats = {"allocs": 0, "frees": 0, "prefix_hits": 0,
                       "prefix_misses": 0, "evictions": 0,
                       "demotions": 0}
+        if self.ring:
+            self.stats["window_pages_recycled"] = 0
+
+    # -- the window layers' pool -------------------------------------
+
+    def _take_ring(self, slot: int):
+        """``slot``'s ring out of the window pool's free list, whole or
+        not at all."""
+        if len(self._wfree) < self.ring:
+            raise OutOfPagesError(
+                f"window page pool exhausted ({len(self._wfree)} of "
+                f"{self.window.num_pages - 1} usable pages free, a slot's "
+                f"ring takes {self.ring})")
+        self._slot_ring[slot] = [self._wfree.popleft()
+                                 for _ in range(self.ring)]
+
+    def _drop_ring(self, slot: int):
+        ring = self._slot_ring.pop(slot, None)
+        if ring is None:
+            return
+        self._wfree.extend(ring)
+        # Every page of the sequence past the ring's first round was
+        # written over an older one's entry.
+        pages = -(-self._slot_tokens.get(slot, 0) // self.page)
+        self.stats["window_pages_recycled"] += max(pages - self.ring, 0)
+
+    def window_pages(self, slot: int) -> int:
+        """Pages ``slot`` holds in a window layer: its ring, or 0."""
+        return len(self._slot_ring.get(slot, ()))
 
     # -- raw pool ----------------------------------------------------
 
@@ -985,6 +1131,8 @@ class BlockManager:
                     pages.append(pid)
                 else:
                     pages.append(self._take_page())
+            if self.ring:
+                self._take_ring(slot)
         except OutOfPagesError:
             self._pending_prefix.pop(slot, None)
             for pid in pages:
@@ -1064,6 +1212,8 @@ class BlockManager:
         try:
             for _ in range(n_pages):
                 pages.append(self._take_page())
+            if self.ring:
+                self._take_ring(slot)
         except OutOfPagesError:
             for pid in pages:
                 self._drop_ref(pid)
@@ -1141,6 +1291,7 @@ class BlockManager:
         self._pending_prefix.pop(slot, None)
         for pid in self._slot_pages.pop(slot, []):
             self._drop_ref(pid)
+        self._drop_ring(slot)
         self._slot_tokens.pop(slot, None)
         self._slot_hits.pop(slot, None)
 
@@ -1170,6 +1321,8 @@ class BlockManager:
             "pending_prefix": {s: list(v) for s, v in
                                self._pending_prefix.items()},
             "stats": dict(self.stats),
+            "window_free": list(self._wfree),
+            "slot_ring": {s: list(p) for s, p in self._slot_ring.items()},
         }
 
     def load_snapshot(self, snap: dict) -> None:
@@ -1200,12 +1353,26 @@ class BlockManager:
                                 snap["pending_prefix"].items()}
         self.stats = dict(snap["stats"])
         self.stats.setdefault("demotions", 0)
+        if self.ring:
+            self._wfree = deque(snap["window_free"])
+            self._slot_ring = {int(s): list(p) for s, p in
+                               snap["slot_ring"].items()}
+
+    @property
+    def table_width(self) -> int:
+        """Entries of a slot's table row: ``p_max`` pages, and behind
+        them the window layers' ring where the pool has any."""
+        return self.p_max + self.ring
 
     def table_row(self, slot: int):
-        """This slot's block-table row, scratch-padded to p_max."""
+        """This slot's block-table row, scratch-padded to p_max; behind
+        it the slot's ring in the window layers' pool, entry by entry,
+        where the pool has window layers."""
         row = [SCRATCH_PAGE] * self.p_max
         for i, pid in enumerate(self._slot_pages.get(slot, [])):
             row[i] = pid
+        if self.ring:
+            row += self._slot_ring.get(slot, [SCRATCH_PAGE] * self.ring)
         return row
 
     def fragmentation(self) -> dict:
@@ -1226,6 +1393,13 @@ class BlockManager:
             "utilization": used_tokens / cap if held_pages else 1.0,
             **self.stats,
         }
+        if self.ring:
+            out.update(
+                window_num_pages=self.window.num_pages,
+                window_free_pages=len(self._wfree),
+                window_used_pages=(self.window.num_pages - 1
+                                   - len(self._wfree)),
+                window_pages_a_slot=self.ring)
         if self.page_bytes:
             # The quantization capacity surface: HBM cost per resident
             # token, and how many MORE pages the same pool bytes buy

@@ -126,8 +126,11 @@ class ChunkedPrefill:
               if k in ("moe_impl", "ep_ctx")}
         # Quantized pools carry per-page scale leaves — the chunk
         # dispatch's cache spec must match the pool it writes.
+        # (and, for a pool with window layers, the ring its tree holds).
+        ring = getattr(cache_shardings, "ring", 0)
         kv_spec = model.paged_cache_specs(
-            axis, quantized=cache_shardings.quantized)
+            axis, quantized=cache_shardings.quantized,
+            **({"ring": ring} if ring else {}))
         # A model with ``STEP_STATS`` (counts of the step) or
         # ``ROW_STATS`` (one number a head row) returns them last from
         # every step; they ride the picked tokens out

@@ -509,6 +509,8 @@ class ServingEngine:
         # Bytes of what the sequences keep beside their pages (a pool
         # that states such arrays; else 0).
         self._seq_state_bytes = 0
+        # The two pools of a model with window layers (else empty).
+        self._pool_bytes = {}
         self.prefill_buckets = (tuple(sorted(set(int(b) for b in
                                                  prefill_buckets)))
                                 if prefill_buckets else None)
@@ -665,7 +667,8 @@ class ServingEngine:
                 num_pages, page, self.p_max, prefix_reuse=prefix_reuse,
                 page_bytes=self.plan["page_bytes_per_rank"],
                 native_page_bytes=self.plan[
-                    "native_page_bytes_per_rank"])
+                    "native_page_bytes_per_rank"],
+                window=self._window_layers(num_slots, prefix_reuse))
             self._build_layer_path(num_slots, num_pages)
 
         self.sched = Scheduler(num_slots, max_queue=max_queue,
@@ -678,6 +681,41 @@ class ServingEngine:
         self._toks = np.zeros((num_slots,), np.int32)
 
     # -- layer-path construction ------------------------------------
+
+    def _window_layers(self, num_slots: int, prefix_reuse: bool):
+        """The window layers the served model's pool states, sized for
+        this server (``WindowLayers.sized``: the ring holds the window
+        and the largest chunk program's rows), or None. A ring's pages
+        are written over while their request runs and are found through
+        a table of their own, so whatever shares, moves or rolls back
+        PAGES by the one table is refused here, by name."""
+        states = getattr(self.engine.model, "paged_pool", None)
+        keeps = states(self.cfg)[2:] if states is not None else ()
+        window = dict(keeps[0]).get("window") if keeps else None
+        if window is None:
+            return None
+        refused = [
+            (bool(self.spec_k), "spec_k: a refused candidate's entry "
+             "would have written over a key the ring still needs"),
+            (prefix_reuse, "prefix_reuse: a ring's pages are written "
+             "over while their request runs, and none can be another "
+             "request's prefix"),
+            (self.tiers is not None, "kv_tiers (and with them park and "
+             "resume): a tier moves pages by the one table, and the "
+             "rings would stay behind"),
+            (not self.prefill_buckets, "the monolithic prompt blit: "
+             "only a chunk program writes a ring "
+             "(prefill_buckets=...)"),
+            (self.kv_dtype not in (None, "bf16", "native"),
+             f"kv_dtype={self.kv_dtype!r}: the window layers' pool is "
+             "not quantized")]
+        for on, why in refused:
+            if on:
+                raise NotImplementedError(
+                    "a model with window layers is served without "
+                    f"{why}")
+        return window.sized(self.page, max(self.prefill_buckets),
+                            num_slots)
 
     def _build_layer_path(self, num_slots: int, num_pages: int):
         import jax
@@ -704,6 +742,16 @@ class ServingEngine:
         pool_cls, per_token, *keeps = model.paged_pool(cfg)
         keeps = dict(keeps[0]) if keeps else {}
         pool_layers = keeps.pop("layers", cfg.num_hidden_layers)
+        # Window layers, where the pool states any: as the manager
+        # holds them, sized; the cache's specs are then asked for with
+        # the ring, which is static in its tree.
+        window = self.manager.window
+        cache_specs = model.paged_cache_specs
+        if window is not None:
+            import functools as _ft
+
+            keeps["window"] = window
+            cache_specs = _ft.partial(cache_specs, ring=window.ring)
         if pool_cls is not PagedKVCache and (
                 self.tiers is not None or not self.prefill_buckets):
             raise NotImplementedError(
@@ -731,7 +779,7 @@ class ServingEngine:
                         "a model whose sequences keep state beside "
                         f"their pages is served without {why}")
         cache, shardings = pool_cls.empty_sharded(
-            mesh, model.paged_cache_specs, axis,
+            mesh, cache_specs, axis,
             pool_layers, num_pages, self.page,
             *per_token, num_slots=num_slots, p_max=self.p_max,
             dtype=jax.tree.leaves(eng.params)[0].dtype,
@@ -745,8 +793,22 @@ class ServingEngine:
             keeps.get("seq_state") or {}).values()), default=0)
         self._seq_state_bytes = sum(int(v.nbytes)
                                     for v in cache.seq.values())
-        kv_spec = model.paged_cache_specs(
-            axis, quantized=cache.quantized)
+        if window is not None:
+            # The two pools' bytes over the mesh, and what ONE table
+            # for all paged layers would take at the same slots and
+            # max_len (every layer a page for every position).
+            a_page = 2 * cache.k_pages.dtype.itemsize * int(
+                np.prod(cache.k_pages.shape[2:]))
+            self._pool_bytes = {
+                "window_layers": window.layers,
+                "global_layers": pool_layers,
+                "pool_bytes_global": a_page * pool_layers * num_pages,
+                "pool_bytes_window": (a_page * window.layers
+                                      * window.num_pages),
+                "pool_bytes_one_table": a_page * (
+                    pool_layers + window.layers) * (
+                        1 + num_slots * self.p_max)}
+        kv_spec = cache_specs(axis, quantized=cache.quantized)
         self.cache = cache
         # The pool's pinned shardings — every writer into it (prompt
         # blit, chunk steps, page-migration scatter) must return leaves
@@ -1182,6 +1244,16 @@ class ServingEngine:
             out["seq_state_slots"] = self.num_slots
             out["seq_state_layers"] = self._seq_layers
             out["paged_layers"] = self._pool_layers
+        if self._pool_bytes:
+            # Window layers: how many layers keep a window and how many
+            # every position, the pages of a slot's ring, ring entries
+            # written over while their request ran (counted as a slot
+            # is freed), and the pools' bytes beside what one table for
+            # all layers would take.
+            out.update(self._pool_bytes)
+            out["window_pages_a_slot"] = self.manager.ring
+            out["window_pages_recycled"] = self.manager.stats[
+                "window_pages_recycled"]
         if self._row_stats:
             # Layers applied several times: how often, the layers the
             # pool keeps and the layer applications of one step program
@@ -2062,6 +2134,9 @@ class ServingEngine:
                    for block in STEP_KERNELS}
         passes = ({"passes": self.cfg.num_passes} if self._row_stats
                   else {})
+        if p.manager.ring:
+            # Pages the chunk's slot holds in a window layer: its ring.
+            passes["window_pages"] = p.manager.window_pages(slot)
         toks = np.zeros((bucket,), np.int32)
         toks[:valid] = seq[start:start + valid]
         row = np.asarray(p.manager.table_row(slot), np.int32)
@@ -2805,7 +2880,8 @@ class ServingEngine:
                     preempted.append(h)
         if preempted:
             active = [h for h in active if h not in preempted]
-        tbl = np.zeros((self.num_slots, self.p_max), np.int32)
+        tbl = np.zeros((self.num_slots, self.p_max if self.manager is None
+                        else self.manager.table_width), np.int32)
         if self.manager is not None:
             for h in active:
                 tbl[h.slot] = self.manager.table_row(h.slot)
