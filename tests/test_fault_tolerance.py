@@ -323,7 +323,10 @@ def test_dropped_second_chunk_of_a_riding_tick_fails_its_request_alone(
     # A two-program tail stays two programs (4+4+3 would be one of 16).
     doomed = srv.submit(list(range(4, 11)), max_new_tokens=3)  # 4+3
     nxt = srv.submit(list(range(20, 26)), max_new_tokens=3)    # 4+2
-    lens, told = int(srv._lens[ok.slot]), len(ok.tokens)
+    # The first tick is still in flight (nothing forbade launching
+    # ahead of it): the tokens it owes count, and the armed plan lands
+    # it before the faulted tick is launched.
+    lens, told = int(srv._lens[ok.slot]), len(ok.tokens) + ok.in_flight
     with faults.inject(faults.get_plan("fail_kth_call",
                                        op="chunked_prefill", k=1)):
         assert srv.step() == 1

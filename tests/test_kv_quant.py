@@ -77,7 +77,10 @@ def test_quantized_logit_divergence_bounded(engine):
         srv.manager.append(0, int(srv._lens[0]))
         tbl = np.zeros((srv.num_slots, srv.p_max), np.int32)
         tbl[0] = srv.manager.table_row(0)
-        return np.asarray(srv._dispatch(tbl, False)[0])
+        from triton_dist_tpu.serving.server import _Flight
+
+        _, _, logits, _ = srv._enqueue_decode(_Flight(0), [h], tbl, False)
+        return np.asarray(logits[0])
 
     base = first_decode_logits("bf16")
     # Thresholds: the CPU battery's empirical bound with ~5x margin

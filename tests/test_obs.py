@@ -541,7 +541,7 @@ def test_tick_spans_tile_the_tick(engine):
     assert [s.attrs["tick"] for s in ticks] == list(range(len(ticks)))
     assert len(ticks) == srv.stats()["ticks"]
     parents, back_dated = ("tick", "decode"), ("queue_wait", "request")
-    seen = set()
+    seen, launches = set(), {}     # tick -> its batch rode a chunk
     for tick in ticks:
         idx = tick.attrs["tick"]
         inside = [s for s in spans if s is not tick
@@ -559,15 +559,24 @@ def test_tick_spans_tile_the_tick(engine):
         kinds = [s.kind for s in leaves]
         seen.update(kinds)
         assert kinds[0] == "schedule"
+        # The launch: the batch's upload follows its prep, and the
+        # chunk program it is aboard (if any) follows the upload.
+        if "decode_enqueue" in kinds:
+            at = kinds.index("decode_enqueue")
+            assert kinds[at - 1] == "decode_prep"
+            launches[idx] = kinds[at + 1:at + 2] == ["prefill_chunk"]
+        # The landing: ``decode`` holds the wait and the fetch and
+        # nothing else, and lies behind everything the tick launched
+        # (the tick it lands is the LAST one's: ``launched``).
         for dec in (s for s in inside if s.kind == "decode"):
             kids = [s.kind for s in leaves if dec.t0 < s.t0 < dec.t1]
-            # The batch aboard a chunk program: the chunks are enqueued
-            # between the batch's upload and the wait.
-            chunks = [k for k in kids if k == "prefill_chunk"]
-            assert bool(chunks) == bool(dec.attrs["fused"])
-            assert kids == (["decode_enqueue"] + chunks
-                            + ["decode_wait", "decode_fetch"])
-            assert kinds[kinds.index("decode_enqueue") - 1] == "decode_prep"
+            assert kids == ["decode_wait", "decode_fetch"]
+            assert all(s.t1 < dec.t0 for s in leaves if s.kind in (
+                "decode_prep", "decode_enqueue", "prefill_chunk"))
+            assert dec.attrs["launched"] == idx - 1
+            assert launches[idx - 1] == bool(dec.attrs["fused"])
+            assert tick.attrs.get("ahead") == 1 or (
+                "decode_enqueue" not in kinds)
     assert seen == set(TICK_KINDS) - {"tick", "submit"} | {"prefill_chunk"}
     # What is recorded between two ticks carries no index.
     submits = [s for s in spans if s.kind == "submit"]
@@ -605,8 +614,8 @@ def test_fused_tick_holds_every_leaf_kind(engine):
                   "decode_enqueue", "decode_wait", "decode_fetch",
                   "prefill_fetch", "sample", "emit"}
     full = 0
-    for dec in rode:
-        idx = dec.attrs["tick"]
+
+    def leaves_of(idx):
         tick = next(s for s in spans if s.kind == "tick"
                     and s.attrs["tick"] == idx)
         leaves = sorted((s for s in spans if s.attrs.get("tick") == idx
@@ -615,20 +624,29 @@ def test_fused_tick_holds_every_leaf_kind(engine):
         assert all(tick.t0 < s.t0 and s.t1 < tick.t1 for s in leaves)
         for a, b in zip(leaves, leaves[1:]):
             assert a.t1 < b.t0, f"{a.kind} overlaps {b.kind} in tick {idx}"
-        kinds = [s.kind for s in leaves]
+        return [s.kind for s in leaves]
+
+    for dec in rode:
+        # The tick that LAUNCHED the riding batch: its upload, then the
+        # chunk program it is aboard, before anything is waited for.
+        assert dec.attrs["launched"] == dec.attrs["tick"] - 1
+        kinds = leaves_of(dec.attrs["launched"])
         assert kinds[:4] == ["schedule", "decode_prep", "decode_enqueue",
                              "prefill_chunk"]
-        # The wait comes after the tick's LAST chunk was enqueued, and
-        # a slot whose prompt became resident is fetched after the
-        # decode rows' tokens.
-        assert kinds.index("decode_wait") > max(
-            i for i, k in enumerate(kinds) if k == "prefill_chunk")
+        # The tick that LANDS it, the next one, launches its own
+        # programs first: the wait comes after the tick's LAST chunk
+        # was enqueued, and a slot whose prompt became resident is
+        # fetched after the decode rows' tokens.
+        kinds = leaves_of(dec.attrs["tick"])
+        launch = [i for i, k in enumerate(kinds) if k in (
+            "decode_prep", "decode_enqueue", "prefill_chunk")]
+        assert kinds.index("decode_wait") > max(launch, default=0)
         assert dec.attrs["batch"] == kinds.count("sample") - kinds.count(
             "prefill_fetch")
         if "prefill_fetch" in kinds:
             assert kinds.index("prefill_fetch") > kinds.index("sample")
         full += set(kinds) == leaf_kinds
-    assert full >= 1, "no fused tick held every leaf kind"
+    assert full >= 1, "no tick that landed a riding batch held every leaf"
 
 
 def test_greedy_tick_fetches_tokens_and_keeps_its_spans(engine):
@@ -696,6 +714,74 @@ def test_spans_reach_a_profiler_capture(engine, tmp_path):
     tick = next(ev for ev in events if ev.name == "tdt.tick")
     fetch = next(ev for ev in events if ev.name == "tdt.decode_fetch")
     assert tick.duration_ns > 0 and fetch.duration_ns > 0
+
+
+def test_a_tick_launched_ahead_keeps_its_span_tree_in_a_capture(
+        engine, tmp_path):
+    """What the benchmark's serving-tick metrics read, out of a real
+    capture of ticks launched ahead: every leaf of the tick occurs once
+    a tick that lands a riding batch, ``tdt.decode`` carries ``batch``,
+    ``fused`` and ``step`` and holds the wait and the fetch,
+    ``tdt.decode_enqueue`` lies under ``tdt.tick`` and outside it, and
+    ``tdt.tick`` says whether its launch stayed in flight (``ahead``)."""
+    from jax.profiler import ProfileData
+
+    srv = ServingEngine(engine, num_slots=3, page=PAGE,
+                        prefill_buckets=(4, 8))
+    prompts = [[7, 8], list(range(1, 11)), list(range(1, 20))]
+    srv.generate(prompts, max_new_tokens=4)         # compile outside
+    before = srv.stats()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        srv.generate(prompts, max_new_tokens=4)
+    finally:
+        jax.profiler.stop_trace()
+    st = srv.stats()
+    ahead = st["ticks_launched_ahead"] - before["ticks_launched_ahead"]
+    assert ahead > 0 and st["ticks_in_order"] == 0
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = [ev for plane in ProfileData.from_file(str(path)).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("tdt.")]
+    rows = [(ev.name[4:], ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats)) for ev in events]
+    ticks = {st["tick"]: (t0, t1, st) for k, t0, t1, st in rows
+             if k == "tick"}
+    assert sum(st.get("ahead", 0) for _, _, st in ticks.values()) == ahead
+    assert all("ahead" in st for _, _, st in ticks.values()
+               if any(k == "decode_enqueue" and s["tick"] == st["tick"]
+                      for k, _, _, s in rows))
+
+    def inside(outer, kind):
+        return [(k, t0, t1, st) for k, t0, t1, st in rows
+                if k == kind and outer[0] <= t0 and t1 <= outer[1]]
+
+    decodes = [r for r in rows if r[0] == "decode"]
+    assert decodes and all({"batch", "fused", "step", "tick"} <= set(
+        st) for _, _, _, st in decodes)
+    assert [st["step"] for _, _, _, st in decodes] == list(range(
+        before["decode_dispatches"], st["decode_dispatches"]))
+    leaf_kinds = ("schedule", "decode_prep", "decode_enqueue",
+                  "prefill_chunk", "decode_wait", "decode_fetch",
+                  "prefill_fetch", "sample", "emit")
+    full = 0
+    for _, t0, t1, dst in decodes:
+        tick = ticks[dst["tick"]]
+        assert tick[0] <= t0 and t1 <= tick[1]
+        assert len(inside((t0, t1), "decode_wait")) == 1
+        assert len(inside((t0, t1), "decode_fetch")) == 1
+        assert not inside((t0, t1), "decode_enqueue")
+        assert not inside((t0, t1), "prefill_chunk")
+        held = {k: len(inside(tick, k)) for k in leaf_kinds}
+        assert held["decode_wait"] == held["decode_fetch"] == 1
+        assert held["decode_enqueue"] <= 1 and held["schedule"] == 1
+        assert held["sample"] == held["emit"] >= dst["batch"]
+        full += all(held.values())
+    assert full >= 1, "no tick of the capture held every leaf kind"
+    assert sum(dst["fused"] for _, _, _, dst in decodes) == (
+        st["decode_dispatches_fused"] - before["decode_dispatches_fused"])
 
 
 def test_spec_spans_bit_identical(engine):

@@ -536,7 +536,9 @@ def test_riding_tick_prefills_one_programs_worth(engine, case):
         assert got[0] == ["D4"]
         got = got[1:]
     assert got == want_ticks
-    rode = {s.attrs["tick"] for s in spans
+    # A ``decode`` span is the step's LANDING; ``launched`` names the
+    # tick whose chunk program carried it.
+    rode = {s.attrs["launched"] for s in spans
             if s.kind == "decode" and s.attrs["fused"]}
     for t in rode:
         assert sum(int(c[1:]) for c in ticks[t]) <= max(TICK_BUCKETS)
@@ -642,9 +644,14 @@ def test_greedy_tokens_are_the_programs_own_picks(engine, case):
     chunk_calls = [c for c in calls if c[1] == "chunk"]
     assert len(chunk_spans) == len(chunk_calls)
     served = {h.request.request_id: [] for h in hs.values()}
+    # A token is sampled in the tick that LANDS its step; the ``decode``
+    # span of that tick names the one that launched its program.
+    launched = {s.attrs["tick"]: s.attrs["launched"] for s in spans
+                if s.kind == "decode"}
     for s in (s for s in spans if s.kind == "sample"):
         assert s.attrs["device"] == 1
-        tick, toks = s.attrs["tick"], served[s.request_id]
+        toks = served[s.request_id]
+        tick = launched.get(s.attrs["tick"])
         if not toks:     # the prompt's token: its last chunk's row 0
             i = max(i for i, c in enumerate(chunk_spans)
                     if c.request_id == s.request_id)
@@ -663,7 +670,7 @@ def test_greedy_tokens_are_the_programs_own_picks(engine, case):
     in_tick = [s for s in chunk_spans
                if s.attrs["tick"] == last.attrs["tick"]]
     rode = [s for s in spans if s.kind == "decode" and s.attrs["fused"]
-            and s.attrs["tick"] == last.attrs["tick"]]
+            and s.attrs["launched"] == last.attrs["tick"]]
     if last_place is None:
         assert not rode
         assert any(s.kind == "decode" and not s.attrs["fused"]

@@ -68,12 +68,13 @@ _OP_HIST_KINDS = frozenset({
 # ``exit_pass``: a decode's mean over its rows, known once its tokens
 # are on the host, so set while the span is open; of a model with
 # window layers, ``window_pages``: the pages a chunk's slot holds in
-# such a layer, its ring).
+# such a layer, its ring; on ``tick``, ``ahead`` 0/1: whether what it
+# launched stayed in flight when it closed, set as it closes).
 _ANNOTATED = frozenset({"request_id", "slot", "step", "batch", "fused",
                         "bucket", "valid", "walk_kernel", "scan_kernel",
                         "experts_kernel", "padded_up", "chunks",
                         "waited_ms", "passes", "exit_pass",
-                        "window_pages",
+                        "window_pages", "ahead",
                         # ``expert_load``: a step program's held experts
                         "rows", "held_pairs", "routed_pairs",
                         "expert_rows_max", "expert_imbalance"})

@@ -324,7 +324,11 @@ def check_invariants(srv) -> None:
             if srv._live[s] != 1:
                 raise InvariantViolation(
                     f"running slot {s} has live={srv._live[s]}")
-            want = len(h.request.prompt) + len(h.tokens) - 1
+            # Tokens a launched tick owes the request have moved the
+            # mirror on already (docs/serving.md, "The tick in two
+            # halves").
+            want = (len(h.request.prompt) + len(h.tokens)
+                    + h.in_flight - 1)
             if srv._lens[s] != want:
                 raise InvariantViolation(
                     f"slot {s} length mirror {srv._lens[s]} != "

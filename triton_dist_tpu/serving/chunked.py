@@ -147,8 +147,10 @@ class ChunkedPrefill:
 
         self.decode_rows = int(decode_rows)
         # What :meth:`step` feeds the decode rows (see
-        # :meth:`_parked_rows`), made at its first call.
+        # :meth:`_parked_rows`), made at its first call, and the
+        # sharding their tokens go up under.
         self._parked = None
+        self._row_sh = NamedSharding(mesh, P(None))
         if not self.decode_rows:
             def _chunk(params, toks, cache, table_row, start, wfrom,
                        valid, *more):
@@ -262,17 +264,21 @@ class ChunkedPrefill:
     def _parked_rows(self, cache):
         """``(dec_toks, cache)`` with every decode row parked: scratch
         table row, length 0, not live. Uploaded as the serving engine
-        uploads a live batch's (plain host arrays), so a parked and a
-        ridden dispatch share one compiled program; the three cache
-        leaves anew at every call, since the pool's donation takes them
-        along."""
+        uploads a live batch's (the tokens committed under the sharding
+        a program's picked tokens come back with, since a live batch's
+        may be fed from those on the device; the rest plain host
+        arrays), so a parked and a ridden dispatch share one compiled
+        program; the three cache leaves anew at every call, since the
+        pool's donation takes them along."""
         import dataclasses
 
+        import jax
         import jax.numpy as jnp
 
         if self._parked is None:
             self._parked = (
-                jnp.asarray(np.zeros((self.decode_rows,), np.int32)),
+                jax.device_put(np.zeros((self.decode_rows,), np.int32),
+                               self._row_sh),
                 np.zeros(cache.block_table.shape, np.int32),
                 np.zeros(cache.lens.shape, np.int32))
         dec_toks, table, rows = self._parked

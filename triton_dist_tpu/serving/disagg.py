@@ -483,6 +483,13 @@ class DisaggServingEngine(ServingEngine):
         self._pending.append((h, last, payload, dst_ids,
                               len(pages) - hits, pw, digest))
 
+    def _in_order_by(self):
+        # The handoff activates a slot whole, with its first token's
+        # value (``_activate`` at ``_complete_migrations``), and a
+        # failover requeues what is in flight: every tick lands in the
+        # step() that launched it.
+        return super()._in_order_by() or "disaggregated"
+
     def step(self) -> int:
         # Collect LAST tick's migrations first: their extracts (and
         # the bridge put) have been in flight across this gap —

@@ -621,6 +621,7 @@ class FleetRouter:
         h.chunks = []
         h.resume_key = None
         h.resume_t0 = None
+        h.in_flight = 0       # what a lost tick owed is recomputed
         h.queued_at = self.obs.now()
 
     def _handoff_session(self, victim: _Fleet, h: RequestHandle, *,
@@ -716,6 +717,14 @@ class FleetRouter:
         victim.dead = True
         self.counters["fleet_failovers"] += 1
         preparked = set(victim.engine._parked)
+        # 0. A tick the victim launched and had not landed: a reachable
+        # victim lands it (its requests move with every token they are
+        # owed); a dead one's is lost with its device, and what it owed
+        # is recomputed by the re-prefill like any other token.
+        if reachable:
+            victim.engine._land()
+        else:
+            victim.engine._flight = None
         # 1. On a reachable victim, park every running session with
         # tokens into ITS tier — the two-phase offload: a faulted park
         # leaves the session for the re-prefill path below.
@@ -730,9 +739,13 @@ class FleetRouter:
         # mirrors are abandoned — a real dead fleet's memory is gone).
         parked = list(victim.engine._parked.values())
         victim.engine._parked.clear()
-        inflight = [h for h in victim.engine.sched.running()
+        # (A request released by count, its last token lost in flight,
+        # held a slot too.)
+        inflight = [h for h in (victim.engine.sched.running()
+                                + victim.engine.sched.landing)
                     if not h.done]
         victim.engine.sched.slots.clear()
+        victim.engine.sched.landing = []
         victim.engine._resuming = []
         queued = [h for h in victim.engine.sched.queue if not h.done]
         victim.engine.sched.queue.clear()

@@ -154,6 +154,11 @@ class RequestHandle:
     queued_at: float = 0.0
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None
+    # Tokens a launched step program owes this request and the host has
+    # not fetched yet (docs/serving.md, "The tick in two halves"): with
+    # ``len(tokens)`` it says, by count, whether the request has a next
+    # step to run. Host-side only, 0 at every checkpoint.
+    in_flight: int = 0
 
     @property
     def done(self) -> bool:
@@ -179,6 +184,10 @@ class Scheduler:
         self.clock = clock
         self.queue: deque[RequestHandle] = deque()
         self.slots: Dict[int, RequestHandle] = {}
+        # Requests whose slot went back to admission while their last
+        # token is still in flight (:meth:`release`): they keep the
+        # scheduler busy until they retire.
+        self.landing: List[RequestHandle] = []
         self._ids = itertools.count()
         self.counters = {
             "submitted": 0, "rejected": 0, "completed": 0, "failed": 0,
@@ -239,15 +248,25 @@ class Scheduler:
         """Handles currently owning a slot, slot-ordered."""
         return [self.slots[s] for s in sorted(self.slots)]
 
+    def release(self, h: RequestHandle):
+        """Hand ``h``'s slot back to admission before ``h`` retires: the
+        server knows by count that the step in flight is its last. The
+        handle keeps its ``slot`` and a non-terminal status, and the
+        scheduler stays busy, until :meth:`retire`."""
+        self.slots.pop(h.slot, None)
+        self.landing.append(h)
+
     def retire(self, h: RequestHandle, status: str,
                error: Optional[BaseException] = None):
-        """Finish a request and recycle its slot."""
+        """Finish a request and recycle its slot (unless
+        :meth:`release` already handed it on)."""
         h.status = status
         h.error = error
         h.finished_at = self.now()
-        if h.slot is not None:
-            self.slots.pop(h.slot, None)
-            h.slot = None
+        self.landing = [x for x in self.landing if x is not h]
+        if h.slot is not None and self.slots.get(h.slot) is h:
+            del self.slots[h.slot]
+        h.slot = None
         key = {"done": "completed", "timeout": "timed_out"}.get(
             status, "failed")
         self.counters[key] += 1
@@ -290,4 +309,4 @@ class Scheduler:
 
     @property
     def idle(self) -> bool:
-        return not self.queue and not self.slots
+        return not self.queue and not self.slots and not self.landing

@@ -109,6 +109,58 @@ class _Rows:
                     None if self.exits is None else int(self.exits[slot]))
 
 
+class _Flight:
+    """A launched tick: what :meth:`ServingEngine._land` needs of what
+    :meth:`ServingEngine._launch` handed the device.
+
+    ``rows``: the ``(handle, slot)`` pairs whose decode step a program
+    of the tick carries, ``picked`` that program's tokens (on the
+    device; row ``base + slot`` is slot's), ``logits`` its decode rows'
+    logits (the megakernel lane's only output), ``sampled`` whether a
+    row's floats are wanted on the host, ``ecounts`` an EP program's
+    expert counts, ``stat_rows`` the rows its ``STEP_STATS`` counted,
+    ``fused`` 1 where it is a chunk program, ``first`` that chunk's
+    request. ``finished``: ``(handle, last)`` for every prompt that
+    became resident in the tick and is owed its first token from
+    ``last``, its final chunk's ``(picked, logits)``. ``token_at``:
+    ``id(handle) -> (picked array, index)``, where each request's newest
+    token lies on the device, for the next launch to feed from.
+    ``programs`` counts the tick's dispatches, ``decoded`` what a tick
+    that picks on the host (speculation) already served; ``tick`` is
+    the index of the tick that launched it."""
+
+    __slots__ = ("t0", "tick", "rows", "picked", "base", "logits", "sampled",
+                 "ecounts", "stat_rows", "fused", "first", "finished",
+                 "token_at", "programs", "decoded")
+
+    def __init__(self, tick: int):
+        self.t0, self.tick = time.perf_counter(), tick
+        self.rows, self.finished, self.token_at = [], [], {}
+        self.picked = self.logits = self.ecounts = self.first = None
+        self.base = self.stat_rows = self.fused = 0
+        self.sampled = False
+        self.programs = self.decoded = 0
+
+    def board(self, active, picked, base, logits, ecounts=None, *,
+              sampled, stat_rows, fused=0, first=None):
+        """Book the program that carries ``active``'s decode step."""
+        self.rows = [(h, h.slot) for h in active]
+        self.picked, self.base, self.logits = picked, base, logits
+        self.sampled, self.stat_rows, self.fused = sampled, stat_rows, fused
+        self.ecounts, self.first = ecounts, first
+        for h, slot in self.rows:
+            h.in_flight += 1
+            if picked is not None and h.request.temperature <= 0.0:
+                self.token_at[id(h)] = (picked, base + slot)
+
+    def owe_first(self, h, last):
+        """Book ``h``'s first token, owed from ``last``."""
+        self.finished.append((h, last))
+        h.in_flight += 1
+        if last[0] is not None and h.request.temperature <= 0.0:
+            self.token_at[id(h)] = (last[0], 0)
+
+
 def save_checkpoint(snap: dict, path: str) -> str:
     """Persist a :meth:`ServingEngine.checkpoint` snapshot to ``path``
     (pickle; numpy pools incl. ml_dtypes fp8 round-trip bit-exact).
@@ -238,7 +290,7 @@ class ServingEngine:
         ``prefill_buckets`` (layer path): switch prefill from the
         monolithic per-length dispatch to FIXED-SHAPE chunked prefill —
         prompts stream into the page pool in bucketed chunks (padded to
-        bucket), a few chunks per serving tick (:meth:`_advance_chunks`),
+        bucket), a few chunks per serving tick (:meth:`_launch`),
         interleaved with decode.
         The prefill jit cache is then bounded by the bucket count
         (:meth:`prefill_cache_size`) instead of growing per distinct
@@ -494,6 +546,13 @@ class ServingEngine:
             "router_prefetched_pages": 0, "worker_prefetched_pages": 0,
             "integrity_failures": 0, "slo_preemptions": 0,
             "ticks": 0,
+            # The tick in two halves: dispatching ticks left in flight
+            # (the next is launched before their tokens are fetched),
+            # those landed in the step() that launched them (by the
+            # first reason that held: ``stats()["in_order_by"]``), and
+            # decode rows launched for a request that had ended.
+            "ticks_launched_ahead": 0, "ticks_in_order": 0,
+            "rows_discarded": 0,
             # Held experts (a model with STEP_STATS): token-expert
             # pairs the read programs routed, the ones that fell to
             # held experts, the fullest expert's rows a layer, and
@@ -504,6 +563,11 @@ class ServingEngine:
             # whose pool states one).
             "seq_state_resets": 0,
         }
+        # The tick launched and not yet landed (a :class:`_Flight`), why
+        # ticks were landed in order, and when the last one landed.
+        self._flight = None
+        self._in_order: dict = {}
+        self._landed_at = 0.0
         self._step_stats = ()
         self._row_stats = ()
         # Bytes of what the sequences keep beside their pages (a pool
@@ -936,6 +1000,18 @@ class ServingEngine:
             self._picked_by_pass = np.zeros((cfg.num_passes,), np.int64)
         row_sh = NamedSharding(mesh, P(None))
         logits_sh = NamedSharding(mesh, P(None, None))
+        # The decode rows' input tokens go up under the sharding the
+        # programs' picked tokens come back with, and where a row's
+        # token is still on the device (its program not landed) this
+        # one small program takes it from there: ``idx[slot]`` is the
+        # row's index in ``picked``, or -1 to keep what the host sent.
+        # Either way a step program sees one committed (num_slots,)
+        # int32 array, so a bucket keeps its ONE compiled program.
+        self._row_sh = row_sh
+        self._feed = jax.jit(
+            lambda toks, idx, picked: jnp.where(
+                idx >= 0, picked[jnp.maximum(idx, 0)], toks),
+            out_shardings=row_sh)
         out_specs = (P(None), P(None, None), kv_spec)
         out_sh = (row_sh, logits_sh, shardings)
         if self.ep:
@@ -1094,25 +1170,98 @@ class ServingEngine:
         return h
 
     def step(self) -> int:
-        """One serving tick: deadlines → admission/prefill → one joint
-        decode dispatch → per-slot token handling. Returns how many
-        live slots decoded (0 = idle tick). Where the tick has a
-        prefill chunk and this engine's chunker carries the decode
-        batch, the decode step rides the chunk's program instead of a
-        dispatch of its own (:meth:`_fused_tick`)."""
+        """One serving tick: deadlines → admission → the tick's step
+        programs LAUNCHED (:meth:`_launch`: prefill chunks, and one
+        joint decode step, aboard the first chunk's program where this
+        engine's chunker carries the decode batch) → a launched tick's
+        results LANDED (:meth:`_land`: tokens fetched, sampled, emitted,
+        requests retired). Which tick lands follows
+        :meth:`_in_order_by`: where it names a reason, the one just
+        launched (``launch(T); land(T)``); where it names none, the one
+        the LAST call launched, and this call's stays in flight, its
+        programs queued on the device behind the last one's
+        (``launch(T+1); land(T)``), so the host's turn runs under a
+        program (docs/serving.md, "The tick in two halves"). A call
+        with nothing to launch lands what is in flight. Returns how many
+        sequences the landed tick decoded (0 = none landed)."""
         tick = self.stats_counters["ticks"]
         self.stats_counters["ticks"] = tick + 1
         # The root span: every span and event below carries ``tick``,
-        # and the leaves (schedule, prefill_chunk, prefill_fetch,
-        # decode_prep, decode_enqueue/wait/fetch, sample, emit) tile it.
-        with self.obs.span("tick", tick=tick):
+        # and the leaves (schedule, decode_prep, decode_enqueue,
+        # prefill_chunk of the launch; decode_wait/fetch, prefill_fetch,
+        # sample, emit of the landing) tile it.
+        with self.obs.span("tick", tick=tick) as span:
+            why = self._in_order_by()
+            if why is not None:
+                self._land()
             with self.obs.span("schedule"):
+                self._release_ended()
                 self._schedule()
-            if self._prefiller is not None:
-                decoded = self._advance_chunks()
-                if decoded is not None:
-                    return decoded
-            return self._decode_tick()
+            flight = self._launch()
+            decoded = self._land()
+            if flight is None:
+                return decoded
+            # Admission may have brought a reason (a request that
+            # samples).
+            why = why or self._in_order_by()
+            if why is None:
+                self._flight = flight
+                self.stats_counters["ticks_launched_ahead"] += 1
+            else:
+                decoded = self._land(flight)
+                self.stats_counters["ticks_in_order"] += 1
+                self._in_order[why] = self._in_order.get(why, 0) + 1
+            if self.obs.enabled:
+                span.fields["ahead"] = int(why is None)
+            return decoded
+
+    def _in_order_by(self) -> Optional[str]:
+        """Why a launched tick is landed before the next is launched:
+        the first that holds of the reasons below, or None, and the
+        next tick is then launched ahead. Each is something this engine
+        can observe of itself, and each names what reads a token VALUE
+        (or rests on the length mirrors) between two launches."""
+        from triton_dist_tpu.resilience import faults
+
+        if self.mega:
+            return "megakernel"       # its step returns host rows
+        if self.spec_k:
+            return "spec_k"           # drafts start from the last token
+        if self.ep or self.ep2d:
+            return "expert_parallel"  # a step's counts steer the next
+        if not self._rides:
+            # No buckets: admission itself prefills, picks and emits.
+            # Or chunk programs that carry no decode rows.
+            return "not_riding"
+        # Containment rests on "the length mirrors never advanced".
+        if self.timeout_s is not None:
+            return "watchdog"
+        if self.retry_policies:
+            return "retry_policy"
+        if faults.active_plan() is not None:
+            return "fault_plan"
+        if self.slo is not None:
+            return "slo"              # arbitration preempts and parks
+        if _samples(self.sched.running()):
+            return "sampled"          # its next input is made on the host
+        return None
+
+    def _release_ended(self):
+        """What the host knows by count it does not wait for: a request
+        whose token in flight is its ``max_new_tokens``-th gives its
+        slot and pages back to admission now (the device's stream order
+        makes that safe: whatever a later program writes there runs
+        after the one in flight). Its status stays non-terminal, its
+        ``slot`` readable and the scheduler busy until the landing has
+        emitted the token and retired it."""
+        if self._flight is None:
+            return
+        for h in self.sched.running():
+            if (h.status == "running" and h.in_flight
+                    and len(h.tokens) + h.in_flight
+                    >= h.request.max_new_tokens):
+                self.sched.release(h)
+                self._vacate(h.slot)
 
     def _schedule(self):
         """The tick's head: resumes, deadlines, SLO arbitration and
@@ -1194,6 +1343,9 @@ class ServingEngine:
         bench read)."""
         out = dict(self.stats_counters)
         out.update(self.sched.counters)
+        # Why ticks were landed in the step() that launched them: the
+        # first reason of ``_in_order_by`` that held, counted.
+        out["in_order_by"] = dict(self._in_order)
         out["queue_depth"] = len(self.sched.queue) + (
             len(self.slo.queued_handles()) if self.slo is not None
             else 0)
@@ -1451,6 +1603,7 @@ class ServingEngine:
         queued, exactly like mid-chunk-stream ones).
         """
         t_ck = self.obs.now()
+        self._land()        # a snapshot holds nothing in flight
         running = [h for h in self.sched.running()
                    if h.status == "running"]
         inflight = [h for h in self.sched.running()
@@ -1536,6 +1689,7 @@ class ServingEngine:
         import jax.numpy as jnp
 
         t_rs = self.obs.now()
+        self._land()
         meta = snap.get("meta", {})
         if meta.get("format") != self.CHECKPOINT_FORMAT:
             raise ValueError(
@@ -1860,7 +2014,7 @@ class ServingEngine:
         """Admit into the chunk stream: allocate the slot's pages in
         the prefiller's pool now (backpressure = the same requeue as
         monolithic admission), then leave the handle in ``"prefill"``
-        status — :meth:`_advance_chunks` streams its bucketed chunks
+        status — :meth:`_launch` streams its bucketed chunks
         tick by tick, interleaved with decode, until the prompt is
         resident. Prefix hits skip straight past already-resident
         pages: the compute cursor starts at the first non-shared page
@@ -1898,30 +2052,79 @@ class ServingEngine:
         self._live[slot] = 0
         self._toks[slot] = 0
 
-    def _advance_chunks(self):
-        """The tick's prefill chunks. Where this engine's chunker
-        carries the decode batch and a decoder is live, the tick is one
-        program's worth of prefill rows, oldest prompt first, with the
-        batch aboard the first of them (:meth:`_fused_tick`), and the
-        count of sequences that decoded is returned. Otherwise one
-        bucketed chunk per prefilling slot (nothing rides, so waiting
-        saves no weight read), None is returned, and the caller runs
-        the decode tick."""
-        prefilling = [h for h in self.sched.running()
-                      if h.status == "prefill"]
-        if self._rides and prefilling:
-            active = [h for h in self.sched.running()
-                      if h.status == "running"]
+    def _launch(self):
+        """The LAUNCH half of a tick, which needs no token's value: the
+        tick's prefill chunks and its joint decode step are handed to
+        the device, and everything the host can book without their
+        results is booked (page growth, the chunk cursors, the lengths,
+        a resident prompt's slot going live). Returns the
+        :class:`_Flight` that :meth:`_land` takes, None where no
+        program was dispatched.
+
+        Where this engine's chunker carries the decode batch and a
+        decoder is live, the tick is one program's worth of prefill
+        rows, oldest prompt first, with the batch aboard the first of
+        them (:meth:`_launch_fused`). Otherwise one bucketed chunk per
+        prefilling slot (nothing rides, so waiting saves no weight
+        read) and then the decode step's own program, which a slot
+        whose prompt just became resident joins."""
+        flight = _Flight(self.stats_counters["ticks"] - 1)
+        if self._prefiller is not None:
+            prefilling = [h for h in self.sched.running()
+                          if h.status == "prefill"]
+            if self._rides and prefilling:
+                active, tbl = self._prepared()
+                if active:
+                    self._launch_fused(flight, prefilling, active, tbl)
+                    return flight
+            for h in prefilling:
+                self._launch_chunk(flight, h)
+        if self.spec_k or any(id(h) not in flight.token_at
+                              for h, _ in flight.finished):
+            # The next input of these rows is made on the host (a
+            # draft's history, a sampled token, a lane whose chunks
+            # pick none): their first tokens land before the step.
+            self._first_tokens(flight)
+        if self.spec_k:
+            flight.decoded = self._spec_tick()
+            flight.programs += bool(flight.decoded)
+        else:
+            active, tbl = self._prepared()
             if active:
-                with self.obs.span("decode_prep"):
-                    active, tbl = self._decode_prep(active)
-            if active:
-                return self._fused_tick(prefilling, active, tbl)
-        for h in prefilling:
-            last = self._advance_chunk(h)
-            if last is not None:
-                self._finish_prefill(h, last)
-        return None
+                self._launch_decode(flight, active, tbl)
+        return flight if flight.programs else None
+
+    def _prepared(self):
+        """The tick's decoders with their pages grown and the table
+        built (:meth:`_decode_prep`), or ``([], None)``."""
+        active = self._decoders()
+        if not active:
+            return active, None
+        with self.obs.span("decode_prep"):
+            return self._decode_prep(active)
+
+    def _decoders(self) -> List[RequestHandle]:
+        """The handles whose decode step the tick runs: running, and by
+        count not yet at their last token (``in_flight``: tokens a
+        launched program owes them). Layer-path slots still
+        mid-chunk-stream (or mid-migration in the disaggregated
+        subclass) are parked: they join the decode batch only once
+        their prompt is resident. The megakernel's prefill lane rides
+        the decode dispatch itself."""
+        return [h for h in self.sched.running()
+                if (h.status == "running"
+                    and len(h.tokens) + h.in_flight
+                    < h.request.max_new_tokens)
+                or (self.mega and self._prefiller is None
+                    and h.status == "prefill")]
+
+    def _launch_chunk(self, flight, h: RequestHandle):
+        """``h``'s next chunk, in a program with the decode rows parked;
+        a prompt that it makes resident goes live."""
+        last = self._advance_chunk(h)
+        flight.programs += 1
+        if last is not None and self._finish_prefill(h, last):
+            flight.owe_first(h, last)
 
     def _riding_chunks(self, prefilling):
         """The handles whose next chunk a riding tick runs, one at a
@@ -1942,62 +2145,34 @@ class ServingEngine:
                 room -= bucket
                 yield h
 
-    def _fused_tick(self, prefilling, active, tbl) -> int:
+    def _launch_fused(self, flight, prefilling, active, tbl):
         """A tick whose decode batch rides its first chunk's program:
         the weights are read once for the chunk and the step. The
         tick's other chunks (:meth:`_riding_chunks`; decode rows
-        parked) are enqueued behind that program BEFORE the host waits
-        on it, so the fetch and the sampling run under them; slots
-        whose last chunk ran go live after the decode rows' tokens and
-        decode from the next tick.
+        parked) are enqueued behind that program; slots whose last
+        chunk ran go live now and decode from the next tick, their
+        first tokens landing after the decode rows'.
 
-        A fault is contained where its scope is: one raised at
+        A fault raised here is contained where its scope is: one at
         ``chunked_prefill`` fails the chunk's request alone, as
         :meth:`_advance_chunk` does, and the decoders (whose lengths
         never advanced) redo their step next tick; anything else — a
-        drop at ``serving_decode``, a watchdog miss on the joint
-        program — is the decode tick's containment, and the first
-        chunk's request goes with it."""
+        drop at ``serving_decode`` — is the decode tick's containment,
+        and the first chunk's request goes with it. A watchdog miss on
+        the joint program is the landing's (:meth:`_land_failed`)."""
         import jax.numpy as jnp
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
         chunks = self._riding_chunks(prefilling)
         first = next(chunks)
-        finished = []          # (handle, its last chunk's result)
         sampled = _samples(active)
-        t0 = time.perf_counter()
         try:
-            with self.obs.span(
-                    "decode", step=self.stats_counters["decode_dispatches"],
-                    batch=len(active), fused=1) as span:
-                with self.obs.span("decode_enqueue"):
-                    batch = tuple(jnp.asarray(a) for a in (
-                        self._toks, tbl, self._lens, self._live))
-                picked, logits, dec, plan = self._enqueue_chunk(
-                    first, batch, rows=sampled)
-                # Booked at enqueue: the prompt's next chunk may run
-                # in this tick.
-                first_done = self._chunk_done(first, plan)
-                for h in chunks:
-                    last = self._advance_chunk(h)
-                    if last is not None:
-                        finished.append((h, last))
-                with self.obs.span("decode_wait"):
-                    picked = self._wait_decode(picked)
-                with self.obs.span("decode_fetch"):
-                    picked = self._read(picked)
-                    exits = self._exit_passes(picked)
-                    rows = _Rows(picked[1:], dec,
-                                 self._read(dec) if sampled else None,
-                                 None if exits is None else exits[1:])
-                    self._note_step_stats(picked,
-                                          plan[1] + self.num_slots)
-                    self._note_exit_passes(span, rows, active)
-                if first_done:
-                    # Its token came with the batch's: row 0 of the
-                    # one array, already on the host.
-                    finished.insert(0, (first, (picked, logits)))
+            with self.obs.span("decode_enqueue"):
+                batch = (self._input_tokens(flight, active),
+                         jnp.asarray(tbl), *self._mirrors())
+            picked, logits, dec, plan = self._enqueue_chunk(
+                first, batch, rows=sampled)
         except (CommTimeoutError, faults.InjectedFault) as e:
             if getattr(e, "op", None) == "chunked_prefill":
                 self._chunk_failed(first, e)
@@ -2006,14 +2181,67 @@ class ServingEngine:
                 if first.status == "prefill":
                     self._fail(first, "timeout" if isinstance(
                         e, CommTimeoutError) else "failed", e)
-            decoded = 0
         else:
-            self.stats_counters["decode_dispatches_fused"] += 1
-            decoded = self._decode_commit(active, rows, t0)
-        for h, last in finished:
-            if h.status == "prefill":
-                self._finish_prefill(h, last)
-        return decoded
+            flight.programs += 1
+            self._board(flight, active, picked, 1, dec, sampled=sampled,
+                        stat_rows=plan[1] + self.num_slots, fused=1,
+                        first=first)
+            # Booked at enqueue: the prompt's next chunk may run in
+            # this tick.
+            if (self._chunk_done(first, plan)
+                    and self._finish_prefill(first, (picked, logits))):
+                flight.owe_first(first, (picked, logits))
+        for h in chunks:
+            self._launch_chunk(flight, h)
+
+    def _board(self, flight, active, *program, **booked):
+        """``active``'s decode step is aboard ``program``: booked on
+        the flight, and the length mirrors advance (the next launch
+        writes one position further, whether or not this one landed)."""
+        flight.board(active, *program, **booked)
+        for _, slot in flight.rows:
+            self._lens[slot] += 1
+
+    def _mirrors(self):
+        """The length and live mirrors as a step program takes them.
+        Copies go up: the launch moves the mirrors on right behind the
+        enqueue, while an upload may still read the host's buffer."""
+        import jax.numpy as jnp
+
+        return jnp.asarray(self._lens.copy()), jnp.asarray(self._live.copy())
+
+    def _input_tokens(self, flight, active):
+        """The decode rows' input tokens as a step program takes them,
+        ``(num_slots,)`` int32: each row's newest token. The host sends
+        the ones it knows (``h.tokens[-1]``; a lane's next token); one
+        that a program not yet landed picked (the tick in flight, or
+        this tick's own chunk for a slot that just went live) is taken
+        from that program's ``picked`` on the device (``self._feed``),
+        so the token never crosses to the host between two programs."""
+        import jax
+        import jax.numpy as jnp
+
+        prev = self._flight
+        feeds = {}        # id(picked) -> (picked, index by slot or -1)
+        for h in active:
+            at = flight.token_at.get(id(h))
+            if at is None and prev is not None:
+                at = prev.token_at.get(id(h))
+            if at is None:
+                self._toks[h.slot] = (
+                    h.lane[h.prompt_pos]
+                    if self.mega and h.status == "prefill"
+                    else h.tokens[-1])
+                continue
+            picked, index = at
+            feeds.setdefault(id(picked), (picked, np.full(
+                (self.num_slots,), -1, np.int32)))[1][h.slot] = index
+        if self.mega:
+            return jnp.asarray(self._toks.copy())
+        toks = jax.device_put(self._toks.copy(), self._row_sh)
+        for picked, idx in feeds.values():
+            toks = self._feed(toks, idx, picked)
+        return toks
 
     def _run_op_with_retry(self, op: str, fn, retry_on=None):
         """Run one retryable serving op under its configured
@@ -2269,44 +2497,69 @@ class ServingEngine:
         self.stats_counters["prefill_calls"] += 1
         return True
 
-    def _finish_prefill(self, h: RequestHandle, last):
-        """Prompt fully resident: activate the slot (in-place chunked
-        mode — the disaggregated subclass migrates pages first)."""
-        self._activate(h, last)
+    def _finish_prefill(self, h: RequestHandle, last) -> bool:
+        """Prompt fully resident, at the launch of its last chunk:
+        the slot goes live (in-place chunked mode — the disaggregated
+        subclass migrates pages first, and activates whole, later).
+        True where the landing owes ``h`` its first token from
+        ``last``."""
+        self._go_live(h)
+        return not h.tokens
 
     def _activate(self, h: RequestHandle, last):
-        """Flip a fully-prefilled slot live; seed the first generated
-        token from the final chunk's result ``last``, ``(picked tokens,
-        last-valid-token logits)``: a greedy request's from row 0 of the
-        picked tokens, a sampled one's (and the megakernel lane's,
-        whose chunks pick none) from the logits row. Resumed requests
-        already know their next token."""
+        """Both halves of an activation in order, for a caller that
+        holds the last chunk's result ``last`` already landed (the
+        disaggregated handoff): the slot goes live, and a fresh request
+        is given its first token."""
+        self._go_live(h)
+        if not h.tokens:
+            self._first_token(h, last)
+
+    def _go_live(self, h: RequestHandle):
+        """Flip a fully-prefilled slot live: structure only, no token's
+        value. Resumed requests already know their next token."""
         slot = h.slot
-        # Every page's content is resident in THIS engine's pool (the
-        # last chunk just landed — or, disaggregated, the migration
-        # scatter): publish the slot's staged prefix pages.
+        # Every page's content is resident in THIS engine's pool, or
+        # will be before any later program reads it (the last chunk is
+        # enqueued — or, disaggregated, the migration scatter landed):
+        # publish the slot's staged prefix pages.
         self.manager.commit_prefix(slot)
         self._lens[slot] = len(h.lane)
         self._live[slot] = 1
         self._toks[slot] = h.lane[-1]
         h.status = "running"
         self._close_resume_span(h, path="reprefill")
-        if not h.tokens:
-            picked, logits = last
-            with self.obs.span("prefill_fetch", slot=slot,
-                               request_id=h.request.request_id,
-                               chunks=len(h.chunks)) as span:
-                if picked is None or h.request.temperature > 0.0:
-                    row = self._read(logits)
-                else:
-                    picked = self._read(picked)
-                    exits = self._exit_passes(picked)
-                    row = _Row(picked[0], logits, exit_pass=(
-                        None if exits is None else int(exits[0])))
-                    if exits is not None and self.obs.enabled:
-                        span.fields.update(passes=self.cfg.num_passes,
-                                           exit_pass=row.exit_pass)
-            self._sample_emit(h, row)
+
+    def _first_token(self, h: RequestHandle, last):
+        """Seed the first generated token from the final chunk's result
+        ``last``, ``(picked tokens, last-valid-token logits)``: a
+        greedy request's from row 0 of the picked tokens, a sampled
+        one's (and the megakernel lane's, whose chunks pick none) from
+        the logits row."""
+        picked, logits = last
+        with self.obs.span("prefill_fetch", slot=h.slot,
+                           request_id=h.request.request_id,
+                           chunks=len(h.chunks)) as span:
+            if picked is None or h.request.temperature > 0.0:
+                row = self._read(logits)
+            else:
+                picked = self._read(picked)
+                exits = self._exit_passes(picked)
+                row = _Row(picked[0], logits, exit_pass=(
+                    None if exits is None else int(exits[0])))
+                if exits is not None and self.obs.enabled:
+                    span.fields.update(passes=self.cfg.num_passes,
+                                       exit_pass=row.exit_pass)
+        self._sample_emit(h, row)
+
+    def _first_tokens(self, flight):
+        """Land the first tokens ``flight`` owes (a request that ended
+        meanwhile is owed none)."""
+        owed, flight.finished = flight.finished, []
+        for h, last in owed:
+            h.in_flight -= 1
+            if not h.done:
+                self._first_token(h, last)
 
     def _read(self, out) -> np.ndarray:
         """A step program's output on the host: the one place the layer
@@ -2552,6 +2805,7 @@ class ServingEngine:
                 "park() needs kv_tiers — the tier store holds the "
                 "parked payload (docs/serving.md, 'KV memory "
                 "hierarchy')")
+        self._land()        # the payload and the tokens it parks with
         if h.status != "running" or h.slot is None or not h.tokens:
             raise ValueError(
                 f"park() needs a running slot-holder; request "
@@ -2599,8 +2853,7 @@ class ServingEngine:
             # running).
             self.sched.slots.pop(slot, None)
             h.slot = None
-            self._live[slot] = self._lens[slot] = self._toks[slot] = 0
-            self.manager.free_slot(slot)
+            self._vacate(slot)
             h.status = "parked"
             self._parked[rid] = h
             self.stats_counters["parks"] += 1
@@ -2737,62 +2990,89 @@ class ServingEngine:
 
     # -- the decode tick --------------------------------------------
 
-    def _decode_tick(self) -> int:
-        import jax.numpy as jnp
-
-        if self.spec_k:
-            return self._spec_tick()
-        # Layer-path slots still mid-chunk-stream (or mid-migration in
-        # the disaggregated subclass) are parked: they join the decode
-        # batch only once their prompt is resident. The megakernel's
-        # prefill lane rides the decode dispatch itself.
-        active = [h for h in self.sched.running()
-                  if h.status == "running"
-                  or (self.mega and self._prefiller is None
-                      and h.status == "prefill")]
-        if not active:
-            return 0
-        with self.obs.span("decode_prep"):
-            active, tbl = self._decode_prep(active)
-        if not active:
-            return 0
-
+    def _launch_decode(self, flight, active, tbl):
+        """The joint decode step as a program of its own."""
         from triton_dist_tpu.resilience import faults
         from triton_dist_tpu.resilience.watchdog import CommTimeoutError
 
-        t0 = time.perf_counter()
-        try:
-            # The joint decode rides its own fault-op scope: chaos /
-            # fault plans can drop or wedge the k-th decode dispatch
-            # and the containment below fails the victim, not the
-            # server (survivors redo the identical dispatch — length
-            # mirrors never advanced).
-            # A TRANSIENT drop (InjectedFault — raised at the fault
-            # scope's entry, before the dispatch mutates anything) is
-            # absorbed by one retry pass when a serving_decode
-            # RetryPolicy is armed: the length mirrors only advance on
-            # success, so the replayed joint dispatch is byte-
-            # identical. A WEDGE (CommTimeoutError) is deliberately
-            # NOT in retry_on — a wedged joint dispatch blocks its own
-            # replay (docs/resilience.md) — and goes straight to the
-            # fail-one containment below.
-            def _attempt():
-                with self.obs.span(
-                        "decode",
-                        step=self.stats_counters["decode_dispatches"],
-                        batch=len(active), fused=0) as span, \
-                        faults.on_op_call("serving_decode"):
-                    rows = self._dispatch(tbl, _samples(active))
-                    self._note_exit_passes(span, rows, active)
-                    return rows
+        sampled = _samples(active)
 
-            rows = self._run_op_with_retry(
+        # The joint decode rides its own fault-op scope: chaos /
+        # fault plans can drop or wedge the k-th decode dispatch
+        # and the containment below fails the victim, not the
+        # server (survivors redo the identical dispatch — length
+        # mirrors never advanced).
+        # A TRANSIENT drop (InjectedFault — raised at the fault
+        # scope's entry, before the dispatch mutates anything) is
+        # absorbed by one retry pass when a serving_decode
+        # RetryPolicy is armed: the length mirrors only advance on
+        # success, so the replayed joint dispatch is byte-
+        # identical. A WEDGE (CommTimeoutError) is deliberately
+        # NOT in retry_on — a wedged joint dispatch blocks its own
+        # replay (docs/resilience.md) — and goes straight to the
+        # fail-one containment below.
+        def _attempt():
+            with faults.on_op_call("serving_decode"):
+                return self._enqueue_decode(flight, active, tbl, sampled)
+
+        try:
+            program = self._run_op_with_retry(
                 "serving_decode", _attempt,
                 retry_on=(faults.InjectedFault,))
         except (CommTimeoutError, faults.InjectedFault) as e:
             self._decode_contain(e)
-            return 0
-        return self._decode_commit(active, rows, t0)
+            return
+        flight.programs += 1
+        self._board(flight, active, *program, sampled=sampled,
+                    stat_rows=self.num_slots)
+
+    def _land(self, flight=None) -> int:
+        """The LAND half of a tick, which needs the values: ``flight``
+        (default: the one in flight) has its decode rows' tokens
+        waited for, fetched, sampled and emitted, the stats that ride
+        behind them booked, and the first tokens of the prompts that
+        became resident in it emitted: after the decode rows' where
+        those rode a chunk's program (the slots went live for the NEXT
+        tick), before them where the step was a program of its own
+        (which the slots joined). Returns how many sequences it
+        decoded."""
+        from triton_dist_tpu.resilience.watchdog import CommTimeoutError
+
+        if flight is None:
+            flight, self._flight = self._flight, None
+            if flight is None:
+                return 0
+        if not flight.fused:
+            self._first_tokens(flight)
+        decoded = flight.decoded
+        if flight.rows:
+            try:
+                with self.obs.span(
+                        "decode",
+                        step=self.stats_counters["decode_dispatches"],
+                        batch=len(flight.rows), fused=flight.fused,
+                        launched=flight.tick) as span:
+                    rows = self._fetch_rows(flight, span)
+            except CommTimeoutError as e:
+                self._land_failed(flight, e)
+            else:
+                decoded = self._decode_commit(flight, rows)
+        self._first_tokens(flight)
+        self._landed_at = time.perf_counter()
+        return decoded
+
+    def _land_failed(self, flight, e):
+        """The watchdog missed ``flight``'s joint program: nothing of
+        the step is known, so the length mirrors go back to where it
+        started, the victim(s) fail, and the survivors redo the
+        identical step; a chunk that program carried fails with it."""
+        for h, slot in flight.rows:
+            h.in_flight -= 1
+            if self.sched.slots.get(slot) is h:
+                self._lens[slot] -= 1
+        self._decode_contain(e)
+        if flight.first is not None and not flight.first.done:
+            self._fail(flight.first, "timeout", e)
 
     def _decode_contain(self, e):
         """The joint decode was wedged or dropped: fail the victim(s),
@@ -2816,19 +3096,26 @@ class ServingEngine:
         for victim in victims:
             self._fail(victim, "timeout" if timed_out else "failed", e)
 
-    def _decode_commit(self, active, rows, t0) -> int:
-        """Book a decode step that ran for ``active``: counters, the
-        length mirrors, and each slot's token from its row of ``rows``
-        (the layer path's :class:`_Rows`, each with its program's own
-        pick; the megakernel lane's host logits). Returns how many
-        sequences decoded."""
-        self.stats_counters["decode_time_s"] += time.perf_counter() - t0
+    def _decode_commit(self, flight, rows) -> int:
+        """Book the decode step that ``flight`` carried: counters, and
+        each slot's token from its row of ``rows`` (the layer path's
+        :class:`_Rows`, each with its program's own pick; the
+        megakernel lane's host logits). A row whose request ended
+        while the step was in flight (a stop token the tick before, a
+        deadline) is dropped: the position it wrote lies in pages that
+        were freed with the slot. Returns how many sequences decoded."""
+        now = time.perf_counter()
+        self.stats_counters["decode_time_s"] += now - max(
+            flight.t0, self._landed_at)
         self.stats_counters["decode_dispatches"] += 1
+        self.stats_counters["decode_dispatches_fused"] += flight.fused
         self._maybe_rebalance()
 
-        for h in active:
-            slot = h.slot
-            self._lens[slot] += 1
+        for h, slot in flight.rows:
+            h.in_flight -= 1
+            if h.done:
+                self.stats_counters["rows_discarded"] += 1
+                continue
             if self.mega and h.status == "prefill":
                 h.prompt_pos += 1
                 if h.prompt_pos < len(h.lane):
@@ -2845,20 +3132,18 @@ class ServingEngine:
             h.decode_steps += 1
             self.stats_counters["decode_tokens"] += 1
             self._sample_emit(h, rows[slot])
-        return len(active)
+        return len(flight.rows)
 
     def _decode_prep(self, active):
-        """Host work before the joint dispatch: each slot's input
-        token, page growth for the position it writes, and the block
-        table. Returns the handles that still decode (pool-dry ones are
-        preempted) and the table."""
+        """Host work before the joint dispatch: page growth for the
+        position each slot writes, and the block table. Returns the
+        handles that still decode (pool-dry ones are preempted) and the
+        table. A preempted request requeues with the tokens it has, so
+        the tick in flight lands first, and the prep starts over on
+        what that leaves."""
         preempted = []
         for h in active:
             slot = h.slot
-            if self.mega and h.status == "prefill":
-                self._toks[slot] = h.lane[h.prompt_pos]
-            else:
-                self._toks[slot] = h.tokens[-1]
             if self.manager is not None and not (
                     self.mega and h.status == "prefill"):
                 # Page-boundary growth for the (generated) token being
@@ -2870,6 +3155,9 @@ class ServingEngine:
                 try:
                     self.manager.append(slot, int(self._lens[slot]))
                 except OutOfPagesError as e:
+                    if self._flight is not None:
+                        self._land()
+                        return self._decode_prep(self._decoders())
                     # Pool dry MID-DECODE: preempt this request —
                     # release its pages, requeue it at the head, and
                     # let it resume later via re-prefill of prompt +
@@ -3212,18 +3500,52 @@ class ServingEngine:
                 self._emit(h, tok)
         return len(active)
 
-    def _dispatch(self, tbl: np.ndarray, sampled: bool):
-        """Run the joint decode under the (optional) watchdog; returns
-        its rows by slot, as :meth:`_decode_commit` takes them: the
-        layer path's :class:`_Rows` (the picked tokens on the host, the
-        logits too where a row of the batch samples, ``sampled``), the
-        megakernel lane's host logits (num_slots, vocab)."""
+    def _enqueue_decode(self, flight, active, tbl: np.ndarray,
+                        sampled: bool):
+        """Hand the device the joint decode step; returns the program
+        as :meth:`_Flight.board` takes it, ``(picked tokens, index of
+        slot 0 in them, logits, an EP program's expert counts or
+        None)``: all still on the device. The
+        megakernel lane's step picks no token and its rows come back
+        whole, ``(None, 0, logits (num_slots, vocab))``."""
+        import dataclasses as _dc
+
         import jax.numpy as jnp
 
-        if not self.mega:
-            return self._dispatch_layers(tbl, sampled)
-        lens = jnp.asarray(self._lens)
-        toks = jnp.asarray(self._toks)
+        if self.mega:
+            return self._enqueue_mega(flight, active, tbl)
+        # Uploads and the jitted call returning: the part of the
+        # dispatch a device idle gap can fall under at this end.
+        with self.obs.span("decode_enqueue"):
+            toks = self._input_tokens(flight, active)
+            lens, live = self._mirrors()
+            cache = _dc.replace(self.cache, block_table=jnp.asarray(tbl),
+                                lens=lens, live=live)
+            if self.ep and self.replicas is not None:
+                picked, logits, self.cache, ecounts = self._decode(
+                    self.engine.params, toks, cache, self.replicas)
+            elif self.ep:
+                picked, logits, self.cache, ecounts = self._decode(
+                    self.engine.params, toks, cache)
+            else:
+                ecounts = None
+                picked, logits, self.cache = self._decode(
+                    self.engine.params, toks, cache)
+            # Ask for the copies now, behind the program: the landing's
+            # wait then puts no host round trip between the program's
+            # end and a copy's start.
+            picked.copy_to_host_async()
+            if sampled:
+                logits.copy_to_host_async()
+        return picked, 0, logits, ecounts
+
+    def _enqueue_mega(self, flight, active, tbl: np.ndarray):
+        """The megakernel lane's joint step, under the engine's own
+        watchdog."""
+        import jax.numpy as jnp
+
+        toks = self._input_tokens(flight, active)
+        lens, _ = self._mirrors()
         if self.manager is not None:
             # Paged megakernel: install THIS tick's allocator table
             # (flat (batch·p_max,), the builder's prefetch layout) —
@@ -3253,75 +3575,56 @@ class ServingEngine:
             total = self.engine.expert_counts()
             self._note_expert_counts(total - self._mk_counts_base)
             self._mk_counts_base = total
-        return np.asarray(out)
+        return None, 0, out
 
-    def _dispatch_layers(self, tbl: np.ndarray, sampled: bool):
-        """The layer path's joint decode in the three parts a device
-        idle gap can fall under: uploads and the jitted call returning
-        (``decode_enqueue``), the host blocked until the picked tokens
-        exist (``decode_wait``), their copy to the host, and the
-        logits' where a row samples (``decode_fetch``)."""
-        import dataclasses as _dc
-
-        import jax.numpy as jnp
-
-        with self.obs.span("decode_enqueue"):
-            toks = jnp.asarray(self._toks)
-            cache = _dc.replace(self.cache,
-                                block_table=jnp.asarray(tbl),
-                                lens=jnp.asarray(self._lens),
-                                live=jnp.asarray(self._live))
-            if self.ep and self.replicas is not None:
-                picked, logits, self.cache, ecounts = self._decode(
-                    self.engine.params, toks, cache, self.replicas)
-            elif self.ep:
-                picked, logits, self.cache, ecounts = self._decode(
-                    self.engine.params, toks, cache)
-            else:
-                ecounts = None
-                picked, logits, self.cache = self._decode(
-                    self.engine.params, toks, cache)
-            # Ask for the copies now, behind the program: the explicit
-            # wait below then puts no host round trip between the
-            # program's end and a copy's start.
-            picked.copy_to_host_async()
-            if sampled:
-                logits.copy_to_host_async()
+    def _fetch_rows(self, flight, span):
+        """A landing's two parts a device idle gap can fall under: the
+        host blocked until the picked tokens exist (``decode_wait``),
+        then their copy to the host, and the logits' where a row
+        samples (``decode_fetch``), with what rides behind the tokens
+        booked (``STEP_STATS``, ``ROW_STATS`` onto the open ``decode``
+        span, an EP program's counts). Returns the step's rows by slot,
+        as :meth:`_decode_commit` takes them: the layer path's
+        :class:`_Rows`, the megakernel lane's host logits (num_slots,
+        vocab)."""
+        if flight.picked is None:
+            return np.asarray(flight.logits)
+        picked, ecounts = flight.picked, flight.ecounts
         with self.obs.span("decode_wait"):
             # The counts output rides the SAME dispatch: it must sit
             # inside the watchdog-bounded wait, or a wedged collective
             # would hang the host in the counts conversion below
             # before the deadline ever fires.
-            guarded = self._wait_decode(
-                picked if ecounts is None else (picked, ecounts))
-            picked, ecounts = (guarded if ecounts is not None
-                               else (guarded, None))
+            self._wait_decode(picked if ecounts is None
+                              else (picked, ecounts))
         with self.obs.span("decode_fetch"):
             if ecounts is not None:
                 self._note_expert_counts(
                     self._read(ecounts).astype(np.int64))
-            picked = self._read(picked)
-            self._note_step_stats(picked, self.num_slots)
-            return _Rows(picked, logits,
-                         self._read(logits) if sampled else None,
-                         self._exit_passes(picked))
+            host = self._read(picked)
+            # A prompt that rode this program reads row 0 of the same
+            # array: it is on the host now.
+            flight.finished = [
+                (h, (host, last[1]) if last[0] is picked else last)
+                for h, last in flight.finished]
+            exits = self._exit_passes(host)
+            rows = _Rows(host[flight.base:], flight.logits,
+                         self._read(flight.logits) if flight.sampled
+                         else None,
+                         None if exits is None else exits[flight.base:])
+            self._note_step_stats(host, flight.stat_rows)
+            if self.obs.enabled and exits is not None:
+                span.fields.update(
+                    passes=self.cfg.num_passes,
+                    exit_pass=float(np.mean(
+                        [rows.exits[slot] for _, slot in flight.rows])))
+        return rows
 
     def _exit_passes(self, picked: np.ndarray):
         """The pass each head row of a step program took, 1-based: what
         a model with ``ROW_STATS`` appends to its picked tokens, row for
         row; None for any other model."""
         return picked[len(picked) // 2:] if self._row_stats else None
-
-    def _note_exit_passes(self, span, rows, active):
-        """The open ``decode`` span's stats ``passes`` and ``exit_pass``
-        (the mean over the slots that decoded) for a model with
-        ``ROW_STATS``; nothing for any other."""
-        if (self.obs.enabled and isinstance(rows, _Rows)
-                and rows.exits is not None):
-            span.fields.update(
-                passes=self.cfg.num_passes,
-                exit_pass=float(np.mean([rows.exits[h.slot]
-                                         for h in active])))
 
     def _note_step_stats(self, picked: np.ndarray, rows: int):
         """Book the ``STEP_STATS`` a step program of ``rows`` rows
@@ -3564,10 +3867,7 @@ class ServingEngine:
         slot = h.slot
         self.sched.slots.pop(slot, None)
         h.slot = None
-        self._live[slot] = 0
-        self._lens[slot] = 0
-        self._toks[slot] = 0
-        self.manager.free_slot(slot)
+        self._vacate(slot)
         if not self.sched.slots:
             h.slot = slot            # _fail/retire bookkeeping no-op path
             self._fail(h, "failed", error)
@@ -3581,6 +3881,9 @@ class ServingEngine:
 
     def _retire(self, h: RequestHandle, status: str, error=None):
         slot = h.slot
+        # A request released by count (``_release_ended``) handed its
+        # slot and pages on already; they may be another's by now.
+        holds = slot is not None and self.sched.slots.get(slot) is h
         if getattr(h, "resume_key", None) is not None \
                 and self.tiers is not None:
             # A mid-resume failure (deadline, timeout victim) must not
@@ -3589,12 +3892,8 @@ class ServingEngine:
             h.resume_key = None
         self._close_resume_span(h, path=status)
         self.sched.retire(h, status, error)
-        if slot is not None:
-            self._live[slot] = 0
-            self._lens[slot] = 0
-            self._toks[slot] = 0
-            if self.manager is not None:
-                self.manager.free_slot(slot)
+        if holds:
+            self._vacate(slot)
         # The whole-request span closes at the terminal transition —
         # submit -> done|failed|timeout, with the generated volume.
         self.obs.complete_span(
@@ -3604,6 +3903,12 @@ class ServingEngine:
             tokens=len(h.tokens), decode_steps=h.decode_steps)
         if self.slo is not None:
             self.slo.on_retire(self, h)
+
+    def _vacate(self, slot: int):
+        """Clear ``slot``'s host mirrors and free its pages."""
+        self._live[slot] = self._lens[slot] = self._toks[slot] = 0
+        if self.manager is not None:
+            self.manager.free_slot(slot)
 
     def _fail(self, h: RequestHandle, status: str, error):
         self._retire(h, status, error)
